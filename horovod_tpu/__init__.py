@@ -67,17 +67,12 @@ def init(config: Optional[Config] = None) -> None:
         if _runtime is not None and _runtime.running:
             return
         cfg = config or Config.from_env()
-        # XLA perf-flag preset (docs/overlap.md): must land in XLA_FLAGS
+        # XLA perf-flag preset (docs/overlap.md): must land in LIBTPU_INIT_ARGS
         # before the first backend touch below (jax.distributed /
         # jax.devices); idempotent if horovod_tpu.jax already applied it.
         from .common import env as _env_mod
 
-        try:
-            _env_mod.apply_xla_perf_preset(cfg.xla_perf_preset)
-        except ValueError:
-            raise
-        except Exception:  # noqa: BLE001 - never block init on flag plumbing
-            pass
+        _env_mod.apply_xla_perf_preset(cfg.xla_perf_preset)
         topo = _topology_mod.detect()
         import os as _os
 
@@ -172,27 +167,19 @@ def init(config: Optional[Config] = None) -> None:
 
             executor = XlaPlanExecutor(topo, config=cfg)
         if kind == "native":
-            try:
-                from .core.native_runtime import NativeRuntime
+            # A core that cannot be built from cpp/src or loaded raises
+            # here. The pure-Python runtime is HOROVOD_TPU_CORE=python's
+            # to choose, never a quiet second try.
+            from .core.native_runtime import NativeRuntime
 
-                _runtime = NativeRuntime(
-                    cfg, topo, executor=executor,
-                    coord_addr=coord_addr, coord_port=coord_port,
-                )
-                _start_profiler(cfg)
-                _start_metrics_pusher(topo)
-                _start_trace_pusher(topo)
-                return
-            except NotImplementedError:
-                raise
-            except Exception as exc:  # noqa: BLE001 - build/load failure
-                import logging
-
-                logging.getLogger("horovod_tpu").warning(
-                    "native core unavailable (%s); using the pure-Python "
-                    "runtime",
-                    exc,
-                )
+            _runtime = NativeRuntime(
+                cfg, topo, executor=executor,
+                coord_addr=coord_addr, coord_port=coord_port,
+            )
+            _start_profiler(cfg)
+            _start_metrics_pusher(topo)
+            _start_trace_pusher(topo)
+            return
         _runtime = Runtime(cfg, topo)
         _runtime.start()
         _start_profiler(cfg)

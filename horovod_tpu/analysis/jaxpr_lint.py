@@ -204,11 +204,11 @@ def _check_axes(
 
 
 def _is_select(eqn: Any) -> bool:
-    """select_n, or a pjit wrapper whose body is only select_n — how
+    """select_n, or a ``jit`` wrapper whose body is only select_n — how
     ``jnp.where`` appears in a jaxpr."""
     if eqn.primitive.name == "select_n":
         return True
-    if eqn.primitive.name == "pjit":
+    if eqn.primitive.name == "jit":
         for sub in _sub_jaxprs_of_eqn(eqn):
             if any(e.primitive.name != "select_n" for e in sub.eqns):
                 return False

@@ -10,6 +10,8 @@ completion.
 from __future__ import annotations
 
 import ctypes
+import fcntl
+import glob
 import json
 import os
 import subprocess
@@ -26,18 +28,46 @@ class NativeCoreUnavailable(RuntimeError):
     pass
 
 
-def ensure_built(rebuild: bool = False) -> str:
-    """Build libhvd_core.so with make if it is missing."""
-    if rebuild or not os.path.exists(_LIB_PATH):
-        try:
+def _stale() -> bool:
+    """True when the library is missing or older than anything it is
+    built from."""
+    if not os.path.exists(_LIB_PATH):
+        return True
+    built = os.path.getmtime(_LIB_PATH)
+    deps = [os.path.join(_CPP_DIR, "Makefile")]
+    for pattern in ("src/*.cc", "include/hvd/*.h"):
+        deps += glob.glob(os.path.join(_CPP_DIR, pattern))
+    return any(os.path.getmtime(d) > built for d in deps)
+
+
+def ensure_built() -> str:
+    """Bring libhvd_core.so up to date with cpp/src, so a stale binary is
+    never used as it stands. Where the sources are present and one is
+    newer than the library (or the library is missing), ``make`` rebuilds
+    it; ranks of one host start together, so the build is serialized on a
+    lock file. An installed package ships the library without its sources
+    (setup.py): it is loaded as it is, and nothing is written."""
+    if not os.path.exists(os.path.join(_CPP_DIR, "Makefile")):
+        if os.path.exists(_LIB_PATH):
+            return _LIB_PATH
+        raise NativeCoreUnavailable(
+            f"no native core at {_LIB_PATH} and no sources to build it from"
+        )
+    if not _stale():
+        return _LIB_PATH
+    try:
+        with open(os.path.join(_CPP_DIR, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
             subprocess.run(
-                ["make", "-C", _CPP_DIR], check=True, capture_output=True
+                ["make", "-j", str(min(8, os.cpu_count() or 1)),
+                 "-C", _CPP_DIR],
+                check=True, capture_output=True,
             )
-        except (subprocess.CalledProcessError, OSError) as e:
-            out = getattr(e, "stderr", b"") or b""
-            raise NativeCoreUnavailable(
-                f"failed to build native core: {out.decode()[:500]}"
-            ) from e
+    except (subprocess.CalledProcessError, OSError) as e:
+        out = getattr(e, "stderr", b"") or b""
+        raise NativeCoreUnavailable(
+            f"failed to build native core: {out.decode()[:500]}"
+        ) from e
     return _LIB_PATH
 
 
@@ -58,9 +88,7 @@ def load() -> ctypes.CDLL:
         ctypes.c_char_p, ctypes.c_int,
     ]
     lib.hvd_core_shutdown.restype = None
-    # Older prebuilt cores may predate the flush-hint export.
-    if hasattr(lib, "hvd_core_flush_hint"):
-        lib.hvd_core_flush_hint.restype = None
+    lib.hvd_core_flush_hint.restype = None
     lib.hvd_core_initialized.restype = ctypes.c_int
     for fn in ("rank", "size", "local_rank", "local_size", "cross_rank",
                "cross_size"):

@@ -1,99 +1,9 @@
-"""JAX version-compatibility helpers shared by ops/ and parallel/.
-
-``jax.lax.axis_size`` only exists in newer jax; on older versions the
-static-size idiom is ``lax.psum(1, axis_name)``, which constant-folds to a
-Python int at trace time for a bound named axis (so it remains usable in
-Python-level loops like the butterfly/binomial schedules).
-"""
+"""Named-axis helpers shared by ops/ and parallel/."""
 
 from __future__ import annotations
 
 from jax import lax
 
-
-def axis_size(axis_name) -> int:
-    """Size of a bound named mesh axis (or product over a tuple of axes),
-    as a static Python int inside a trace."""
-    size_fn = getattr(lax, "axis_size", None)
-    if size_fn is not None:
-        return size_fn(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def _make_psum_identity_bwd():
-    import functools
-
-    import jax
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-    def psum_identity_bwd(x, axis_name):
-        return lax.psum(x, axis_name)
-
-    def fwd(x, axis_name):
-        return lax.psum(x, axis_name), None
-
-    def bwd(axis_name, _res, ct):
-        return (ct,)
-
-    psum_identity_bwd.defvjp(fwd, bwd)
-    return psum_identity_bwd
-
-
-_psum_identity_bwd = None
-
-
-def psum_replicated_grad(x, axis_name):
-    """``lax.psum`` whose transpose treats the cotangent as replicated
-    (identity backward) — the behavior newer jax's vma rewrite produces
-    for the share-then-reduce idiom (``psum(x * mask, axis)`` whose
-    output feeds a replicated loss). On old jax the builtin transpose is
-    ``psum(ct)``, which multiplies every upstream gradient by the axis
-    size; this wrapper restores the correct cotangent. Only use when the
-    consumer of the psum result is SPMD-identical across the axis (a
-    replicated loss), which makes the cotangent replicated."""
-    if not needs_explicit_grad_reduce():
-        return lax.psum(x, axis_name)
-    global _psum_identity_bwd
-    if _psum_identity_bwd is None:
-        _psum_identity_bwd = _make_psum_identity_bwd()
-    return _psum_identity_bwd(x, axis_name)
-
-
-def needs_explicit_grad_reduce() -> bool:
-    """True on old jax (pre-vma shard_map): the checked transpose does
-    NOT psum the cotangent of a replicated-in parameter over the axes it
-    is invariant on — the caller must reduce explicitly. Newer jax's
-    varying-manifest-axes machinery inserts that psum itself (an explicit
-    one would double-count)."""
-    return not (hasattr(lax, "pcast") or hasattr(lax, "pvary"))
-
-
-def grad_psum(tree, axis_names):
-    """Explicit data-parallel cotangent reduction for old jax; identity
-    on new jax (see :func:`needs_explicit_grad_reduce`)."""
-    if not needs_explicit_grad_reduce():
-        return tree
-    import jax
-
-    return jax.tree.map(lambda g: lax.psum(g, axis_names), tree)
-
-
-def assert_replicated(tree, axis_names):
-    """Give every leaf of ``tree`` a replicated typing over ``axis_names``
-    for old-jax replication-checked shard_map bodies.
-
-    Newer jax's varying-manifest-axes tracking infers replication through
-    optax/scan bodies on its own; the old ``check_rep`` checker cannot,
-    and rejects out_specs that omit an axis it failed to prove. On old
-    jax each leaf is washed through ``lax.pmax`` over the axes — the
-    identity for values that are in fact equal across those ranks (which
-    the callers guarantee: gradients were already psummed over every
-    invariant axis by the checked transpose), dtype-preserving for ints
-    (optimizer step counters), and rep-typed as replicated. On new jax
-    this is a no-op. Only call on values that ARE replicated — the wash
-    would silently pick the max of genuinely divergent shards."""
-    if hasattr(lax, "pcast") or hasattr(lax, "pvary"):
-        return tree
-    import jax
-
-    return jax.tree.map(lambda t: lax.pmax(t, axis_names), tree)
+# Size of a bound named mesh axis (or product over a tuple of axes), as a
+# static Python int inside a trace.
+axis_size = lax.axis_size

@@ -53,9 +53,10 @@ HOROVOD_TPU_EAGER_BACKEND = "HOROVOD_TPU_EAGER_BACKEND"
 # docs/overlap.md). The reference HOROVOD_FUSION_THRESHOLD above is honored
 # as the default for every later bucket.
 HOROVOD_FUSION_FIRST_BUCKET_BYTES = "HOROVOD_FUSION_FIRST_BUCKET_BYTES"
-# XLA performance-flag preset (docs/overlap.md): "auto" (default — the
-# overlap preset when a TPU platform is detected, off elsewhere),
-# "overlap" (async collectives + latency-hiding scheduler), or "off".
+# XLA performance-flag preset (docs/overlap.md): "off" (default) or
+# "overlap" (async collectives + latency-hiding scheduler). Flags must be
+# in place before the backend starts, when the device cannot be asked
+# yet, so nothing is applied unless the user says so.
 HOROVOD_XLA_PERF_PRESET = "HOROVOD_XLA_PERF_PRESET"
 # Opt-in collective-safety pre-flight (docs/static_analysis.md).
 HOROVOD_TPU_STATIC_CHECKS = "HOROVOD_TPU_STATIC_CHECKS"
@@ -236,9 +237,12 @@ FUSION_BUFFER_ATOMIC_UNIT = 64
 # --- XLA performance-flag presets (docs/overlap.md) ---
 # The flags the streamed-reduction path needs to turn N independent bucket
 # psums into async all-reduce-start/-done pairs hidden behind backward
-# compute. Applied to XLA_FLAGS before the backend initializes (flag
-# parsing happens at first backend/compiler touch) and usable as
-# compiler_options for AOT compiles (tools/tpu_profile_overlap.py).
+# compute. They are libtpu's flags, so they go to LIBTPU_INIT_ARGS, which
+# only the TPU runtime reads, before the backend initializes: libtpu
+# 0.0.34 on a v5e took all four there, and jaxlib refuses every one of
+# them in XLA_FLAGS ("Unknown flag in XLA_FLAGS", fatal, with or without a
+# chip — chip run, PR 21). Also usable as compiler_options for AOT
+# compiles (tools/tpu_profile_overlap.py).
 XLA_PERF_PRESETS = {
     "off": {},
     "overlap": {
@@ -254,40 +258,22 @@ XLA_PERF_PRESETS = {
 _applied_perf_preset = None
 
 
-def _tpu_platform_hinted() -> bool:
-    """TPU detection WITHOUT initializing a jax backend: only an EXPLICIT
-    platform pin counts. A merely-importable libtpu wheel is not enough —
-    a CPU-platform process whose XLA flag registry doesn't know the
-    xla_tpu_* names dies with "Unknown flags in XLA_FLAGS" at first
-    backend touch, so guessing wrong is fatal, not just noisy. On a TPU VM
-    with an unpinned platform, set HOROVOD_XLA_PERF_PRESET=overlap."""
-    plats = (
-        os.environ.get("JAX_PLATFORMS", "")
-        or os.environ.get("JAX_PLATFORM_NAME", "")
-    ).lower()
-    return "tpu" in plats
-
-
 def resolve_perf_preset(preset: str | None = None) -> tuple:
     """Resolve a preset name (None reads HOROVOD_XLA_PERF_PRESET, default
-    "auto") to (name, flags). "auto" means the overlap preset on TPU and
-    off elsewhere — the TPU-only xla_tpu_* flags would be noise on other
-    platforms."""
+    "off") to (name, flags)."""
     name = (preset or os.environ.get(HOROVOD_XLA_PERF_PRESET, "")
-            or "auto").strip().lower()
-    if name == "auto":
-        name = "overlap" if _tpu_platform_hinted() else "off"
+            or "off").strip().lower()
     if name not in XLA_PERF_PRESETS:
         raise ValueError(
             f"unknown {HOROVOD_XLA_PERF_PRESET} {name!r}; "
-            f"choose from {sorted(XLA_PERF_PRESETS)} or 'auto'"
+            f"choose from {sorted(XLA_PERF_PRESETS)}"
         )
     return name, dict(XLA_PERF_PRESETS[name])
 
 
 def apply_xla_perf_preset(preset: str | None = None) -> dict:
-    """Append the resolved preset's flags to XLA_FLAGS (idempotent — a flag
-    already mentioned there is left alone, so user overrides win) and
+    """Append the resolved preset's flags to LIBTPU_INIT_ARGS (idempotent — a
+    flag already mentioned there is left alone, so user overrides win) and
     record what happened for the timeline/metrics. Must run before the
     first jax backend touch to take effect; when it runs late the record
     says so instead of lying about the flags being live."""
@@ -295,7 +281,7 @@ def apply_xla_perf_preset(preset: str | None = None) -> dict:
     name, flags = resolve_perf_preset(preset)
     applied = []
     if flags:
-        current = os.environ.get("XLA_FLAGS", "")
+        current = os.environ.get("LIBTPU_INIT_ARGS", "")
         extra = []
         for k, v in flags.items():
             if k in current:
@@ -303,7 +289,9 @@ def apply_xla_perf_preset(preset: str | None = None) -> dict:
             extra.append(f"--{k}={v}")
             applied.append(k)
         if extra:
-            os.environ["XLA_FLAGS"] = (current + " " + " ".join(extra)).strip()
+            os.environ["LIBTPU_INIT_ARGS"] = (
+                current + " " + " ".join(extra)
+            ).strip()
     # A flag appended after the first backend touch is parsed too late to
     # take effect; record that rather than claiming the flags are live.
     late = False
@@ -335,6 +323,31 @@ def apply_xla_perf_preset(preset: str | None = None) -> dict:
 def applied_perf_preset() -> dict | None:
     """The record of the last preset application (None before any)."""
     return _applied_perf_preset
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for a script that runs
+    on the chip (chip_smoke.py, bench.py call this first thing; the
+    library never does on import) and return the directory in use.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    no directory is set in code. Otherwise the cache lives at
+    ``<checkout>/.jax_cache``, computed from this file's location: the
+    path is part of the cache key, so it must not move between runs. The
+    thresholds are lowered so every step program is written, not only
+    those that took long to compile."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not path:
+        checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)
+        )))
+        path = os.path.join(checkout, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
 
 
 def _get_bool(name: str, default: bool = False) -> bool:
@@ -375,9 +388,9 @@ class Config:
 
     fusion_threshold_bytes: int = 64 * 1024 * 1024
     # Streamed (overlap) reduction: first-bucket cap (DDP idiom) and the
-    # XLA perf-flag preset name ("auto" resolves per platform).
+    # XLA perf-flag preset name.
     fusion_first_bucket_bytes: int = 1024 * 1024
-    xla_perf_preset: str = "auto"
+    xla_perf_preset: str = "off"
     # Compiled-path pinned tuning file ("" = untuned; docs/autotune.md).
     tuned_file: str = ""
     calibration_file: str = ""

@@ -112,7 +112,7 @@ class NativeRuntime:
         self._ticket_names: Dict[int, str] = {}
         self._done: Dict[int, tuple] = {}
         self._cv = threading.Condition()
-        # Inline execution fast path (VERDICT r4 #2): a caller blocked in
+        # Inline execution fast path: a caller blocked in
         # synchronize() is a hot, already-scheduled thread — letting IT
         # pop and run the plan skips the executor-thread wakeup hop
         # entirely, and since every rank's caller spins the same way,

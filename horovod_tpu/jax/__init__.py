@@ -67,37 +67,27 @@ from ..parallel.mesh import (
 _logger = logging.getLogger("horovod_tpu")
 
 # Compiled-mode users reach collectives through jit, never through hvd.init;
-# the perf-preset flags must land in XLA_FLAGS before the first backend
-# touch, so the resolver runs at import (idempotent; "auto" is off-platform
-# safe — it only adds TPU flags when a TPU platform is hinted).
+# an explicit HOROVOD_XLA_PERF_PRESET must land in LIBTPU_INIT_ARGS before the
+# first backend touch, so the resolver runs at import (idempotent; applies
+# nothing unless the variable is set).
 from ..common import env as _env_mod  # noqa: E402
 
-try:
-    _env_mod.apply_xla_perf_preset()
-except Exception:  # noqa: BLE001 - preset application must never block import
-    pass
+_env_mod.apply_xla_perf_preset()
 
 def _shard_map(fn, mesh, *, in_specs, out_specs, check: bool = False):
-    """shard_map with version compatibility (check_vma in jax>=0.7,
-    check_rep before; module moved from jax.experimental to jax core).
+    """``jax.shard_map`` with varying-axes checking off by default.
 
     ``check=True`` enables replication/varying-ness tracking — REQUIRED
     when differentiating through an in-body ``psum`` (e.g. the tensor-
-    parallel row-parallel matmul): without it the psum transpose cannot
-    see that the cotangent is replicated and multiplies gradients by the
-    axis size. The default stays off for the collective-executor bodies,
-    whose hand-written patterns predate the vma checker.
+    parallel row-parallel matmul) unless the conjugates are hand-written
+    (as ``parallel/tp.py`` does where it finds itself unchecked): without
+    it the psum transpose cannot see that the cotangent is replicated and
+    multiplies gradients by the axis size. The default stays off for the
+    collective-executor bodies, whose hand-written patterns (bucket
+    packing, ppermute rings) the checker cannot type as replicated.
     """
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check)
-    except TypeError:  # pragma: no cover - older jax
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 # In-jit primitives (usable inside shard_map/pmap bodies).
@@ -1363,7 +1353,7 @@ def _build_zero1_train_step(
 # places the param tree on a (data, model) mesh; the loss runs on local
 # shards calling parallel/tp.py layers bound to the model axis (ONE
 # forward psum per Megatron half-block, its backward conjugate handled
-# by tp_block_input/psum_replicated_grad); and the ENTIRE PR-4/9/12
+# by parallel/tp.py's explicit conjugates); and the ENTIRE PR-4/9/12
 # reduction stack — streamed per-bucket reduce-scatter ZeRO-1, the int8
 # wire, bucket fusion — runs scoped to the DATA axis only. TP psums are
 # never bucketized, never quantized, never re-planned onto DCN.
@@ -1456,7 +1446,6 @@ def _build_composed_train_step(
     """
     import optax
 
-    from ..common.compat import needs_explicit_grad_reduce
     from ..parallel import rules as _rules
     from ..parallel import tp as _tp
     from ..parallel import zero as _zero
@@ -1526,11 +1515,6 @@ def _build_composed_train_step(
     n_data = 1
     for ax in dp_axes:
         n_data *= int(mesh.shape[ax])
-    # Old jax: the custom_vjp conjugate psums carry transpose
-    # correctness and check_rep only constrains; new jax (vma): the
-    # checker IS the transpose machinery and must be on.
-    check = not needs_explicit_grad_reduce()
-
     built: dict = {}
 
     def _build(params, opt_state):
@@ -1587,6 +1571,9 @@ def _build_composed_train_step(
                 # tp.overlap_active() so `tp_overlap=True` here reaches
                 # the model without threading a flag through user code.
                 # None keeps HOROVOD_TP_OVERLAP in charge.
+                # The step runs unchecked (the bucket-fused DP reduction
+                # cannot be typed by the vma checker); the TP layers see
+                # that and write their f/g conjugates out as custom VJPs.
                 with _tp.overlap_scope(tp_overlap):
                     return loss_fn(p, b)
 
@@ -1674,7 +1661,7 @@ def _build_composed_train_step(
             1 if nonfinite_policy == "abort" else 0
         )
         fn = _shard_map(
-            step, mesh, check=check,
+            step, mesh,
             in_specs=(specs, state_spec, P(axis_name)),
             out_specs=(specs, state_spec, P()) + (P(),) * extra,
         )
@@ -1915,7 +1902,6 @@ def make_decode_step(
     The build is deferred to the first call: the live params + cache
     decide the spec trees.
     """
-    from ..common.compat import needs_explicit_grad_reduce
     from ..models.transformer import tp_decode_apply
     from ..parallel import rules as _rules
 
@@ -1964,7 +1950,7 @@ def make_decode_step(
             return next_tokens, new_cache
 
         fn = _shard_map(
-            step, mesh, check=not needs_explicit_grad_reduce(),
+            step, mesh, check=True,
             in_specs=(specs, cache_specs, P(), P(), P()),
             out_specs=(P(), cache_specs),
         )

@@ -18,15 +18,12 @@ alternate. These two primitives make them share a timeline:
 which is what makes the fused Megatron block numerically equivalent to
 the classic one-psum-per-half-block schedule (tests lock <=5e-7).
 
-Following the ``ops/pallas_attention.py`` pattern, each primitive has
-two lowerings selected by backend:
-
-1. an interpret/shard_map REFERENCE — a chunked ``lax.ppermute`` loop
-   that is CPU-testable and numerically provable today (this is what
-   CI executes, and what the HLO assertions count ppermutes on);
-2. a Pallas TPU kernel using double-buffered async remote copies
-   (``pltpu.make_async_remote_copy``), one DMA in flight per direction
-   while the MXU multiplies the resident chunk.
+Each primitive is ONE lowering on every backend: a chunked
+``lax.ppermute`` ring inside the caller's shard_map, which XLA compiles
+to collective-permutes interleaved with the per-chunk matmuls (the HLO
+assertions count those permutes). There is no hand-written kernel: how
+much of the wire the chip's scheduler actually hides behind the MXU is
+a measurement, not a property of this file.
 
 Both primitives carry a custom VJP whose backward is built from the
 DUAL primitive — d(all_gather_matmul)/dx is a matmul_reduce_scatter
@@ -120,7 +117,7 @@ def _record(payload_bytes: int, axis_name: str, collective: str) -> None:
     _fusion.record_axis_wire_bytes(payload_bytes, axis_name, collective)
 
 
-# ------------------------------------------- reference ring lowerings
+# ----------------------------------------------------- ring lowerings
 
 
 def _upd_tokens(out, val, row_start):
@@ -135,7 +132,7 @@ def _seg_tokens(x, start, size):
     return lax.dynamic_slice_in_dim(x, start, size, axis=-2)
 
 
-def _ag_matmul_ref(x, w, axis_name: str, chunks: int):
+def _ag_matmul_ring(x, w, axis_name: str, chunks: int):
     """Reference all_gather_matmul: bidirectional chunked ppermute ring.
 
     ``x`` [..., Tc, D] (this rank's token chunk), ``w`` [D, F]. Returns
@@ -169,7 +166,7 @@ def _ag_matmul_ref(x, w, axis_name: str, chunks: int):
     return out
 
 
-def _mrs_ref(y, w, axis_name: str, chunks: int):
+def _mrs_ring(y, w, axis_name: str, chunks: int):
     """Reference matmul_reduce_scatter: partial products per
     DESTINATION token chunk, reduced bidirectionally along the ring.
 
@@ -251,195 +248,6 @@ def _ring_grad_w(circ, full, axis_name: str, circ_is_lhs: bool):
     return dw
 
 
-# ----------------------------------------------------- Pallas kernels
-#
-# TPU-only: double-buffered VMEM chunks moved with async remote copies
-# so each hop's DMA flies while the MXU multiplies the resident chunk
-# (see /opt/skills guides — the bidirectional ring-collective pattern).
-# CI has no TPU; these compile-gate behind ``jax.default_backend()``
-# and the interpret reference above is the provable lowering.
-
-
-def _tpu_compiler_params(collective_id: int):
-    from jax.experimental import pallas as pl  # noqa: F401
-    from jax.experimental.pallas import tpu as pltpu
-
-    kw = dict(has_side_effects=True, collective_id=int(collective_id))
-    try:
-        return pltpu.CompilerParams(**kw)
-    except (AttributeError, TypeError):
-        return pltpu.TPUCompilerParams(**kw)  # pre-0.5 jax
-
-
-def _ag_matmul_tpu(x, w, axis_name: str, chunks: int):  # pragma: no cover
-    """Pallas all-gather-matmul: each phase posts the next chunk's
-    remote copy in BOTH ring directions, multiplies the chunk that
-    arrived last phase, and writes its output rows."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n = _axis_size(axis_name)
-    tc, d = x.shape[-2], x.shape[-1]
-    f = w.shape[-1]
-    h_fwd, h_bwd = ring_hops(n)
-
-    def kernel(x_ref, w_ref, out_ref, buf, send_sem, recv_sem):
-        my = lax.axis_index(axis_name)
-        right = lax.rem(my + 1, n)
-        left = lax.rem(my + n - 1, n)
-        barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(barrier, device_id=(left,),
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
-        pltpu.semaphore_signal(barrier, device_id=(right,),
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
-        pltpu.semaphore_wait(barrier, 2)
-        # slot 0 rides the forward ring, slot 1 the backward ring.
-        buf[0] = x_ref[...]
-        buf[1] = x_ref[...]
-        out_ref[pl.ds(my * tc, tc), :] = jnp.dot(
-            x_ref[...], w_ref[...], preferred_element_type=jnp.float32
-        ).astype(out_ref.dtype)
-        for k in range(1, max(h_fwd, h_bwd) + 1):
-            copies = []
-            if k <= h_fwd:
-                copies.append(pltpu.make_async_remote_copy(
-                    src_ref=buf.at[0], dst_ref=buf.at[0],
-                    send_sem=send_sem.at[0], recv_sem=recv_sem.at[0],
-                    device_id=(right,),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
-                ))
-            if k <= h_bwd:
-                copies.append(pltpu.make_async_remote_copy(
-                    src_ref=buf.at[1], dst_ref=buf.at[1],
-                    send_sem=send_sem.at[1], recv_sem=recv_sem.at[1],
-                    device_id=(left,),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
-                ))
-            for cp in copies:
-                cp.start()
-            for cp in copies:
-                cp.wait()
-            if k <= h_fwd:
-                src = lax.rem(my + n - k, n)
-                out_ref[pl.ds(src * tc, tc), :] = jnp.dot(
-                    buf[0], w_ref[...],
-                    preferred_element_type=jnp.float32,
-                ).astype(out_ref.dtype)
-            if k <= h_bwd:
-                src = lax.rem(my + k, n)
-                out_ref[pl.ds(src * tc, tc), :] = jnp.dot(
-                    buf[1], w_ref[...],
-                    preferred_element_type=jnp.float32,
-                ).astype(out_ref.dtype)
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n * tc, f), x.dtype),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((2, tc, d), x.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=_tpu_compiler_params(0xC0),
-    )(x, w)
-
-
-def _mrs_tpu(y, w, axis_name: str, chunks: int):  # pragma: no cover
-    """Pallas matmul-reduce-scatter: per-destination partials computed
-    as the accumulator rides the ring — one hop in flight per direction
-    while the MXU produces the next partial."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n = _axis_size(axis_name)
-    t, fl = y.shape[-2], y.shape[-1]
-    tc = t // n
-    d = w.shape[-1]
-    h_fwd, h_bwd = ring_hops(n)
-
-    def kernel(y_ref, w_ref, out_ref, acc, send_sem, recv_sem):
-        my = lax.axis_index(axis_name)
-        right = lax.rem(my + 1, n)
-        left = lax.rem(my + n - 1, n)
-        barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(barrier, device_id=(left,),
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
-        pltpu.semaphore_signal(barrier, device_id=(right,),
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
-        pltpu.semaphore_wait(barrier, 2)
-
-        def part(dest):
-            seg = pl.load(
-                y_ref, (pl.ds(dest * tc, tc), slice(None))
-            )
-            return jnp.dot(seg, w_ref[...],
-                           preferred_element_type=jnp.float32)
-
-        out = part(my)
-        if h_fwd:
-            acc[0] = part(lax.rem(my + h_fwd, n)).astype(acc.dtype)
-            for k in range(h_fwd - 1, -1, -1):
-                cp = pltpu.make_async_remote_copy(
-                    src_ref=acc.at[0], dst_ref=acc.at[0],
-                    send_sem=send_sem.at[0], recv_sem=recv_sem.at[0],
-                    device_id=(right,),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
-                )
-                cp.start()
-                nxt = part(lax.rem(my + k, n)) if k else None
-                cp.wait()
-                if k:
-                    acc[0] = (acc[0] + nxt.astype(acc.dtype))
-            out = out + acc[0].astype(out.dtype)
-        if h_bwd:
-            acc[1] = part(lax.rem(my + n - h_bwd, n)).astype(acc.dtype)
-            for k in range(h_bwd - 1, -1, -1):
-                cp = pltpu.make_async_remote_copy(
-                    src_ref=acc.at[1], dst_ref=acc.at[1],
-                    send_sem=send_sem.at[1], recv_sem=recv_sem.at[1],
-                    device_id=(left,),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
-                )
-                cp.start()
-                nxt = part(lax.rem(my + n - k, n)) if k else None
-                cp.wait()
-                if k:
-                    acc[1] = (acc[1] + nxt.astype(acc.dtype))
-            out = out + acc[1].astype(out.dtype)
-        out_ref[...] = out.astype(out_ref.dtype)
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((tc, d), y.dtype),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[
-            pltpu.VMEM((2, tc, d), jnp.float32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=_tpu_compiler_params(0xC1),
-    )(y, w)
-
-
-def _use_pallas(x) -> bool:
-    # 2-D only (the composed path flattens batch dims before calling
-    # the TPU kernel; the reference handles any rank).
-    return (
-        jax.default_backend() == "tpu"
-        and x.ndim == 2
-        and x.shape[-1] % 128 == 0
-    )
-
-
 # --------------------------------------------------- public primitives
 
 
@@ -447,9 +255,7 @@ def _use_pallas(x) -> bool:
 def _agmm(axis_name, chunks, x, w):
     n = _axis_size(axis_name)
     _record(x.size * x.dtype.itemsize * n, axis_name, "all_gather_matmul")
-    if _use_pallas(x):  # pragma: no cover - needs a TPU
-        return _ag_matmul_tpu(x, w, axis_name, chunks)
-    return _ag_matmul_ref(x, w, axis_name, chunks)
+    return _ag_matmul_ring(x, w, axis_name, chunks)
 
 
 def _agmm_fwd(axis_name, chunks, x, w):
@@ -462,7 +268,7 @@ def _agmm_bwd(axis_name, chunks, res, ct):
     # dx = reduce_scatter(ct @ w^T): the DUAL primitive — the backward
     # overlaps its wire exactly like the forward.
     _record(ct.size * ct.dtype.itemsize, axis_name, "matmul_reduce_scatter")
-    dx = _mrs_ref(ct, w.T, axis_name, chunks).astype(x.dtype)
+    dx = _mrs_ring(ct, w.T, axis_name, chunks).astype(x.dtype)
     # dw = all_gather(x)^T @ ct, accumulated as the x chunks ride the
     # same bidirectional ring (a second pass of the forward's bytes).
     _record(x.size * x.dtype.itemsize * n, axis_name, "all_gather_matmul")
@@ -479,9 +285,7 @@ def _mrs(axis_name, chunks, y, w):
         (y.size // max(y.shape[-1], 1)) * w.shape[-1] * y.dtype.itemsize,
         axis_name, "matmul_reduce_scatter",
     )
-    if _use_pallas(y):  # pragma: no cover - needs a TPU
-        return _mrs_tpu(y, w, axis_name, chunks)
-    return _mrs_ref(y, w, axis_name, chunks)
+    return _mrs_ring(y, w, axis_name, chunks)
 
 
 def _mrs_fwd(axis_name, chunks, y, w):
@@ -493,7 +297,7 @@ def _mrs_bwd(axis_name, chunks, res, ct):
     n = _axis_size(axis_name)
     # dy = all_gather(ct) @ w^T: again the dual primitive.
     _record(ct.size * ct.dtype.itemsize * n, axis_name, "all_gather_matmul")
-    dy = _ag_matmul_ref(ct, w.T, axis_name, chunks).astype(y.dtype)
+    dy = _ag_matmul_ring(ct, w.T, axis_name, chunks).astype(y.dtype)
     # dw = y^T @ all_gather(ct): the ct chunks ride the ring while each
     # arriving chunk contracts with its local y token slice.
     _record(ct.size * ct.dtype.itemsize * n, axis_name, "all_gather_matmul")

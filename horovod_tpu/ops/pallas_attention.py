@@ -19,10 +19,10 @@ O(T * block_k) live memory, never a [T, T] residual. The ring block's VJP
 recomputes its single [T, T/n] block densely (the same memory class as the
 forward block it differentiates).
 
-Interpret mode (``interpret=True``, the default off-TPU) runs the same
-kernels on CPU; the tests exercise it via the transformer/ring test suites
-and ``tests/test_models.py``/``tests/test_ring_attention.py`` plus the
-dedicated kernel tests in ``tests/test_flash_attention.py``.
+Interpret mode runs the same kernels on the CPU backend (the tests'
+virtual mesh): it is chosen when the caller asks for it or when the
+default backend is ``cpu``, and refused on a TPU backend — there the
+kernel is compiled or the call fails (:func:`_resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -41,12 +41,24 @@ _LANES = 128  # TPU lane width; m/l carriers keep a lane dim like the
               # upstream jax flash kernel's lse outputs.
 
 
-def _compiler_params(**kw):
-    """pltpu.CompilerParams was named TPUCompilerParams before jax 0.5."""
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    return cls(**kw)
+def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``None`` means "interpret exactly when the program runs on the CPU
+    backend". On a TPU backend the kernel is never interpreted."""
+    backend = jax.default_backend()
+    if interpret is None:
+        return backend == "cpu"
+    if interpret and backend == "tpu":
+        raise ValueError(
+            "interpret=True on a TPU backend would replace the flash "
+            "kernel with the Pallas interpreter; compile it instead"
+        )
+    return bool(interpret)
+
+
+def _vma(*arrays) -> frozenset:
+    """The mesh axes any of ``arrays`` varies over inside a checked
+    shard_map (empty outside one, or in an unchecked one)."""
+    return frozenset().union(*(jax.typeof(x).vma for x in arrays))
 
 
 def _pick_block(t: int, pref: int) -> int:
@@ -194,15 +206,18 @@ def _flash_call(q, k, v, delta, *, sm_scale, causal, block_q, block_k,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
     )
+    # Inside a checked shard_map the outputs vary over every mesh axis any
+    # input varies over; pallas_call wants that stated on out_shape.
+    vma = _vma(q, k, v)
     o, m, l = pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_q, d), out_dtype),
-            jax.ShapeDtypeStruct((bh, t_q, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((bh, t_q, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((bh, t_q, d), out_dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_q, _LANES), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_q, _LANES), jnp.float32, vma=vma),
         ],
         grid_spec=grid_spec,
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -270,9 +285,13 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
         dv_b = jnp.einsum("bqk,bqd->bkd", p, dof)
         return dq_acc, (dk_b, dv_b)
 
-    dq, (dks, dvs) = lax.scan(
-        body, jnp.zeros(q.shape, jnp.float32), jnp.arange(n_blocks)
-    )
+    dq0 = jnp.zeros(q.shape, jnp.float32)
+    # The scan carry must enter with the type it leaves with: inside a
+    # checked shard_map dq varies over every axis the operands vary over.
+    vma = _vma(q, k, v, do)
+    if vma:
+        dq0 = lax.pcast(dq0, tuple(sorted(vma)), to="varying")
+    dq, (dks, dvs) = lax.scan(body, dq0, jnp.arange(n_blocks))
     dk = jnp.moveaxis(dks, 0, 1).reshape(k.shape)
     dv = jnp.moveaxis(dvs, 0, 1).reshape(v.shape)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -295,13 +314,12 @@ def flash_attention(
     """Fused attention over ``[..., T, D]`` (leading dims fold into one
     batch x heads grid axis). Differentiable; backward recomputes blockwise.
 
-    ``interpret`` defaults to True off-TPU so the same code runs in tests
-    on the virtual CPU mesh.
+    ``interpret=None`` interprets on the CPU backend only, so the same
+    code runs in tests on the virtual CPU mesh.
     """
     if q.ndim < 3:
         raise ValueError("expected [..., T, D] with at least one batch dim")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
     lead = q.shape[:-2]
     t_q, d = q.shape[-2:]
     t_k = k.shape[-2]
@@ -416,8 +434,7 @@ def flash_attention_block(
     (traced — ring steps compute it from ``lax.axis_index``). Returns
     ``(o_unnormalized_f32, m, l)`` for the caller's online-softmax merge
     (``parallel/ring_attention.py``)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
     delta = jnp.asarray(delta, jnp.float32)
     return _flash_block(q, k, v, delta, sm_scale, causal, block_q, block_k,
                         interpret)

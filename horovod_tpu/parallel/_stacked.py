@@ -10,7 +10,6 @@ import jax
 from jax import lax
 
 from ..common.compat import axis_size as _axis_size
-from ..common.compat import grad_psum
 
 
 def init_stacked_state(optimizer, params_stacked):
@@ -49,9 +48,6 @@ def stacked_train_update(optimizer, params, opt_state, value_and_grad_fn,
     p_local = jax.tree.map(lambda t: t[0], params)
     loss, grads = value_and_grad_fn(p_local)
     nd = _axis_size(data_axis)
-    # Old jax: the checked transpose leaves per-rank cotangents — reduce
-    # explicitly (no-op on new jax, whose transpose already psummed).
-    grads = grad_psum(grads, data_axis)
     grads = jax.tree.map(lambda g: g / nd, grads)
     new_params, new_state = apply_stacked_update(
         optimizer, params, opt_state, grads

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..common.compat import assert_replicated, grad_psum, psum_replicated_grad
 from ..common.compat import axis_size as _axis_size
 from .mesh import DATA_AXIS
 
@@ -54,15 +53,7 @@ def _pvary(x, axis_name):
     """Mark a replicated value as device-varying over ``axis_name`` (vma
     bookkeeping only — the values are unchanged). Needed so the pipeline
     scan's carry has a consistent varying type across iterations."""
-    pcast = getattr(lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, (axis_name,), to="varying")
-    pvary = getattr(lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, (axis_name,))
-    # Pre-vma jax: shard_map has no varying-type tracking (check_rep
-    # bodies predate it), so there is no bookkeeping to satisfy.
-    return x
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def _gpipe_scan(axis_name, n_micro, feed, stage_apply, emit, emit0):
@@ -169,7 +160,7 @@ def make_pp_train_step(
             mask = (lax.axis_index(stage_axis) == n_stages - 1).astype(
                 outs.dtype
             )
-            outs = psum_replicated_grad(outs * mask, stage_axis)
+            outs = lax.psum(outs * mask, stage_axis)
             return loss_fn(outs, y_micro)
 
         params, opt_state, loss = stacked_train_update(
@@ -177,10 +168,6 @@ def make_pp_train_step(
             jax.value_and_grad(local_loss), data_axis,
         )
         loss = lax.pmean(loss, data_axis)
-        # Old-jax check_rep cannot infer the data-axis replication of the
-        # updated shards through optax; no-op on new jax.
-        params = assert_replicated(params, data_axis)
-        opt_state = assert_replicated(opt_state, data_axis)
         return params, opt_state, loss
 
     fn = _shard_map(
@@ -273,7 +260,7 @@ def pipeline_lm_loss(
     # wiring) is SPMD-identical everywhere.
     n_stages = _axis_size(axis_name)
     mask = (lax.axis_index(axis_name) == n_stages - 1).astype(losses.dtype)
-    losses = psum_replicated_grad(losses * mask, axis_name)
+    losses = lax.psum(losses * mask, axis_name)
     return losses.mean()
 
 
@@ -328,18 +315,11 @@ def make_pp_lm_train_step(
         loss, grads = jax.value_and_grad(loss_of, argnums=(0, 1, 2))(
             params["embed"], stages_local, params["head"]
         )
-        # New jax: the vma-checked transpose already psummed each
-        # gradient over every axis its parameter is invariant on
-        # (stage+data for embed/head, data for stage params). Old jax
-        # leaves per-rank cotangents — grad_psum reduces them explicitly
-        # (identity on new jax). Divide by the data size to average.
-        g_embed, g_stages, g_head = grads
-        g_embed = grad_psum(g_embed, (stage_axis, data_axis))
-        g_head = grad_psum(g_head, (stage_axis, data_axis))
-        g_stages = grad_psum(g_stages, (data_axis,))
-        g_embed, g_stages, g_head = jax.tree.map(
-            lambda g: g / nd, (g_embed, g_stages, g_head)
-        )
+        # The vma-checked transpose already psummed each gradient over
+        # every axis its parameter is invariant on (stage+data for
+        # embed/head, data for stage params). Divide by the data size
+        # to average.
+        g_embed, g_stages, g_head = jax.tree.map(lambda g: g / nd, grads)
 
         new_params, new_state = {}, {}
         up, new_state["embed"] = optimizer.update(
@@ -353,16 +333,6 @@ def make_pp_lm_train_step(
             g_head, opt_state["head"], params["head"]
         )
         new_params["head"] = optax.apply_updates(params["head"], up)
-        # Old-jax check_rep cannot infer these replications through
-        # optax/scan; no-op on new jax. embed/head are replicated over
-        # both axes (P()), stage shards over data only.
-        for key, axes in (
-            ("embed", (stage_axis, data_axis)),
-            ("stages", (data_axis,)),
-            ("head", (stage_axis, data_axis)),
-        ):
-            new_params[key] = assert_replicated(new_params[key], axes)
-            new_state[key] = assert_replicated(new_state[key], axes)
         return new_params, new_state, lax.pmean(loss, data_axis)
 
     pspec = {"embed": P(), "stages": P(stage_axis), "head": P()}

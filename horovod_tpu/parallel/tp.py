@@ -20,6 +20,7 @@ column-parallel over heads, the output projection row-parallel).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import jax
@@ -28,37 +29,74 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..common.compat import axis_size as _axis_size
-from ..common.compat import psum_replicated_grad
 from .mesh import DATA_AXIS
 
 MODEL_AXIS = "model"
 
-
-def _make_block_input_psum_bwd():
-    import functools
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-    def f(x, axis_name):
-        return x
-
-    def fwd(x, axis_name):
-        return x, None
-
-    def bwd(axis_name, _res, ct):
-        from ..ops import fusion as _fusion
-
-        # The conjugate psum moves the same activation bytes the forward
-        # g-psum moves — charge the model axis (trace-time).
-        _fusion.record_axis_wire_bytes(
-            ct.size * ct.dtype.itemsize, axis_name, "psum"
-        )
-        return (lax.psum(ct, axis_name),)
-
-    f.defvjp(fwd, bwd)
-    return f
+# Who writes the Megatron f/g conjugates.
+#
+# Inside a vma-CHECKED shard_map (make_tp_train_step, parallel/pp.py, the
+# decode step) the varying-axes transpose inserts them itself: the psum of
+# a row-parallel output transposes to the identity, and a replicated value
+# entering sharded compute gets a cotangent psum. Spelling them out there
+# would double-count.
+#
+# The composed DP x TP step (jax/__init__.py) runs UNCHECKED: its bucket-
+# fused data-axis reduction packs model-replicated and model-sharded
+# leaves into one buffer and its int8 ring returns ppermute results, and
+# the checker can type neither as replicated. There every psum transposes
+# to a psum, so the conjugates are custom VJPs.
+#
+# Each layer reads which of the two it is being traced in off the trace
+# itself (:func:`_vma_checked`), so no caller has to say, and a layer
+# reused under the other kind of shard_map is retraced for it (the
+# setting is part of jit's cache key).
 
 
-_block_input_psum_bwd = None
+def _vma_checked(axis_name) -> bool:
+    """Whether the enclosing shard_map types values by their varying axes
+    (``check_vma=True``): only there is the axis index typed as varying."""
+    return axis_name in jax.typeof(lax.axis_index(axis_name)).vma
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _psum_identity_bwd(x, axis_name):
+    return lax.psum(x, axis_name)
+
+
+_psum_identity_bwd.defvjp(
+    lambda x, axis_name: (lax.psum(x, axis_name), None),
+    lambda axis_name, _res, ct: (ct,),
+)
+
+
+def _psum_replicated_grad(x, axis_name):
+    """``lax.psum`` whose cotangent is replicated (the consumer is an
+    SPMD-identical loss), so its transpose is the identity."""
+    if _vma_checked(axis_name):
+        return lax.psum(x, axis_name)
+    return _psum_identity_bwd(x, axis_name)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _block_input_psum_bwd(x, axis_name):
+    return x
+
+
+def _block_input_bwd(axis_name, _res, ct):
+    from ..ops import fusion as _fusion
+
+    # The conjugate psum moves the same activation bytes the forward
+    # g-psum moves — charge the model axis (trace-time).
+    _fusion.record_axis_wire_bytes(
+        ct.size * ct.dtype.itemsize, axis_name, "psum"
+    )
+    return (lax.psum(ct, axis_name),)
+
+
+_block_input_psum_bwd.defvjp(
+    lambda x, axis_name: (x, None), _block_input_bwd
+)
 
 
 def tp_block_input(x: jax.Array, *, axis_name: str = MODEL_AXIS) -> jax.Array:
@@ -70,16 +108,10 @@ def tp_block_input(x: jax.Array, *, axis_name: str = MODEL_AXIS) -> jax.Array:
     upstream (earlier blocks' sharded weights, embeddings) differentiates
     wrong in multi-block stacks.
 
-    On new jax (vma shard_map, ``check_vma=True``) the replication
-    tracker inserts exactly this transpose itself and this function is
-    the identity — an explicit psum there would double-count."""
-    from ..common.compat import needs_explicit_grad_reduce
-
-    if not needs_explicit_grad_reduce():
+    In a checked shard_map the vma transpose inserts exactly this psum
+    and the function is the identity (see the note at the top)."""
+    if _vma_checked(axis_name):
         return x
-    global _block_input_psum_bwd
-    if _block_input_psum_bwd is None:
-        _block_input_psum_bwd = _make_block_input_psum_bwd()
     return _block_input_psum_bwd(x, axis_name)
 
 
@@ -127,9 +159,7 @@ def row_parallel(x_shard: jax.Array, w_shard: jax.Array, b_shard=None, *,
     _fusion.record_axis_wire_bytes(
         y.size * y.dtype.itemsize, axis_name, "psum"
     )
-    # Replicated-cotangent psum: the block output feeds an SPMD-identical
-    # loss, so the transpose must be the identity (see compat).
-    return psum_replicated_grad(y, axis_name)
+    return _psum_replicated_grad(y, axis_name)
 
 
 # ------------------------------------------------- fused TP overlap
@@ -190,61 +220,50 @@ def tp_scatter_tokens(x: jax.Array, *,
     """Enter the fused path: slice this rank's token chunk (dim −2) off
     a REPLICATED activation — free of communication forward; the
     backward reassembles and psums the cotangent over the model axis
-    (the embedding-boundary conjugate, explicit on old jax exactly like
-    :func:`tp_block_input`)."""
-    from ..common.compat import needs_explicit_grad_reduce
-
+    (the embedding-boundary conjugate, like :func:`tp_block_input`)."""
     n = _axis_size(axis_name)
-    tc = x.shape[-2] // n
-    if tc * n != x.shape[-2]:
+    if x.shape[-2] % n:
         raise ValueError(
             f"tp_scatter_tokens needs tokens ({x.shape[-2]}) divisible "
             f"by the model-axis size ({n})"
         )
-    if not needs_explicit_grad_reduce():
-        i = lax.axis_index(axis_name)
-        return lax.dynamic_slice_in_dim(x, i * tc, tc, axis=-2)
-    global _scatter_tokens_psum_bwd
-    if _scatter_tokens_psum_bwd is None:
-        _scatter_tokens_psum_bwd = _make_scatter_tokens_psum_bwd()
+    if _vma_checked(axis_name):
+        return _scatter_tokens(x, axis_name)
     return _scatter_tokens_psum_bwd(x, axis_name)
 
 
-def _make_scatter_tokens_psum_bwd():
-    import functools
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-    def f(x, axis_name):
-        n = _axis_size(axis_name)
-        i = lax.axis_index(axis_name)
-        return lax.dynamic_slice_in_dim(
-            x, i * (x.shape[-2] // n), x.shape[-2] // n, axis=-2
-        )
-
-    def fwd(x, axis_name):
-        return f(x, axis_name), None
-
-    def bwd(axis_name, res, ct):
-        from ..ops import fusion as _fusion
-
-        n = _axis_size(axis_name)
-        shape = list(ct.shape)
-        shape[-2] = shape[-2] * n
-        i = lax.axis_index(axis_name)
-        full = jnp.zeros(tuple(shape), ct.dtype)
-        idx = [0] * len(shape)
-        idx[-2] = i * ct.shape[-2]
-        full = lax.dynamic_update_slice(full, ct, tuple(idx))
-        _fusion.record_axis_wire_bytes(
-            full.size * full.dtype.itemsize, axis_name, "psum"
-        )
-        return (lax.psum(full, axis_name),)
-
-    f.defvjp(fwd, bwd)
-    return f
+def _scatter_tokens(x, axis_name):
+    tc = x.shape[-2] // _axis_size(axis_name)
+    i = lax.axis_index(axis_name)
+    return lax.dynamic_slice_in_dim(x, i * tc, tc, axis=-2)
 
 
-_scatter_tokens_psum_bwd = None
+_scatter_tokens_psum_bwd = jax.custom_vjp(
+    _scatter_tokens, nondiff_argnums=(1,)
+)
+
+
+def _scatter_tokens_bwd(axis_name, _res, ct):
+    from ..ops import fusion as _fusion
+
+    n = _axis_size(axis_name)
+    shape = list(ct.shape)
+    shape[-2] = shape[-2] * n
+    i = lax.axis_index(axis_name)
+    full = jnp.zeros(tuple(shape), ct.dtype)
+    idx = [0] * len(shape)
+    idx[-2] = i * ct.shape[-2]
+    full = lax.dynamic_update_slice(full, ct, tuple(idx))
+    _fusion.record_axis_wire_bytes(
+        full.size * full.dtype.itemsize, axis_name, "psum"
+    )
+    return (lax.psum(full, axis_name),)
+
+
+_scatter_tokens_psum_bwd.defvjp(
+    lambda x, axis_name: (_scatter_tokens(x, axis_name), None),
+    _scatter_tokens_bwd,
+)
 
 
 def tp_gather_tokens(x_shard: jax.Array, *,
@@ -253,49 +272,40 @@ def tp_gather_tokens(x_shard: jax.Array, *,
     to a replicated activation. The backward takes this rank's LOCAL
     cotangent slice — downstream cotangents are replicated-identical
     (the loss is pmean'd over the model axis), so the all_gather's
-    psum-scatter transpose would n-fold count; explicit on old jax,
-    the vma machinery's job on new jax."""
-    from ..common.compat import needs_explicit_grad_reduce
-
-    if not needs_explicit_grad_reduce():
+    psum-scatter transpose would n-fold count."""
+    if _vma_checked(axis_name):
         return lax.all_gather(
             x_shard, axis_name, axis=x_shard.ndim - 2, tiled=True
         )
-    global _gather_tokens_slice_bwd
-    if _gather_tokens_slice_bwd is None:
-        _gather_tokens_slice_bwd = _make_gather_tokens_slice_bwd()
     return _gather_tokens_slice_bwd(x_shard, axis_name)
 
 
-def _make_gather_tokens_slice_bwd():
-    import functools
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _gather_tokens_slice_bwd(x_shard, axis_name):
+    from ..ops import fusion as _fusion
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-    def f(x_shard, axis_name):
-        from ..ops import fusion as _fusion
-
-        n = _axis_size(axis_name)
-        _fusion.record_axis_wire_bytes(
-            x_shard.size * x_shard.dtype.itemsize * n, axis_name,
-            "allgather",
-        )
-        return lax.all_gather(
-            x_shard, axis_name, axis=x_shard.ndim - 2, tiled=True
-        )
-
-    def fwd(x_shard, axis_name):
-        return f(x_shard, axis_name), None
-
-    def bwd(axis_name, res, ct):
-        tc = ct.shape[-2] // _axis_size(axis_name)
-        i = lax.axis_index(axis_name)
-        return (lax.dynamic_slice_in_dim(ct, i * tc, tc, axis=-2),)
-
-    f.defvjp(fwd, bwd)
-    return f
+    n = _axis_size(axis_name)
+    _fusion.record_axis_wire_bytes(
+        x_shard.size * x_shard.dtype.itemsize * n, axis_name,
+        "allgather",
+    )
+    return lax.all_gather(
+        x_shard, axis_name, axis=x_shard.ndim - 2, tiled=True
+    )
 
 
-_gather_tokens_slice_bwd = None
+def _gather_tokens_bwd(axis_name, _res, ct):
+    tc = ct.shape[-2] // _axis_size(axis_name)
+    i = lax.axis_index(axis_name)
+    return (lax.dynamic_slice_in_dim(ct, i * tc, tc, axis=-2),)
+
+
+_gather_tokens_slice_bwd.defvjp(
+    lambda x_shard, axis_name: (
+        _gather_tokens_slice_bwd(x_shard, axis_name), None
+    ),
+    _gather_tokens_bwd,
+)
 
 
 def tp_replicated_params(tree: Any, *,
@@ -485,7 +495,6 @@ def make_tp_train_step(
     model rank owns its shard); the loss/replicated stats reduce over both
     axes.
     """
-    from ..common.compat import assert_replicated
     from ..jax import _shard_map
     from ._stacked import stacked_train_update
 
@@ -495,10 +504,6 @@ def make_tp_train_step(
             jax.value_and_grad(lambda p: loss_fn(p, batch)), data_axis,
         )
         loss = lax.pmean(lax.pmean(loss, data_axis), model_axis)
-        # Old-jax check_rep cannot infer the data-axis replication of the
-        # updated shards through optax; no-op on new jax.
-        params = assert_replicated(params, data_axis)
-        opt_state = assert_replicated(opt_state, data_axis)
         return params, opt_state, loss
 
     fn = _shard_map(

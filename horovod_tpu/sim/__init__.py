@@ -1,6 +1,6 @@
 """Calibrated fleet simulator — the 256–4096-rank digital twin.
 
-Real-TPU evidence has been unreachable since round 5, yet the runtime
+No fleet of hundreds of chips is at hand, yet the runtime
 carries topology plans, a quantized wire, streamed ZeRO-1 and a tuner
 whose wins are claimed *at scale*. This package makes those claims
 observable from a CPU box by composing three models the repo already

@@ -17,11 +17,11 @@ at a real communicator size, three latencies per payload size:
    timed (``ref_psum_*`` columns) for cross-checking, but it is a
    DIFFERENT collective program — at bandwidth-bound sizes its time can
    exceed the eager path's, which is why basing overhead on it produced
-   negative rows (VERDICT r4 #2).
+   negative rows.
 
 ``eager_* - compiled`` is the per-call overhead of the eager control plane —
-the number the reference pays between framework op and NCCL launch
-(VERDICT round-1 weak #3). Rank 0 prints one JSON line ``{"rows": [...]}``.
+the number the reference pays between framework op and NCCL launch.
+Rank 0 prints one JSON line ``{"rows": [...]}``.
 
 This is a CPU tool by design: multi-rank needs one device per process, and
 the benchmark's subject (host-side pipeline overhead) is
@@ -81,7 +81,7 @@ def main() -> int:
     # pipeline's negotiation aligns the ranks right before its collective
     # launches, so an UNsynchronized floor loop measures peer-arrival
     # skew as latency and can exceed the full eager time at
-    # bandwidth-bound sizes (negative overhead, VERDICT r4 #2). A tiny
+    # bandwidth-bound sizes (negative overhead). A tiny
     # psum aligns ranks to within microseconds at negligible cost
     # (psum_fn specializes per shape; only the array is tiny).
     _bar = global_arr(np.zeros(1, np.float32))
@@ -94,7 +94,7 @@ def main() -> int:
         n = nbytes // 4
         x_np = np.random.RandomState(rank).randn(n).astype(np.float32)
         x_dev = jnp.asarray(x_np)
-        # Rep counts sized so the median is stable (VERDICT r4 #2: 3 reps
+        # Rep counts sized so the median is stable (3 reps
         # at 16 MB let harness noise exceed signal and produced negative
         # overhead rows): >=10 even for the largest payload, 100 for the
         # latency-dominated small ones.

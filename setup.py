@@ -39,7 +39,7 @@ setup(
     packages=find_packages(include=["horovod_tpu", "horovod_tpu.*"]),
     package_data={"horovod_tpu": ["../cpp/libhvd_core.so"]},
     python_requires=">=3.10",
-    # jax range pinned deliberately (VERDICT r4 #4): elastic in-process
+    # jax range pinned deliberately: elastic in-process
     # recovery rides two private surfaces (xla_bridge._clear_backends,
     # the jax_enable_recoverability flag) that are capability-probed at
     # init — outside this validated range the probe may flip recovery to

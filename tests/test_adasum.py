@@ -115,7 +115,7 @@ def test_hierarchical_adasum_matches_numpy_reference():
 
 def test_adasum_reduce_fn_accepts_axis_tuple():
     """adasum_reduce_fn routes a (cross, local) tuple to the hierarchical
-    variant instead of raising (VERDICT round-1 missing #4)."""
+    variant instead of raising."""
     from horovod_tpu.ops.adasum import adasum_reduce_fn
     from horovod_tpu.parallel.mesh import build_hierarchical_mesh
 

@@ -120,7 +120,7 @@ def test_nested_scan_pjit_collectives_are_found():
     jx = jax.make_jaxpr(fn)(jnp.ones((8, 4)))
     sites = analysis.collect_collectives(jx)
     assert len(sites) == 2
-    assert {"scan" in s.path or "pjit" in s.path for s in sites} == {True}
+    assert {"scan" in s.path or "jit" in s.path for s in sites} == {True}
 
 
 def test_non_bijective_ppermute_hole():

@@ -195,8 +195,7 @@ def test_mesh_axis_spec_parsing():
 
 def test_hierarchical_lowering_contains_reduce_scatter():
     """The hierarchical lowering must actually change the program: its
-    StableHLO contains a reduce_scatter stage, the flat op's does not
-    (VERDICT round-1 next-step #2 'assert via jaxpr/HLO')."""
+    StableHLO contains a reduce_scatter stage, the flat op's does not."""
     from horovod_tpu.jax import _shard_map
 
     mesh = build_hierarchical_mesh(local_size=4)

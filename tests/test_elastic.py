@@ -511,7 +511,7 @@ def test_elastic_sampler():
 
 
 def test_elastic_rejoin_mode_probe(monkeypatch):
-    """Capability probe behind rejoin-mode selection (VERDICT r4 #4): the
+    """Capability probe behind rejoin-mode selection: the
     in-process path rides private JAX surfaces; with either one absent
     the mode must fall back to 'respawn' instead of failing
     mid-crash-recovery."""
@@ -569,7 +569,7 @@ def test_elastic_rejoin_mode_probe(monkeypatch):
 
 
 def test_elastic_respawn_fallback_recovery():
-    """VERDICT r4 #4 done-bar: with the private in-process surfaces gone
+    """With the private in-process surfaces gone
     (monkeypatched away inside every worker) and the job in the respawn
     fallback, a mid-training crash still recovers — survivors persist
     their last commit and exit with the rejoin status, the driver drains

@@ -166,16 +166,7 @@ def test_kernel_lowers_for_tpu_target():
         np.random.RandomState(0).randn(2, 256, 64).astype(np.float32)
     )
     f = jax.jit(partial(flash_attention, causal=True, interpret=False))
-    try:
-        traced = f.trace(q, q, q)
-    except (TypeError, AttributeError) as e:  # pragma: no cover - old jax
-        pytest.skip(f"trace API unavailable: {e!r}")
-    try:
-        lowered = traced.lower(lowering_platforms=("tpu",))
-    except TypeError as e:  # pragma: no cover - kwarg unavailable
-        pytest.skip(f"cross-platform lowering unavailable: {e!r}")
-    # Mosaic serialization errors must FAIL, not skip — they are the bug
-    # class this test guards against.
+    lowered = f.trace(q, q, q).lower(lowering_platforms=("tpu",))
     text = lowered.as_text()
     assert "tpu_custom_call" in text
 
@@ -201,14 +192,7 @@ def test_ring_attention_lowers_for_tpu_target():
         ),
         mesh, in_specs=(P(None, "seq"),) * 3, out_specs=P(None, "seq"),
     ))
-    try:
-        traced = fn.trace(q, q, q)
-    except (TypeError, AttributeError) as e:  # pragma: no cover - old jax
-        pytest.skip(f"trace API unavailable: {e!r}")
-    try:
-        lowered = traced.lower(lowering_platforms=("tpu",))
-    except TypeError as e:  # pragma: no cover - kwarg unavailable
-        pytest.skip(f"cross-platform lowering unavailable: {e!r}")
+    lowered = fn.trace(q, q, q).lower(lowering_platforms=("tpu",))
     text = lowered.as_text()
     assert "tpu_custom_call" in text          # the Mosaic flash block
     assert "collective_permute" in text        # the K/V rotation

@@ -389,3 +389,93 @@ def test_runtime_timeline_start_stop(tmp_path):
         assert all(e.get("name") != "CYCLE" for e in events2), events2
     finally:
         hvd.shutdown()
+
+
+def test_init_raises_when_the_core_cannot_be_built(monkeypatch):
+    """No quiet second try: a native core that cannot be built from
+    cpp/src fails ``hvd.init()``; the Python runtime is only ever chosen
+    by ``HOROVOD_TPU_CORE=python``."""
+    from horovod_tpu.common import basics
+
+    hvd.shutdown()
+    monkeypatch.delenv("HOROVOD_TPU_CORE", raising=False)
+    monkeypatch.setattr(basics, "_lib", None)
+
+    def no_compiler():
+        raise basics.NativeCoreUnavailable("failed to build native core")
+
+    monkeypatch.setattr(basics, "ensure_built", no_compiler)
+    with pytest.raises(basics.NativeCoreUnavailable):
+        hvd.init()
+    assert not hvd.is_initialized()
+    monkeypatch.setenv("HOROVOD_TPU_CORE", "python")
+    hvd.init()
+    try:
+        assert type(hvd._rt()).__name__ == "Runtime"
+    finally:
+        hvd.shutdown()
+
+
+def test_shipped_library_loads_without_sources(tmp_path, monkeypatch):
+    """An installed package holds cpp/libhvd_core.so and neither Makefile
+    nor sources (setup.py): the library is loaded as it is, ``make`` is
+    not called and nothing is written beside it."""
+    import ctypes
+    import shutil
+    import subprocess
+
+    from horovod_tpu.common import basics
+
+    shipped = tmp_path / "cpp"
+    shipped.mkdir()
+    shutil.copy(basics.ensure_built(), shipped / "libhvd_core.so")
+    monkeypatch.setattr(basics, "_CPP_DIR", str(shipped))
+    monkeypatch.setattr(basics, "_LIB_PATH",
+                        str(shipped / "libhvd_core.so"))
+
+    def no_make(*a, **k):
+        raise AssertionError(f"make called: {a}")
+
+    monkeypatch.setattr(subprocess, "run", no_make)
+    path = basics.ensure_built()
+    assert path == str(shipped / "libhvd_core.so")
+    assert ctypes.CDLL(path).hvd_core_initialized() == 0
+    assert os.listdir(shipped) == ["libhvd_core.so"]
+    os.remove(path)
+    with pytest.raises(basics.NativeCoreUnavailable, match="no sources"):
+        basics.ensure_built()
+
+
+def test_core_rebuilds_only_when_a_source_is_newer(tmp_path, monkeypatch):
+    """With the sources present, ``make`` runs when a source or header is
+    newer than the library or the library is missing, and not otherwise
+    (a current checkout is not written to)."""
+    import subprocess
+
+    from horovod_tpu.common import basics
+
+    cpp = tmp_path / "cpp"
+    (cpp / "src").mkdir(parents=True)
+    (cpp / "include" / "hvd").mkdir(parents=True)
+    for rel in ("Makefile", "src/core.cc", "include/hvd/core.h"):
+        (cpp / rel).write_text("")
+        os.utime(cpp / rel, (1000, 1000))
+    lib = cpp / "libhvd_core.so"
+    monkeypatch.setattr(basics, "_CPP_DIR", str(cpp))
+    monkeypatch.setattr(basics, "_LIB_PATH", str(lib))
+    calls = []
+    monkeypatch.setattr(subprocess, "run",
+                        lambda cmd, **k: calls.append(cmd))
+
+    basics.ensure_built()
+    assert len(calls) == 1 and calls[0][0] == "make", calls
+    lib.write_text("")
+    os.utime(lib, (2000, 2000))
+    basics.ensure_built()
+    assert len(calls) == 1, "current library rebuilt"
+    for i, rel in enumerate(("src/core.cc", "include/hvd/core.h",
+                             "Makefile")):
+        os.utime(cpp / rel, (3000, 3000))
+        basics.ensure_built()
+        assert len(calls) == 2 + i, (rel, calls)
+        os.utime(cpp / rel, (1000, 1000))

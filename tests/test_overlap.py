@@ -419,24 +419,28 @@ def test_fusion_threshold_env_reaches_bucket_plan(monkeypatch):
 
 def test_perf_preset_resolution(monkeypatch):
     monkeypatch.delenv(env_mod.HOROVOD_XLA_PERF_PRESET, raising=False)
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    # Nothing is applied unless asked for, whatever JAX_PLATFORMS says.
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
     name, flags = env_mod.resolve_perf_preset(None)
     assert name == "off" and flags == {}
     name, flags = env_mod.resolve_perf_preset("overlap")
     assert name == "overlap"
     assert flags["xla_tpu_enable_latency_hiding_scheduler"] == "true"
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
-    assert env_mod.resolve_perf_preset("auto")[0] == "overlap"
+    monkeypatch.setenv(env_mod.HOROVOD_XLA_PERF_PRESET, "overlap")
+    assert env_mod.resolve_perf_preset(None)[0] == "overlap"
     with pytest.raises(ValueError, match="unknown"):
         env_mod.resolve_perf_preset("warpspeed")
 
 
 def test_perf_preset_application_idempotent(monkeypatch):
     monkeypatch.setenv(
-        "XLA_FLAGS", "--xla_tpu_enable_latency_hiding_scheduler=false"
+        "LIBTPU_INIT_ARGS", "--xla_tpu_enable_latency_hiding_scheduler=false"
     )
+    xla_flags = os.environ.get("XLA_FLAGS")
     record = env_mod.apply_xla_perf_preset("overlap")
-    flags = os.environ["XLA_FLAGS"]
+    # jaxlib refuses these names in XLA_FLAGS; they are libtpu's.
+    assert os.environ.get("XLA_FLAGS") == xla_flags
+    flags = os.environ["LIBTPU_INIT_ARGS"]
     # The user's explicit setting wins; the missing flags are appended.
     assert flags.count("xla_tpu_enable_latency_hiding_scheduler") == 1
     assert "--xla_enable_async_all_reduce=true" in flags
@@ -445,7 +449,7 @@ def test_perf_preset_application_idempotent(monkeypatch):
     assert env_mod.applied_perf_preset() is record
     # Re-application adds nothing.
     again = env_mod.apply_xla_perf_preset("overlap")
-    assert os.environ["XLA_FLAGS"] == flags
+    assert os.environ["LIBTPU_INIT_ARGS"] == flags
     assert again["applied"] == []
 
 

@@ -1,0 +1,169 @@
+"""Compile the main path's kernels and steps for a described TPU v5e.
+
+The TPU's compiler is installed even where no chip is: it compiles for a
+``v5e:2x2`` topology that is described, not attached, and refuses what the
+chip's compiler would refuse (misaligned blocks, too much VMEM, a program
+that does not fit 16 GB, a kernel that cannot be partitioned). A compile
+that passes is not a chip run — ``chip_smoke.py`` is that — but it guards
+every later PR at no chip time.
+
+Everything that touches the topology lives in the module-scoped fixture:
+only one process may load the TPU's library, so nothing here may run at
+import, and the compiles happen in this process, all in this one file.
+"""
+
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+import optax
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import horovod_tpu.jax as hvdj
+from horovod_tpu.models.transformer import TransformerLM, make_gpt_loss_fn
+from horovod_tpu.ops import pallas_attention as pa
+from horovod_tpu.parallel import rules as R
+
+# Full width, cut depth: the chip's compiler sees the real block shapes.
+VOCAB, D_MODEL, HEADS, LAYERS, SEQ = 32768, 768, 12, 2, 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip; keep it out.
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def compile_kernel(monkeypatch):
+    """The process runs on the CPU backend, where ``interpret=None`` means
+    "interpret"; these tests compile for the chip, so the kernel is."""
+    monkeypatch.setattr(pa, "_resolve_interpret", lambda interpret: False)
+
+
+def _compile(jitted, *avals):
+    compiled = jitted.lower(*avals).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _qkv(topo, shape, dtype=jnp.bfloat16):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return (jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip),) * 3
+
+
+def test_flash_forward_compiles(topo):
+    fwd = functools.partial(pa.flash_attention, causal=True, interpret=False)
+    _compile(jax.jit(fwd), *_qkv(topo, (96, 1024, 64)))
+
+
+def test_flash_backward_compiles(topo):
+    def loss(q, k, v):
+        out = pa.flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    _compile(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+        *_qkv(topo, (96, 1024, 64)),
+    )
+
+
+def test_ring_block_compiles(topo):
+    """One ring step of T=1024 over four ranks: [B*H, T/4, D] blocks and a
+    traced offset, forward and backward."""
+    def loss(q, k, v, delta):
+        o, m, l = pa.flash_attention_block(
+            q, k, v, delta, sm_scale=0.125, interpret=False
+        )
+        return o.sum() + m.sum() + l.sum()
+
+    delta = jax.ShapeDtypeStruct(
+        (), jnp.float32, sharding=SingleDeviceSharding(topo.devices[0])
+    )
+    _compile(
+        jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))),
+        *_qkv(topo, (96, 256, 64)), delta,
+    )
+
+
+def _lm_shapes(batch):
+    model = TransformerLM(vocab_size=VOCAB, d_model=D_MODEL, n_heads=HEADS,
+                          n_layers=LAYERS, max_len=SEQ)
+    params = jax.eval_shape(
+        lambda: model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, SEQ), jnp.int32)
+        )["params"]
+    )
+    tokens = jax.ShapeDtypeStruct((batch, SEQ), jnp.int32)
+    return model, params, (tokens, tokens)
+
+
+def _placed(tree, mesh, specs):
+    return jax.tree.map(
+        lambda x, spec: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec or P())
+        ),
+        tree, specs,
+    )
+
+
+def test_lm_step_compiles_on_one_chip(topo, compile_kernel):
+    model, params, batch = _lm_shapes(batch=8)
+    mesh = hvdj.build_mesh({"data": 1}, devices=topo.devices[:1])
+    tx = optax.adamw(3e-4)
+
+    def loss_fn(p, b):
+        logits = model.apply({"params": p}, b[0])
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, b[1]
+        ).mean()
+
+    step = hvdj.make_train_step(loss_fn, tx, mesh)
+    state = jax.eval_shape(tx.init, params)
+    rep = lambda tree: jax.tree.map(lambda _: P(), tree)
+    compiled = _compile(
+        step,
+        _placed(params, mesh, rep(params)),
+        _placed(state, mesh, rep(state)),
+        _placed(batch, mesh, jax.tree.map(lambda _: P("data"), batch)),
+    )
+    assert compiled.as_text().count("tpu_custom_call") >= LAYERS
+
+
+def test_composed_step_compiles_on_2x2(topo, compile_kernel):
+    """``rules="gpt"`` DP2 x TP2 on the described mesh: the kernel
+    partitions under shard_map and both axes' collectives are there."""
+    _, params, batch = _lm_shapes(batch=8)
+    mesh = hvdj.build_mesh({"data": 2, "model": 2}, devices=topo.devices)
+    tx = optax.adamw(3e-4)
+    step = hvdj.make_train_step(
+        make_gpt_loss_fn(HEADS, model_axis="model"), tx, mesh, rules="gpt"
+    )
+    state = jax.eval_shape(tx.init, params)
+    # The composed builder builds on its first call; shapes are enough.
+    jax.eval_shape(step, params, state, batch)
+    compiled = _compile(
+        step.jitted,
+        _placed(params, mesh, R.match_partition_rules("gpt", params)),
+        _placed(state, mesh, R.match_partition_rules("gpt", state)),
+        _placed(batch, mesh, jax.tree.map(lambda _: P("data"), batch)),
+    )
+    assert "all-reduce" in compiled.as_text()

@@ -121,7 +121,7 @@ def test_zero1_quantized_tracks_replicated(mesh):
 
 
 def test_quantized_convergence_tracks_fp32(mesh):
-    """End-to-end convergence evidence (round-3 VERDICT weak #7): the
+    """End-to-end convergence evidence: the
     int8-wire and int8+ZeRO-1 training curves must track full-precision
     DP — asserted on the final loss after real optimization steps, not a
     per-call error bound. The committed 300-step artifact is

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""Per-phase latency breakdown of one eager allreduce (VERDICT r4 #2:
-"profile the split between linger, TCP negotiation RTT, and dispatch").
+"""Per-phase latency breakdown of one eager allreduce: "profile
+the split between linger, TCP negotiation RTT, and dispatch").
 
 Run under the launcher:
 

@@ -1,5 +1,5 @@
 #!/bin/sh
-# Real two-container ssh end-to-end (VERDICT r4 #8). Needs a docker
+# Real two-container ssh end-to-end. Needs a docker
 # daemon (absent in the TPU build environment — in-tree proxy coverage
 # is tests/test_run.py::test_ssh_fanout_end_to_end_via_shim).
 #
