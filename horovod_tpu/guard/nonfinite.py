@@ -28,6 +28,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from ..trace import SCOPE_GUARD
+
 logger = logging.getLogger("horovod_tpu.guard")
 
 # Per-thread trace ledger for the skip/abort agreement seam: the analysis
@@ -58,6 +60,7 @@ def _float_leaves(tree: Any):
     ]
 
 
+@jax.named_scope(SCOPE_GUARD)
 def local_flag(tree: Any) -> jax.Array:
     """1.0 when any float leaf of ``tree`` holds a non-finite value on
     THIS rank, else 0.0 (float32 so it can ride a psum)."""
@@ -71,6 +74,7 @@ def local_flag(tree: Any) -> jax.Array:
     return flag.astype(jnp.float32)
 
 
+@jax.named_scope(SCOPE_GUARD)
 def sanitize(tree: Any) -> Any:
     """Replace non-finite entries of every float leaf with 0 (policy
     ``zero``). Non-float leaves pass through untouched."""
@@ -82,6 +86,7 @@ def sanitize(tree: Any) -> Any:
     return jax.tree.map(fix, tree)
 
 
+@jax.named_scope(SCOPE_GUARD)
 def agree_flag(flag: jax.Array, axis_name: Any) -> jax.Array:
     """Cross-rank agreement on the skip/abort flag: psum over the
     reduction axis (or axes) — nonzero on EVERY rank when ANY rank
@@ -126,6 +131,7 @@ def note_detection(policy: str, path: str):
     return emit
 
 
+@jax.named_scope(SCOPE_GUARD)
 def select_on_flag(flag: jax.Array, when_set: Any, when_clear: Any) -> Any:
     """Leaf-wise select between two same-structure pytrees on a scalar
     flag (used to keep params/opt-state unchanged on a skipped step)."""

@@ -980,17 +980,12 @@ def _build_train_step(
         else:
             grad_fn = jax.value_and_grad(loss_fn, has_aux=has_aux)
         new_ef = ef
-        if overlap and use_ef:
-            if has_aux:
-                (loss, aux), (grads, new_ef) = grad_fn(params, ef, batch)
+        with jax.named_scope(_trace.SCOPE_LOSS_GRAD):
+            if overlap and use_ef:
+                out, (grads, new_ef) = grad_fn(params, ef, batch)
             else:
-                loss, (grads, new_ef) = grad_fn(params, ef, batch)
-                aux = None
-        elif has_aux:
-            (loss, aux), grads = grad_fn(params, batch)
-        else:
-            loss, grads = grad_fn(params, batch)
-            aux = None
+                out, grads = grad_fn(params, batch)
+        loss, aux = out if has_aux else (out, None)
         flag = None
         if not overlap:
             if nonfinite_policy in ("skip", "abort"):
@@ -1047,8 +1042,11 @@ def _build_train_step(
             flag = _nf.agree_flag(flag, axis_name)
             _nf.note_detection(nonfinite_policy, "train_step")(flag)
         loss = lax.pmean(loss, axis_name)
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope(_trace.SCOPE_OPTIMIZER):
+            updates, new_opt_state = optimizer.update(
+                grads, opt_state, params
+            )
+            new_params = optax.apply_updates(params, updates)
         if flag is not None:
             # Skipped step: params and optimizer state held on EVERY rank.
             new_params = _nf.select_on_flag(flag, params, new_params)
@@ -1230,11 +1228,6 @@ def _build_zero1_train_step(
             grad_fn = jax.value_and_grad(
                 streamed_loss_ef, argnums=(0, 1), has_aux=has_aux
             )
-            if has_aux:
-                (loss, aux), (grads, new_ef) = grad_fn(params, ef, batch)
-            else:
-                loss, (grads, new_ef) = grad_fn(params, ef, batch)
-                aux = None
         elif overlap:
             def streamed_loss(p, b):
                 p = _fusion.stream_param_groups(
@@ -1245,18 +1238,15 @@ def _build_zero1_train_step(
                 return loss_fn(p, b)
 
             grad_fn = jax.value_and_grad(streamed_loss, has_aux=has_aux)
-            if has_aux:
-                (loss, aux), grads = grad_fn(params, batch)
-            else:
-                loss, grads = grad_fn(params, batch)
-                aux = None
         else:
             grad_fn = jax.value_and_grad(loss_fn, has_aux=has_aux)
-            if has_aux:
-                (loss, aux), grads = grad_fn(params, batch)
+        with jax.named_scope(_trace.SCOPE_LOSS_GRAD):
+            if overlap and use_ef:
+                out, (grads, new_ef) = grad_fn(params, ef, batch)
             else:
-                loss, grads = grad_fn(params, batch)
-                aux = None
+                out, grads = grad_fn(params, batch)
+        loss, aux = out if has_aux else (out, None)
+        if not overlap:
             if nonfinite_policy == "zero":
                 grads = _nf.sanitize(grads)
             grads, new_ef = _zero.zero1_posthoc_reduce(
@@ -1277,11 +1267,12 @@ def _build_zero1_train_step(
         elif nonfinite_policy == "warn":
             _nf.note_detection("warn", "zero1")(_nf.local_flag(grads))
         loss = lax.pmean(loss, axis_name)
-        new_params, new_opt = _zero.zero1_stream_update(
-            optimizer, params, state.opt, grads,
-            axis_name=axis_name, n_shards=n_shards,
-            quantized=quantized, **knobs,
-        )
+        with jax.named_scope(_trace.SCOPE_OPTIMIZER):
+            new_params, new_opt = _zero.zero1_stream_update(
+                optimizer, params, state.opt, grads,
+                axis_name=axis_name, n_shards=n_shards,
+                quantized=quantized, **knobs,
+            )
         if flag is not None:
             new_params = _nf.select_on_flag(flag, params, new_params)
             new_opt = _nf.select_on_flag(flag, state.opt, new_opt)
@@ -1578,11 +1569,9 @@ def _build_composed_train_step(
                     return loss_fn(p, b)
 
             grad_fn = jax.value_and_grad(local_loss, has_aux=has_aux)
-            if has_aux:
-                (loss, aux), grads = grad_fn(params, batch)
-            else:
-                loss, grads = grad_fn(params, batch)
-                aux = None
+            with jax.named_scope(_trace.SCOPE_LOSS_GRAD):
+                out, grads = grad_fn(params, batch)
+            loss, aux = out if has_aux else (out, None)
             flag = None
             if overlap:
                 _fusion.take_stream_registrations()
@@ -1620,11 +1609,12 @@ def _build_composed_train_step(
                 )
             loss = lax.pmean(lax.pmean(loss, axis_name), model_axis)
             if zero1:
-                new_params, new_opt = _zero.zero1_stream_update(
-                    optimizer, params, state.opt, grads,
-                    axis_name=axis_name, n_shards=n_data,
-                    quantized=quantized, **knobs,
-                )
+                with jax.named_scope(_trace.SCOPE_OPTIMIZER):
+                    new_params, new_opt = _zero.zero1_stream_update(
+                        optimizer, params, state.opt, grads,
+                        axis_name=axis_name, n_shards=n_data,
+                        quantized=quantized, **knobs,
+                    )
                 if flag is not None:
                     new_params = _nf.select_on_flag(
                         flag, params, new_params
@@ -1635,10 +1625,11 @@ def _build_composed_train_step(
                     Zero1State(opt=new_opt, ef=state.ef),
                 )
             else:
-                updates, new_opt = optimizer.update(
-                    grads, opt_state, params
-                )
-                new_params = optax.apply_updates(params, updates)
+                with jax.named_scope(_trace.SCOPE_OPTIMIZER):
+                    updates, new_opt = optimizer.update(
+                        grads, opt_state, params
+                    )
+                    new_params = optax.apply_updates(params, updates)
                 if flag is not None:
                     new_params = _nf.select_on_flag(
                         flag, params, new_params

@@ -227,23 +227,29 @@ def fused_allreduce(
     for bucket in buckets:
         if len(bucket) == 1:
             i = bucket[0]
-            results[i] = reduce_fn(
-                leaves[i],
+            with jax.named_scope(_trace.SCOPE_EXCHANGE_REDUCE):
+                results[i] = reduce_fn(
+                    leaves[i],
+                    op=op,
+                    axis_name=axis_name,
+                    prescale_factor=prescale_factor,
+                    postscale_factor=postscale_factor,
+                )
+            continue
+        with jax.named_scope(_trace.SCOPE_EXCHANGE_PACK):
+            packed = pack_bucket([leaves[i] for i in bucket])
+        with jax.named_scope(_trace.SCOPE_EXCHANGE_REDUCE):
+            reduced = reduce_fn(
+                packed,
                 op=op,
                 axis_name=axis_name,
                 prescale_factor=prescale_factor,
                 postscale_factor=postscale_factor,
             )
-            continue
-        packed = pack_bucket([leaves[i] for i in bucket])
-        reduced = reduce_fn(
-            packed,
-            op=op,
-            axis_name=axis_name,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor,
-        )
-        unpacked = unpack_bucket(reduced, [leaves[i].shape for i in bucket])
+        with jax.named_scope(_trace.SCOPE_EXCHANGE_UNPACK):
+            unpacked = unpack_bucket(
+                reduced, [leaves[i].shape for i in bucket]
+            )
         for i, r in zip(bucket, unpacked):
             results[i] = r
     return jax.tree.unflatten(treedef, results)
@@ -518,7 +524,8 @@ def fused_reduce_scatter(
     average = op == ReduceOp.AVERAGE
     for bi, bucket in enumerate(buckets):
         bleaves = [leaves[i] for i in bucket]
-        packed = pack_bucket(bleaves)
+        with jax.named_scope(_trace.SCOPE_EXCHANGE_PACK):
+            packed = pack_bucket(bleaves)
         total = packed.shape[0]
         if total == 0:
             # Zero-length leaves are identities — no ring, no state.
@@ -529,59 +536,62 @@ def fused_reduce_scatter(
         is_float = jnp.issubdtype(dtype, jnp.floating)
         k = zero1_shard_len(total, n, quantized and is_float)
         padded = n * k
-        buf = jnp.pad(packed, (0, padded - total))
-        if quantized and is_float:
-            from .quantized import (
-                quantize_roundtrip,
-                quantized_ring_reduce_scatter,
-            )
+        with jax.named_scope(_trace.SCOPE_EXCHANGE_PACK):
+            buf = jnp.pad(packed, (0, padded - total))
+        with jax.named_scope(_trace.SCOPE_EXCHANGE_REDUCE):
+            if quantized and is_float:
+                from .quantized import (
+                    quantize_roundtrip,
+                    quantized_ring_reduce_scatter,
+                )
 
-            work = buf.astype(jnp.float32)
-            ef_key = f"b{bi}"
-            if ef is not None:
-                if ef_key not in ef:
-                    raise ValueError(
-                        f"sharded EF residual is missing bucket "
-                        f"{ef_key!r} — build it with "
-                        f"parallel/zero.init_zero1_stream_state"
+                work = buf.astype(jnp.float32)
+                ef_key = f"b{bi}"
+                if ef is not None:
+                    if ef_key not in ef:
+                        raise ValueError(
+                            f"sharded EF residual is missing bucket "
+                            f"{ef_key!r} — build it with "
+                            f"parallel/zero.init_zero1_stream_state"
+                        )
+                    chunk = lax.dynamic_slice(work, (idx * k,), (k,))
+                    corrected = chunk + ef[ef_key]
+                    work = lax.dynamic_update_slice(
+                        work, corrected, (idx * k,)
                     )
-                chunk = lax.dynamic_slice(work, (idx * k,), (k,))
-                corrected = chunk + ef[ef_key]
-                work = lax.dynamic_update_slice(
-                    work, corrected, (idx * k,)
-                )
-                new_ef[ef_key] = corrected - quantize_roundtrip(corrected)
-            shard = quantized_ring_reduce_scatter(
-                work, axis_name=axes[0], average=average
-            ).astype(dtype)
-        elif op in (ReduceOp.SUM, ReduceOp.AVERAGE):
-            if len(axes) > 1:
-                from ..topo import compositor as _compositor
+                    new_ef[ef_key] = corrected - quantize_roundtrip(corrected)
+                shard = quantized_ring_reduce_scatter(
+                    work, axis_name=axes[0], average=average
+                ).astype(dtype)
+            elif op in (ReduceOp.SUM, ReduceOp.AVERAGE):
+                if len(axes) > 1:
+                    from ..topo import compositor as _compositor
 
-                shard = _compositor.lower_reducescatter(
-                    buf, axes, op=ReduceOp.SUM, algorithm="two-level"
-                )
+                    shard = _compositor.lower_reducescatter(
+                        buf, axes, op=ReduceOp.SUM, algorithm="two-level"
+                    )
+                else:
+                    shard = lax.psum_scatter(buf, axes[0], tiled=True)
+                if average:
+                    shard = shard / n if is_float else shard // n
             else:
-                shard = lax.psum_scatter(buf, axes[0], tiled=True)
-            if average:
-                shard = shard / n if is_float else shard // n
-        else:
-            # MIN/MAX: no native reduce-scatter — reduce then slice
-            # (exact, bitwise with the flat reduction; no wire saving).
-            red = lax.pmin if op == ReduceOp.MIN else lax.pmax
-            full = red(buf, axes if len(axes) > 1 else axes[0])
-            shard = lax.dynamic_slice(full, (idx * k,), (k,))
+                # MIN/MAX: no native reduce-scatter — reduce then slice
+                # (exact, bitwise with the flat reduction; no wire saving).
+                red = lax.pmin if op == ReduceOp.MIN else lax.pmax
+                full = red(buf, axes if len(axes) > 1 else axes[0])
+                shard = lax.dynamic_slice(full, (idx * k,), (k,))
         _record_zero1_bucket(
             n, k, dtype_size(dtype_from_array(packed)),
             quantized and is_float, label,
         )
-        image = lax.dynamic_update_slice(
-            jnp.zeros((padded,), dtype), shard.astype(dtype), (idx * k,)
-        )
-        for i, r in zip(
-            bucket,
-            unpack_bucket(image[:total], [leaves[i].shape for i in bucket]),
-        ):
+        with jax.named_scope(_trace.SCOPE_EXCHANGE_UNPACK):
+            image = lax.dynamic_update_slice(
+                jnp.zeros((padded,), dtype), shard.astype(dtype), (idx * k,)
+            )
+            unpacked = unpack_bucket(
+                image[:total], [leaves[i].shape for i in bucket]
+            )
+        for i, r in zip(bucket, unpacked):
             results[i] = r
     out = jax.tree.unflatten(treedef, results)
     if ef is None:
@@ -756,31 +766,37 @@ def quantized_ef_allreduce(
             # Exact sums stay exact: no int8 round trip, residual
             # untouched (zero).
             for i in bucket:
-                out = _c.allreduce(leaves[i], op=op, axis_name=axis_name)
+                with jax.named_scope(_trace.SCOPE_EXCHANGE_REDUCE):
+                    out = _c.allreduce(
+                        leaves[i], op=op, axis_name=axis_name
+                    )
                 results[i] = out.astype(leaves[i].dtype)
                 residuals[i] = ef_leaves[i]
             continue
-        corrected = [
-            leaves[i].astype(jnp.float32) + ef_leaves[i] for i in bucket
-        ]
-        packed = pack_bucket(corrected)
+        with jax.named_scope(_trace.SCOPE_EXCHANGE_PACK):
+            corrected = [
+                leaves[i].astype(jnp.float32) + ef_leaves[i] for i in bucket
+            ]
+            packed = pack_bucket(corrected)
         if packed.size == 0:
             for i in bucket:
                 results[i] = leaves[i]
                 residuals[i] = ef_leaves[i]
             continue
         record_wire_bytes(packed.size * 4, label)
-        new_res = packed - quantize_roundtrip(packed)
-        reduced = quantized_ring_allreduce(
-            packed, axis_name=axis_name, average=average
-        )
+        with jax.named_scope(_trace.SCOPE_EXCHANGE_REDUCE):
+            new_res = packed - quantize_roundtrip(packed)
+            reduced = quantized_ring_allreduce(
+                packed, axis_name=axis_name, average=average
+            )
         shapes = [leaves[i].shape for i in bucket]
-        for i, r, e in zip(
-            bucket, unpack_bucket(reduced, shapes),
-            unpack_bucket(new_res, shapes),
-        ):
-            results[i] = r.astype(leaves[i].dtype)
-            residuals[i] = e
+        with jax.named_scope(_trace.SCOPE_EXCHANGE_UNPACK):
+            for i, r, e in zip(
+                bucket, unpack_bucket(reduced, shapes),
+                unpack_bucket(new_res, shapes),
+            ):
+                results[i] = r.astype(leaves[i].dtype)
+                residuals[i] = e
     return (
         jax.tree.unflatten(treedef, results),
         jax.tree.unflatten(ef_treedef, residuals),

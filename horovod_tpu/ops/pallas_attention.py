@@ -36,6 +36,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..trace import SCOPE_FLASH_BWD
+
 _NEG_INF = -1e30
 _LANES = 128  # TPU lane width; m/l carriers keep a lane dim like the
               # upstream jax flash kernel's lse outputs.
@@ -249,6 +251,7 @@ def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     return o, (q, k, v, o, lse)
 
 
+@jax.named_scope(SCOPE_FLASH_BWD)
 def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     """Flash backward: probabilities are recomputed per K/V block from the
     saved logsumexp inside a ``lax.scan`` — live memory is O(T * block_k),
@@ -401,6 +404,7 @@ def _flash_block_vjp_fwd(q, k, v, delta, sm_scale, causal, block_q, block_k,
     return out, (q, k, v, delta)
 
 
+@jax.named_scope(SCOPE_FLASH_BWD)
 def _flash_block_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res,
                          cts):
     q, k, v, delta = res

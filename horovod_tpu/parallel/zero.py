@@ -38,6 +38,7 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
+from .. import trace as _trace
 from .mesh import DATA_AXIS
 
 __all__ = [
@@ -393,7 +394,8 @@ def zero1_stream_update(
         g_opt: Dict[str, Any] = {}
         for bi, bucket in enumerate(F.plan_buckets(p_leaves, threshold)):
             bkey = f"b{bi}"
-            packed_p = F.pack_bucket([p_leaves[i] for i in bucket])
+            with jax.named_scope(_trace.SCOPE_EXCHANGE_PACK):
+                packed_p = F.pack_bucket([p_leaves[i] for i in bucket])
             total = packed_p.shape[0]
             if (
                 total == 0
@@ -407,29 +409,32 @@ def zero1_stream_update(
                     f"different partition (threshold/first-bucket/"
                     f"quantized knobs must match init_zero1_stream_state)"
                 )
-            packed_g = F.pack_bucket([g_leaves[i] for i in bucket])
             k = F.zero1_shard_len(total, n_shards, quantized)
             pad = n_shards * k - total
-            buf_p = jnp.pad(packed_p, (0, pad))
-            buf_g = jnp.pad(packed_g, (0, pad))
-            g_shard = lax.dynamic_slice(buf_g, (idx * k,), (k,))
-            p_shard = lax.dynamic_slice(buf_p, (idx * k,), (k,))
+            with jax.named_scope(_trace.SCOPE_EXCHANGE_PACK):
+                packed_g = F.pack_bucket([g_leaves[i] for i in bucket])
+                buf_p = jnp.pad(packed_p, (0, pad))
+                buf_g = jnp.pad(packed_g, (0, pad))
+                g_shard = lax.dynamic_slice(buf_g, (idx * k,), (k,))
+                p_shard = lax.dynamic_slice(buf_p, (idx * k,), (k,))
             updates, new_state = optimizer.update(
                 g_shard, states[bkey], p_shard
             )
             new_p_shard = optax.apply_updates(p_shard, updates)
-            if len(axes) > 1:
-                from ..topo import compositor as _compositor
+            with jax.named_scope(_trace.SCOPE_EXCHANGE_REDUCE):
+                if len(axes) > 1:
+                    from ..topo import compositor as _compositor
 
-                full = _compositor.lower_allgather(
-                    new_p_shard, axes, algorithm="two-level"
-                )
-            else:
-                full = lax.all_gather(new_p_shard, axes[0], tiled=True)
+                    full = _compositor.lower_allgather(
+                        new_p_shard, axes, algorithm="two-level"
+                    )
+                else:
+                    full = lax.all_gather(new_p_shard, axes[0], tiled=True)
             ag_payload += n_shards * k * np.dtype(packed_p.dtype).itemsize
-            unpacked = F.unpack_bucket(
-                full[:total], [p_leaves[i].shape for i in bucket]
-            )
+            with jax.named_scope(_trace.SCOPE_EXCHANGE_UNPACK):
+                unpacked = F.unpack_bucket(
+                    full[:total], [p_leaves[i].shape for i in bucket]
+                )
             for i, r in zip(bucket, unpacked):
                 results[i] = r
             g_opt[bkey] = new_state

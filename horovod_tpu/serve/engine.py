@@ -60,6 +60,8 @@ class Request:
     submit_t: float
     enqueued_us: int
     requeues: int = 0
+    # time.perf_counter() at submit: what Completion.token_times_s counts from
+    submit_pc: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -72,6 +74,10 @@ class Completion:
     outcome: str  # "ok" | "dropped" | "rejected"
     latency_s: float
     replica: Optional[int] = None
+    # Seconds from submit to each output token reaching the host: one
+    # time.perf_counter() per decode launch, so tokens of one launch share
+    # a stamp. token_times_s[0] is the time to first token.
+    token_times_s: Tuple[float, ...] = ()
 
 
 class _Replica:
@@ -146,6 +152,10 @@ class ServeEngine:
         # Occupancy accounting (bench.py --serve reports the mean).
         self.batches = 0
         self.batched_requests = 0
+        # Decode launches, and the slots that held a live request in them:
+        # occupancy per step is slot_launches / (launches * max_batch_size).
+        self.launches = 0
+        self.slot_launches = 0
 
     # --------------------------------------------------------- lifecycle
     def start(self) -> "ServeEngine":
@@ -237,6 +247,7 @@ class ServeEngine:
             req = Request(
                 id=rid, prompt=prompt, max_tokens=max_tokens,
                 submit_t=time.time(), enqueued_us=self._now_us(),
+                submit_pc=time.perf_counter(),
             )
             self._requests[rid] = req
             self._done_events[rid] = threading.Event()
@@ -365,6 +376,7 @@ class ServeEngine:
         seqs = [list(r.prompt) for r in batch]
         pos = [0] * len(batch)
         active = [True] * len(batch)
+        times: List[List[float]] = [[] for _ in batch]
         for i, r in enumerate(batch):
             pages = rep.pages[r.id]
             page_table[i, : len(pages)] = pages
@@ -381,17 +393,23 @@ class ServeEngine:
                 self.params, rep.cache, tokens, positions, page_table
             )
             out = np.asarray(out)
+            now = time.perf_counter()
+            with self._cond:
+                self.launches += 1
+                self.slot_launches += sum(active)
             for i, r in enumerate(batch):
                 if not active[i]:
                     continue
                 if pos[i] == len(seqs[i]) - 1:
                     seqs[i].append(int(out[i]))
+                    times[i].append(now - r.submit_pc)
                 pos[i] += 1
                 if len(seqs[i]) - len(r.prompt) >= r.max_tokens:
                     active[i] = False
                     page_table[i, :] = 0  # slot back to scratch
                     self._finish(
-                        rep, r, tuple(seqs[i][len(r.prompt):]), "ok"
+                        rep, r, tuple(seqs[i][len(r.prompt):]), "ok",
+                        token_times_s=tuple(times[i]),
                     )
 
     def _on_replica_killed(self, rep: _Replica,
@@ -422,7 +440,8 @@ class ServeEngine:
 
     # --------------------------------------------------------- recording
     def _finish(self, rep: Optional[_Replica], req: Request,
-                tokens: Tuple[int, ...], outcome: str) -> None:
+                tokens: Tuple[int, ...], outcome: str,
+                token_times_s: Tuple[float, ...] = ()) -> None:
         with self._cond:
             if req.id in self._done:
                 return  # exactly-once: a duplicate answer is dropped here
@@ -435,6 +454,7 @@ class ServeEngine:
                 id=req.id, prompt=req.prompt, tokens=tokens,
                 outcome=outcome, latency_s=latency,
                 replica=None if rep is None else rep.idx,
+                token_times_s=token_times_s,
             )
             self._done[req.id] = comp
             if outcome == "ok":

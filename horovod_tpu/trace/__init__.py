@@ -47,7 +47,6 @@ import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
 logger = logging.getLogger("horovod_tpu.trace")
@@ -67,6 +66,28 @@ DEFAULT_STRAGGLER_THRESHOLD_S = 0.01
 
 # Current flight-dump / pushed-window schema.
 SCHEMA = 1
+
+# Scopes inside the compiled step (docs/timeline.md "Scopes in the compiled
+# step"): ``jax.named_scope`` names, always on — metadata on the HLO, nothing
+# at run time. The device trace carries them as each op's scope path, which
+# is what ``benchmark/scope_reduce.py`` groups a step's device time by.
+SCOPE_LOSS_GRAD = "hvd_loss_grad"
+SCOPE_EXCHANGE = "hvd_exchange"
+SCOPE_EXCHANGE_PACK = SCOPE_EXCHANGE + "/pack"
+SCOPE_EXCHANGE_REDUCE = SCOPE_EXCHANGE + "/reduce"
+SCOPE_EXCHANGE_UNPACK = SCOPE_EXCHANGE + "/unpack"
+SCOPE_OPTIMIZER = "hvd_optimizer"
+SCOPE_GUARD = "hvd_guard"
+SCOPE_FLASH_BWD = "flash_bwd"
+STEP_SCOPES = (
+    SCOPE_LOSS_GRAD,
+    SCOPE_EXCHANGE_PACK,
+    SCOPE_EXCHANGE_REDUCE,
+    SCOPE_EXCHANGE_UNPACK,
+    SCOPE_OPTIMIZER,
+    SCOPE_GUARD,
+    SCOPE_FLASH_BWD,
+)
 
 
 def _ring_capacity() -> int:
@@ -159,31 +180,6 @@ class TraceTap:
             self._ring.append(rec)
         return rec
 
-    @contextmanager
-    def span(self, name: str, cat: str = "phase", **args):
-        t0 = time.time()
-        try:
-            yield
-        finally:
-            self.event(name, ph="X", cat=cat, ts=t0,
-                       dur=time.time() - t0, **args)
-
-    @contextmanager
-    def request(self, request_id: Any, **args):
-        """One serving-request span (docs/serving.md): an ``hvd_request``
-        "X" event on cat ``request`` covering admission → completion,
-        stamped with the request id — the serving analogue of the step
-        span, renderable by ``tools/trace_merge.py`` on the same lane
-        machinery."""
-        t0 = time.time()
-        try:
-            yield
-        finally:
-            self.event(
-                "hvd_request", ph="X", cat="request", ts=t0,
-                dur=time.time() - t0, request_id=str(request_id), **args,
-            )
-
     def timeline_event(self, ev: dict) -> None:
         """Mirror one catapult-timeline record into the ring (wall-clock
         restamped — the timeline's own clock is perf_counter-relative).
@@ -224,14 +220,6 @@ class TraceTap:
             self._ring.append(rec)
             self._steps.append((idx, t0, t1))
             self._wrapped_steps += 1
-
-    @contextmanager
-    def step(self, **args):
-        token = self.begin_step()
-        try:
-            yield token[0]
-        finally:
-            self.end_step(token, **args)
 
     def commit_step(self, **args) -> None:
         """Mark one elastic commit boundary (``State.commit``). Between
@@ -382,14 +370,6 @@ class _NullTraceTap:
     def event(self, *a, **kw) -> dict:
         return {}
 
-    @contextmanager
-    def span(self, *a, **kw):
-        yield
-
-    @contextmanager
-    def request(self, *a, **kw):
-        yield
-
     def timeline_event(self, ev: dict) -> None:
         pass
 
@@ -398,10 +378,6 @@ class _NullTraceTap:
 
     def end_step(self, token, **args) -> None:
         pass
-
-    @contextmanager
-    def step(self, **args):
-        yield 0
 
     def commit_step(self, **args) -> None:
         pass
@@ -500,11 +476,18 @@ def wrap_step(fn, **meta):
     every step span's args alongside the noted plan/correlation ids."""
     if not ACTIVE:
         return fn
+    from jax.profiler import StepTraceAnnotation
+
     tap_ref = TAP
 
     def traced_step(*args, **kwargs):
         token = tap_ref.begin_step()
-        out = fn(*args, **kwargs)
+        # The same span in the profiler's trace, when a session is open
+        # (HOROVOD_PROFILER_DIR or the user's own): on the device's clock,
+        # with the step number. Its duration is the ENQUEUE, like the
+        # ring's: the jitted call returns before the device has finished.
+        with StepTraceAnnotation("hvd_step", step_num=token[0]):
+            out = fn(*args, **kwargs)
         tap_ref.end_step(token, **meta)
         return out
 

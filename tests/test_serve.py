@@ -238,6 +238,55 @@ def test_batched_engine_matches_one_at_a_time_reference():
             )
 
 
+def _one_batch_engine(params, requests):
+    """All ``requests`` (prompt, max_tokens) submitted BEFORE the engine
+    starts, so the first dequeue takes them as one batch."""
+    engine = ServeEngine(
+        params, make_decode_step(n_heads=HEADS, dtype=jnp.float32),
+        n_layers=LAYERS, n_heads=HEADS, head_dim=D // HEADS,
+        num_pages=64, page_size=4, max_batch_size=4, max_wait_us=500,
+        max_context=T, cache_dtype=jnp.float32,
+    )
+    rids = [engine.submit(p, max_tokens=n) for p, n in requests]
+    with engine:
+        engine.drain(timeout=120.0)
+    return engine, [engine.result(r) for r in rids]
+
+
+def test_completion_carries_one_time_per_output_token():
+    requests = [([1, 2, 3], 5), ([4], 2), ([5, 6], 4)]
+    _, got = _one_batch_engine(_tiny_params(), requests)
+    for (_, n), c in zip(requests, got):
+        assert c.outcome == "ok" and len(c.tokens) == n
+        assert len(c.token_times_s) == n
+        # one stamp per decode launch, one token a launch: strictly rising,
+        # from submit, the last no later than the request's own latency
+        assert all(b > a for a, b in zip(c.token_times_s, c.token_times_s[1:]))
+        assert 0.0 < c.token_times_s[0]
+        assert c.token_times_s[-1] <= c.latency_s + 0.05
+    # refused requests carry no token times
+    engine = ServeEngine(
+        _tiny_params(), make_decode_step(n_heads=HEADS, dtype=jnp.float32),
+        n_layers=LAYERS, n_heads=HEADS, head_dim=D // HEADS, num_pages=8,
+        page_size=4, max_batch_size=2, max_context=T, queue_bound=1,
+    )
+    engine.submit([1], max_tokens=1)
+    refused = engine.result(engine.submit([1], max_tokens=1))
+    assert refused.outcome == "rejected" and refused.token_times_s == ()
+
+
+def test_launch_counters_add_up_on_members_of_different_lengths():
+    # a member needs len(prompt) + max_tokens - 1 launches: 7, 2 and 5
+    requests = [([1, 2, 3], 5), ([4], 2), ([5, 6], 4)]
+    engine, got = _one_batch_engine(_tiny_params(), requests)
+    assert [c.outcome for c in got] == ["ok"] * 3
+    assert engine.batches == 1 and engine.batched_requests == 3
+    assert engine.launches == 7            # the batch runs to its longest
+    assert engine.slot_launches == 7 + 2 + 5
+    occupancy = engine.slot_launches / (engine.launches * 4)
+    assert occupancy == pytest.approx(0.5)
+
+
 def test_tp_sharded_decode_matches_dense(devices):
     params = _tiny_params()
     mesh = build_mesh({"model": 2}, devices=devices[:2])

@@ -45,8 +45,7 @@ def test_disabled_tap_is_shared_noop_singleton():
     # No-ops never record anything.
     hvd_trace.TAP.event("x", foo=1)
     hvd_trace.TAP.commit_step()
-    with hvd_trace.TAP.step():
-        pass
+    hvd_trace.TAP.end_step(hvd_trace.TAP.begin_step())
     assert hvd_trace.TAP.window() == {}
     assert hvd_trace.TAP.step_summary() == {"steps": 0}
     assert hvd_trace.flight_dump("nope") is None
@@ -83,6 +82,45 @@ def test_activate_from_env(monkeypatch):
 
 
 # ------------------------------------------------------------- recording
+def test_wrap_step_enters_the_profilers_step_annotation(monkeypatch):
+    """Armed, every call of the wrapped step lies inside a
+    ``jax.profiler.StepTraceAnnotation("hvd_step", step_num=idx)``, so a
+    profiler session holds the program's step span on the device's clock;
+    off, nothing is wrapped at all."""
+    import jax.profiler
+
+    seen = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            self.rec = [name, kw, "made"]
+            seen.append(self.rec)
+
+        def __enter__(self):
+            self.rec[2] = "entered"
+
+        def __exit__(self, *exc):
+            self.rec[2] = "left"
+
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", Annotation)
+
+    def f():
+        # the step runs INSIDE the annotation
+        return [list(r) for r in seen]
+
+    assert hvd_trace.wrap_step(f) is f and not seen
+    hvd_trace.install(True)
+    step = hvd_trace.wrap_step(f)
+    assert step is not f
+    assert step()[-1] == ["hvd_step", {"step_num": 0}, "entered"]
+    step()
+    assert seen == [["hvd_step", {"step_num": 0}, "left"],
+                    ["hvd_step", {"step_num": 1}, "left"]]
+    steps = [e["args"]["step"] for e in hvd_trace.TAP.window()["events"]
+             if e["name"] == "hvd_step"]
+    assert steps == [0, 1]
+
+
 def test_wrap_step_records_spans_with_meta_and_plan_args():
     hvd_trace.install(True)
     hvd_trace.TAP.note_plan(topo_algorithm="ring", wire_dtype="int8")
@@ -132,10 +170,10 @@ def test_commit_step_spans_between_commits_and_defers_to_wrapped():
     assert len(tap.window()["steps"]) == 1
 
 
-def test_span_contextmanager_and_timeline_mirror():
+def test_span_event_and_timeline_mirror():
     hvd_trace.install(True)
-    with hvd_trace.TAP.span("phase_x", cat="op", foo=3):
-        pass
+    rec = hvd_trace.TAP.event("phase_x", ph="X", cat="op", dur=0.5, foo=3)
+    assert rec["dur"] == 0.5 and rec["args"] == {"foo": 3}
     hvd_trace.TAP.timeline_event(
         {"name": "NEGOTIATE_ALLREDUCE", "ph": "B", "pid": 0, "tid": 4}
     )
