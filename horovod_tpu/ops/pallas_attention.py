@@ -6,6 +6,24 @@ softmax state live in VMEM scratch, and the [block_q, block_k] score
 matmul + [block_k, d] value matmul hit the MXU. O(T) memory instead of
 materializing the [T, T] probability matrix.
 
+What one grid step of the forward does follows from what the kernel sees
+in its inputs (:func:`_plan`), not from a knob:
+
+- the MXU's operands are q, k and v in the dtype the caller passed (bf16
+  from the model, f32 from a caller that wants f32 products); scores,
+  mask, running max and sum, ``exp`` and the accumulator are f32, and
+  only the PV product's left operand is cast to ``v.dtype``;
+- ``block_q`` and ``block_k`` default to 512 (halved while a step would
+  overrun the VMEM budget) and several rows of the folded batch x heads
+  axis share a step, so a call at ``[128, 1024, 64]`` is 128 grid steps
+  of 4 rows, not 8192 of one;
+- with ``causal`` a (q block, k block) pair wholly above the diagonal
+  (for the traced ``delta``) is neither computed nor fetched, a pair
+  wholly below it builds no mask, and only a pair that straddles it
+  does;
+- the running max and sum leave the kernel as lane-dense ``[1, block_q]``
+  rows of a ``[bh, t_q / block_q, 1, block_q]`` array.
+
 The reference framework has no kernels at all (it is gradient plumbing;
 SURVEY.md §2.3) — this powers the model-side extensions: it is the default
 ``attn_fn`` of ``models/transformer.py`` (via :func:`flash_attention_bthd`)
@@ -36,11 +54,17 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import trace as _trace
 from ..trace import SCOPE_FLASH_BWD
 
 _NEG_INF = -1e30
-_LANES = 128  # TPU lane width; m/l carriers keep a lane dim like the
-              # upstream jax flash kernel's lse outputs.
+_LANES = 128  # TPU lane width: the running max and sum are kept lane-
+              # replicated in [block_q, 128] scratch, as the upstream jax
+              # flash kernel keeps them.
+_PREF_BLOCK = 512           # block_q / block_k where the caller names none
+_PREF_ROWS = 8              # rows of bh one grid step takes, at most
+_VMEM_BUDGET = 12 * 2 ** 20  # under Mosaic's 16 MiB default scoped limit
+_BWD_BLOCK_K = 128          # the backward scan's K/V block (not this kernel's)
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -80,14 +104,14 @@ def _pick_block(t: int, pref: int) -> int:
     return b
 
 
-def flashable(t_q: int, t_k: int, block_q: int = 128,
-              block_k: int = 128) -> bool:
+def flashable(t_q: int, t_k: int, block_q: Optional[int] = None,
+              block_k: Optional[int] = None) -> bool:
     """Whether the kernel accepts these sequence lengths (callers with
     arbitrary shapes use this to fall back to dense attention instead of
     crashing on prime/odd lengths)."""
     try:
-        _pick_block(t_q, block_q)
-        _pick_block(t_k, block_k)
+        _pick_block(t_q, block_q or _PREF_BLOCK)
+        _pick_block(t_k, block_k or _PREF_BLOCK)
         return True
     except ValueError:
         return False
@@ -109,68 +133,232 @@ def _dense_full(q, k, v, causal, sm_scale):
     ).astype(q.dtype)
 
 
+def _lanes(x, n: int):
+    """A lane-replicated ``[rows, 128]`` statistic widened (or cut) to
+    ``n`` lanes without a lane broadcast where the shape allows it."""
+    if n == _LANES:
+        return x
+    if n < _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref,
                 o_ref, m_out_ref, l_out_ref,
                 acc_ref, m_ref, l_ref, *,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
-                normalize: bool):
+                rows: int, normalize: bool):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    d = q_ref.shape[-1]
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32)   # [Bq, D]
-    k = k_ref[0].astype(jnp.float32)   # [Bk, D]
-    v = v_ref[0].astype(jnp.float32)   # [Bk, D]
+    def update(bias):
+        """One online-softmax step of every row of ``bh`` in this grid
+        step against the resident K/V block; ``bias`` is the additive
+        causal mask of a pair that straddles the diagonal, or None."""
+        def one(g, carry):
+            q, k, v = q_ref[g], k_ref[g], v_ref[g]     # operands as passed
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale                                # [Bq, Bk] f32
+            if bias is not None:
+                s = s + bias
+            m_prev, l_prev = m_ref[g], l_ref[g]         # [Bq, 128]
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(s - _lanes(m_next, block_k))
+            l_ref[g] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            m_ref[g] = m_next
+            acc_ref[g] = acc_ref[g] * _lanes(alpha, d) + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            return carry
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale                        # [Bq, Bk]
+        lax.fori_loop(0, rows, one, None)
 
     if causal:
         # Global positions: q at q_pos, k at k_pos + delta, where delta is
         # the (dynamic) offset of the K block's sequence origin relative to
         # Q's — 0 for self-attention, src*T - rank*T inside ring attention.
-        delta = delta_ref[0]
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        ) + delta
-        mask = q_pos >= k_pos
-        s = jnp.where(mask, s, _NEG_INF)
+        # ``first_k`` is the global position of this K block's first key
+        # relative to this Q block's first query.
+        first_k = ki * block_k + delta_ref[0] - qi * block_q
+        visible = first_k <= block_q - 1          # some (q, k) pair is kept
+        whole = first_k + block_k - 1 <= 0        # every pair is kept
 
-    m_prev = m_ref[:, :1]                       # [Bq, 1]
-    l_prev = l_ref[:, :1]
-    m_curr = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_curr)
-    p = jnp.exp(s - m_curr)                     # [Bq, Bk]
-    if causal:
-        # A fully-masked row has m_curr == _NEG_INF and would turn the
-        # masked entries into exp(0) = 1; re-apply the mask to p.
-        p = jnp.where(mask, p, 0.0)
-    l_curr = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_ref[:] = jnp.broadcast_to(m_curr, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_curr, l_ref.shape)
+        @pl.when(whole)
+        def _below_diagonal():
+            update(None)
+
+        @pl.when(jnp.logical_and(visible, jnp.logical_not(whole)))
+        def _on_diagonal():
+            rel = jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0
+            ) - jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+            # Masked scores sit BELOW the running max's floor, so a row
+            # with nothing visible yet keeps m = -1e30 and gets p =
+            # exp(-1e30) = 0 without a second select.
+            update(jnp.where(rel >= first_k, 0.0, 2 * _NEG_INF))
+    else:
+        update(None)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
-        if normalize:
-            l = l_ref[:, :1]
-            l = jnp.where(l == 0.0, 1.0, l)     # fully-masked rows -> 0 out
-            o_ref[0] = (acc_ref[:] / l).astype(o_ref.dtype)
-        else:
-            o_ref[0] = acc_ref[:].astype(o_ref.dtype)
-        m_out_ref[0] = m_ref[:]
-        l_out_ref[0] = l_ref[:]
+        def one(g, carry):
+            l = l_ref[g]
+            if normalize:
+                inv = 1.0 / jnp.where(l == 0.0, 1.0, l)  # masked rows -> 0
+                o_ref[g] = (acc_ref[g] * _lanes(inv, d)).astype(o_ref.dtype)
+            else:
+                o_ref[g] = acc_ref[g].astype(o_ref.dtype)
+            # [Bq, 128] lane-replicated -> one lane-dense [1, Bq] row.
+            m_out_ref[g, 0] = m_ref[g].T[:1]
+            l_out_ref[g, 0] = l.T[:1]
+            return carry
+
+        lax.fori_loop(0, rows, one, None)
+
+
+def _step_vmem_bytes(rows, block_q, block_k, d, in_size, out_size):
+    """What one grid step keeps in VMEM: the pipeline's two buffers of
+    every q/k/v/o block (a minor dim under 128 lanes is padded to them),
+    the f32 accumulator and statistics, and the [Bq, Bk] f32 temporaries
+    of one row of ``bh`` (scores, probabilities, the mask's bias, the PV
+    operand)."""
+    dl = -(-d // _LANES) * _LANES
+    blocks = 2 * rows * dl * (
+        (block_q + 2 * block_k) * in_size + block_q * out_size
+    )
+    scratch = rows * block_q * (dl + 2 * _LANES) * 4
+    return blocks + scratch + 4 * block_q * block_k * 4
+
+
+def _plan(bh, t_q, t_k, d, in_size, out_size, block_q, block_k):
+    """``(block_q, block_k, rows)`` of one forward call. A block size the
+    caller passed is a preference as before; one left to the kernel
+    starts from ``_PREF_BLOCK`` and is halved while a grid step of one row
+    overruns ``_VMEM_BUDGET``. ``rows`` is how many rows of the folded
+    batch x heads axis one grid step takes: the largest divisor of ``bh``
+    up to ``_PREF_ROWS`` that still fits."""
+    def blocks(pref):
+        return (_pick_block(t_q, block_q or pref),
+                _pick_block(t_k, block_k or pref))
+
+    def fits(rows):
+        return _step_vmem_bytes(
+            rows, bq, bk, d, in_size, out_size) <= _VMEM_BUDGET
+
+    pref = _PREF_BLOCK
+    bq, bk = blocks(pref)
+    while not fits(1) and pref > _LANES:
+        pref //= 2
+        try:
+            bq, bk = blocks(pref)
+        except ValueError:  # no divisor that small: keep what divides
+            break
+    rows = min(_PREF_ROWS, bh)
+    while bh % rows or (rows > 1 and not fits(rows)):
+        rows -= 1
+    return bq, bk, rows
+
+
+def _pairs_visited(t_q, t_k, block_q, block_k, causal):
+    """Share of the (q block, k block) pairs a call computes, at
+    ``delta`` 0 (ring steps shift the diagonal at run time)."""
+    n_q, n_k = t_q // block_q, t_k // block_k
+    if not causal:
+        return 1.0
+    seen = sum(
+        1 for i in range(n_q) for j in range(n_k)
+        if j * block_k <= i * block_q + block_q - 1
+    )
+    return seen / (n_q * n_k)
+
+
+def _forward(bh, t_q, t_k, d, out_dtype, sm_scale, causal, block_q, block_k,
+             rows, normalize, interpret, vma):
+    """The forward ``pallas_call`` at one plan, as a function of
+    ``(delta[1] int32, q, k, v)`` returning ``(o, m, l)`` with the
+    statistics as ``[bh, t_q / block_q, 1, block_q]``."""
+    n_q, n_k = t_q // block_q, t_k // block_k
+    kernel = functools.partial(
+        _fwd_kernel, sm_scale=sm_scale, causal=causal,
+        block_q=block_q, block_k=block_k, rows=rows, normalize=normalize,
+    )
+
+    def kv_index(b, i, j, delta_ref):
+        if not causal:
+            return (b, j, 0)
+        # A pair wholly above the diagonal is not computed; point it at
+        # the last K/V block this q block needs, so the pipeline sees an
+        # unchanged index and fetches nothing.
+        last_q = i * block_q + (block_q - 1) - delta_ref[0]
+        last = lax.min(lax.div(lax.max(last_q, 0), block_k), n_k - 1)
+        return (b, lax.min(j, last), 0)
+
+    q_index = lambda b, i, j, delta_ref: (b, i, 0)
+    stat_index = lambda b, i, j, delta_ref: (b, i, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(bh // rows, n_q, n_k),
+        in_specs=[
+            pl.BlockSpec((rows, block_q, d), q_index),
+            pl.BlockSpec((rows, block_k, d), kv_index),
+            pl.BlockSpec((rows, block_k, d), kv_index),
+        ],
+        out_specs=[
+            pl.BlockSpec((rows, block_q, d), q_index),
+            pl.BlockSpec((rows, 1, 1, block_q), stat_index),
+            pl.BlockSpec((rows, 1, 1, block_q), stat_index),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((rows, block_q, d), jnp.float32),
+            pltpu.VMEM((rows, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((rows, block_q, _LANES), jnp.float32),
+        ],
+    )
+    # Inside a checked shard_map the outputs vary over every mesh axis any
+    # input varies over; pallas_call wants that stated on out_shape.
+    stat = jax.ShapeDtypeStruct((bh, n_q, 1, block_q), jnp.float32, vma=vma)
+    return pl.pallas_call(
+        kernel,
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t_q, d), out_dtype, vma=vma),
+            stat, stat,
+        ],
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )
+
+
+@functools.lru_cache(maxsize=64)
+def _forward_jaxpr(mesh, in_dtypes, *plan):
+    """:func:`_forward` traced once per distinct call. Pallas traces a
+    kernel body anew on every ``pallas_call``, and a model calls the
+    kernel once a layer at one shape: 24 layers of tracing are seconds of
+    a program's set-up on a chip's host. Evaluating the kept jaxpr binds
+    the same ``pallas_call`` under the caller's own name stack. ``mesh``
+    is the abstract mesh of the caller's context: avals carry it, so a
+    jaxpr is kept per context."""
+    bh, t_q, t_k, d = plan[:4]
+    shapes = ((1,), (bh, t_q, d), (bh, t_k, d), (bh, t_k, d))
+    return jax.make_jaxpr(_forward(*plan, vma=frozenset()))(*(
+        jax.ShapeDtypeStruct(shape, dtype)
+        for shape, dtype in zip(shapes, (jnp.int32,) + in_dtypes)
+    ))
 
 
 def _flash_call(q, k, v, delta, *, sm_scale, causal, block_q, block_k,
@@ -179,52 +367,35 @@ def _flash_call(q, k, v, delta, *, sm_scale, causal, block_q, block_k,
     [bh, t_q] (row max / softmax denominator in the online recurrence)."""
     bh, t_q, d = q.shape
     t_k = k.shape[1]
-    block_q = _pick_block(t_q, block_q)
-    block_k = _pick_block(t_k, block_k)
-    grid = (bh, t_q // block_q, t_k // block_k)
-
-    kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, normalize=normalize,
+    out_dtype = jnp.dtype(out_dtype)
+    block_q, block_k, rows = _plan(
+        bh, t_q, t_k, d, q.dtype.itemsize, out_dtype.itemsize,
+        block_q, block_k,
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j, ref: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j, ref: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j, ref: (b, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j, ref: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda b, i, j, ref: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda b, i, j, ref: (b, i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-        ],
-    )
-    # Inside a checked shard_map the outputs vary over every mesh axis any
-    # input varies over; pallas_call wants that stated on out_shape.
+    if _trace.ACTIVE:
+        # Trace-time, one note per compile (the fusion plan's discipline):
+        # what one grid step of this program's forward kernel is.
+        _trace.TAP.note_plan(
+            flash_block_q=block_q, flash_block_k=block_k,
+            flash_rows_per_step=rows,
+            flash_grid_steps=(bh // rows) * (t_q // block_q)
+            * (t_k // block_k),
+            flash_pairs_visited=round(
+                _pairs_visited(t_q, t_k, block_q, block_k, causal), 4),
+        )
+    plan = (bh, t_q, t_k, d, out_dtype, sm_scale, causal, block_q, block_k,
+            rows, normalize, interpret)
+    args = (jnp.asarray(delta, jnp.int32).reshape(1), q, k, v)
     vma = _vma(q, k, v)
-    o, m, l = pl.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, t_q, d), out_dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, t_q, _LANES), jnp.float32, vma=vma),
-            jax.ShapeDtypeStruct((bh, t_q, _LANES), jnp.float32, vma=vma),
-        ],
-        grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(jnp.asarray(delta, jnp.int32).reshape(1), q, k, v)
-    return o, m[:, :, 0], l[:, :, 0]
+    if vma:   # typed per mesh axis: traced where the axes are bound
+        o, m, l = _forward(*plan, vma=vma)(*args)
+    else:
+        closed = _forward_jaxpr(
+            jax.sharding.get_abstract_mesh(), (q.dtype, k.dtype, v.dtype),
+            *plan,
+        )
+        o, m, l = jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *args)
+    return o, m.reshape(bh, t_q), l.reshape(bh, t_q)
 
 
 # --------------------------------------------------------------------------
@@ -259,7 +430,7 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     q, k, v, o, lse = res
     bh, t_q, d = q.shape
     t_k = k.shape[1]
-    bk = _pick_block(t_k, block_k)
+    bk = _pick_block(t_k, block_k or _BWD_BLOCK_K)
     n_blocks = t_k // bk
 
     qf = q.astype(jnp.float32)
@@ -310,8 +481,8 @@ def flash_attention(
     *,
     causal: bool = False,
     sm_scale: Optional[float] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused attention over ``[..., T, D]`` (leading dims fold into one
@@ -429,8 +600,8 @@ def flash_attention_block(
     *,
     sm_scale: float,
     causal: bool = True,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> tuple:
     """One ring-attention block: q/k/v are ``[BH, T, D]``; ``delta`` is a

@@ -12,6 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.ops import pallas_attention as pa
 from horovod_tpu.ops.pallas_attention import (
     flash_attention,
     flash_attention_block,
@@ -36,7 +37,7 @@ def _dense(q, k, v, causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("blocks", [(128, 128), (8, 16)])
+@pytest.mark.parametrize("blocks", [(128, 128), (8, 16), (None, None)])
 def test_forward_matches_dense(causal, blocks):
     q, k, v = _qkv_bhtd()
     bq, bk = blocks
@@ -78,6 +79,78 @@ def test_bf16_dtype_preserved():
         np.asarray(out, np.float32), np.asarray(expected, np.float32),
         rtol=5e-2, atol=5e-2,
     )
+
+
+@pytest.mark.parametrize("bh,t,d,pref,plan", [
+    # The cells' shape class at its real T: 512 x 512 tiles, 4 rows of bh
+    # a grid step, one pair of four above the diagonal.
+    (8, 1024, 64, None, (512, 512, 4)),
+    # Scaled down (tiles of 64 stand for 512): bh a multiple of the
+    # preferred rows, not divisible by them (12 -> 6, 11 -> 1), d 128.
+    (16, 256, 64, 64, (64, 64, 8)),
+    (12, 256, 64, 64, (64, 64, 6)),
+    (11, 256, 64, 64, (64, 64, 1)),
+    (4, 256, 128, 64, (64, 64, 4)),
+])
+def test_bf16_rows_per_step(monkeypatch, bh, t, d, pref, plan):
+    """bf16 operands go to the MXU as passed, several rows of ``bh`` share
+    a grid step, and the pairs the causal mask empties are skipped."""
+    if pref is not None:
+        monkeypatch.setattr(pa, "_PREF_BLOCK", pref)
+    assert pa._plan(bh, t, t, d, 2, 2, None, None) == plan
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv_bhtd(bh, t, d, seed=1))
+    out = flash_attention(q, k, v, causal=True)
+    assert out.dtype == jnp.bfloat16
+    expected = pa._dense_full(q, k, v, True, d ** -0.5)
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(expected, np.float32),
+        rtol=2e-2, atol=2e-2,
+    )
+
+
+def test_explicit_blocks_are_honoured():
+    assert pa._plan(4, 32, 32, 16, 4, 4, 8, 16)[:2] == (8, 16)
+    assert pa._plan(4, 32, 64, 16, 4, 4, None, 16)[:2] == (32, 16)
+    # One block may be the whole sequence, whatever its length; a prime
+    # length over the preferred block has no divisor to tile by.
+    assert pa.flashable(262, 262) and pa.flashable(131, 131)
+    assert not pa.flashable(521, 521) and not pa.flashable(1024, 521)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("blocks", [(None, None), (8, 16)])
+@pytest.mark.parametrize("delta", [0, -64, -20, 8, 24, 32, 200])
+def test_block_matches_dense_block(monkeypatch, delta, blocks, dtype):
+    """The ring block's ``(o, m, l)`` against its dense twin for a TRACED
+    offset, ``t_q != t_k``: every key visible (-64), the diagonal shifted
+    either way, whole q rows masked (8, 24: ``m = -1e30``, ``l = 0``,
+    ``o = 0`` there), and every pair masked (32, 200)."""
+    monkeypatch.setattr(pa, "_PREF_BLOCK", 16)   # 2 x 4 block pairs
+    bh, t_q, t_k, d = 6, 32, 64, 16
+    rng = np.random.RandomState(7)
+    mk = lambda t: jnp.asarray(
+        rng.randn(bh, t, d).astype(np.float32) * 0.5).astype(dtype)
+    q, k, v = mk(t_q), mk(t_k), mk(t_k)
+    scale = d ** -0.5
+    bq, bk = blocks
+
+    @jax.jit
+    def run(q, k, v, delta):
+        return flash_attention_block(
+            q, k, v, delta, sm_scale=scale, block_q=bq, block_k=bk)
+
+    o, m, l = run(q, k, v, jnp.float32(delta))
+    eo, em, el = pa._dense_block(q, k, v, delta, scale, True)
+    assert o.dtype == jnp.float32 and m.shape == l.shape == (bh, t_q)
+    tol = dict(rtol=2e-4, atol=2e-5) if dtype == jnp.float32 else dict(
+        rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(np.asarray(m), np.asarray(em), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(l), np.asarray(el), **tol)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(eo), **tol)
+    masked = np.arange(t_q) < delta          # rows that see no key at all
+    assert (np.asarray(m)[:, masked] == pa._NEG_INF).all()
+    assert (np.asarray(l)[:, masked] == 0.0).all()
+    assert (np.asarray(o)[:, masked] == 0.0).all()
 
 
 def test_bthd_adapter_matches_reference():
@@ -140,12 +213,14 @@ def test_block_grad_flows():
         )
 
 
-def test_odd_length_falls_back_to_dense():
-    """Prime sequence lengths can't satisfy the kernel's block constraint;
-    the [B,T,H,D] adapter (transformer default / Ulysses local attention)
-    must fall back to dense instead of raising."""
+@pytest.mark.parametrize("T", [131, 521])
+def test_odd_length_falls_back_to_dense(T):
+    """Prime sequence lengths over the preferred block can't satisfy the
+    kernel's block constraint (521); the [B,T,H,D] adapter (transformer
+    default / Ulysses local attention) must fall back to dense instead of
+    raising. A prime length under it (131) is one block of the kernel."""
     rng = np.random.RandomState(5)
-    B, T, H, D = 1, 131, 2, 8  # 131 is prime
+    B, H, D = 1, 2, 8
     mk = lambda: jnp.asarray(rng.randn(B, T, H, D).astype(np.float32) * 0.5)
     q, k, v = mk(), mk(), mk()
     out = flash_attention_bthd(q, k, v, causal=True)

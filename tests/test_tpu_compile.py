@@ -70,9 +70,20 @@ def _qkv(topo, shape, dtype=jnp.bfloat16):
     return (jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip),) * 3
 
 
-def test_flash_forward_compiles(topo):
+@pytest.mark.parametrize("shape,stats", [
+    ((96, 1024, 64), "f32[96,2,1,512]"),
+    # The benchmark cells' own call: 8 sequences x 16 heads a chip.
+    ((128, 1024, 64), "f32[128,2,1,512]"),
+    # What the TP forward passes with few local heads.
+    ((8, 1024, 64), "f32[8,2,1,512]"),
+])
+def test_flash_forward_compiles(topo, shape, stats):
+    """The forward at the tiles the kernel picks from the shapes (a VMEM
+    overrun or an unsupported layout fails here, not on the chip); the
+    running max and sum leave it lane-dense."""
     fwd = functools.partial(pa.flash_attention, causal=True, interpret=False)
-    _compile(jax.jit(fwd), *_qkv(topo, (96, 1024, 64)))
+    compiled = _compile(jax.jit(fwd), *_qkv(topo, shape))
+    assert stats in compiled.as_text()
 
 
 def test_flash_backward_compiles(topo):

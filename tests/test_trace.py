@@ -498,6 +498,40 @@ def test_distributed_optimizer_notes_plan_when_tracing():
     assert plan["wire_dtype"] == "int8"
 
 
+def test_flash_kernel_notes_its_plan_once_per_compile(monkeypatch):
+    """The forward flash kernel's tile plan is a trace-time plan note: one
+    emission per compile when tracing is armed, none when it is not."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import pallas_attention as pa
+
+    q = jnp.zeros((8, 64, 16), jnp.float32)
+    fresh = lambda: jax.jit(functools.partial(pa.flash_attention, causal=True))
+    fresh()(q, q, q)
+    assert hvd_trace.TAP is hvd_trace.NULL_TAP
+    assert hvd_trace.TAP.plan_args() == {}
+
+    hvd_trace.install(True)
+    notes = []
+    note_plan = hvd_trace.TAP.note_plan
+    monkeypatch.setattr(
+        hvd_trace.TAP, "note_plan",
+        lambda **kw: (notes.append(kw), note_plan(**kw)),
+    )
+    monkeypatch.setattr(pa, "_PREF_BLOCK", 16)
+    step = fresh()
+    step(q, q, q)
+    step(q, q, q)                       # the cached executable: no new note
+    assert len(notes) == 1
+    assert hvd_trace.TAP.plan_args() == {
+        "flash_block_q": 16, "flash_block_k": 16, "flash_rows_per_step": 8,
+        "flash_grid_steps": 16, "flash_pairs_visited": 0.625,
+    }
+
+
 # --------------------------------------------------- timeline satellites
 def test_timeline_writer_crash_warns_once_and_counts_drops(caplog):
     hvd_metrics.install(True)
