@@ -20,6 +20,7 @@ Design notes (the GShard/Switch dispatch pattern, re-derived for shard_map):
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -27,6 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import trace as _trace
 from ..common.compat import axis_size as _axis_size
 from .mesh import DATA_AXIS, EXPERT_AXIS
 
@@ -149,6 +151,192 @@ def moe_ffn(
     out = out.reshape(e_total, capacity, d_model)
     y = jnp.einsum("sec,ecd->sd", combine, out)
     return y.astype(x.dtype), aux_loss
+
+
+# --------------------------------------------------------------------------
+# Dropless top-k layer: this device's experts' part of the result.
+# --------------------------------------------------------------------------
+
+def route_top_k(x, w_router, *, top_k: int, norm_topk: bool = True):
+    """Softmax routing over ALL experts: ``(weights [S, k] f32, expert ids
+    [S, k] int32)``. The router's product and softmax are float32 at full
+    precision, whatever ``x`` is: a token whose k-th and (k+1)-th
+    probabilities are near a tie must choose as the float32 model does."""
+    logits = jnp.matmul(x.astype(jnp.float32), w_router.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    weights, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm_topk:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, ids.astype(jnp.int32)
+
+
+def _held_groups(ids, first_expert: int, experts_held: int):
+    """``(key [S * k], sizes [experts_held])``: per (token, expert) choice the
+    held expert's local id (``experts_held`` for an expert that lives
+    elsewhere), and how many choices fall on each held expert."""
+    local = ids.reshape(-1) - first_expert
+    key = jnp.where((local >= 0) & (local < experts_held), local,
+                    experts_held)
+    sizes = jnp.bincount(key, length=experts_held + 1)[:experts_held]
+    return key, sizes.astype(jnp.int32)
+
+
+def held_load(ids, *, first_expert: int, experts_held: int):
+    """``(pairs, largest)``: how many (token, expert) choices of ``ids`` fall
+    on the ``experts_held`` experts from ``first_expert`` on (what the
+    layer's grouped products compute), and the load of the busiest of them."""
+    _, sizes = _held_groups(ids, first_expert, experts_held)
+    return jnp.sum(sizes), jnp.max(sizes)
+
+
+def _tile_rows(x, scale, w_gate, w_up, w_down, order, sizes, top_k):
+    """One tile of the sorted (token, expert) pairs: gather the rows, three
+    grouped products over the tile's part of the ragged assignment, and the
+    weighted rows ``[rows, D]`` float32. ``sizes`` is each held expert's
+    count of rows in this tile."""
+    rows = order.shape[0]
+    with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+        valid = jnp.arange(rows) < jnp.sum(sizes)
+        # Rows past the last group belong to no expert. A grouped product
+        # leaves whatever was in memory there (zeros on the CPU, anything on
+        # the chip), forward AND transposed, so both ends are masked: the
+        # rows' output below, and here their way back into x's gradient.
+        xs = jnp.where(valid[:, None], x[order // top_k], 0).astype(
+            w_gate.dtype)                                   # [rows, D]
+    with jax.named_scope(_trace.SCOPE_MOE_EXPERTS):
+        grouped = lambda a, w: lax.ragged_dot(
+            a, w, sizes, preferred_element_type=jnp.float32)
+        h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+        ys = grouped(h.astype(w_down.dtype), w_down)        # [rows, D] f32
+    with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+        weight = jnp.where(valid, scale[order], 0.0)
+        return jnp.where(valid[:, None], ys, 0.0) * weight[:, None]
+
+
+def _tile(i, order, starts, ends):
+    """Tile ``i``'s pairs and its part of every group: rows
+    ``[i * rows, (i + 1) * rows)`` of the sorted pairs."""
+    rows = order.shape[1]
+    lo = i * rows
+    sizes = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo), 0)
+    return order[i], sizes
+
+
+def _tiles_needed(order, ends):
+    return (ends[-1] + order.shape[1] - 1) // order.shape[1]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def _experts(x, scale, w_gate, w_up, w_down, order, starts, ends, top_k):
+    """The held experts' part of the result for the sorted pairs ``order``
+    (``[tiles, rows]``): the first tile always, further tiles in a loop that
+    runs as far as this batch's load reaches. The loop's length is read on
+    the device, so it has no transpose of JAX's: the backward below walks
+    the same tiles again, one tile's rows alive at a time."""
+    def add_tile(i, y):
+        order_t, sizes_t = _tile(i, order, starts, ends)
+        ys = _tile_rows(x, scale, w_gate, w_up, w_down, order_t, sizes_t,
+                        top_k)
+        with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+            return y.at[order_t // top_k].add(ys)
+
+    y = add_tile(0, jnp.zeros(x.shape, jnp.float32))
+    return lax.fori_loop(1, _tiles_needed(order, ends), add_tile, y)
+
+
+def _experts_fwd(x, scale, w_gate, w_up, w_down, order, starts, ends, top_k):
+    y = _experts(x, scale, w_gate, w_up, w_down, order, starts, ends, top_k)
+    return y, (x, scale, w_gate, w_up, w_down, order, starts, ends)
+
+
+def _experts_bwd(top_k, res, dy):
+    x, scale, w_gate, w_up, w_down, order, starts, ends = res
+    f32 = lambda tree: jax.tree.map(lambda g: g.astype(jnp.float32), tree)
+
+    def tile_grads(i):
+        order_t, sizes_t = _tile(i, order, starts, ends)
+        _, vjp = jax.vjp(
+            lambda *a: _tile_rows(*a, order_t, sizes_t, top_k),
+            x, scale, w_gate, w_up, w_down)
+        with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+            rows = dy[order_t // top_k]
+        return f32(vjp(rows))
+
+    grads = lax.fori_loop(
+        1, _tiles_needed(order, ends),
+        lambda i, acc: jax.tree.map(jnp.add, acc, tile_grads(i)),
+        tile_grads(0))
+    primals = (x, scale, w_gate, w_up, w_down)
+    return tuple(g.astype(p.dtype) for g, p in zip(grads, primals)) + (
+        None, None, None)
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def dropless_moe(
+    x: jax.Array,
+    w_router: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    *,
+    top_k: int,
+    first_expert: int = 0,
+    norm_topk: bool = True,
+    dtype=jnp.bfloat16,
+) -> jax.Array:
+    """This device's experts' part of a top-k MoE feed-forward, no token
+    dropped and no capacity set.
+
+    ``x``: ``[S, D]`` local tokens. ``w_router``: ``[D, E_total]``,
+    replicated. ``w_gate``/``w_up``: ``[E_held, D, F]`` and ``w_down``:
+    ``[E_held, F, D]``: the experts ``first_expert .. first_expert +
+    E_held`` that live here. Every token is routed over all ``E_total``
+    experts by softmax and top-k (weights normalised over all k when
+    ``norm_topk``), and the sum ``sum_k w_k * down_e(silu(gate_e(x)) *
+    up_e(x))`` runs over the chosen experts that are held here; what the
+    others would add belongs to their owners (across an ``expert`` axis the
+    exchange that brings their tokens here is ROADMAP's debt; on one chip
+    the layer runs without it). Returns ``[S, D]`` float32.
+
+    The (token, expert) pairs held here are sorted by expert and multiplied
+    by grouped products over the ragged assignment (``lax.ragged_dot``): no
+    ``[tokens, experts, capacity]`` tensor exists. Shapes are static, so the
+    sorted pairs are cut into tiles of as many rows as balanced routing
+    fills twice over, and a loop computes as many tiles as this batch's
+    load reaches (:func:`held_load`): balanced routing is one tile, and
+    every token choosing only held experts is computed in full, tile by
+    tile."""
+    s_tokens, d_model = x.shape
+    e_total = w_router.shape[-1]
+    e_held = w_gate.shape[0]
+    worst = s_tokens * min(top_k, e_held)
+    rows = min(worst, _round_up(
+        2 * s_tokens * top_k * e_held // e_total + 8 * e_held, 512))
+    tiles = -(-worst // rows)
+    if _trace.ACTIVE:
+        _trace.TAP.note_plan(
+            moe_experts_total=e_total, moe_experts_held=e_held,
+            moe_top_k=top_k, moe_tile_rows=rows, moe_tiles=tiles,
+        )
+    with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+        weights, ids = route_top_k(x, w_router, top_k=top_k,
+                                   norm_topk=norm_topk)
+        key, sizes = _held_groups(ids, first_expert, e_held)
+        order = jnp.argsort(key, stable=True)[:worst].astype(jnp.int32)
+        order = jnp.pad(order, (0, tiles * rows - worst)).reshape(tiles, rows)
+        ends = jnp.cumsum(sizes)
+    with jax.named_scope(_trace.SCOPE_MOE_EXPERTS):
+        # cast once, outside the loop over tiles
+        w_gate, w_up, w_down = (w.astype(dtype)
+                                for w in (w_gate, w_up, w_down))
+    return _experts(x, weights.reshape(-1), w_gate, w_up, w_down, order,
+                    ends - sizes, ends, top_k)
+
+
+def _round_up(n: int, to: int) -> int:
+    return -(-n // to) * to
 
 
 def expert_sharding_specs(tree, expert_axis: str = EXPERT_AXIS):

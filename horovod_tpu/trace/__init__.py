@@ -79,6 +79,22 @@ SCOPE_EXCHANGE_UNPACK = SCOPE_EXCHANGE + "/unpack"
 SCOPE_OPTIMIZER = "hvd_optimizer"
 SCOPE_GUARD = "hvd_guard"
 SCOPE_FLASH_BWD = "flash_bwd"
+# The layers of models/qwen3_next.py (docs/models.md): entered in the model,
+# in ops/gated_delta.py's callers and in parallel/ep.py's dropless layer.
+SCOPE_GDN_CONV = "gdn_conv"
+SCOPE_GDN_SCAN = "gdn_scan"        # the chunked rule alone, no projection
+SCOPE_GATED_ATTN = "gated_attn"
+SCOPE_MOE_ROUTE = "moe_route"      # router, top-k, sort, gather, combine
+SCOPE_MOE_EXPERTS = "moe_experts"  # the grouped products
+SCOPE_MOE_SHARED = "moe_shared"
+MODEL_SCOPES = (
+    SCOPE_GDN_CONV,
+    SCOPE_GDN_SCAN,
+    SCOPE_GATED_ATTN,
+    SCOPE_MOE_ROUTE,
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_SHARED,
+)
 STEP_SCOPES = (
     SCOPE_LOSS_GRAD,
     SCOPE_EXCHANGE_PACK,

@@ -76,6 +76,9 @@ def _qkv(topo, shape, dtype=jnp.bfloat16):
     ((128, 1024, 64), "f32[128,2,1,512]"),
     # What the TP forward passes with few local heads.
     ((8, 1024, 64), "f32[8,2,1,512]"),
+    # The Qwen3-Next cell's attention layer: 16 heads of width 256 over
+    # one sequence of 8192 tokens (2 rows a grid step fit VMEM).
+    ((16, 8192, 256), "f32[16,16,1,512]"),
 ])
 def test_flash_forward_compiles(topo, shape, stats):
     """The forward at the tiles the kernel picks from the shapes (a VMEM
@@ -95,6 +98,59 @@ def test_flash_backward_compiles(topo):
         jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
         *_qkv(topo, (96, 1024, 64)),
     )
+
+
+def test_flash_backward_compiles_at_width_256(topo):
+    """The backward scan at the Qwen3-Next cell's shape: 64 blocks of 128
+    keys over 8192 queries, f32 temporaries of [16, 8192, 128]."""
+    def loss(q, k, v):
+        out = pa.flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    _compile(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+        *_qkv(topo, (16, 8192, 256)),
+    )
+
+
+def test_gated_delta_rule_compiles(topo):
+    """The chunked rule at the published head sizes (32 heads of 128 x 128,
+    chunks of 64), forward and backward, on a quarter of the cell's
+    sequence: batched products and a scan, no kernel of ours."""
+    from horovod_tpu.ops.gated_delta import gated_delta_chunked
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    arr = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    qkv = arr((1, 2048, 32, 128), jnp.bfloat16)
+    gate = arr((1, 2048, 32), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return gated_delta_chunked(q, k, v, g, beta)[0].sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qkv, qkv, qkv, gate, gate).compile()
+    assert "while" in compiled.as_text()
+
+
+def test_dropless_expert_layer_compiles(topo):
+    """The expert layer at the published widths (32 of 512 experts held,
+    top-10, 2048 -> 512 -> 2048) on 2048 tokens: the grouped products are
+    the chip's own ragged-dot kernel, and no [tokens, experts, capacity]
+    tensor is in the program."""
+    from horovod_tpu.parallel.ep import dropless_moe
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    arr = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    fwd = functools.partial(dropless_moe, top_k=10)
+    compiled = jax.jit(fwd).lower(
+        arr((2048, 2048), jnp.bfloat16), arr((2048, 512)),
+        arr((32, 2048, 512)), arr((32, 2048, 512)), arr((32, 512, 2048)),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "[2048,512," not in text.replace(" ", "")  # tokens x experts x .
 
 
 def test_ring_block_compiles(topo):
