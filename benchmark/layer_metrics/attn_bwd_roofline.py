@@ -1,0 +1,81 @@
+"""The backward flash kernels' share of their roofline: the least time the
+chip could take for one step's attention backward over the summed device time
+per step of the kernels' events. Median over the traced steps, chip 0.
+
+The events: custom calls named ``flash_bwd.<n>``, after the two backward
+``pallas_call``s of ``ops/pallas_attention.py`` under the scope ``flash_bwd``
+(a dK/dV and a dQ kernel a layer). The first rule of ``scope_groups/*.json``
+that a ``pallas_call`` meets is ``attn_fwd``, so the GROUP ``attn_fwd`` holds
+these kernels beside the forward's and ``attn_bwd_ms.train`` only what lies
+round them; this reader goes by event name and opcode and holds the kernels
+alone. A program whose backward is no kernel (an XLA ``while``) has no such
+event, and the metric is left out.
+
+The least time: the larger of operations over peak FLOP/s and bytes over peak
+bytes/s, both twice the family's ``attn_fwd_cost``: the four products the
+mathematics needs beside the forward's two (dV, dP, dQ, dK, as ``mfu.train``
+counts them; the scores the kernels recompute are overhead, so two kernels
+that redo QK^T and dP cannot pass 4/7 = 57%), and q, k, v, o, dO read and dq,
+dk, dv written once where the forward moves four such arrays.
+
+Also prints the line ``attn_bwd_kernels: {...}`` with the kernels' milliseconds
+per step, which is the number ``attn_bwd_ms.train`` no longer holds."""
+
+import json
+import re
+
+from benchmark import manifest, scope_reduce
+from benchmark import trace_reduce as tr
+
+KERNEL = re.compile(r"^flash_bwd(\.\d+)?$")  # the backward kernels in the trace
+OPCODE = "custom-call"
+
+
+def kernel_ns(trace, opcodes, match):
+    """``(median ns per step, calls per step)`` of the backward kernels on
+    chip 0 of a plain or scoped trace; ``None`` where no launch holds one."""
+    planes = tr.device_planes(trace)
+    if not planes:
+        return None
+    per_step = []
+    for launch in tr.per_launch(planes[0], match):
+        mine = [e[2] for e in launch["ops"]
+                if KERNEL.search(e[0]) and opcodes.get(e[0]) == OPCODE]
+        if mine:
+            per_step.append((sum(mine), len(mine)))
+    if not per_step:
+        return None
+    return (tr.median([t for t, _ in per_step]),
+            tr.median([n for _, n in per_step]))
+
+
+def bound(run):
+    """``(least_seconds, which)`` for one step's backward kernel calls."""
+    peak = manifest.peak_for(run.devices[0].device_kind)
+    ops, nbytes = run.cell.family.attn_fwd_cost(
+        run.cell.config, run.cell.traffic, run.counters["per_chip_batch"]
+    )
+    by_ops = 2.0 * ops / peak["bf16_flops"]
+    by_bytes = 2.0 * nbytes / peak["hbm_bytes_per_s"]
+    return max(by_ops, by_bytes), ("compute" if by_ops >= by_bytes
+                                   else "memory")
+
+
+def compute(run):
+    if not run.trace or not hasattr(run.cell.family, "attn_fwd_cost"):
+        return None
+    try:
+        path = tr.find_xplane(run.trace_dir)
+    except FileNotFoundError:
+        return None
+    _, opcodes = scope_reduce.op_metadata(path)
+    found = kernel_ns(run.device_trace, opcodes, run.launch_match())
+    if found is None:
+        return None
+    ns, calls = found
+    least, which = bound(run)
+    print("attn_bwd_kernels: " + json.dumps({
+        "kernels_ms": ns / 1e6, "calls_per_step": calls,
+        "least_ms": least * 1e3, "bound": which,
+    }), flush=True)
+    return 100.0 * least / (ns / 1e9)

@@ -31,11 +31,20 @@ and the per-block compute of ``parallel/ring_attention.py`` (via
 :func:`flash_attention_block`, which returns the unnormalized numerator and
 the online-softmax statistics so ring steps merge outside the kernel).
 
-Backward: :func:`flash_attention` uses a custom VJP that recomputes
-probabilities from the saved logsumexp blockwise under a ``lax.scan`` —
-O(T * block_k) live memory, never a [T, T] residual. The ring block's VJP
-recomputes its single [T, T/n] block densely (the same memory class as the
-forward block it differentiates).
+Backward: :func:`flash_attention`'s custom VJP is Pallas kernels under the
+forward's rules (operands as passed, f32 scores, ``exp`` and accumulators,
+masked pairs neither fetched nor computed), fed the saved logsumexp and
+``D = rowsum(do * o)`` as lane-dense rows. The dK/dV kernel walks the Q
+blocks of one K/V block with ``dk`` and ``dv`` in VMEM and works on
+transposed ``[block_k, block_q]`` scores, so the statistics broadcast
+along sublanes. Where some rows' WHOLE ``dq`` fits in VMEM beside its
+tiles (T 1024 at d 64: 0.5 MB a row) that kernel accumulates ``dq`` in the
+same walk, 5 products a pair; where it does not (T 8192 at d 256: 8 MB) a
+dQ kernel walks the K/V blocks of one Q block with ``dq`` in VMEM, and the
+two recompute the scores and ``dp`` (7 products). Either way ``dq`` is
+written once. :func:`_plan_bwd` picks tiles, rows and form from the shapes.
+The ring block's VJP recomputes its single [T, T/n] block densely (the
+same memory class as the forward block it differentiates).
 
 Interpret mode runs the same kernels on the CPU backend (the tests'
 virtual mesh): it is chosen when the caller asks for it or when the
@@ -64,7 +73,6 @@ _LANES = 128  # TPU lane width: the running max and sum are kept lane-
 _PREF_BLOCK = 512           # block_q / block_k where the caller names none
 _PREF_ROWS = 8              # rows of bh one grid step takes, at most
 _VMEM_BUDGET = 12 * 2 ** 20  # under Mosaic's 16 MiB default scoped limit
-_BWD_BLOCK_K = 128          # the backward scan's K/V block (not this kernel's)
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -243,8 +251,9 @@ def _step_vmem_bytes(rows, block_q, block_k, d, in_size, out_size):
     return blocks + scratch + 4 * block_q * block_k * 4
 
 
-def _plan(bh, t_q, t_k, d, in_size, out_size, block_q, block_k):
-    """``(block_q, block_k, rows)`` of one forward call. A block size the
+def _fit_plan(bh, t_q, t_k, block_q, block_k, step_bytes):
+    """``(block_q, block_k, rows)`` of one call whose grid step keeps
+    ``step_bytes(rows, block_q, block_k)`` in VMEM. A block size the
     caller passed is a preference as before; one left to the kernel
     starts from ``_PREF_BLOCK`` and is halved while a grid step of one row
     overruns ``_VMEM_BUDGET``. ``rows`` is how many rows of the folded
@@ -255,8 +264,7 @@ def _plan(bh, t_q, t_k, d, in_size, out_size, block_q, block_k):
                 _pick_block(t_k, block_k or pref))
 
     def fits(rows):
-        return _step_vmem_bytes(
-            rows, bq, bk, d, in_size, out_size) <= _VMEM_BUDGET
+        return step_bytes(rows, bq, bk) <= _VMEM_BUDGET
 
     pref = _PREF_BLOCK
     bq, bk = blocks(pref)
@@ -270,6 +278,16 @@ def _plan(bh, t_q, t_k, d, in_size, out_size, block_q, block_k):
     while bh % rows or (rows > 1 and not fits(rows)):
         rows -= 1
     return bq, bk, rows
+
+
+def _plan(bh, t_q, t_k, d, in_size, out_size, block_q, block_k):
+    """``(block_q, block_k, rows)`` of one forward call
+    (:func:`_fit_plan` with the forward's VMEM count)."""
+    return _fit_plan(
+        bh, t_q, t_k, block_q, block_k,
+        lambda rows, bq, bk: _step_vmem_bytes(
+            rows, bq, bk, d, in_size, out_size),
+    )
 
 
 def _pairs_visited(t_q, t_k, block_q, block_k, causal):
@@ -422,53 +440,338 @@ def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     return o, (q, k, v, o, lse)
 
 
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T: both contract their minor dim
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+def _on_visible_pairs(update, causal, qi, ki, block_q, block_k, q_axis):
+    """Run ``update(bias)`` for this (q block, k block) pair as the forward
+    does at ``delta`` 0: not at all wholly above the diagonal, without a
+    mask wholly below it, with an additive one where the pair straddles
+    it. ``q_axis`` is the axis of the score tile that runs over the
+    queries."""
+    if not causal:
+        update(None)
+        return
+    first_k = ki * block_k - qi * block_q
+    visible = first_k <= block_q - 1
+    whole = first_k + block_k - 1 <= 0
+
+    @pl.when(whole)
+    def _below_diagonal():
+        update(None)
+
+    @pl.when(jnp.logical_and(visible, jnp.logical_not(whole)))
+    def _on_diagonal():
+        # 0 where the key is visible, far under any logsumexp where not:
+        # exp(s - lse) is 0 there without a select.
+        shape = (block_q, block_k) if q_axis == 0 else (block_k, block_q)
+        rel = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis) \
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+        update(jnp.where(rel >= first_k, 0.0, 2 * _NEG_INF))
+
+
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, *outs,
+                sm_scale: float, causal: bool, block_q: int, block_k: int,
+                rows: int, one_pass: bool):
+    """dK and dV of one K/V block: the Q axis is the innermost grid axis
+    and ``dk``/``dv`` stay in f32 scratch across it. Scores are held
+    transposed, ``[block_k, block_q]``: the lane-dense ``lse`` and ``D``
+    rows broadcast along sublanes, and the four products are plain
+    ``a @ b`` or ``a @ b.T``.
+
+    With ``one_pass`` the same walk gives dQ too: the rows' WHOLE ``dq``
+    lies in f32 scratch across both inner axes and takes ``ds.T @ k`` at
+    each pair, so scores and ``dp`` are computed once, 5 products a pair;
+    it is written after the last pair."""
+    if one_pass:
+        dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc = outs
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = outs
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+    last_q = qi == pl.num_programs(2) - 1
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+        if one_pass:
+            @pl.when(ki == 0)
+            def _init_dq():
+                dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def update(bias):
+        def one(g, carry):
+            q, k, v, do = q_ref[g], k_ref[g], v_ref[g], do_ref[g]
+            st = jax.lax.dot_general(
+                k, q, _NT, preferred_element_type=jnp.float32,
+            ) * sm_scale                                # [Bk, Bq] f32
+            if bias is not None:
+                st = st + bias
+            pt = jnp.exp(st - lse_ref[g, 0])            # lse: [1, Bq]
+            dv_acc[g] += jax.lax.dot_general(
+                pt.astype(do.dtype), do, _NN,
+                preferred_element_type=jnp.float32,
+            )
+            dpt = jax.lax.dot_general(
+                v, do, _NT, preferred_element_type=jnp.float32,
+            )
+            # ds without its factor sm_scale: dk and dq take that once,
+            # as they leave.
+            ds = (pt * (dpt - dd_ref[g, 0])).astype(q.dtype)
+            dk_acc[g] += jax.lax.dot_general(
+                ds, q, _NN, preferred_element_type=jnp.float32,
+            )
+            if one_pass:
+                at = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+                dq_acc[g, at] += jax.lax.dot_general(
+                    ds, k, _TN, preferred_element_type=jnp.float32,
+                )
+            return carry
+
+        lax.fori_loop(0, rows, one, None)
+
+    _on_visible_pairs(update, causal, qi, ki, block_q, block_k, q_axis=1)
+
+    @pl.when(last_q)
+    def _finalize():
+        dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        if one_pass:
+            @pl.when(ki == pl.num_programs(1) - 1)
+            def _finalize_dq():
+                dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
+               dq_ref, dq_acc, lse_col, dd_col, *,
+               sm_scale: float, causal: bool, block_q: int, block_k: int,
+               rows: int):
+    """dQ of one Q block: the K/V axis is the innermost grid axis, ``dq``
+    stays in f32 scratch across it and is written once. The lane-dense
+    ``lse`` and ``D`` rows are turned into lane-replicated columns once a
+    Q block (the forward's last-step transpose, the other way)."""
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        def one(g, carry):
+            lse_col[g] = jnp.broadcast_to(
+                lse_ref[g, 0], (_LANES, block_q)).T     # [Bq, 128]
+            dd_col[g] = jnp.broadcast_to(
+                dd_ref[g, 0], (_LANES, block_q)).T
+            return carry
+
+        lax.fori_loop(0, rows, one, None)
+
+    def update(bias):
+        def one(g, carry):
+            q, k, v, do = q_ref[g], k_ref[g], v_ref[g], do_ref[g]
+            s = jax.lax.dot_general(
+                q, k, _NT, preferred_element_type=jnp.float32,
+            ) * sm_scale                                # [Bq, Bk] f32
+            if bias is not None:
+                s = s + bias
+            p = jnp.exp(s - _lanes(lse_col[g], block_k))
+            dp = jax.lax.dot_general(
+                do, v, _NT, preferred_element_type=jnp.float32,
+            )
+            ds = p * (dp - _lanes(dd_col[g], block_k))  # sm_scale: below
+            dq_acc[g] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, _NN,
+                preferred_element_type=jnp.float32,
+            )
+            return carry
+
+        lax.fori_loop(0, rows, one, None)
+
+    _on_visible_pairs(update, causal, qi, ki, block_q, block_k, q_axis=0)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _finalize():
+        dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _bwd_step_vmem_bytes(rows, block_q, block_k, d, in_size, whole_t_q=0):
+    """What one grid step of the backward keeps in VMEM: the pipeline's two
+    buffers of the q, do, k, v blocks, of the ``lse`` and ``D`` rows (a
+    ``[1, Bq]`` f32 row fills 8 sublanes) and of the outputs, the f32
+    accumulators, and the [Bq, Bk] temporaries of one row of ``bh``
+    (scores, probabilities, ``dp``, ``ds``, the mask's bias in f32 and the
+    MXU operands cast from them). With ``whole_t_q`` the one-pass kernel,
+    which holds dK/dV's buffers, a ``dq`` of that many queries and ``ds``
+    transposed; without, the larger of the dK/dV and the dQ kernel (the
+    latter with its two statistic columns)."""
+    dl = -(-d // _LANES) * _LANES
+    held = dl * (2 * in_size + 4)    # an output's two buffers and its f32 sum
+    inputs = 2 * rows * (
+        2 * (block_q + block_k) * dl * in_size + 2 * 8 * block_q * 4
+    )
+    dkv = 2 * rows * block_k * held
+    if whole_t_q:
+        return (inputs + dkv + rows * whole_t_q * held
+                + 7 * block_q * block_k * 4)
+    dq = rows * block_q * (held + 2 * _LANES * 4)
+    return inputs + max(dkv, dq) + 6 * block_q * block_k * 4
+
+
+def _plan_bwd(bh, t_q, t_k, d, in_size, block_q, block_k):
+    """``(block_q, block_k, rows, one_pass)`` of one backward call:
+    :func:`_plan`'s rule with the backward's own VMEM count gives the two
+    kernels' tiles; where some rows' whole ``dq`` fits beside them at those
+    tiles, the one-pass kernel runs instead (5 products a pair for 7)."""
+    bq, bk, rows = _fit_plan(
+        bh, t_q, t_k, block_q, block_k,
+        lambda rows, bq, bk: _bwd_step_vmem_bytes(rows, bq, bk, d, in_size),
+    )
+    for r in range(rows, 0, -1):
+        if bh % r == 0 and _bwd_step_vmem_bytes(
+                r, bq, bk, d, in_size, t_q) <= _VMEM_BUDGET:
+            return bq, bk, r, True
+    return bq, bk, rows, False
+
+
+def _backward(bh, t_q, t_k, d, dtypes, sm_scale, causal, block_q, block_k,
+              rows, one_pass, interpret, vma):
+    """The backward ``pallas_call``s at one plan (the one-pass kernel, or
+    the dK/dV and the dQ kernel), as a function of ``(q, k, v, do, lse,
+    D)`` returning ``(dq, dk, dv)``; ``lse`` and ``D`` are
+    ``[bh, t_q / block_q, 1, block_q]`` f32."""
+    n_q, n_k = t_q // block_q, t_k // block_k
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  block_k=block_k, rows=rows)
+
+    def first_q(j):
+        # dK/dV grid (b, j, i): a Q block wholly above K block j's
+        # diagonal is not computed; point it at the first one that is
+        # needed, so the pipeline fetches nothing for it.
+        if not causal:
+            return 0
+        return lax.min(lax.div(j * block_k, block_q), n_q - 1)
+
+    def last_k(i):
+        # dQ grid (b, i, j): the forward's clamp of the K/V side.
+        if not causal:
+            return n_k - 1
+        return lax.min(lax.div(i * block_q + block_q - 1, block_k), n_k - 1)
+
+    def specs(q_of, k_of):
+        """The six inputs' specs from where a grid step's Q and K/V blocks
+        lie."""
+        q_spec = pl.BlockSpec(
+            (rows, block_q, d), lambda b, x, y: (b, q_of(x, y), 0))
+        k_spec = pl.BlockSpec(
+            (rows, block_k, d), lambda b, x, y: (b, k_of(x, y), 0))
+        stat = pl.BlockSpec(
+            (rows, 1, 1, block_q), lambda b, x, y: (b, q_of(x, y), 0, 0))
+        return [q_spec, k_spec, k_spec, q_spec, stat, stat]
+
+    dq_dtype, dk_dtype, dv_dtype = dtypes
+    dq_shape = jax.ShapeDtypeStruct((bh, t_q, d), dq_dtype, vma=vma)
+    kv_spec = pl.BlockSpec((rows, block_k, d), lambda b, j, i: (b, j, 0))
+    kv_acc = pltpu.VMEM((rows, block_k, d), jnp.float32)
+    # With one_pass a third output and accumulator: the rows' whole dq,
+    # resident across both inner axes (so neither is parallel).
+    dkv = pl.pallas_call(
+        functools.partial(_dkv_kernel, one_pass=one_pass, **static),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t_k, d), dk_dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_k, d), dv_dtype, vma=vma),
+        ] + [dq_shape] * one_pass,
+        grid=(bh // rows, n_k, n_q),
+        in_specs=specs(lambda j, i: lax.max(i, first_q(j)),
+                       lambda j, i: j),
+        out_specs=[kv_spec, kv_spec] + [
+            pl.BlockSpec((rows, t_q, d), lambda b, j, i: (b, 0, 0))
+        ] * one_pass,
+        scratch_shapes=[kv_acc, kv_acc] + [
+            pltpu.VMEM((rows, t_q, d), jnp.float32)] * one_pass,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "arbitrary" if one_pass else "parallel", "arbitrary",
+        )),
+        interpret=interpret,
+    )
+    dq = None if one_pass else pl.pallas_call(
+        functools.partial(_dq_kernel, **static),
+        out_shape=dq_shape,
+        grid=(bh // rows, n_q, n_k),
+        in_specs=specs(lambda i, j: i,
+                       lambda i, j: lax.min(j, last_k(i))),
+        out_specs=pl.BlockSpec((rows, block_q, d),
+                               lambda b, i, j: (b, i, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((rows, block_q, d), jnp.float32),
+            pltpu.VMEM((rows, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((rows, block_q, _LANES), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )
+
+    def run(*args):
+        dk, dv, *whole_dq = dkv(*args)
+        return (whole_dq[0] if one_pass else dq(*args)), dk, dv
+
+    return run
+
+
+@functools.lru_cache(maxsize=64)
+def _backward_jaxpr(mesh, *plan):
+    """:func:`_backward` traced once per distinct call, as
+    :func:`_forward_jaxpr` keeps the forward: the kernel bodies of a layer
+    would otherwise be traced 24 times a program. ``do`` has the dtype of
+    the output it is the cotangent of, which is q's."""
+    bh, t_q, t_k, d, (q_dtype, k_dtype, v_dtype), _, _, block_q = plan[:8]
+    stat = ((bh, t_q // block_q, 1, block_q), jnp.float32)
+    return jax.make_jaxpr(_backward(*plan, vma=frozenset()))(*(
+        jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
+            ((bh, t_q, d), q_dtype), ((bh, t_k, d), k_dtype),
+            ((bh, t_k, d), v_dtype), ((bh, t_q, d), q_dtype), stat, stat,
+        )
+    ))
+
+
 @jax.named_scope(SCOPE_FLASH_BWD)
 def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
-    """Flash backward: probabilities are recomputed per K/V block from the
-    saved logsumexp inside a ``lax.scan`` — live memory is O(T * block_k),
-    no [T, T] tensor is ever materialized."""
+    """Flash backward: the kernels of :func:`_backward` on the residuals
+    the forward left. Probabilities are recomputed per block pair from the
+    saved logsumexp; no [T, T] tensor and no f32 ``dq`` carry ever reach
+    HBM."""
     q, k, v, o, lse = res
     bh, t_q, d = q.shape
     t_k = k.shape[1]
-    bk = _pick_block(t_k, block_k or _BWD_BLOCK_K)
-    n_blocks = t_k // bk
-
-    qf = q.astype(jnp.float32)
-    dof = do.astype(jnp.float32)
-    # D_i = sum_j dO_ij O_ij (the softmax-jacobian row term).
-    D = jnp.sum(dof * o.astype(jnp.float32), axis=-1)   # [bh, tq]
-    q_pos = jnp.arange(t_q)
-
-    def body(dq_acc, idx):
-        kb = lax.dynamic_slice_in_dim(k, idx * bk, bk, axis=1)
-        vb = lax.dynamic_slice_in_dim(v, idx * bk, bk, axis=1)
-        kbf = kb.astype(jnp.float32)
-        vbf = vb.astype(jnp.float32)
-        s = jnp.einsum("bqd,bkd->bqk", qf, kbf) * sm_scale
-        if causal:
-            k_pos = idx * bk + jnp.arange(bk)
-            mask = q_pos[:, None] >= k_pos[None, :]
-            s = jnp.where(mask[None], s, _NEG_INF)
-        p = jnp.exp(s - lse[:, :, None])                # [bh, tq, bk]
-        if causal:
-            p = jnp.where(mask[None], p, 0.0)
-        dp = jnp.einsum("bqd,bkd->bqk", dof, vbf)
-        ds = p * (dp - D[:, :, None]) * sm_scale
-        dq_acc = dq_acc + jnp.einsum("bqk,bkd->bqd", ds, kbf)
-        dk_b = jnp.einsum("bqk,bqd->bkd", ds, qf)
-        dv_b = jnp.einsum("bqk,bqd->bkd", p, dof)
-        return dq_acc, (dk_b, dv_b)
-
-    dq0 = jnp.zeros(q.shape, jnp.float32)
-    # The scan carry must enter with the type it leaves with: inside a
-    # checked shard_map dq varies over every axis the operands vary over.
-    vma = _vma(q, k, v, do)
-    if vma:
-        dq0 = lax.pcast(dq0, tuple(sorted(vma)), to="varying")
-    dq, (dks, dvs) = lax.scan(body, dq0, jnp.arange(n_blocks))
-    dk = jnp.moveaxis(dks, 0, 1).reshape(k.shape)
-    dv = jnp.moveaxis(dvs, 0, 1).reshape(v.shape)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    block_q, block_k, rows, one_pass = _plan_bwd(
+        bh, t_q, t_k, d, q.dtype.itemsize, block_q, block_k)
+    if _trace.ACTIVE:
+        # Beside the forward's note: what one grid step of this program's
+        # backward is, which form runs, and the steps of all its kernels.
+        _trace.TAP.note_plan(
+            flash_bwd_block_q=block_q, flash_bwd_block_k=block_k,
+            flash_bwd_rows_per_step=rows,
+            flash_bwd_one_pass=one_pass,
+            flash_bwd_grid_steps=(1 if one_pass else 2) * (bh // rows)
+            * (t_q // block_q) * (t_k // block_k),
+            flash_bwd_pairs_visited=round(
+                _pairs_visited(t_q, t_k, block_q, block_k, causal), 4),
+        )
+    # D_i = sum_j dO_ij O_ij (the softmax-jacobian row term), and the
+    # statistics as the lane-dense rows the forward kernel writes.
+    dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    stat_shape = (bh, t_q // block_q, 1, block_q)
+    args = (q, k, v, do, lse.reshape(stat_shape), dd.reshape(stat_shape))
+    plan = (bh, t_q, t_k, d, (q.dtype, k.dtype, v.dtype), sm_scale, causal,
+            block_q, block_k, rows, one_pass, interpret)
+    vma = _vma(*args)
+    if vma:   # typed per mesh axis: traced where the axes are bound
+        return _backward(*plan, vma=vma)(*args)
+    closed = _backward_jaxpr(jax.sharding.get_abstract_mesh(), *plan)
+    return tuple(jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *args))
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -486,7 +789,8 @@ def flash_attention(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused attention over ``[..., T, D]`` (leading dims fold into one
-    batch x heads grid axis). Differentiable; backward recomputes blockwise.
+    batch x heads grid axis). Differentiable; the backward is kernels too,
+    which recompute the probabilities per block pair.
 
     ``interpret=None`` interprets on the CPU backend only, so the same
     code runs in tests on the virtual CPU mesh.

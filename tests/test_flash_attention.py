@@ -48,25 +48,92 @@ def test_forward_matches_dense(causal, blocks):
     )
 
 
+# (bh, t_q, t_k, d, block_q, block_k, _PREF_BLOCK): what the two backward
+# kernels must get right beside the plain case.
+_GRAD_SHAPES = {
+    "small": (2, 16, 16, 8, 8, 8, None),
+    # the cells' head widths, tiles of 16 standing for 512: several rows
+    # of bh a grid step, 4 x 4 block pairs
+    "d64-rows": (8, 64, 64, 64, None, None, 16),
+    "d256": (2, 32, 32, 256, None, None, 16),
+    "bq<bk": (4, 64, 64, 16, 16, 32, None),
+    "bq>bk": (4, 64, 64, 16, 32, 16, None),
+    "blocks-8-16": (4, 32, 32, 16, 8, 16, None),
+    # causal: the later Q blocks see every key unmasked
+    "tq>tk": (4, 64, 32, 16, 16, 16, None),
+    # causal: the second K/V block is seen by the last Q block only, the
+    # later ones by none (their dk and dv are zeros the kernel must write)
+    "tq<tk": (4, 32, 64, 16, 16, 16, None),
+}
+
+
+@pytest.mark.parametrize("shape", list(_GRAD_SHAPES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_grad_matches_dense(causal):
-    q, k, v = _qkv_bhtd(bh=2, t=16, d=8)
+@pytest.mark.parametrize("one_pass", [True, False],
+                         ids=["one-pass", "two-kernels"])
+def test_grad_matches_dense(monkeypatch, one_pass, causal, dtype, shape):
+    """dq, dk and dv of the backward kernels against JAX's own gradient of
+    the dense reference, under a cotangent that is not uniform. Shapes
+    this small always plan the one-pass kernel (a whole dq fits VMEM), so
+    the form is forced either way."""
+    bh, t_q, t_k, d, bq, bk, pref = _GRAD_SHAPES[shape]
+    if pref is not None:
+        monkeypatch.setattr(pa, "_PREF_BLOCK", pref)
+    plan_bwd = pa._plan_bwd
+    assert plan_bwd(bh, t_q, t_k, d, 4, bq, bk)[3]
+    monkeypatch.setattr(
+        pa, "_plan_bwd", lambda *a: plan_bwd(*a)[:3] + (one_pass,))
+    rng = np.random.RandomState(11)
+    mk = lambda t: jnp.asarray(
+        rng.randn(bh, t, d).astype(np.float32) * 0.5).astype(dtype)
+    q, k, v = mk(t_q), mk(t_k), mk(t_k)
+    w = mk(t_q).astype(jnp.float32)
 
     def loss_flash(q, k, v):
-        return jnp.sum(
-            flash_attention(q, k, v, causal=causal, block_q=8, block_k=8)
-            ** 2
-        )
+        out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
+        return jnp.sum(out.astype(jnp.float32) * w)
 
     def loss_dense(q, k, v):
-        return jnp.sum(_dense(q, k, v, causal) ** 2)
+        out = pa._dense_full(q, k, v, causal, d ** -0.5)
+        return jnp.sum(out.astype(jnp.float32) * w)
 
     gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    tol = dict(rtol=1e-4, atol=1e-5) if dtype == jnp.float32 else dict(
+        rtol=2e-2, atol=2e-2)
     for a, b in zip(gf, gd):
+        assert a.dtype == dtype
         np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-4
-        )
+            np.asarray(a, np.float32), np.asarray(b, np.float32), **tol)
+
+
+def test_grad_under_checked_shard_map():
+    """Inside a vma-checked shard_map the kernels' outputs are typed
+    varying over the axes their inputs vary over (traced in place, not
+    from the kept jaxpr): the sharded gradient is the unsharded one."""
+    from jax.sharding import PartitionSpec as P
+
+    from horovod_tpu.jax import _shard_map
+    from horovod_tpu.parallel.mesh import build_mesh
+
+    n = len(jax.devices())
+    mesh = build_mesh({"data": n})
+    q, k, v = _qkv_bhtd(bh=2 * n, t=32, d=16, seed=4)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, block_q=16, block_k=8)
+        return jnp.sum(out ** 2)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))
+    sharded = jax.jit(_shard_map(
+        grads, mesh, in_specs=(P("data"),) * 3, out_specs=(P("data"),) * 3,
+        check=True,
+    ))(q, k, v)
+    for a, b in zip(sharded, grads(q, k, v)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
 
 
 def test_bf16_dtype_preserved():
@@ -244,6 +311,27 @@ def test_kernel_lowers_for_tpu_target():
     lowered = f.trace(q, q, q).lower(lowering_platforms=("tpu",))
     text = lowered.as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape,kernels", [
+    ((128, 1024, 64), 2),      # forward + the one-pass backward
+    ((16, 8192, 256), 3),      # forward + dK/dV + dQ
+])
+def test_backward_kernels_lower_for_tpu_target(shape, kernels):
+    """The backward at the benchmark cells' own shapes, bf16: the forward
+    and the backward calls serialize for Mosaic (lane-dense statistic
+    blocks, the clamped index maps, the whole-dq block), and no loop of
+    XLA's is left in the gradient."""
+    from functools import partial
+
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    attn = partial(flash_attention, causal=True, interpret=False)
+    grad = jax.jit(jax.grad(
+        lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))
+    text = grad.trace(q, q, q).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == kernels
+    assert "stablehlo.while" not in text
 
 
 def test_ring_attention_lowers_for_tpu_target():

@@ -2,7 +2,8 @@
 configurations: the GPT-2-medium cells' call is what it was before the second
 model came (512 x 512 tiles, 4 rows a grid step), and the Qwen3-Next cell's
 call (16 heads of width 256 at T 8192) fits the kernel's VMEM budget as it
-stands, with fewer rows a step."""
+stands, with fewer rows a step. The backward kernels' plan (``_plan_bwd``)
+fits the same budget at both shapes by its own count."""
 
 import pytest
 
@@ -33,3 +34,35 @@ def test_plan_at_head_width_256_and_8192_tokens_fits_vmem():
 ])
 def test_plan_follows_width_and_operand_size(d, in_size, expect):
     assert pa._plan(64, 2048, 2048, d, in_size, in_size, None, None) == expect
+
+
+@pytest.mark.parametrize("bh,t,d,expect", [
+    # the GPT-2-medium cells: a row's whole dq (0.5 MB f32) fits beside
+    # 512 x 512 tiles, so one kernel does 5 products a pair
+    (128, 1024, 64, (512, 512, 1, True)),
+    # the Qwen3-Next cell: a row's dq is 8 MB, so dK/dV and dQ kernels
+    (16, 8192, 256, (512, 512, 1, False)),
+    # d 64 at T 4096: a row's dq no longer fits; two kernels, 2 rows a step
+    (16, 4096, 64, (512, 512, 2, False)),
+])
+def test_backward_plan_at_the_cells_shapes_fits_vmem(bh, t, d, expect):
+    """Beside q, k, v the backward holds do, two accumulators and six
+    [Bq, Bk] f32 temporaries: fewer rows of bh a step than the forward;
+    the form follows from whether some rows' whole dq fits as well."""
+    bq, bk, rows, one_pass = pa._plan_bwd(bh, t, t, d, 2, None, None)
+    assert (bq, bk, rows, one_pass) == expect
+    whole = t if one_pass else 0
+    count = lambda r: pa._bwd_step_vmem_bytes(r, bq, bk, d, 2, whole)
+    assert count(rows) <= pa._VMEM_BUDGET
+    nxt = next(r for r in range(rows + 1, bh + 1) if bh % r == 0)
+    assert count(nxt) > pa._VMEM_BUDGET
+    assert pa._bwd_step_vmem_bytes(1, bq, bk, d, 2, t) > pa._VMEM_BUDGET or (
+        one_pass)
+    assert rows <= pa._plan(bh, t, t, d, 2, 2, None, None)[2]
+
+
+def test_backward_plan_keeps_the_callers_blocks_and_halves_its_own():
+    assert pa._plan_bwd(4, 32, 64, 16, 4, 8, 16) == (8, 16, 4, True)
+    # f32 operands at width 256: a 512 x 512 step of one row overruns
+    assert pa._bwd_step_vmem_bytes(1, 512, 512, 256, 4) > pa._VMEM_BUDGET
+    assert pa._plan_bwd(16, 2048, 2048, 256, 4, None, None)[:2] == (256, 256)

@@ -89,28 +89,34 @@ def test_flash_forward_compiles(topo, shape, stats):
     assert stats in compiled.as_text()
 
 
-def test_flash_backward_compiles(topo):
+@pytest.mark.parametrize("shape,kernels", [
+    ((96, 1024, 64), 2),
+    # The cells' own calls. GPT-2-medium's 8 sequences x 16 heads: the
+    # forward and the one-pass backward, a row's whole dq in VMEM. The
+    # Qwen3-Next attention layer's 16 heads of width 256 over 8192 tokens:
+    # a row's dq is 8 MB, so the dK/dV and the dQ kernel.
+    ((128, 1024, 64), 2),
+    ((16, 8192, 256), 3),
+    # f32 operands: a whole dq does not fit beside 512 x 512 tiles.
+    ((8, 1024, 64, "float32"), 3),
+])
+def test_flash_backward_compiles(topo, shape, kernels):
+    """The backward at the tiles and the form ``_plan_bwd`` picks: the
+    chip's compiler takes its VMEM (accumulators, the [Bq, Bk] f32
+    temporaries, the whole-dq block or the statistic columns), the
+    transposed product and the row-to-column transposes, and the gradient
+    holds kernels and no loop."""
     def loss(q, k, v):
         out = pa.flash_attention(q, k, v, causal=True, interpret=False)
         return out.astype(jnp.float32).sum()
 
-    _compile(
+    dtype = jnp.dtype(shape[3]) if len(shape) == 4 else jnp.bfloat16
+    compiled = _compile(
         jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-        *_qkv(topo, (96, 1024, 64)),
-    )
-
-
-def test_flash_backward_compiles_at_width_256(topo):
-    """The backward scan at the Qwen3-Next cell's shape: 64 blocks of 128
-    keys over 8192 queries, f32 temporaries of [16, 8192, 128]."""
-    def loss(q, k, v):
-        out = pa.flash_attention(q, k, v, causal=True, interpret=False)
-        return out.astype(jnp.float32).sum()
-
-    _compile(
-        jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-        *_qkv(topo, (16, 8192, 256)),
-    )
+        *_qkv(topo, shape[:3], dtype))
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == kernels
+    assert " while(" not in text
 
 
 def test_gated_delta_rule_compiles(topo):

@@ -532,6 +532,43 @@ def test_flash_kernel_notes_its_plan_once_per_compile(monkeypatch):
     }
 
 
+def test_flash_backward_notes_its_plan_beside_the_forwards(monkeypatch):
+    """The backward kernels' plan is noted when the gradient is traced,
+    under names of its own, once per compile."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import pallas_attention as pa
+
+    q = jnp.zeros((8, 64, 16), jnp.float32)
+    attn = functools.partial(pa.flash_attention, causal=True)
+    hvd_trace.install(True)
+    notes = []
+    note_plan = hvd_trace.TAP.note_plan
+    monkeypatch.setattr(
+        hvd_trace.TAP, "note_plan",
+        lambda **kw: (notes.append(kw), note_plan(**kw)),
+    )
+    monkeypatch.setattr(pa, "_PREF_BLOCK", 16)
+    grad = jax.jit(jax.grad(lambda q, k, v: attn(q, k, v).sum(),
+                            argnums=(0, 1, 2)))
+    grad(q, q, q)
+    grad(q, q, q)                       # the cached executable: no new note
+    assert [sorted(n)[0] for n in notes] == ["flash_block_k",
+                                             "flash_bwd_block_k"]
+    plan = hvd_trace.TAP.plan_args()
+    assert {k: v for k, v in plan.items() if k.startswith("flash_bwd")} == {
+        "flash_bwd_block_q": 16, "flash_bwd_block_k": 16,
+        "flash_bwd_rows_per_step": 8,
+        # a whole dq of 8 rows fits: one kernel, 1 x 4 q blocks x 4 k blocks
+        "flash_bwd_one_pass": True,
+        "flash_bwd_grid_steps": 16, "flash_bwd_pairs_visited": 0.625,
+    }
+    assert plan["flash_grid_steps"] == 16
+
+
 # --------------------------------------------------- timeline satellites
 def test_timeline_writer_crash_warns_once_and_counts_drops(caplog):
     hvd_metrics.install(True)
