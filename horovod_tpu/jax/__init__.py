@@ -22,8 +22,12 @@ Typical use::
 
 from __future__ import annotations
 
+import contextlib as _contextlib
+import functools as _functools
 import logging
+import math as _math
 from typing import Any, Callable, Optional
+from typing import NamedTuple as _NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -212,19 +216,9 @@ def _select_reduce_fn(op: ReduceOp, hierarchical, quantized: bool = False,
         )
     if hierarchical:
         # axis_name must be the (cross, local) tuple: reduce-scatter rides
-        # ICI (local), the shard psum rides DCN (cross).
-        def fn(x, *, op, axis_name, prescale_factor=1.0, postscale_factor=1.0):
-            cross_axis, local_axis = axis_name
-            if prescale_factor != 1.0:
-                x = x * prescale_factor
-            out = _c.hierarchical_allreduce(
-                x, op=op, local_axis=local_axis, cross_axis=cross_axis
-            )
-            if postscale_factor != 1.0:
-                out = out * postscale_factor
-            return out
-
-        return fn
+        # ICI (local), the shard psum rides DCN (cross). The streamed
+        # path's two-level reduce, post-hoc.
+        return _fusion._hier_reduce_fn
     return _c.allreduce
 
 
@@ -377,13 +371,150 @@ def _resolve_error_feedback(error_feedback: Optional[bool],
     return True if error_feedback is None else bool(error_feedback)
 
 
+def _open_state(opt_state, like, *, use_ef: bool, zero1_lead: int = 0,
+                expects: str = ""):
+    """Split what a step threads as ``opt_state`` into ``(inner, ef,
+    close)``: the state the update consumes, the error-feedback residual
+    (None without EF) and ``close(new_inner, new_ef)``, which puts both
+    back in the shape they came in. The residual rides the opt_state as
+    ``EFState(inner, residual)``; a plain opt_state (first step, old
+    checkpoint) materializes a zero residual shaped like ``like``. With
+    ``zero1_lead`` the state is a :class:`Zero1State` stacked on that
+    many leading axes (its row is ``s[0]``, or ``s[0, 0]`` on a (data,
+    model) mesh) and carries the SHARDED residual in ``.ef``."""
+    if zero1_lead:
+        if not isinstance(opt_state, Zero1State):
+            raise TypeError(f"{expects}; got {type(opt_state).__name__}")
+        row = jax.tree.map(lambda s: s[(0,) * zero1_lead], opt_state)
+        if use_ef and row.ef is None:
+            raise ValueError(
+                "the quantized zero1 wire carries a SHARDED "
+                "error-feedback residual in the optimizer state; "
+                "rebuild it with init_zero1_stream_state(..., "
+                "quantized=True) or pass error_feedback=False"
+            )
+
+        def close(new_opt, new_ef):
+            return jax.tree.map(
+                lambda s: s[(None,) * zero1_lead],
+                Zero1State(opt=new_opt, ef=new_ef if use_ef else row.ef),
+            )
+
+        return row.opt, (row.ef if use_ef else None), close
+    if not use_ef:
+        return opt_state, None, lambda new_inner, _: new_inner
+    if isinstance(opt_state, EFState):
+        opt_state, ef = opt_state.inner, opt_state.residual
+    else:
+        ef = ef_like(like)
+    return opt_state, ef, lambda new_inner, new_ef: EFState(
+        inner=new_inner, residual=new_ef
+    )
+
+
+def _exchange_posthoc(
+    grads: Any, ef: Any, *, nonfinite: str, labels, op: ReduceOp,
+    axis_name: Any, threshold_bytes: Optional[int], quantized: bool,
+    reduce: bool = True, flag_axes: Any = None, pre_flag: bool = True,
+    recheck_streamed: bool = False,
+    first_bucket_bytes: Optional[int] = None, zero1: bool = False,
+    fused_label: Optional[str] = None, compression=Compression.none,
+    hierarchical: Any = False, algorithm: Optional[str] = None,
+):
+    """The ONE place where a tree of local gradients becomes reduced
+    gradients under the non-finite guard: the step ``make_train_step``
+    builds and both ``DistributedOptimizer`` wrappers come through here.
+    Returns ``(reduced, new_ef, flag)``; ``flag`` is None unless the
+    resolved policy ``nonfinite`` is ``skip``/``abort``.
+
+    The wire follows what the caller states, under the keywords
+    ``_fusion.stream_param_groups`` takes for the streamed form of the
+    same exchange (one dict serves both). ``reduce=False``: the
+    gradients arrived ALREADY reduced from a streamed backward (the
+    custom_vjp backward rules issued the bucket collectives); only the
+    guard's post-reduce half applies, and ``ef`` passes through.
+
+    ``labels``: the metric labels of (the post-hoc ``warn`` note, the
+    streamed one, the skip/abort note). ``flag_axes`` (default
+    ``axis_name``): the axes the flag is agreed over. ``pre_flag=False``
+    leaves out the pre-reduce detection (zero1 is SUM/AVERAGE-only, so a
+    NaN from any rank propagates into its shard image).
+    ``recheck_streamed``: the registrations were not the caller's own,
+    so streamed gradients are guarded as if local."""
+    guarded = nonfinite in ("skip", "abort")
+    local = reduce or recheck_streamed
+    flag = None
+    if guarded and pre_flag and local:
+        # Pre-reduce local detection: catches a bad local gradient even
+        # under MIN/MAX reductions, where NaN may not propagate.
+        flag = _nf.local_flag(grads)
+    if nonfinite == "zero" and local:
+        # Sentinel BEFORE the wire (a poisoned rank's NaN never reaches
+        # its peers, and would poison its block's scale in the
+        # quantizer). Streamed groups sanitize pre-reduce when registered
+        # with the policy; sanitizing the already-reduced grads again is
+        # a harmless belt for manual registrations.
+        grads = _nf.sanitize(grads)
+    new_ef = ef
+    if not reduce:
+        pass
+    elif zero1:
+        # Per-bucket reduce-scatter into shard images; ef is SHARDED.
+        grads, new_ef = zero1_posthoc_reduce(
+            grads, op=op, axis_name=axis_name, quantized=quantized, ef=ef,
+            threshold_bytes=threshold_bytes,
+            first_bucket_bytes=first_bucket_bytes,
+        )
+    elif ef is not None:
+        # Reduce g + e over the int8 wire and carry the fresh residual.
+        grads, new_ef = _fusion.quantized_ef_allreduce(
+            grads, ef, op=op, axis_name=axis_name,
+            threshold_bytes=threshold_bytes, label="posthoc-ef",
+        )
+    elif fused_label is not None:
+        # The composed path's data-axis buckets: never re-planned.
+        grads = _fusion.fused_allreduce(
+            grads, op=op, axis_name=axis_name,
+            threshold_bytes=threshold_bytes,
+            reduce_fn=_q.quantized_reduce_fn("flat") if quantized else None,
+            label=fused_label,
+            wire_dtype="int8" if quantized else "f32",
+        )
+    else:
+        grads = allreduce_gradients(
+            grads, op=op, axis_name=axis_name,
+            fusion_threshold_bytes=threshold_bytes,
+            compression=compression, hierarchical=hierarchical,
+            quantized=quantized, nonfinite="off", topo_algorithm=algorithm,
+        )
+    if nonfinite == "warn":
+        # Post-reduce detection: a NaN from ANY rank propagates through
+        # SUM/AVERAGE, so every rank observes (and logs) the same event.
+        _nf.note_detection("warn", labels[0 if reduce else 1])(
+            _nf.local_flag(grads)
+        )
+    if guarded:
+        # Agreement seam (psum of the flag, the shape the preemption
+        # commit check uses): no rank applies a step another rank
+        # skipped. Post-reduce detection is OR-ed in so an overflow
+        # created BY the summation is also caught; for streamed
+        # gradients it is the only detection point (the flag cannot be
+        # carried out of the custom_vjp backward rules).
+        post = _nf.local_flag(grads)
+        flag = post if flag is None else jnp.maximum(flag, post)
+        flag = _nf.agree_flag(
+            flag, axis_name if flag_axes is None else flag_axes
+        )
+        _nf.note_detection(nonfinite, labels[2])(flag)
+    return grads, new_ef, flag
+
+
 def _zero1_distributed_optimizer(
     optimizer,
     *,
     op: ReduceOp,
     axis_name: str,
     fusion_threshold_bytes: Optional[int],
-    first_bucket_bytes: Optional[int],
     compression,
     hierarchical: Any,
     quantized: bool,
@@ -397,8 +528,6 @@ def _zero1_distributed_optimizer(
     public wrapper's docstring for the contract."""
     import optax
 
-    from ..parallel import zero as _zero
-
     if zero1_shards is None or int(zero1_shards) < 1:
         raise ValueError(
             "DistributedOptimizer(zero1=True) needs zero1_shards=<data-"
@@ -406,16 +535,7 @@ def _zero1_distributed_optimizer(
             "is bound, so the shard count cannot be inferred"
         )
     n_shards = int(zero1_shards)
-    if op not in (ReduceOp.SUM, ReduceOp.AVERAGE):
-        raise ValueError(
-            f"zero1=True shards the optimizer update over a summed "
-            f"gradient; op must be SUM/AVERAGE, got {ReduceOp(op).name}"
-        )
-    if compression is not Compression.none:
-        raise ValueError(
-            "zero1=True reduce-scatters raw buckets; cast compression "
-            "has no shard-image form — use quantized=True instead"
-        )
+    _check_zero1_args(op, compression, None)
     if bool(hierarchical):
         raise ValueError(
             "DistributedOptimizer(zero1=True) runs over the flat data "
@@ -442,10 +562,7 @@ def _zero1_distributed_optimizer(
             "(make_train_step); the zero1 optax wrapper supports "
             "off/zero/warn"
         )
-    knobs = dict(
-        threshold_bytes=fusion_threshold_bytes,
-        first_bucket_bytes=first_bucket_bytes,
-    )
+    knobs = dict(threshold_bytes=fusion_threshold_bytes, quantized=quantized)
     if _trace.ACTIVE:
         _trace.TAP.note_plan(
             optimizer="DistributedOptimizer",
@@ -454,9 +571,8 @@ def _zero1_distributed_optimizer(
         )
 
     def init_fn(params):
-        return _zero.init_zero1_stream_state(
-            optimizer, params, n_shards,
-            quantized=quantized, error_feedback=False, **knobs,
+        return init_zero1_stream_state(
+            optimizer, params, n_shards, error_feedback=False, **knobs
         )
 
     def update_fn(grads, state, params=None, **extra):
@@ -466,12 +582,11 @@ def _zero1_distributed_optimizer(
                 "argument: the shard-local update slices this rank's "
                 "parameter shard"
             )
-        if not isinstance(state, Zero1State):
-            raise TypeError(
-                "zero1 update expects the Zero1State this wrapper's "
-                f"init built; got {type(state).__name__}"
-            )
-        state_rows = jax.tree.map(lambda s: s[0], state)
+        opt_rows, _, close = _open_state(
+            state, None, use_ef=False, zero1_lead=1,
+            expects="zero1 update expects the Zero1State this wrapper's "
+                    "init built",
+        )
         do_reduce = True
         if overlap:
             reg = _fusion.take_stream_registrations()
@@ -482,27 +597,19 @@ def _zero1_distributed_optimizer(
                     "registered with stream_param_groups(zero1=True); "
                     "reduce-scattering post-hoc (correct, zero overlap)"
                 )
-        if nonfinite_policy == "zero" and do_reduce:
-            grads = _nf.sanitize(grads)
-        if do_reduce:
-            grads, _ = _zero.zero1_posthoc_reduce(
-                grads, op=op, axis_name=axis_name, quantized=quantized,
-                **knobs,
-            )
-        if nonfinite_policy == "warn":
-            _nf.note_detection("warn", "zero1-optimizer")(
-                _nf.local_flag(grads)
-            )
-        new_params, new_opt = _zero.zero1_stream_update(
-            optimizer, params, state_rows.opt, grads,
-            axis_name=axis_name, n_shards=n_shards,
-            quantized=quantized, **knobs,
+        # The thinner guard: sanitize, reduce, warn; no flag (skip/abort
+        # are rejected above).
+        grads, _, _ = _exchange_posthoc(
+            grads, None, reduce=do_reduce, nonfinite=nonfinite_policy,
+            labels=("zero1-optimizer", "zero1-optimizer", None),
+            op=op, axis_name=axis_name, zero1=True, **knobs,
         )
-        updates = jax.tree.map(
-            lambda a, b: a - b, new_params, params
+        new_params, new_opt = zero1_stream_update(
+            optimizer, params, opt_rows, grads,
+            axis_name=axis_name, n_shards=n_shards, **knobs,
         )
-        new_state = Zero1State(opt=new_opt, ef=state_rows.ef)
-        return updates, jax.tree.map(lambda s: s[None], new_state)
+        updates = jax.tree.map(lambda a, b: a - b, new_params, params)
+        return updates, close(new_opt, None)
 
     return optax.GradientTransformation(init_fn, update_fn)
 
@@ -592,7 +699,6 @@ def DistributedOptimizer(  # noqa: N802 - API parity with hvd.DistributedOptimiz
     zero overlap). Error feedback needs the backward side channel only
     ``make_train_step`` owns and is rejected here.
     """
-    import jax.numpy as jnp
     import optax
 
     from .. import tune as _tune
@@ -601,7 +707,6 @@ def DistributedOptimizer(  # noqa: N802 - API parity with hvd.DistributedOptimiz
         return _zero1_distributed_optimizer(
             optimizer, op=op, axis_name=axis_name,
             fusion_threshold_bytes=fusion_threshold_bytes,
-            first_bucket_bytes=None,
             compression=compression, hierarchical=hierarchical,
             quantized=_resolve_quantized(quantized),
             error_feedback=error_feedback, overlap=overlap,
@@ -705,20 +810,13 @@ def DistributedOptimizer(  # noqa: N802 - API parity with hvd.DistributedOptimiz
 
     def init_fn(params):
         if _knobs(params, "init")["use_ef"]:
-            return EFState(
-                inner=optimizer.init(params), residual=ef_like(params)
-            )
+            return error_feedback_state(optimizer.init(params), params)
         return optimizer.init(params)
 
     def update_fn(grads, state, params=None, **extra):
         k = _knobs(grads, "update")
         prescale = 1.0 / backward_passes_per_step if backward_passes_per_step > 1 else 1.0
-        ef = None
-        if k["use_ef"]:
-            if isinstance(state, EFState):
-                state, ef = state.inner, state.residual
-            else:
-                ef = ef_like(grads)
+        state, ef, close = _open_state(state, grads, use_ef=k["use_ef"])
         do_reduce = True
         if overlap:
             reg = _fusion.take_stream_registrations()
@@ -738,76 +836,29 @@ def DistributedOptimizer(  # noqa: N802 - API parity with hvd.DistributedOptimiz
             else:
                 for f in findings:
                     _logger.warning("%s", f.render())
-        flag = None
-        if nonfinite_policy in ("skip", "abort"):
-            # Pre-reduce local detection: catches a bad local gradient
-            # even under MIN/MAX reductions, where NaN may not propagate.
-            flag = _nf.local_flag(grads)
-        new_ef = ef
-        if do_reduce and ef is not None:
-            # Error-feedback path: sentinel BEFORE the quantizer (a NaN
-            # would poison its block's scale), then reduce g + e over
-            # the int8 wire and carry the fresh residual.
-            if nonfinite_policy == "zero":
-                grads = _nf.sanitize(grads)
-            reduced, new_ef = _fusion.quantized_ef_allreduce(
-                grads, ef,
-                op=op,
-                axis_name=k["norm_axis"],
-                threshold_bytes=k["fusion_threshold_bytes"],
-                label="posthoc-ef",
-            )
-            if nonfinite_policy == "warn":
-                _nf.note_detection("warn", "reduce")(
-                    _nf.local_flag(reduced)
-                )
-        elif do_reduce:
-            reduced = allreduce_gradients(
-                grads,
-                op=op,
-                axis_name=axis_name,
-                fusion_threshold_bytes=k["fusion_threshold_bytes"],
-                compression=compression,
-                hierarchical=k["hierarchical"],
-                quantized=k["quantized"],
-                nonfinite=nonfinite_policy,
-                topo_algorithm=k["topo_algorithm"],
-            )
-        else:
-            reduced = grads
-            if nonfinite_policy == "zero":
-                # Streamed groups sanitize pre-reduce when registered
-                # with the policy; sanitizing the already-reduced grads
-                # again is a harmless belt for manual registrations.
-                reduced = _nf.sanitize(reduced)
-            elif nonfinite_policy == "warn":
-                _nf.note_detection("warn", "overlap")(
-                    _nf.local_flag(reduced)
-                )
-        if flag is not None:
-            # Agreement seam: psum of the flag — no rank applies a step
-            # another rank skipped (same agreement shape the preemption
-            # commit check uses). Post-reduce detection is OR-ed in so an
-            # overflow created BY the summation is also caught.
-            flag = jnp.maximum(flag, _nf.local_flag(reduced))
-            flag = _nf.agree_flag(flag, k["norm_axis"])
-            _nf.note_detection(nonfinite_policy, "optimizer")(flag)
+        reduced, new_ef, flag = _exchange_posthoc(
+            grads, ef, reduce=do_reduce, recheck_streamed=True,
+            nonfinite=nonfinite_policy,
+            labels=("reduce", "overlap", "optimizer"),
+            op=op, axis_name=k["norm_axis"],
+            threshold_bytes=k["fusion_threshold_bytes"],
+            quantized=k["quantized"], compression=compression,
+            hierarchical=k["hierarchical"], algorithm=k["topo_algorithm"],
+        )
         if prescale != 1.0:
             reduced = jax.tree.map(lambda g: g * prescale, reduced)
         updates, new_state = optimizer.update(reduced, state, params, **extra)
         if flag is not None:
-            # Skipped step: zero updates, optimizer state held.
+            # Skipped step: zero updates, optimizer state held; it
+            # discards the gradient, so the residual computed from it
+            # must not carry either.
             updates = _nf.select_on_flag(
                 flag, jax.tree.map(jnp.zeros_like, updates), updates
             )
             new_state = _nf.select_on_flag(flag, state, new_state)
-        if k["use_ef"]:
-            if flag is not None:
-                # A skipped step discards the gradient, so the residual
-                # computed from it must not carry either.
+            if ef is not None:
                 new_ef = _nf.select_on_flag(flag, ef, new_ef)
-            new_state = EFState(inner=new_state, residual=new_ef)
-        return updates, new_state
+        return updates, close(new_state, new_ef)
 
     return optax.GradientTransformation(init_fn, update_fn)
 
@@ -829,336 +880,21 @@ def broadcast_variables(
     return jax.jit(fn)(variables)
 
 
-def _build_train_step(
-    loss_fn: Callable[..., jax.Array],
-    optimizer,
-    mesh: Mesh,
-    *,
-    axis_name: str = DATA_AXIS,
-    op: ReduceOp = Average,
-    fusion_threshold_bytes: Optional[int] = None,
-    compression=Compression.none,
-    hierarchical: Any = False,
-    quantized: Optional[bool] = None,
-    error_feedback: Optional[bool] = None,
-    donate: bool = True,
-    has_aux: bool = False,
-    overlap: bool = False,
-    first_bucket_bytes: Optional[int] = None,
-    nonfinite: Optional[str] = None,
-    topo_algorithm: Optional[str] = None,
-    zero1: bool = False,
-):
-    """Build a jitted SPMD training step: per-shard grads → fused allreduce
-    → optax update, with the batch sharded over ``axis_name`` and
-    params/opt-state replicated.
+class _Placement(_NamedTuple):
+    """Where a step's leaves live on the mesh: the step body reads this
+    and never asks which mode it is in. Built from nothing (replicated)
+    or from the rules table on the first call (composed). PartitionSpecs
+    act as pytree prefixes; loss, aux and the abort flag leave replicated."""
 
-    ``loss_fn(params, batch) -> loss`` (or ``(loss, aux)`` with
-    ``has_aux=True``; aux leaves are pmean-averaged) is evaluated on each
-    rank's local shard; gradient reduction uses the configured
-    op/compression — the whole reference ``DistributedOptimizer`` pipeline
-    as one XLA program. With ``hierarchical=True`` the mesh must have
-    (cross, local) axes (see ``build_hierarchical_mesh``).
-
-    ``overlap=True`` switches from the post-hoc whole-tree reduction to the
-    streamed path (docs/overlap.md): the top-level children of ``params``
-    are packed into DDP-style reverse-order groups (a smaller first bucket,
-    ``first_bucket_bytes`` / HOROVOD_FUSION_FIRST_BUCKET_BYTES) and each
-    group's psums are issued INSIDE the backward pass as soon as that
-    group's gradients exist — independent collectives XLA can overlap with
-    the remaining backward compute. Numerically identical to
-    ``overlap=False`` (elementwise reductions commute with the split).
-
-    ``quantized=True`` (None reads ``HOROVOD_QUANTIZED_WIRE``) moves each
-    gradient bucket over the int8 wire (``ops/quantized.py``) — composed
-    with ``overlap=True`` the quantize→ring-reduce→dequantize runs inside
-    the backward trace per streamed bucket, preserving the
-    scheduler-overlap property; composed with ``hierarchical`` only the
-    outermost (DCN) hop is compressed. On the flat wire an error-feedback
-    residual (``error_feedback``, default on; EF-SGD) rides the optimizer
-    state: the step accepts a plain ``optimizer.init(params)`` opt_state
-    and returns ``EFState(inner=..., residual=...)`` from the first call
-    on (or start from :func:`error_feedback_state` for a stable
-    structure, e.g. under ``lax.scan``).
-
-    ``nonfinite`` (None reads ``HOROVOD_GUARD_NONFINITE``, resolved when
-    the step is built) applies the non-finite gradient guard around the
-    reduce: ``zero`` sanitizes before the wire (per streamed group under
-    ``overlap=True``), ``warn`` logs detections, ``skip`` cross-rank
-    agrees on a skip flag and leaves params/opt-state UNCHANGED on every
-    rank for that step, ``abort`` additionally raises
-    ``HorovodInternalError`` from the returned step function so the
-    elastic layer rolls back — docs/fault_tolerance.md "Data-plane
-    integrity".
-    """
-    import jax.numpy as jnp
-    import optax
-
-    quantized = _resolve_quantized(quantized)
-    _check_overlap_rejections(overlap, quantized, op)
-    if quantized and compression is not Compression.none:
-        raise ValueError(
-            "quantized=True already compresses the wire to int8; "
-            "stacking cast compression would add loss for no bandwidth win"
-        )
-    if zero1:
-        return _build_zero1_train_step(
-            loss_fn, optimizer, mesh,
-            axis_name=axis_name, op=op,
-            fusion_threshold_bytes=fusion_threshold_bytes,
-            compression=compression, hierarchical=hierarchical,
-            quantized=quantized, error_feedback=error_feedback,
-            donate=donate, has_aux=has_aux, overlap=overlap,
-            first_bucket_bytes=first_bucket_bytes, nonfinite=nonfinite,
-            topo_algorithm=topo_algorithm,
-        )
-    # "auto": the mesh decides — a (pod,) cross, local hierarchy engages
-    # per-bucket compositor plan selection (flat/two-level/split by
-    # payload bytes, docs/topology.md); a flat data mesh stays flat. This
-    # is what makes make_train_step(overlap=True) go hierarchical
-    # automatically on multi-slice topologies.
-    hierarchical, hier_axes = _resolve_hierarchical(hierarchical, mesh)
-    if hierarchical == "planned" and hier_axes and axis_name == DATA_AXIS:
-        axis_name = hier_axes
-    axis_name = _normalize_axis(axis_name, hierarchical)
-    nonfinite_policy = _resolve_nonfinite(nonfinite)
-    use_ef = _resolve_error_feedback(error_feedback, quantized, hierarchical)
-    # A pinned compositor algorithm only reaches the lowering in planned
-    # mode; anywhere else (flat mesh, forced two-level) it is moot.
-    pin_algorithm = topo_algorithm if hierarchical == "planned" else None
-
-    def step(params, opt_state, batch):
-        # EF residual rides the opt_state as EFState(inner, residual);
-        # a plain opt_state (first step, old checkpoint) materializes a
-        # zero residual and the step returns EFState from then on.
-        ef = None
-        if use_ef:
-            if isinstance(opt_state, EFState):
-                opt_state, ef = opt_state.inner, opt_state.residual
-            else:
-                ef = ef_like(params)
-        if overlap and use_ef:
-            def streamed_loss_ef(p, e, b):
-                p = _fusion.stream_param_groups(
-                    p,
-                    op=op,
-                    axis_name=axis_name,
-                    threshold_bytes=fusion_threshold_bytes,
-                    first_bucket_bytes=first_bucket_bytes,
-                    hierarchical=hierarchical,
-                    compression=compression,
-                    quantized=True,
-                    ef=e,
-                    nonfinite=nonfinite_policy,
-                    algorithm=pin_algorithm,
-                )
-                return loss_fn(p, b)
-
-            # Differentiating w.r.t. the residual is the EF side
-            # channel: the streamed backward rule returns the NEXT
-            # residual as ef's "gradient" (ops/fusion.py).
-            grad_fn = jax.value_and_grad(
-                streamed_loss_ef, argnums=(0, 1), has_aux=has_aux
-            )
-        elif overlap:
-            def streamed_loss(p, b):
-                p = _fusion.stream_param_groups(
-                    p,
-                    op=op,
-                    axis_name=axis_name,
-                    threshold_bytes=fusion_threshold_bytes,
-                    first_bucket_bytes=first_bucket_bytes,
-                    hierarchical=hierarchical,
-                    compression=compression,
-                    quantized=quantized,
-                    nonfinite=nonfinite_policy,
-                    algorithm=pin_algorithm,
-                )
-                return loss_fn(p, b)
-
-            grad_fn = jax.value_and_grad(streamed_loss, has_aux=has_aux)
-        else:
-            grad_fn = jax.value_and_grad(loss_fn, has_aux=has_aux)
-        new_ef = ef
-        with jax.named_scope(_trace.SCOPE_LOSS_GRAD):
-            if overlap and use_ef:
-                out, (grads, new_ef) = grad_fn(params, ef, batch)
-            else:
-                out, grads = grad_fn(params, batch)
-        loss, aux = out if has_aux else (out, None)
-        flag = None
-        if not overlap:
-            if nonfinite_policy in ("skip", "abort"):
-                # Pre-reduce local detection (robust under MIN/MAX, where
-                # NaN may not propagate through the reduction).
-                flag = _nf.local_flag(grads)
-            if use_ef:
-                # Sentinel BEFORE the quantizer (a NaN would poison its
-                # block's scale), then reduce g + e over the int8 wire
-                # and carry the fresh residual.
-                if nonfinite_policy == "zero":
-                    grads = _nf.sanitize(grads)
-                grads, new_ef = _fusion.quantized_ef_allreduce(
-                    grads, ef,
-                    op=op,
-                    axis_name=axis_name,
-                    threshold_bytes=fusion_threshold_bytes,
-                    label="posthoc-ef",
-                )
-                if nonfinite_policy == "warn":
-                    _nf.note_detection("warn", "reduce")(
-                        _nf.local_flag(grads)
-                    )
-            else:
-                grads = allreduce_gradients(
-                    grads,
-                    op=op,
-                    axis_name=axis_name,
-                    fusion_threshold_bytes=fusion_threshold_bytes,
-                    compression=compression,
-                    hierarchical=hierarchical,
-                    quantized=quantized,
-                    nonfinite=nonfinite_policy,
-                    topo_algorithm=pin_algorithm,
-                )
-        else:
-            # Streamed: grads left value_and_grad already reduced (the
-            # custom_vjp backward rules issued the bucket psums); consume
-            # the registration ledger so a later overlap DistributedOptimizer
-            # trace doesn't credit THIS trace's registrations.
-            _fusion.take_stream_registrations()
-            if nonfinite_policy == "warn":
-                _nf.note_detection("warn", "overlap")(
-                    _nf.local_flag(grads)
-                )
-        if nonfinite_policy in ("skip", "abort"):
-            # Agreement seam (psum of the flag): no rank applies a step
-            # another rank skipped. Post-reduce detection is OR-ed in so
-            # an overflow created BY the summation is also caught; under
-            # overlap it is the only detection point (the flag cannot be
-            # carried out of the custom_vjp backward rules).
-            post = _nf.local_flag(grads)
-            flag = post if flag is None else jnp.maximum(flag, post)
-            flag = _nf.agree_flag(flag, axis_name)
-            _nf.note_detection(nonfinite_policy, "train_step")(flag)
-        loss = lax.pmean(loss, axis_name)
-        with jax.named_scope(_trace.SCOPE_OPTIMIZER):
-            updates, new_opt_state = optimizer.update(
-                grads, opt_state, params
-            )
-            new_params = optax.apply_updates(params, updates)
-        if flag is not None:
-            # Skipped step: params and optimizer state held on EVERY rank.
-            new_params = _nf.select_on_flag(flag, params, new_params)
-            new_opt_state = _nf.select_on_flag(
-                flag, opt_state, new_opt_state
-            )
-        if use_ef:
-            if flag is not None:
-                # A skipped step discards the gradient, so the residual
-                # computed from it must not carry either.
-                new_ef = _nf.select_on_flag(flag, ef, new_ef)
-            new_opt_state = EFState(inner=new_opt_state, residual=new_ef)
-        outs = [new_params, new_opt_state, loss]
-        if has_aux:
-            aux = jax.tree.map(lambda a: lax.pmean(a, axis_name), aux)
-            outs.append(aux)
-        if nonfinite_policy == "abort":
-            outs.append(flag)
-        return tuple(outs)
-
-    # Params/opt-state replicated; batch sharded on the data axis; every
-    # output replicated. PartitionSpecs act as pytree prefixes.
-    fn = _shard_map(
-        step, mesh, in_specs=(P(), P(), P(axis_name)), out_specs=P()
-    )
-    jitted = jax.jit(fn, donate_argnums=(0, 1) if donate else ())
-
-    def _maybe_trace(step_fn):
-        # Fleet-tracing step tap (docs/timeline.md "Step spans"):
-        # host-side step-boundary timestamps + step index, stamped with
-        # the build-time correlation ids so one trace links step →
-        # bucket → collective → hop. NULL_TAP discipline: disabled →
-        # the jitted function is returned UNCHANGED (wrap_step(f) is f).
-        return _trace.wrap_step(
-            step_fn,
-            overlap=overlap,
-            quantized=quantized,
-            hierarchical=str(hierarchical),
-            wire_dtype="int8" if quantized else "f32",
-            op=ReduceOp(op).name,
-            nonfinite=nonfinite_policy,
-        )
-
-    if nonfinite_policy != "abort":
-        return _maybe_trace(jitted)
-
-    def aborting_step(params, opt_state, batch):
-        import numpy as np
-
-        out = jitted(params, opt_state, batch)
-        flag = out[-1]
-        if float(np.asarray(flag)) > 0:
-            from .. import HorovodInternalError
-
-            if _trace.ACTIVE:
-                # Flight recorder: the abort is about to unwind into the
-                # elastic rollback — persist the last moments first.
-                _trace.TAP.flight_dump("guard-abort")
-            raise HorovodInternalError(
-                "non-finite gradient guard (policy abort): a rank "
-                "produced NaN/Inf gradients this step; the update was "
-                "not applied on any rank (cross-rank agreed) — rolling "
-                "back via the elastic layer if one is active"
-            )
-        return out[:-1]
-
-    return _maybe_trace(aborting_step)
+    params: Any       # spec (tree) of params, in and out
+    state: Any        # of the optimizer state, in and out
+    batch: Any        # of the batch
+    loss_axes: tuple  # loss and aux are pmean-ed over these, in turn
+    flag_axes: Any    # the skip/abort flag is agreed over these
+    zero1_lead: int   # stacked leading axes of a Zero1State row (0: none)
 
 
-def _build_zero1_train_step(
-    loss_fn: Callable[..., jax.Array],
-    optimizer,
-    mesh: Mesh,
-    *,
-    axis_name: str = DATA_AXIS,
-    op: ReduceOp = Average,
-    fusion_threshold_bytes: Optional[int] = None,
-    compression=Compression.none,
-    hierarchical: Any = False,
-    quantized: bool = False,
-    error_feedback: Optional[bool] = None,
-    donate: bool = True,
-    has_aux: bool = False,
-    overlap: bool = False,
-    first_bucket_bytes: Optional[int] = None,
-    nonfinite: Optional[str] = None,
-    topo_algorithm: Optional[str] = None,
-):
-    """The streamed-ZeRO-1 step (docs/overlap.md "Streamed ZeRO-1"):
-    ``step(params, zero1_state, batch)`` with the optimizer state
-    sharded per streamed bucket (``init_zero1_stream_state``). Under
-    ``overlap=True`` each bucket reduce-scatters INSIDE the backward
-    trace — each rank keeps only its shard's cotangents, (n-1)/n of the
-    gradient payload rides the wire, and the scheduler hides it behind
-    the remaining backward compute; ``overlap=False`` runs the identical
-    per-bucket reduction post-hoc (bitwise-equal, zero overlap). The
-    shard-local optax update and parameter all-gather run against the
-    same bucket plan (``parallel/zero.zero1_stream_update``).
-
-    ``quantized=True`` moves each bucket through the int8 ring
-    reduce-scatter with the error-feedback residual carried SHARDED in
-    the ``Zero1State`` (flat axis only — DCN-only compression has no
-    RS+AG form); ``hierarchical="auto"`` on a multi-slice mesh lowers
-    each bucket's RS/AG via the compositor's two-level schedules (only
-    the 1/L shard crosses DCN). ``topo_algorithm`` pins nothing here —
-    the RS lowering is determined by the axis shape — except ``"split"``
-    which has no reduce-scatter form and raises.
-    """
-    import jax.numpy as jnp
-
-    from ..parallel import zero as _zero
-
+def _check_zero1_args(op, compression, topo_algorithm):
     if op not in (ReduceOp.SUM, ReduceOp.AVERAGE):
         raise ValueError(
             f"zero1=True shards the optimizer update over a summed "
@@ -1174,180 +910,94 @@ def _build_zero1_train_step(
             "topo_algorithm='split' has no reduce-scatter decomposition; "
             "zero1 lowers flat or two-level by the mesh shape"
         )
+
+
+def _plan_replicated(
+    mesh: Mesh, *, axis_name, op, fusion_threshold_bytes, compression,
+    hierarchical, quantized, error_feedback, overlap, first_bucket_bytes,
+    nonfinite, topo_algorithm, zero1,
+) -> tuple:
+    """Argument checks of the step with replicated params, plain or
+    ``zero1``. Under ``zero1`` the quantized wire is flat-axis only
+    (DCN-only compression has no RS+AG form) and ``topo_algorithm`` pins
+    nothing — the RS lowering is determined by the axis shape — except
+    ``"split"``, which has no reduce-scatter form and raises. Returns the
+    mode's decisions as data: ``(place(params, opt_state) -> _Placement,
+    keywords of _build_step)``."""
+    quantized = _resolve_quantized(quantized)
+    _check_overlap_rejections(overlap, quantized, op)
+    if quantized and compression is not Compression.none:
+        raise ValueError(
+            "quantized=True already compresses the wire to int8; "
+            "stacking cast compression would add loss for no bandwidth win"
+        )
+    if zero1:
+        _check_zero1_args(op, compression, topo_algorithm)
+    # "auto": the mesh decides — a (pod,) cross, local hierarchy engages
+    # per-bucket compositor plan selection (flat/two-level/split by
+    # payload bytes, docs/topology.md); a flat data mesh stays flat. This
+    # is what makes make_train_step(overlap=True) go hierarchical
+    # automatically on multi-slice topologies.
     hierarchical, hier_axes = _resolve_hierarchical(hierarchical, mesh)
     if hierarchical == "planned" and hier_axes and axis_name == DATA_AXIS:
         axis_name = hier_axes
     axis_name = _normalize_axis(axis_name, hierarchical)
-    if quantized and not isinstance(axis_name, str):
+    if zero1 and quantized and not isinstance(axis_name, str):
         raise ValueError(
             "quantized zero1 runs the flat int8 ring reduce-scatter "
             "over ONE axis; hierarchical (DCN-only) compression is not "
             "defined for the RS+AG decomposition — drop hierarchical or "
             "quantized"
         )
-    nonfinite_policy = _resolve_nonfinite(nonfinite)
-    use_ef = _resolve_error_feedback(error_feedback, quantized, False)
+    policy = _resolve_nonfinite(nonfinite)
+    use_ef = _resolve_error_feedback(
+        error_feedback, quantized, False if zero1 else hierarchical
+    )
     axes = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
-    n_shards = 1
-    for a in axes:
-        n_shards *= int(mesh.shape[a])
-    knobs = dict(
-        threshold_bytes=fusion_threshold_bytes,
-        first_bucket_bytes=first_bucket_bytes,
+    over = P(axes[0] if len(axes) == 1 else axes)
+    n_shards = _math.prod(int(mesh.shape[a]) for a in axes) if zero1 else 0
+    # A Zero1State shards its leading [n_shards] axis as the batch does.
+    place = _Placement(
+        params=P(), state=over if zero1 else P(), batch=over,
+        loss_axes=(axis_name,), flag_axes=axis_name, zero1_lead=int(zero1),
     )
-    state_spec = P(axes[0] if len(axes) == 1 else axes)
-
-    def step(params, opt_state, batch):
-        if not isinstance(opt_state, Zero1State):
-            raise TypeError(
-                "zero1=True expects the sharded Zero1State from "
-                "hvd.init_zero1_stream_state(optimizer, params, "
-                f"{n_shards}, ...); got {type(opt_state).__name__}"
-            )
-        state = jax.tree.map(lambda s: s[0], opt_state)
-        ef = None
-        if use_ef:
-            if state.ef is None:
-                raise ValueError(
-                    "the quantized zero1 wire carries a SHARDED "
-                    "error-feedback residual in the optimizer state; "
-                    "rebuild it with init_zero1_stream_state(..., "
-                    "quantized=True) or pass error_feedback=False"
-                )
-            ef = state.ef
-        new_ef = ef
-        if overlap and use_ef:
-            def streamed_loss_ef(p, e, b):
-                p = _fusion.stream_param_groups(
-                    p, op=op, axis_name=axis_name,
-                    quantized=True, ef=e, nonfinite=nonfinite_policy,
-                    zero1=True, **knobs,
-                )
-                return loss_fn(p, b)
-
-            grad_fn = jax.value_and_grad(
-                streamed_loss_ef, argnums=(0, 1), has_aux=has_aux
-            )
-        elif overlap:
-            def streamed_loss(p, b):
-                p = _fusion.stream_param_groups(
-                    p, op=op, axis_name=axis_name,
-                    hierarchical=hierarchical, quantized=quantized,
-                    nonfinite=nonfinite_policy, zero1=True, **knobs,
-                )
-                return loss_fn(p, b)
-
-            grad_fn = jax.value_and_grad(streamed_loss, has_aux=has_aux)
-        else:
-            grad_fn = jax.value_and_grad(loss_fn, has_aux=has_aux)
-        with jax.named_scope(_trace.SCOPE_LOSS_GRAD):
-            if overlap and use_ef:
-                out, (grads, new_ef) = grad_fn(params, ef, batch)
-            else:
-                out, grads = grad_fn(params, batch)
-        loss, aux = out if has_aux else (out, None)
-        if not overlap:
-            if nonfinite_policy == "zero":
-                grads = _nf.sanitize(grads)
-            grads, new_ef = _zero.zero1_posthoc_reduce(
-                grads, op=op, axis_name=axis_name, quantized=quantized,
-                ef=ef, **knobs,
-            )
-        if overlap:
-            # Consume the registration ledger (same discipline as the
-            # streamed allreduce step).
-            _fusion.take_stream_registrations()
-        flag = None
-        if nonfinite_policy in ("skip", "abort"):
-            # Post-reduce detection: zero1 is SUM/AVERAGE-only, so a NaN
-            # from any rank propagates into its shard image; the psum
-            # agreement seam makes every rank skip together.
-            flag = _nf.agree_flag(_nf.local_flag(grads), axis_name)
-            _nf.note_detection(nonfinite_policy, "train_step")(flag)
-        elif nonfinite_policy == "warn":
-            _nf.note_detection("warn", "zero1")(_nf.local_flag(grads))
-        loss = lax.pmean(loss, axis_name)
-        with jax.named_scope(_trace.SCOPE_OPTIMIZER):
-            new_params, new_opt = _zero.zero1_stream_update(
-                optimizer, params, state.opt, grads,
-                axis_name=axis_name, n_shards=n_shards,
-                quantized=quantized, **knobs,
-            )
-        if flag is not None:
-            new_params = _nf.select_on_flag(flag, params, new_params)
-            new_opt = _nf.select_on_flag(flag, state.opt, new_opt)
-            if use_ef:
-                new_ef = _nf.select_on_flag(flag, ef, new_ef)
-        new_state = Zero1State(
-            opt=new_opt, ef=new_ef if use_ef else state.ef
-        )
-        outs = [
-            new_params,
-            jax.tree.map(lambda s: s[None], new_state),
-            loss,
-        ]
-        if has_aux:
-            aux = jax.tree.map(lambda a: lax.pmean(a, axis_name), aux)
-            outs.append(aux)
-        if nonfinite_policy == "abort":
-            outs.append(flag)
-        return tuple(outs)
-
-    fn = _shard_map(
-        step, mesh,
-        in_specs=(P(), state_spec, P(axes[0] if len(axes) == 1 else axes)),
-        out_specs=(P(), state_spec, P()) + ((P(),) * (
-            (1 if has_aux else 0) + (1 if nonfinite_policy == "abort" else 0)
-        )),
-    )
-    jitted = jax.jit(fn, donate_argnums=(0, 1) if donate else ())
-
-    def _maybe_trace(step_fn):
-        return _trace.wrap_step(
-            step_fn,
-            overlap=overlap,
-            quantized=quantized,
+    return (lambda params, opt_state: place), dict(
+        wire=dict(
+            op=op, axis_name=axis_name, quantized=quantized, zero1=zero1,
+            threshold_bytes=fusion_threshold_bytes,
+            first_bucket_bytes=first_bucket_bytes,
+            hierarchical=hierarchical, compression=compression,
+            nonfinite=policy,
+            # A pinned compositor algorithm only reaches the lowering in
+            # planned mode; anywhere else (flat mesh, forced two-level,
+            # zero1's reduce-scatter) it is moot.
+            algorithm=(
+                topo_algorithm
+                if hierarchical == "planned" and not zero1 else None
+            ),
+        ),
+        guard=dict(
+            labels=("zero1", "zero1", "train_step"), pre_flag=False,
+        ) if zero1 else dict(labels=("reduce", "overlap", "train_step")),
+        use_ef=use_ef,
+        abort_words=(
+            "the zero1 update was not applied on any rank (cross-rank "
+            "agreed)"
+        ) if zero1 else (
+            "the update was not applied on any rank (cross-rank agreed) — "
+            "rolling back via the elastic layer if one is active"
+        ),
+        notes=dict(
             hierarchical=str(hierarchical),
-            wire_dtype="int8" if quantized else "f32",
-            op=ReduceOp(op).name,
-            nonfinite=nonfinite_policy,
-            zero1=True,
-        )
-
-    if nonfinite_policy != "abort":
-        return _maybe_trace(jitted)
-
-    def aborting_step(params, opt_state, batch):
-        import numpy as np
-
-        out = jitted(params, opt_state, batch)
-        flag = out[-1]
-        if float(np.asarray(flag)) > 0:
-            from .. import HorovodInternalError
-
-            if _trace.ACTIVE:
-                _trace.TAP.flight_dump("guard-abort")
-            raise HorovodInternalError(
-                "non-finite gradient guard (policy abort): a rank "
-                "produced NaN/Inf gradients this step; the zero1 update "
-                "was not applied on any rank (cross-rank agreed)"
-            )
-        return out[:-1]
-
-    return _maybe_trace(aborting_step)
-
-
-# --- composed DP x TP fast path ----------------------------------------------
-#
-# docs/parallelism.md "Composed DP x TP fast path": a sharding-rules
-# table (parallel/rules.py, regex -> PartitionSpec, first-match-wins)
-# places the param tree on a (data, model) mesh; the loss runs on local
-# shards calling parallel/tp.py layers bound to the model axis (ONE
-# forward psum per Megatron half-block, its backward conjugate handled
-# by parallel/tp.py's explicit conjugates); and the ENTIRE PR-4/9/12
-# reduction stack — streamed per-bucket reduce-scatter ZeRO-1, the int8
-# wire, bucket fusion — runs scoped to the DATA axis only. TP psums are
-# never bucketized, never quantized, never re-planned onto DCN.
+            **(dict(zero1=True) if zero1 else {}),
+        ),
+        n_shards=n_shards,
+        expects=(
+            "zero1=True expects the sharded Zero1State from "
+            "hvd.init_zero1_stream_state(optimizer, params, "
+            f"{n_shards}, ...)"
+        ),
+    )
 
 
 def init_composed_zero1_state(
@@ -1373,21 +1023,18 @@ def init_composed_zero1_state(
     a single-axis feature); the int8 wire still applies per DP bucket."""
     from ..parallel import rules as _rules
 
-    from ..parallel import zero as _zero
-
     rules = _rules.resolve_rules(rules)
     specs = _rules.match_partition_rules(rules, params)
     n_model = int(mesh.shape[model_axis])
-    n_data = 1
-    for ax in (tuple(axis_name) if isinstance(axis_name, (tuple, list))
-               else (axis_name,)):
-        n_data *= int(mesh.shape[ax])
+    n_data = _math.prod(int(mesh.shape[ax]) for ax in (
+        axis_name if isinstance(axis_name, (tuple, list)) else (axis_name,)
+    ))
     states = []
     for m in range(n_model):
         local = _rules.local_shard_tree(
             params, specs, {model_axis: (m, n_model)}
         )
-        states.append(_zero.init_zero1_stream_state(
+        states.append(init_zero1_stream_state(
             optimizer, local, n_data,
             threshold_bytes=threshold_bytes,
             first_bucket_bytes=first_bucket_bytes,
@@ -1396,51 +1043,22 @@ def init_composed_zero1_state(
     return jax.tree.map(lambda *xs: jnp.stack(xs, axis=1), *states)
 
 
-def _build_composed_train_step(
-    loss_fn: Callable[..., jax.Array],
-    optimizer,
-    mesh: Mesh,
-    *,
-    rules: Any,
-    model_axis: str,
-    axis_name: str = DATA_AXIS,
-    op: ReduceOp = Average,
-    fusion_threshold_bytes: Optional[int] = None,
-    compression=Compression.none,
-    hierarchical: Any = False,
-    quantized: Optional[bool] = None,
-    error_feedback: Optional[bool] = None,
-    donate: bool = True,
-    has_aux: bool = False,
-    overlap: bool = False,
-    first_bucket_bytes: Optional[int] = None,
-    nonfinite: Optional[str] = None,
-    topo_algorithm: Optional[str] = None,
-    zero1: bool = False,
-    tp_overlap: Optional[bool] = None,
-    tuned_cfg: Any = None,
-    tuned_source: str = "none",
-):
-    """The composed step: ``step(params, opt_state, batch)`` with params
-    placed by the rule table (sharded leaves enter as local shards),
-    batch sharded over the data axis, and gradient reduction scoped to
-    the data axis only. Replicated-leaf gradients come out of the
-    backward already FULL and model-identical — ``parallel/tp.py``'s
-    f/g conjugate psums (``tp_block_input`` + ``row_parallel``) reduce
-    the cotangents at every replicated->sharded boundary — so the DP
-    reduction is the only gradient collective this step adds.
-
-    The build is deferred to the first call: the live params decide the
-    spec tree (validated by the Pass 5 preflight ALWAYS — not gated on
-    HOROVOD_TPU_STATIC_CHECKS) and the optimizer state's placement is
-    matched by the same rule table (optax trees embed the param names).
-    """
-    import optax
-
+def _plan_composed(
+    mesh: Mesh, *, rules, model_axis, tp_overlap, axis_name, op,
+    fusion_threshold_bytes, compression, hierarchical, quantized,
+    error_feedback, overlap, first_bucket_bytes, nonfinite, topo_algorithm,
+    zero1,
+) -> tuple:
+    """Argument checks of the composed step (sharded leaves enter as
+    local shards; ONE forward psum per Megatron half-block).
+    Replicated-leaf gradients come out of the backward already FULL and
+    model-identical — ``parallel/tp.py``'s f/g conjugate psums
+    (``tp_block_input`` + ``row_parallel``) reduce the cotangents at
+    every replicated->sharded boundary — so the DP reduction is the only
+    gradient collective this step adds. Composed mode carries no EF
+    residual (the sharded-EF side channel is a single-axis feature)."""
     from ..parallel import rules as _rules
     from ..parallel import tp as _tp
-    from ..parallel import zero as _zero
-    from .. import tune as _tune
 
     rules = _rules.resolve_rules(rules)
     # The DP scope may itself be hierarchical — an explicit
@@ -1501,176 +1119,180 @@ def _build_composed_train_step(
             "data axis; the two-level DP scope has no int8 RS+AG form "
             "— drop quantized or the axis tuple"
         )
-    nonfinite_policy = _resolve_nonfinite(nonfinite)
-    n_model = int(mesh.shape[model_axis])
-    n_data = 1
-    for ax in dp_axes:
-        n_data *= int(mesh.shape[ax])
-    built: dict = {}
+    policy = _resolve_nonfinite(nonfinite)
+    n_data = _math.prod(int(mesh.shape[ax]) for ax in dp_axes)
 
-    def _build(params, opt_state):
-        threshold = fusion_threshold_bytes
-        first = first_bucket_bytes
-        if tuned_cfg is not None:
-            live = _tune.step_signature(params, mesh=mesh)
-            matched = _tune.signatures_match(tuned_cfg.signature, live)
-            if matched:
-                tk = _tune.tuned_step_kwargs(tuned_cfg)
-                if threshold is None:
-                    threshold = tk["fusion_threshold_bytes"]
-                if first is None:
-                    first = tk["first_bucket_bytes"]
-            else:
-                _tune.warn_signature_mismatch(
-                    tuned_cfg, live.get("hash", "?"),
-                    "make_train_step(rules=...)",
-                )
-            _tune.note_applied(
-                tuned_source, tuned_cfg.signature_hash, matched,
-                "make_train_step(rules=...)",
-            )
-        # Pass 5 preflight — ALWAYS enforced for the composed path.
+    def place(params, opt_state):
+        # The Pass 5 preflight validates the live params' spec tree
+        # ALWAYS — not gated on HOROVOD_TPU_STATIC_CHECKS; the same rule
+        # table matches the optimizer state (optax trees embed the param
+        # names); a composed Zero1State is stacked [n_data, n_model, ...].
         _rules.preflight_rules(rules, mesh, params)
         specs = _rules.match_partition_rules(rules, params)
-        if zero1:
-            if not isinstance(opt_state, Zero1State):
-                raise TypeError(
-                    "composed zero1=True expects the Zero1State from "
-                    "hvd.init_composed_zero1_state(optimizer, params, "
-                    f"rules, mesh, ...); got {type(opt_state).__name__}"
-                )
-            state_spec: Any = P(
-                dp_axes if len(dp_axes) > 1 else dp_axes[0], model_axis
-            )
-        else:
+        if not zero1:
             state_spec = _rules.match_partition_rules(rules, opt_state)
-        knobs = dict(threshold_bytes=threshold, first_bucket_bytes=first)
-
-        def step(params, opt_state, batch):
-            if zero1:
-                state = jax.tree.map(lambda s: s[0, 0], opt_state)
-
-            def local_loss(p, b):
-                if overlap:
-                    p = _fusion.stream_param_groups(
-                        p, op=op, axis_name=axis_name,
-                        quantized=quantized, nonfinite=nonfinite_policy,
-                        zero1=zero1, **knobs,
-                    )
-                # Pin the TP-path selection for the trace: tp_apply
-                # (and any user loss built on parallel/tp.py) consults
-                # tp.overlap_active() so `tp_overlap=True` here reaches
-                # the model without threading a flag through user code.
-                # None keeps HOROVOD_TP_OVERLAP in charge.
-                # The step runs unchecked (the bucket-fused DP reduction
-                # cannot be typed by the vma checker); the TP layers see
-                # that and write their f/g conjugates out as custom VJPs.
-                with _tp.overlap_scope(tp_overlap):
-                    return loss_fn(p, b)
-
-            grad_fn = jax.value_and_grad(local_loss, has_aux=has_aux)
-            with jax.named_scope(_trace.SCOPE_LOSS_GRAD):
-                out, grads = grad_fn(params, batch)
-            loss, aux = out if has_aux else (out, None)
-            flag = None
-            if overlap:
-                _fusion.take_stream_registrations()
-            else:
-                if nonfinite_policy in ("skip", "abort"):
-                    flag = _nf.local_flag(grads)
-                if nonfinite_policy == "zero":
-                    grads = _nf.sanitize(grads)
-                if zero1:
-                    grads, _ = _zero.zero1_posthoc_reduce(
-                        grads, op=op, axis_name=axis_name,
-                        quantized=quantized, **knobs,
-                    )
-                else:
-                    grads = _fusion.fused_allreduce(
-                        grads, op=op, axis_name=axis_name,
-                        threshold_bytes=threshold,
-                        reduce_fn=(
-                            _q.quantized_reduce_fn("flat")
-                            if quantized else None
-                        ),
-                        label="composed-posthoc",
-                        wire_dtype="int8" if quantized else "f32",
-                    )
-            if nonfinite_policy in ("skip", "abort"):
-                post = _nf.local_flag(grads)
-                flag = post if flag is None else jnp.maximum(flag, post)
-                # Agreement over EVERY axis: a model rank's NaN must
-                # skip the step on every rank of the whole mesh.
-                flag = _nf.agree_flag(flag, dp_axes + (model_axis,))
-                _nf.note_detection(nonfinite_policy, "composed")(flag)
-            elif nonfinite_policy == "warn":
-                _nf.note_detection("warn", "composed")(
-                    _nf.local_flag(grads)
-                )
-            loss = lax.pmean(lax.pmean(loss, axis_name), model_axis)
-            if zero1:
-                with jax.named_scope(_trace.SCOPE_OPTIMIZER):
-                    new_params, new_opt = _zero.zero1_stream_update(
-                        optimizer, params, state.opt, grads,
-                        axis_name=axis_name, n_shards=n_data,
-                        quantized=quantized, **knobs,
-                    )
-                if flag is not None:
-                    new_params = _nf.select_on_flag(
-                        flag, params, new_params
-                    )
-                    new_opt = _nf.select_on_flag(flag, state.opt, new_opt)
-                new_state = jax.tree.map(
-                    lambda s: s[None, None],
-                    Zero1State(opt=new_opt, ef=state.ef),
-                )
-            else:
-                with jax.named_scope(_trace.SCOPE_OPTIMIZER):
-                    updates, new_opt = optimizer.update(
-                        grads, opt_state, params
-                    )
-                    new_params = optax.apply_updates(params, updates)
-                if flag is not None:
-                    new_params = _nf.select_on_flag(
-                        flag, params, new_params
-                    )
-                    new_opt = _nf.select_on_flag(flag, opt_state, new_opt)
-                new_state = new_opt
-            outs = [new_params, new_state, loss]
-            if has_aux:
-                outs.append(jax.tree.map(
-                    lambda a: lax.pmean(
-                        lax.pmean(a, axis_name), model_axis
-                    ),
-                    aux,
-                ))
-            if nonfinite_policy == "abort":
-                outs.append(flag)
-            return tuple(outs)
-
-        extra = (1 if has_aux else 0) + (
-            1 if nonfinite_policy == "abort" else 0
-        )
-        fn = _shard_map(
-            step, mesh,
-            in_specs=(specs, state_spec, P(axis_name)),
-            out_specs=(specs, state_spec, P()) + (P(),) * extra,
-        )
-        jitted = jax.jit(fn, donate_argnums=(0, 1) if donate else ())
-
-        def _maybe_trace(step_fn):
-            return _trace.wrap_step(
-                step_fn,
-                composed=True, tp=n_model, dp=n_data,
-                tp_overlap=_tp.tp_overlap_enabled(tp_overlap),
-                overlap=overlap, quantized=quantized, zero1=zero1,
-                wire_dtype="int8" if quantized else "f32",
-                op=ReduceOp(op).name, nonfinite=nonfinite_policy,
+        elif isinstance(opt_state, Zero1State):
+            state_spec = P(axis_name, model_axis)
+        else:
+            raise TypeError(
+                "composed zero1=True expects the Zero1State from "
+                "hvd.init_composed_zero1_state(optimizer, params, "
+                f"rules, mesh, ...); got {type(opt_state).__name__}"
             )
+        return _Placement(
+            params=specs, state=state_spec, batch=P(axis_name),
+            loss_axes=(axis_name, model_axis),
+            # Agreement over EVERY axis: a model rank's NaN must skip
+            # the step on every rank of the whole mesh.
+            flag_axes=dp_axes + (model_axis,),
+            zero1_lead=2 if zero1 else 0,
+        )
 
-        if nonfinite_policy != "abort":
-            return _maybe_trace(jitted), jitted, specs, state_spec
+    return place, dict(
+        wire=dict(
+            op=op, axis_name=axis_name, quantized=quantized, zero1=zero1,
+            threshold_bytes=fusion_threshold_bytes,
+            first_bucket_bytes=first_bucket_bytes, nonfinite=policy,
+        ),
+        guard=dict(
+            labels=("composed", "composed", "composed"),
+            fused_label="composed-posthoc",
+        ),
+        use_ef=False,
+        abort_words=(
+            "the composed update was not applied on any rank (cross-rank "
+            "agreed over data AND model axes)"
+        ),
+        notes=dict(
+            composed=True, tp=int(mesh.shape[model_axis]), dp=n_data,
+            tp_overlap=_tp.tp_overlap_enabled(tp_overlap), zero1=zero1,
+        ),
+        n_shards=n_data,
+        # Pin the TP-path selection for the trace: tp_apply (and any
+        # user loss built on parallel/tp.py) consults
+        # tp.overlap_active() so `tp_overlap=True` here reaches the
+        # model without threading a flag through user code. None keeps
+        # HOROVOD_TP_OVERLAP in charge. The step runs unchecked (the
+        # bucket-fused DP reduction cannot be typed by the vma checker);
+        # the TP layers see that and write their f/g conjugates out as
+        # custom VJPs.
+        loss_scope=lambda: _tp.overlap_scope(tp_overlap),
+    )
 
+
+def _loss_and_grads(loss_fn, params, ef, batch, *, has_aux: bool,
+                    stream: Optional[dict], loss_scope: Callable):
+    """Differentiate the user loss on this rank's shard, under
+    ``SCOPE_LOSS_GRAD``: ``(out, grads, new_ef)``. ``stream`` registers
+    the params' children for streamed reduction; with a residual ``ef``
+    as well, differentiating w.r.t. the residual is the EF side channel:
+    the streamed backward rule returns the NEXT residual as ef's
+    "gradient" (ops/fusion.py)."""
+    streamed_ef = stream is not None and ef is not None
+
+    def local_loss(p, e, b):
+        if stream is not None:
+            p = _fusion.stream_param_groups(
+                p, **stream, **({"ef": e} if streamed_ef else {})
+            )
+        with loss_scope():
+            return loss_fn(p, b)
+
+    grad_fn = jax.value_and_grad(
+        local_loss, argnums=(0, 1) if streamed_ef else 0, has_aux=has_aux
+    )
+    with jax.named_scope(_trace.SCOPE_LOSS_GRAD):
+        out, grads = grad_fn(params, ef, batch)
+    new_ef = ef
+    if streamed_ef:
+        grads, new_ef = grads
+    if stream is not None:
+        # Consume the registration ledger so a later overlap
+        # DistributedOptimizer trace doesn't credit THIS trace's
+        # registrations.
+        _fusion.take_stream_registrations()
+    return out, grads, new_ef
+
+
+def _optax_update(optimizer, params, opt_state, grads):
+    import optax
+
+    updates, new_opt_state = optimizer.update(grads, opt_state, params)
+    return optax.apply_updates(params, updates), new_opt_state
+
+
+def _build_step(
+    loss_fn, optimizer, mesh: Mesh, place: _Placement, *, donate: bool,
+    has_aux: bool, overlap: bool, wire: dict, guard: dict, use_ef: bool,
+    abort_words: str, notes: dict, n_shards: int = 0, expects: str = "",
+    loss_scope: Callable = _contextlib.nullcontext,
+):
+    """The one train-step body and its launch wrapper: differentiate,
+    exchange under the guard, update, hold everything on a skipped step.
+    A mode's argument checks hand in its decisions as data: ``wire`` is
+    what ``stream_param_groups`` AND ``_exchange_posthoc`` take,
+    ``guard`` the latter's own (labels, pre_flag, fused_label),
+    ``n_shards`` the rows of a Zero1State and ``expects`` what a state
+    that is none is told. Returns ``(step, jitted)``: what the caller
+    runs, and the inner ``jax.jit`` function (HLO inspection)."""
+    policy, quantized = wire["nonfinite"], wire["quantized"]
+    abort = policy == "abort"
+    # One signature, (optimizer, params, state, grads) -> (params, state):
+    # the optax update, or the shard-local one on the un-stacked
+    # Zero1State row.
+    update = _optax_update
+    if wire["zero1"]:
+        update = _functools.partial(
+            zero1_stream_update, n_shards=n_shards,
+            axis_name=wire["axis_name"], quantized=quantized,
+            threshold_bytes=wire["threshold_bytes"],
+            first_bucket_bytes=wire["first_bucket_bytes"],
+        )
+
+    def average(x):
+        return _functools.reduce(lax.pmean, place.loss_axes, x)
+
+    def step(params, opt_state, batch):
+        inner, ef, close = _open_state(
+            opt_state, params, use_ef=use_ef,
+            zero1_lead=place.zero1_lead, expects=expects,
+        )
+        out, grads, new_ef = _loss_and_grads(
+            loss_fn, params, ef, batch, has_aux=has_aux,
+            stream=wire if overlap else None, loss_scope=loss_scope,
+        )
+        loss, aux = out if has_aux else (out, None)
+        grads, new_ef, flag = _exchange_posthoc(
+            grads, new_ef, reduce=not overlap, flag_axes=place.flag_axes,
+            **wire, **guard,
+        )
+        loss = average(loss)
+        with jax.named_scope(_trace.SCOPE_OPTIMIZER):
+            new_params, new_inner = update(optimizer, params, inner, grads)
+        if flag is not None:
+            # Skipped step: params and optimizer state held on EVERY
+            # rank; it discards the gradient, so the residual computed
+            # from it must not carry either.
+            new_params = _nf.select_on_flag(flag, params, new_params)
+            new_inner = _nf.select_on_flag(flag, inner, new_inner)
+            if ef is not None:
+                new_ef = _nf.select_on_flag(flag, ef, new_ef)
+        outs = [new_params, close(new_inner, new_ef), loss]
+        if has_aux:
+            outs.append(jax.tree.map(average, aux))
+        if abort:
+            outs.append(flag)
+        return tuple(outs)
+
+    fn = _shard_map(
+        step, mesh,
+        in_specs=(place.params, place.state, place.batch),
+        out_specs=(place.params, place.state)
+        + (P(),) * (1 + int(has_aux) + int(abort)),
+    )
+    jitted = jax.jit(fn, donate_argnums=(0, 1) if donate else ())
+    launch = jitted
+    if abort:
         def aborting_step(params, opt_state, batch):
             import numpy as np
 
@@ -1680,37 +1302,60 @@ def _build_composed_train_step(
                 from .. import HorovodInternalError
 
                 if _trace.ACTIVE:
+                    # Flight recorder: the abort is about to unwind into
+                    # the elastic rollback — persist the last moments
+                    # first.
                     _trace.TAP.flight_dump("guard-abort")
                 raise HorovodInternalError(
                     "non-finite gradient guard (policy abort): a rank "
-                    "produced NaN/Inf gradients this step; the composed "
-                    "update was not applied on any rank (cross-rank "
-                    "agreed over data AND model axes)"
+                    "produced NaN/Inf gradients this step; "
+                    + abort_words
                 )
             return out[:-1]
 
-        return _maybe_trace(aborting_step), jitted, specs, state_spec
+        launch = aborting_step
+    # Fleet-tracing step tap (docs/timeline.md "Step spans"): host-side
+    # step-boundary timestamps + step index, stamped with the build-time
+    # correlation ids so one trace links step → bucket → collective →
+    # hop. NULL_TAP discipline: disabled → the function is returned
+    # UNCHANGED (wrap_step(f) is f).
+    return _trace.wrap_step(
+        launch, overlap=overlap, quantized=quantized,
+        wire_dtype="int8" if quantized else "f32",
+        op=ReduceOp(wire["op"]).name, nonfinite=policy, **notes,
+    ), jitted
 
-    def dispatch(params, opt_state, batch):
-        if "step" not in built:
-            step, jitted, specs, state_spec = _build(params, opt_state)
-            built["step"] = step
-            # The inner jax.jit step — HLO inspection (tests assert the
-            # one-psum-per-block TP structure off it).
-            dispatch.jitted = jitted
-            # Digest integration (guard/digest.strip_rank_local): the
-            # spec trees mark which leaves are TP-sharded — attach as
-            # State.sharding_specs so cross-rank digests hash their
-            # LAYOUT, never their (legitimately divergent) bytes.
-            dispatch.sharding_specs = {
-                "params": specs,
-                **({} if zero1 else {"opt_state": state_spec}),
-            }
-        return built["step"](params, opt_state, batch)
 
-    dispatch.sharding_specs = None
-    dispatch.jitted = None
-    return dispatch
+def _fill_from_tuned(kwargs: dict, tunable, tuned_cfg, tuned_source: str,
+                     params, mesh: Mesh, where: str) -> dict:
+    """One build's keywords after the tuned file (``make_train_step``'s
+    ``tuned``): where its key matches the live signature, each knob of
+    ``tunable`` that the caller left at its default takes the pinned
+    value."""
+    from .. import tune as _tune
+
+    live = _tune.step_signature(params, mesh=mesh)
+    matched = _tune.signatures_match(tuned_cfg.signature, live)
+    kw = dict(kwargs)
+    if matched:
+        tk = _tune.tuned_step_kwargs(tuned_cfg)
+        for name in tunable:
+            # "left alone" is None, or False for the tri-state hierarchical
+            if kw[name] is (False if name == "hierarchical" else None):
+                kw[name] = tk[name]
+        if kw["zero1"] and kw["topo_algorithm"] == "split":
+            # No reduce-scatter decomposition of the FlexLink split
+            # exists; the zero1 lowering is decided by the mesh shape —
+            # fall back to per-bucket selection.
+            kw["topo_algorithm"] = None
+    else:
+        _tune.warn_signature_mismatch(
+            tuned_cfg, live.get("hash", "?"), where
+        )
+    _tune.note_applied(
+        tuned_source, tuned_cfg.signature_hash, matched, where
+    )
+    return kw
 
 
 def make_train_step(
@@ -1737,19 +1382,59 @@ def make_train_step(
     model_axis: str = "model",
     tp_overlap: Optional[bool] = None,
 ):
-    """See :func:`_build_train_step` for the core semantics — this public
-    wrapper adds pinned offline tuning (docs/autotune.md "Compiled-path
-    offline tuning").
+    """Build a jitted SPMD training step: per-shard grads → fused allreduce
+    → optax update, with the batch sharded over ``axis_name`` and
+    params/opt-state replicated.
+
+    ``loss_fn(params, batch) -> loss`` (or ``(loss, aux)`` with
+    ``has_aux=True``; aux leaves are pmean-averaged) is evaluated on each
+    rank's local shard; gradient reduction uses the configured
+    op/compression — the whole reference ``DistributedOptimizer`` pipeline
+    as one XLA program. With ``hierarchical=True`` the mesh must have
+    (cross, local) axes (see ``build_hierarchical_mesh``).
+
+    ``overlap=True`` switches from the post-hoc whole-tree reduction to the
+    streamed path (docs/overlap.md): the top-level children of ``params``
+    are packed into DDP-style reverse-order groups (a smaller first bucket,
+    ``first_bucket_bytes`` / HOROVOD_FUSION_FIRST_BUCKET_BYTES) and each
+    group's psums are issued INSIDE the backward pass as soon as that
+    group's gradients exist — independent collectives XLA can overlap with
+    the remaining backward compute. Numerically identical to
+    ``overlap=False`` (elementwise reductions commute with the split).
+
+    ``quantized=True`` (None reads ``HOROVOD_QUANTIZED_WIRE``) moves each
+    gradient bucket over the int8 wire (``ops/quantized.py``) — composed
+    with ``overlap=True`` the quantize→ring-reduce→dequantize runs inside
+    the backward trace per streamed bucket, preserving the
+    scheduler-overlap property; composed with ``hierarchical`` only the
+    outermost (DCN) hop is compressed. On the flat wire an error-feedback
+    residual (``error_feedback``, default on; EF-SGD) rides the optimizer
+    state: the step accepts a plain ``optimizer.init(params)`` opt_state
+    and returns ``EFState(inner=..., residual=...)`` from the first call
+    on (or start from :func:`error_feedback_state` for a stable
+    structure, e.g. under ``lax.scan``).
+
+    ``nonfinite`` (None reads ``HOROVOD_GUARD_NONFINITE``, resolved when
+    the step is built) applies the non-finite gradient guard around the
+    reduce: ``zero`` sanitizes before the wire (per streamed group under
+    ``overlap=True``), ``warn`` logs detections, ``skip`` cross-rank
+    agrees on a skip flag and leaves params/opt-state UNCHANGED on every
+    rank for that step, ``abort`` additionally raises
+    ``HorovodInternalError`` from the returned step function so the
+    elastic layer rolls back — docs/fault_tolerance.md "Data-plane
+    integrity".
 
     ``rules`` (docs/parallelism.md "Composed DP x TP fast path") switches
-    to the composed builder: a sharding-rules table (a ``(regex,
+    to composed mode: a sharding-rules table (a ``(regex,
     PartitionSpec)`` sequence or a shipped name like ``"gpt"`` —
     ``parallel/rules.py``) places params and optimizer state on the
     ``(axis_name, model_axis)`` mesh, ``loss_fn`` runs on the LOCAL
     shards calling ``parallel/tp.py`` layers bound to ``model_axis``
     (e.g. ``models.transformer.tp_apply``), and the whole
     overlap/quantized/zero1 reduction stack applies to the DATA axis
-    only — TP psums are never bucketized, quantized, or re-planned.
+    only — TP psums are never bucketized, quantized, or re-planned. The
+    build is then deferred to the first call, whose live trees the
+    tables are matched against.
     ``tp_overlap=True`` (default: the ``HOROVOD_TP_OVERLAP`` knob)
     additionally routes the TP layers through the chunked
     collective-matmul primitives (docs/parallelism.md "Fused TP
@@ -1765,14 +1450,23 @@ def make_train_step(
     optimizer state per streamed bucket over the data axis: the step
     takes the :class:`Zero1State` from :func:`init_zero1_stream_state`
     (built with the SAME threshold/first-bucket/quantized knobs),
-    reduce-scatters each gradient bucket — inside the backward with
-    ``overlap=True`` — and all-gathers the shard-updated parameters.
-    Composes with ``quantized=True`` (int8 ring RS, sharded EF residual)
-    and ``hierarchical="auto"`` (two-level RS/AG on multi-slice meshes);
-    a matching ``tuned`` config fills the same knobs it fills for the
-    allreduce paths.
+    reduce-scatters each gradient bucket, and runs the shard-local optax
+    update and parameter all-gather against the same bucket plan
+    (``parallel/zero.zero1_stream_update``). Under ``overlap=True`` each
+    bucket reduce-scatters INSIDE the backward trace — each rank keeps
+    only its shard's cotangents, (n-1)/n of the gradient payload rides
+    the wire, and the scheduler hides it behind the remaining backward
+    compute; ``overlap=False`` runs the identical per-bucket reduction
+    post-hoc (bitwise-equal, zero overlap).
+    Composes with ``quantized=True`` (int8 ring RS, the error-feedback
+    residual carried SHARDED in the ``Zero1State``) and
+    ``hierarchical="auto"`` (on a multi-slice mesh each bucket's RS/AG
+    lowers via the compositor's two-level schedules: only the 1/L shard
+    crosses DCN); a matching ``tuned`` config fills the same knobs it
+    fills for the allreduce paths.
 
-    ``tuned`` takes a ``tuned.json`` path, a
+    ``tuned`` (pinned offline tuning, docs/autotune.md "Compiled-path
+    offline tuning") takes a ``tuned.json`` path, a
     :class:`horovod_tpu.tune.TunedConfig`, ``None`` (read
     ``HOROVOD_TUNED_FILE``), or ``False`` (explicitly untuned). With a
     tuning in hand the step build is deferred to the FIRST call: the
@@ -1796,64 +1490,70 @@ def make_train_step(
         fusion_threshold_bytes=fusion_threshold_bytes,
         compression=compression, hierarchical=hierarchical,
         quantized=quantized, error_feedback=error_feedback,
-        donate=donate, has_aux=has_aux, overlap=overlap,
-        first_bucket_bytes=first_bucket_bytes, nonfinite=nonfinite,
-        topo_algorithm=topo_algorithm, zero1=zero1,
+        overlap=overlap, first_bucket_bytes=first_bucket_bytes,
+        nonfinite=nonfinite, topo_algorithm=topo_algorithm, zero1=zero1,
     )
     tuned_cfg, tuned_source = _tune.resolve_tuned(tuned)
+    tunable = ("fusion_threshold_bytes", "first_bucket_bytes")
     if rules is not None:
-        return _build_composed_train_step(
-            loss_fn, optimizer, mesh, rules=rules, model_axis=model_axis,
+        plan_of = _functools.partial(
+            _plan_composed, mesh, rules=rules, model_axis=model_axis,
             tp_overlap=tp_overlap,
-            tuned_cfg=tuned_cfg, tuned_source=tuned_source, **kwargs,
         )
-    if tp_overlap is not None:
+        where = "make_train_step(rules=...)"
+        # Composed mode rejects its arguments at the call; only the
+        # placement waits for the live trees.
+        plan_of(**kwargs)
+    elif tp_overlap is not None:
         raise ValueError(
             "tp_overlap selects the fused collective-matmul TP path of "
             "the composed builder — pass rules=... (and a model axis); "
             "without tensor parallelism there is no TP psum to fuse"
         )
-    if tuned_cfg is None:
-        return _build_train_step(loss_fn, optimizer, mesh, **kwargs)
+    else:
+        plan_of = _functools.partial(_plan_replicated, mesh)
+        where = "make_train_step"
+        # composed mode rejects these, so a tuned file may not fill them
+        tunable += ("quantized", "hierarchical", "topo_algorithm")
 
-    state: dict = {}
+    def build(params=None, opt_state=None):
+        kw = kwargs
+        if tuned_cfg is not None:
+            kw = _fill_from_tuned(
+                kwargs, tunable, tuned_cfg, tuned_source, params, mesh, where
+            )
+        place_of, step_kw = plan_of(**kw)
+        place = place_of(params, opt_state)
+        return _build_step(
+            loss_fn, optimizer, mesh, place, donate=donate,
+            has_aux=has_aux, overlap=overlap, **step_kw,
+        ) + (place,)
+
+    if rules is None and tuned_cfg is None:
+        return build()[0]
+
+    # One deferred first-call build for both needs: a tuned file to match
+    # against the live signature, rules to match against the live trees.
+    built: dict = {}
 
     def dispatch(params, opt_state, batch):
-        step = state.get("step")
-        if step is None:
-            live = _tune.step_signature(params, mesh=mesh)
-            matched = _tune.signatures_match(tuned_cfg.signature, live)
-            kw = dict(kwargs)
-            if matched:
-                tk = _tune.tuned_step_kwargs(tuned_cfg)
-                if kw["fusion_threshold_bytes"] is None:
-                    kw["fusion_threshold_bytes"] = tk[
-                        "fusion_threshold_bytes"]
-                if kw["first_bucket_bytes"] is None:
-                    kw["first_bucket_bytes"] = tk["first_bucket_bytes"]
-                if kw["quantized"] is None:
-                    kw["quantized"] = tk["quantized"]
-                if kw["hierarchical"] is False:
-                    kw["hierarchical"] = tk["hierarchical"]
-                if kw["topo_algorithm"] is None:
-                    kw["topo_algorithm"] = tk["topo_algorithm"]
-                if kw["zero1"] and kw["topo_algorithm"] == "split":
-                    # No reduce-scatter decomposition of the FlexLink
-                    # split exists; the zero1 lowering is decided by the
-                    # mesh shape — fall back to per-bucket selection.
-                    kw["topo_algorithm"] = None
-            else:
-                _tune.warn_signature_mismatch(
-                    tuned_cfg, live.get("hash", "?"), "make_train_step"
-                )
-            _tune.note_applied(
-                tuned_source, tuned_cfg.signature_hash, matched,
-                "make_train_step",
-            )
-            step = _build_train_step(loss_fn, optimizer, mesh, **kw)
-            state["step"] = step
-        return step(params, opt_state, batch)
+        if "step" not in built:
+            built["step"], jitted, place = build(params, opt_state)
+            # The inner jax.jit step — HLO inspection (tests assert the
+            # one-psum-per-block TP structure off it).
+            dispatch.jitted = jitted
+            if rules is not None:
+                # Digest integration (guard/digest.strip_rank_local): the
+                # spec trees mark which leaves are TP-sharded — attach as
+                # State.sharding_specs so cross-rank digests hash their
+                # LAYOUT, never their (legitimately divergent) bytes.
+                dispatch.sharding_specs = {
+                    "params": place.params,
+                    **({} if zero1 else {"opt_state": place.state}),
+                }
+        return built["step"](params, opt_state, batch)
 
+    dispatch.sharding_specs = dispatch.jitted = None
     return dispatch
 
 

@@ -178,7 +178,7 @@ _OVERLAP_SCOPE: list = []
 
 def overlap_scope(enabled):
     """Context manager pinning the fused-path selection during a trace
-    (the composed builder wraps the user loss in one, so
+    (composed ``make_train_step`` traces the user loss in one, so
     ``make_train_step(rules=..., tp_overlap=...)`` reaches every
     ``tp_apply`` call without threading a flag through user code).
     ``enabled=None`` defers to the environment knob."""

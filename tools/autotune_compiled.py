@@ -164,8 +164,8 @@ def _measure_fn_for(args, params_aval):
             knobs=dict(config), signature={}, objectives={}, baseline={},
         )
         kw = T.tuned_step_kwargs(cfg)
-        step = hvdj._build_train_step(
-            loss_fn, tx, mesh, donate=False, overlap=True, **kw
+        step = hvdj.make_train_step(
+            loss_fn, tx, mesh, donate=False, overlap=True, tuned=False, **kw
         )
         opt_state = tx.init(params)
         p, s, _ = step(params, opt_state, batch)  # compile + warm
