@@ -509,6 +509,38 @@ def _exchange_posthoc(
     return grads, new_ef, flag
 
 
+@_functools.cache
+def _distributed_transformation():
+    """The type both ``DistributedOptimizer`` forms return (built on first
+    use: optax is an optional dependency): an
+    ``optax.GradientTransformation`` that ``make_train_step`` can
+    recognise and open. Beside ``init``/``update`` it carries ``wrapped``
+    (the optimizer it was built round), ``options`` (every keyword the
+    wrapper was built with) and ``stated`` (the names of those the caller
+    moved off their defaults)."""
+    import optax
+
+    class DistributedTransformation(optax.GradientTransformation):
+        pass
+
+    return DistributedTransformation
+
+
+def _distributed(init_fn, update_fn, wrapped, **options):
+    """A wrapper's ``(init, update)`` as the openable type; ``options``
+    are ``DistributedOptimizer`` keywords, as the wrapper was built."""
+    import inspect
+
+    tx = _distributed_transformation()(init_fn, update_fn)
+    defaults = inspect.signature(DistributedOptimizer).parameters
+    tx.wrapped, tx.options = wrapped, options
+    tx.stated = frozenset(
+        name for name, value in options.items()
+        if value != defaults[name].default
+    )
+    return tx
+
+
 def _zero1_distributed_optimizer(
     optimizer,
     *,
@@ -526,8 +558,6 @@ def _zero1_distributed_optimizer(
 ):
     """The ``DistributedOptimizer(zero1=True)`` construction — see the
     public wrapper's docstring for the contract."""
-    import optax
-
     if zero1_shards is None or int(zero1_shards) < 1:
         raise ValueError(
             "DistributedOptimizer(zero1=True) needs zero1_shards=<data-"
@@ -611,7 +641,16 @@ def _zero1_distributed_optimizer(
         updates = jax.tree.map(lambda a, b: a - b, new_params, params)
         return updates, close(new_opt, None)
 
-    return optax.GradientTransformation(init_fn, update_fn)
+    # What a step that opens this wrapper must know: the state init builds
+    # has the untuned bucket layout and no residual.
+    return _distributed(
+        init_fn, update_fn, optimizer, op=op, axis_name=axis_name,
+        fusion_threshold_bytes=fusion_threshold_bytes,
+        compression=compression, hierarchical=hierarchical,
+        quantized=quantized, error_feedback=False if quantized else None,
+        overlap=overlap, nonfinite=nonfinite, tuned=False, zero1=True,
+        zero1_shards=n_shards,
+    )
 
 
 def DistributedOptimizer(  # noqa: N802 - API parity with hvd.DistributedOptimizer
@@ -642,6 +681,19 @@ def DistributedOptimizer(  # noqa: N802 - API parity with hvd.DistributedOptimiz
     locally (see ``GradientAccumulator``) — the divisor is folded in here, as
     the reference does in the framework layer
     (``horovod/torch/mpi_ops.py:101-124``).
+
+    Used alone (its ``update`` called inside your own ``shard_map`` /
+    ``jit``) the wrapper reduces. Handed to :func:`make_train_step` it is
+    recognised and OPENED instead: the step already reduces the
+    gradients, so it updates through the wrapped ``optimizer`` (after the
+    ``1 / backward_passes_per_step`` scaling) and this wrapper's own
+    reduction is never traced: one exchange, not two. That exchange takes
+    every option stated here that ``make_train_step`` was left to default
+    on; an option stated on both with different values raises a
+    ``ValueError`` naming it. ``init`` is unchanged, so
+    ``wrapper.init(params)`` is the state that step threads. A wrapper
+    buried inside ``optax.chain(...)`` cannot be seen and still reduces
+    a second time there.
 
     ``overlap=True`` expects the model's layers to have been registered for
     streamed reduction (``hvd.reduce_in_backward`` /
@@ -699,8 +751,6 @@ def DistributedOptimizer(  # noqa: N802 - API parity with hvd.DistributedOptimiz
     zero overlap). Error feedback needs the backward side channel only
     ``make_train_step`` owns and is rejected here.
     """
-    import optax
-
     from .. import tune as _tune
 
     if zero1:
@@ -860,7 +910,14 @@ def DistributedOptimizer(  # noqa: N802 - API parity with hvd.DistributedOptimiz
                 new_ef = _nf.select_on_flag(flag, ef, new_ef)
         return updates, close(new_state, new_ef)
 
-    return optax.GradientTransformation(init_fn, update_fn)
+    return _distributed(
+        init_fn, update_fn, optimizer, op=op, axis_name=axis_name,
+        fusion_threshold_bytes=caller_threshold, compression=compression,
+        hierarchical=caller_hierarchical, quantized=caller_quantized,
+        error_feedback=error_feedback,
+        backward_passes_per_step=backward_passes_per_step, overlap=overlap,
+        nonfinite=nonfinite, tuned=tuned, topo_algorithm=topo_algorithm,
+    )
 
 
 def broadcast_variables(
@@ -1233,10 +1290,20 @@ def _build_step(
     what ``stream_param_groups`` AND ``_exchange_posthoc`` take,
     ``guard`` the latter's own (labels, pre_flag, fused_label),
     ``n_shards`` the rows of a Zero1State and ``expects`` what a state
-    that is none is told. Returns ``(step, jitted)``: what the caller
-    runs, and the inner ``jax.jit`` function (HLO inspection)."""
+    that is none is told. An ``optimizer`` that is a
+    ``DistributedOptimizer`` is opened: this body's exchange is the
+    step's only one (its options were merged into ``wire`` by
+    ``_adopt_wrapper_options``), and the update goes through the
+    optimizer it wraps, after the wrapper's ``1 /
+    backward_passes_per_step``. Returns ``(step, jitted)``: what the
+    caller runs, and the inner ``jax.jit`` function (HLO inspection)."""
     policy, quantized = wire["nonfinite"], wire["quantized"]
     abort = policy == "abort"
+    prescale, wrapper = 1.0, "none"
+    if isinstance(optimizer, _distributed_transformation()):
+        wrapper = "DistributedOptimizer"
+        prescale = 1.0 / optimizer.options.get("backward_passes_per_step", 1)
+        optimizer = optimizer.wrapped
     # One signature, (optimizer, params, state, grads) -> (params, state):
     # the optax update, or the shard-local one on the un-stacked
     # Zero1State row.
@@ -1268,6 +1335,8 @@ def _build_step(
         )
         loss = average(loss)
         with jax.named_scope(_trace.SCOPE_OPTIMIZER):
+            if prescale != 1.0:
+                grads = jax.tree.map(lambda g: g * prescale, grads)
             new_params, new_inner = update(optimizer, params, inner, grads)
         if flag is not None:
             # Skipped step: params and optimizer state held on EVERY
@@ -1322,7 +1391,8 @@ def _build_step(
     return _trace.wrap_step(
         launch, overlap=overlap, quantized=quantized,
         wire_dtype="int8" if quantized else "f32",
-        op=ReduceOp(wire["op"]).name, nonfinite=policy, **notes,
+        op=ReduceOp(wire["op"]).name, nonfinite=policy, grad_exchanges=1,
+        optimizer_wrapper=wrapper, **notes,
     ), jitted
 
 
@@ -1355,6 +1425,29 @@ def _fill_from_tuned(kwargs: dict, tunable, tuned_cfg, tuned_source: str,
     _tune.note_applied(
         tuned_source, tuned_cfg.signature_hash, matched, where
     )
+    return kw
+
+
+def _adopt_wrapper_options(kwargs: dict, wrapper) -> dict:
+    """``make_train_step``'s keywords after the ``DistributedOptimizer``
+    it was handed. The step reduces the gradients ONCE, so that exchange
+    takes each option the wrapper states where the step left it at its
+    default; an option both state, differently, raises."""
+    import inspect
+
+    defaults = inspect.signature(make_train_step).parameters
+    kw = dict(kwargs)
+    for name in sorted(wrapper.stated & set(kwargs)):
+        theirs, mine = wrapper.options[name], kwargs[name]
+        if mine == defaults[name].default:
+            kw[name] = theirs
+        elif mine != theirs:
+            raise ValueError(
+                f"make_train_step({name}={mine!r}) was handed a "
+                f"DistributedOptimizer({name}={theirs!r}): the step "
+                f"reduces the gradients once, so state {name} once (or "
+                "the same on both)"
+            )
     return kw
 
 
@@ -1392,6 +1485,20 @@ def make_train_step(
     op/compression — the whole reference ``DistributedOptimizer`` pipeline
     as one XLA program. With ``hierarchical=True`` the mesh must have
     (cross, local) axes (see ``build_hierarchical_mesh``).
+
+    ``optimizer`` may be a :func:`DistributedOptimizer` (the quick
+    start's form): the step opens it, reduces the gradients ONCE and
+    updates through the optimizer it wraps, keeping the wrapper's
+    ``1 / backward_passes_per_step``; bare ``tx`` and
+    ``DistributedOptimizer(tx)`` build the same program. An exchange
+    option stated on the wrapper and left at its default here
+    (``op``, ``compression``, ``quantized``, ``error_feedback``,
+    ``hierarchical``, ``fusion_threshold_bytes``, ``topo_algorithm``,
+    ``nonfinite``, ``overlap``, ``axis_name``, ``tuned``, ``zero1``)
+    applies to that one exchange; stated on both sides with different
+    values it raises a ``ValueError`` that names the keyword and both
+    values. A wrapper inside ``optax.chain(...)`` is not seen: it
+    reduces again inside the update, as any opaque transformation would.
 
     ``overlap=True`` switches from the post-hoc whole-tree reduction to the
     streamed path (docs/overlap.md): the top-level children of ``params``
@@ -1492,8 +1599,14 @@ def make_train_step(
         quantized=quantized, error_feedback=error_feedback,
         overlap=overlap, first_bucket_bytes=first_bucket_bytes,
         nonfinite=nonfinite, topo_algorithm=topo_algorithm, zero1=zero1,
+        tuned=tuned,
     )
-    tuned_cfg, tuned_source = _tune.resolve_tuned(tuned)
+    shards = None
+    if isinstance(optimizer, _distributed_transformation()):
+        kwargs = _adopt_wrapper_options(kwargs, optimizer)
+        overlap, zero1 = kwargs["overlap"], kwargs["zero1"]
+        shards = optimizer.options.get("zero1_shards")
+    tuned_cfg, tuned_source = _tune.resolve_tuned(kwargs.pop("tuned"))
     tunable = ("fusion_threshold_bytes", "first_bucket_bytes")
     if rules is not None:
         plan_of = _functools.partial(
@@ -1523,6 +1636,12 @@ def make_train_step(
                 kwargs, tunable, tuned_cfg, tuned_source, params, mesh, where
             )
         place_of, step_kw = plan_of(**kw)
+        if shards is not None and shards != step_kw["n_shards"]:
+            raise ValueError(
+                f"DistributedOptimizer(zero1_shards={shards}) built its "
+                f"state for {shards} shards; the mesh's data axis has "
+                f"{step_kw['n_shards']}"
+            )
         place = place_of(params, opt_state)
         return _build_step(
             loss_fn, optimizer, mesh, place, donate=donate,
