@@ -174,8 +174,10 @@ class GatedDeltaNet(nn.Module):
             return yf * jax.lax.rsqrt(
                 jnp.sum(yf * yf, axis=-1, keepdims=True) + self.eps)
 
-        q = jnp.repeat(l2(q) * dk ** -0.5, Hv // Hk, axis=2).astype(self.dtype)
-        k = jnp.repeat(l2(k), Hv // Hk, axis=2).astype(self.dtype)
+        # a key head serves the Hv // Hk value heads of its group: the rule
+        # shares it, nothing is repeated here
+        q = (l2(q) * dk ** -0.5).astype(self.dtype)
+        k = l2(k).astype(self.dtype)
         with jax.named_scope(_trace.SCOPE_GDN_SCAN):
             o, _ = gated_delta_chunked(q, k, v, g, beta, chunk=self.chunk,
                                        dtype=self.dtype)

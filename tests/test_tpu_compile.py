@@ -119,24 +119,33 @@ def test_flash_backward_compiles(topo, shape, kernels):
     assert " while(" not in text
 
 
-def test_gated_delta_rule_compiles(topo):
-    """The chunked rule at the published head sizes (32 heads of 128 x 128,
-    chunks of 64), forward and backward, on a quarter of the cell's
-    sequence: batched products and a scan, no kernel of ours."""
+@pytest.mark.parametrize("chunk,kernels", [(64, 2), (8, 0)])
+def test_gated_delta_rule_compiles(topo, compile_kernel, chunk, kernels):
+    """The chunked rule at the published head sizes (16 key heads shared by
+    32 value heads of 128 x 128), forward and backward, on a quarter of the
+    cell's sequence. Chunks of 64: the chunk-local part is the forward and
+    the backward kernel (their VMEM, the [128, 128] transposes, the float32
+    products at full precision and the lane-block index maps pass the chip's
+    compiler) and the scan over chunks stays a loop. A chunk under the rows
+    of a packed register: batched products and the scan, no kernel of ours."""
     from horovod_tpu.ops.gated_delta import gated_delta_chunked
 
     one_chip = SingleDeviceSharding(topo.devices[0])
     arr = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                     sharding=one_chip)
-    qkv = arr((1, 2048, 32, 128), jnp.bfloat16)
-    gate = arr((1, 2048, 32), jnp.float32)
+    T = 2048
+    qk = arr((1, T, 16, 128), jnp.bfloat16)
+    v = arr((1, T, 32, 128), jnp.bfloat16)
+    gate = arr((1, T, 32), jnp.float32)
 
     def loss(q, k, v, g, beta):
-        return gated_delta_chunked(q, k, v, g, beta)[0].sum()
+        return gated_delta_chunked(q, k, v, g, beta, chunk=chunk)[0].sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        qkv, qkv, qkv, gate, gate).compile()
-    assert "while" in compiled.as_text()
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        qk, qk, v, gate, gate).compile().as_text()
+    assert "while" in text
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == kernels
+    assert ("gdn_fwd" in text and "gdn_bwd" in text) == bool(kernels)
 
 
 def test_dropless_expert_layer_compiles(topo):
