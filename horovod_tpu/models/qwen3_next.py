@@ -328,16 +328,21 @@ class Qwen3NextLM(nn.Module):
                         kernel_init=_normal(c.init_std), name="lm_head")(x)
 
 
-def expert_load(model: Qwen3NextLM, params, tokens):
-    """``[n_layers, 2]`` int32 for one batch: per layer, the (token, expert)
-    pairs that fall on the experts held here (what the layer's grouped
-    products compute) and the busiest held expert's load. With tracing
-    armed the numbers also go onto every later step span as plan notes."""
+def expert_load(model, params, tokens):
+    """``[expert layers, 2]`` int32 for one batch of a model whose sparse
+    layers sow ``held_load`` (this one and ``models/lfm2_moe.py``): per
+    expert layer, in layer order, the (token, expert) pairs that fall on the
+    experts held here (what the layer's grouped products compute) and the
+    busiest held expert's load. With tracing armed the numbers also go onto
+    every later step span as plan notes."""
     _, state = model.apply({"params": params}, tokens,
                            mutable=["intermediates"])
     inter = state["intermediates"]
-    load = jnp.stack([inter[f"layer_{i}"]["mlp"]["held_load"][0]
-                      for i in range(model.cfg.n_layers)])
+    load = jnp.stack([
+        ffn["held_load"][0]
+        for i in range(model.cfg.n_layers)
+        for ffn in inter.get(f"layer_{i}", {}).values() if "held_load" in ffn
+    ])
     if _trace.ACTIVE:
         _trace.TAP.note_plan(
             moe_pairs_held=[int(v) for v in load[:, 0]],

@@ -157,16 +157,37 @@ def moe_ffn(
 # Dropless top-k layer: this device's experts' part of the result.
 # --------------------------------------------------------------------------
 
-def route_top_k(x, w_router, *, top_k: int, norm_topk: bool = True):
-    """Softmax routing over ALL experts: ``(weights [S, k] f32, expert ids
-    [S, k] int32)``. The router's product and softmax are float32 at full
-    precision, whatever ``x`` is: a token whose k-th and (k+1)-th
-    probabilities are near a tie must choose as the float32 model does."""
+def route_top_k(x, w_router, *, top_k: int, norm_topk: bool = True,
+                score: str = "softmax", select_bias=None,
+                norm_eps: float = 0.0, scale: float = 1.0):
+    """Routing over ALL experts: ``(weights [S, k] f32, expert ids [S, k]
+    int32)``. The router's product and score are float32 at full precision,
+    whatever ``x`` is: a token whose k-th and (k+1)-th scores are near a tie
+    must choose as the float32 model does.
+
+    ``score`` is ``"softmax"`` over the experts or ``"sigmoid"`` of each.
+    ``select_bias`` (``[E_total]``) is added to the scores for the CHOICE
+    only: the weights are the chosen experts' scores without it, and it
+    takes no gradient. With ``norm_topk`` the weights are divided by their
+    sum plus ``norm_eps``; ``scale`` multiplies them last. The defaults are
+    softmax, no bias, no epsilon and scale 1."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"score is 'softmax' or 'sigmoid', not {score!r}")
     logits = jnp.matmul(x.astype(jnp.float32), w_router.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    weights, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+              else jax.nn.sigmoid(logits))
+    if select_bias is None:
+        weights, ids = lax.top_k(scores, top_k)
+    else:
+        _, ids = lax.top_k(scores + lax.stop_gradient(
+            select_bias.astype(jnp.float32)), top_k)
+        weights = jnp.take_along_axis(scores, ids, axis=-1)
     if norm_topk:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + norm_eps if norm_eps else total)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, ids.astype(jnp.int32)
 
 
@@ -284,6 +305,10 @@ def dropless_moe(
     top_k: int,
     first_expert: int = 0,
     norm_topk: bool = True,
+    score: str = "softmax",
+    select_bias=None,
+    norm_eps: float = 0.0,
+    scale: float = 1.0,
     dtype=jnp.bfloat16,
 ) -> jax.Array:
     """This device's experts' part of a top-k MoE feed-forward, no token
@@ -293,9 +318,11 @@ def dropless_moe(
     replicated. ``w_gate``/``w_up``: ``[E_held, D, F]`` and ``w_down``:
     ``[E_held, F, D]``: the experts ``first_expert .. first_expert +
     E_held`` that live here. Every token is routed over all ``E_total``
-    experts by softmax and top-k (weights normalised over all k when
-    ``norm_topk``), and the sum ``sum_k w_k * down_e(silu(gate_e(x)) *
-    up_e(x))`` runs over the chosen experts that are held here; what the
+    experts by :func:`route_top_k` (softmax and top-k by default, weights
+    normalised over all k when ``norm_topk``; ``score``, ``select_bias``,
+    ``norm_eps`` and ``scale`` are its), and the sum ``sum_k w_k *
+    down_e(silu(gate_e(x)) * up_e(x))`` runs over the chosen experts that
+    are held here; what the
     others would add belongs to their owners (across an ``expert`` axis the
     exchange that brings their tokens here is ROADMAP's debt; on one chip
     the layer runs without it). Returns ``[S, D]`` float32.
@@ -319,10 +346,12 @@ def dropless_moe(
         _trace.TAP.note_plan(
             moe_experts_total=e_total, moe_experts_held=e_held,
             moe_top_k=top_k, moe_tile_rows=rows, moe_tiles=tiles,
+            moe_score=score, moe_select_bias=select_bias is not None,
         )
     with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
-        weights, ids = route_top_k(x, w_router, top_k=top_k,
-                                   norm_topk=norm_topk)
+        weights, ids = route_top_k(
+            x, w_router, top_k=top_k, norm_topk=norm_topk, score=score,
+            select_bias=select_bias, norm_eps=norm_eps, scale=scale)
         key, sizes = _held_groups(ids, first_expert, e_held)
         order = jnp.argsort(key, stable=True)[:worst].astype(jnp.int32)
         order = jnp.pad(order, (0, tiles * rows - worst)).reshape(tiles, rows)

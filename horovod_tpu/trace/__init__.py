@@ -87,6 +87,11 @@ SCOPE_GATED_ATTN = "gated_attn"
 SCOPE_MOE_ROUTE = "moe_route"      # router, top-k, sort, gather, combine
 SCOPE_MOE_EXPERTS = "moe_experts"  # the grouped products
 SCOPE_MOE_SHARED = "moe_shared"
+# The mixers of models/lfm2_moe.py (LFM2_SCOPES below: its expert layer
+# enters moe_route and moe_experts). MODEL_SCOPES stays the first hybrid's
+# six, which benchmark/scope_groups/qwen3_next.json lists one for one.
+SCOPE_SHORT_CONV = "short_conv"    # the two gates and the taps, no projection
+SCOPE_GQA_ATTN = "gqa_attn"
 MODEL_SCOPES = (
     SCOPE_GDN_CONV,
     SCOPE_GDN_SCAN,
@@ -94,6 +99,12 @@ MODEL_SCOPES = (
     SCOPE_MOE_ROUTE,
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_SHARED,
+)
+LFM2_SCOPES = (
+    SCOPE_SHORT_CONV,
+    SCOPE_GQA_ATTN,
+    SCOPE_MOE_ROUTE,
+    SCOPE_MOE_EXPERTS,
 )
 STEP_SCOPES = (
     SCOPE_LOSS_GRAD,

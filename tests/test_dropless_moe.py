@@ -1,6 +1,7 @@
 """The dropless top-k expert layer (``parallel/ep.dropless_moe``): a device's
-share of the experts, routed over all of them. float32 operands, so the
-tolerance is float32's."""
+share of the experts, routed over all of them, by softmax (the defaults) or
+by sigmoid scores with a selection bias, the published epsilon and a scale
+(``MODES``). float32 operands, so the tolerance is float32's."""
 
 import jax
 import jax.numpy as jnp
@@ -20,10 +21,32 @@ def _weights(seed=0):
             arr(E, D, F, scale=0.3), arr(E, F, D, scale=0.3))
 
 
-def _dense(x, wr, wg, wu, wd, first, held):
+BIAS = jnp.asarray(np.random.default_rng(9).normal(size=E) * 0.2, jnp.float32)
+# the routing's keyword arguments: none (softmax, no bias, no epsilon, scale
+# 1), and the sigmoid-and-bias mode of models/lfm2_moe.py
+MODES = {
+    "softmax": {},
+    "sigmoid_bias": dict(score="sigmoid", select_bias=BIAS, norm_eps=1e-6,
+                         scale=1.5),
+}
+modes = pytest.mark.parametrize("mode", list(MODES))
+
+
+def _route(x, wr, mode):
+    """The routing written out."""
+    logits = jnp.matmul(x, wr, precision=jax.lax.Precision.HIGHEST)
+    if mode == "softmax":
+        w, ids = jax.lax.top_k(jax.nn.softmax(logits, -1), K)
+        return w / w.sum(-1, keepdims=True), ids
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + BIAS, K)
+    w = jnp.take_along_axis(scores, ids, -1)
+    return 1.5 * (w / (w.sum(-1, keepdims=True) + 1e-6)), ids
+
+
+def _dense(x, wr, wg, wu, wd, first, held, mode="softmax"):
     """The layer written out: a loop over the held experts with masks."""
-    w, ids = jax.lax.top_k(jax.nn.softmax(x @ wr, -1), K)
-    w = w / w.sum(-1, keepdims=True)
+    w, ids = _route(x, wr, mode)
     y = jnp.zeros_like(x)
     for e in range(first, first + held):
         mine = jnp.sum(jnp.where(ids == e, w, 0.0), -1)
@@ -32,34 +55,38 @@ def _dense(x, wr, wg, wu, wd, first, held):
     return y
 
 
-def _share(x, wr, wg, wu, wd, first, held, **kw):
+def _share(x, wr, wg, wu, wd, first, held, mode="softmax"):
     sl = slice(first, first + held)
     return ep.dropless_moe(x, wr, wg[sl], wu[sl], wd[sl], top_k=K,
-                           first_expert=first, dtype=jnp.float32, **kw)
+                           first_expert=first, dtype=jnp.float32,
+                           **MODES[mode])
 
 
+@modes
 @pytest.mark.parametrize("shares", [1, 2, 4, 16])
-def test_shares_add_up_to_the_uncut_layer(shares):
+def test_shares_add_up_to_the_uncut_layer(shares, mode):
     """Every chip's part of the result, summed, is the whole layer: 16
     experts in ``shares`` shares (the shared expert is the model's, counted
     once there: tests/test_qwen3_next.py)."""
     args = _weights()
-    whole = _dense(*args, 0, E)
+    whole = _dense(*args, 0, E, mode)
     held = E // shares
-    parts = sum(_share(*args, i * held, held) for i in range(shares))
+    parts = sum(_share(*args, i * held, held, mode) for i in range(shares))
     np.testing.assert_allclose(parts, whole, atol=2e-5)
 
 
+@modes
 @pytest.mark.parametrize("first", [0, 4, 12])
-def test_one_share_equals_the_masked_loop(first):
+def test_one_share_equals_the_masked_loop(first, mode):
     args = _weights(1)
-    np.testing.assert_allclose(_share(*args, first, 4),
-                               _dense(*args, first, 4), atol=2e-5)
+    np.testing.assert_allclose(_share(*args, first, 4, mode),
+                               _dense(*args, first, 4, mode), atol=2e-5)
 
 
-def test_gradients_equal_the_masked_loop():
+@modes
+def test_gradients_equal_the_masked_loop(mode):
     args = _weights(2)
-    loss = lambda f: lambda *a: jnp.sum(f(*a, 4, 4) ** 2)
+    loss = lambda f: lambda *a: jnp.sum(f(*a, 4, 4, mode) ** 2)
     want = jax.grad(loss(_dense), argnums=range(5))(*args)
     got = jax.grad(loss(_share), argnums=range(5))(*args)
     for name, a, b in zip("x router gate up down".split(), got, want):
@@ -110,6 +137,44 @@ def test_router_is_float32_whatever_the_tokens_are():
     np.testing.assert_array_equal(ids_again, ids16)
 
 
+def test_defaults_are_the_softmax_routing_to_the_bit():
+    """``route_top_k`` and ``dropless_moe`` without the new keyword arguments
+    compute what they computed before those came: the same operations in the
+    same order (no epsilon added, no scale multiplied), so the values are
+    equal and not merely close; spelling the defaults out changes nothing."""
+    x, wr, wg, wu, wd = _weights(6)
+    logits = jnp.matmul(x, wr, precision=jax.lax.Precision.HIGHEST)
+    w_old, ids_old = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+    w_old = w_old / jnp.sum(w_old, axis=-1, keepdims=True)
+    spelled = dict(score="softmax", select_bias=None, norm_eps=0.0, scale=1.0)
+    for kw in ({}, spelled):
+        w, ids = ep.route_top_k(x, wr, top_k=K, **kw)
+        np.testing.assert_array_equal(w, w_old)
+        np.testing.assert_array_equal(ids, ids_old)
+    raw, _ = ep.route_top_k(x, wr, top_k=K, norm_topk=False)
+    np.testing.assert_array_equal(
+        raw, jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)[0])
+    layer = lambda **kw: ep.dropless_moe(
+        x, wr, wg[:4], wu[:4], wd[:4], top_k=K, dtype=jnp.float32, **kw)
+    np.testing.assert_array_equal(layer(), layer(**spelled))
+    with pytest.raises(ValueError, match="softmax"):
+        ep.route_top_k(x, wr, top_k=K, score="tanh")
+
+
+def test_selection_bias_takes_no_gradient_and_changes_the_choice():
+    x, wr, wg, wu, wd = _weights(7)
+    kw = {k: v for k, v in MODES["sigmoid_bias"].items() if k != "select_bias"}
+    layer = lambda bias: ep.dropless_moe(
+        x, wr, wg[:4], wu[:4], wd[:4], top_k=K, dtype=jnp.float32,
+        select_bias=bias, **kw)
+    g = jax.grad(lambda b: jnp.sum(layer(b) ** 2))(BIAS)
+    assert g.shape == BIAS.shape and float(jnp.max(jnp.abs(g))) == 0.0
+    _, with_bias = ep.route_top_k(x, wr, top_k=K, select_bias=BIAS, **kw)
+    _, without = ep.route_top_k(x, wr, top_k=K, **kw)
+    assert (np.sort(with_bias, -1) != np.sort(without, -1)).any(-1).mean() > 0.2
+    assert float(jnp.max(jnp.abs(layer(BIAS) - layer(None)))) > 1e-3
+
+
 def test_plan_notes_when_tracing_is_armed(monkeypatch):
     from horovod_tpu import trace
 
@@ -125,3 +190,6 @@ def test_plan_notes_when_tracing_is_armed(monkeypatch):
     assert notes["moe_experts_total"] == E and notes["moe_experts_held"] == 4
     assert notes["moe_top_k"] == K
     assert notes["moe_tile_rows"] * notes["moe_tiles"] >= S * K
+    assert notes["moe_score"] == "softmax" and notes["moe_select_bias"] is False
+    _share(*_weights(5), 0, 4, "sigmoid_bias")
+    assert notes["moe_score"] == "sigmoid" and notes["moe_select_bias"] is True

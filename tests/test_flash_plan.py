@@ -1,9 +1,11 @@
-"""What ``ops/pallas_attention._plan`` gives the benchmark's two
+"""What ``ops/pallas_attention._plan`` gives the benchmark's three
 configurations: the GPT-2-medium cells' call is what it was before the second
-model came (512 x 512 tiles, 4 rows a grid step), and the Qwen3-Next cell's
+model came (512 x 512 tiles, 4 rows a grid step), the Qwen3-Next cell's
 call (16 heads of width 256 at T 8192) fits the kernel's VMEM budget as it
-stands, with fewer rows a step. The backward kernels' plan (``_plan_bwd``)
-fits the same budget at both shapes by its own count."""
+stands, with fewer rows a step, and the LFM2 cell's (4 sequences x 32 heads of
+width 64 at T 8192) is the GPT-2-medium plan over a longer sequence. The
+backward kernels' plan (``_plan_bwd``) fits the same budget at all three
+shapes by its own count."""
 
 import pytest
 
@@ -27,6 +29,16 @@ def test_plan_at_head_width_256_and_8192_tokens_fits_vmem():
         (16 * 17 / 2) / 256)
 
 
+def test_plan_at_head_width_64_and_8192_tokens_is_the_gpt2_plan():
+    # the LFM2 cell: [4 sequences x 32 heads, 8192, 64] bf16 in and out
+    bq, bk, rows = pa._plan(128, 8192, 8192, 64, 2, 2, None, None)
+    assert (bq, bk, rows) == (512, 512, 4)
+    assert pa._step_vmem_bytes(rows, bq, bk, 64, 2, 2) <= pa._VMEM_BUDGET
+    assert pa._step_vmem_bytes(8, bq, bk, 64, 2, 2) > pa._VMEM_BUDGET
+    assert pa._pairs_visited(8192, 8192, bq, bk, True) == pytest.approx(
+        (16 * 17 / 2) / 256)
+
+
 @pytest.mark.parametrize("d,in_size,expect", [
     (64, 4, (512, 512, 2)),      # f32 operands at the old width
     (128, 2, (512, 512, 4)),
@@ -44,6 +56,8 @@ def test_plan_follows_width_and_operand_size(d, in_size, expect):
     (16, 8192, 256, (512, 512, 1, False)),
     # d 64 at T 4096: a row's dq no longer fits; two kernels, 2 rows a step
     (16, 4096, 64, (512, 512, 2, False)),
+    # the LFM2 cell: a row's dq is 2 MB and does not fit either
+    (128, 8192, 64, (512, 512, 2, False)),
 ])
 def test_backward_plan_at_the_cells_shapes_fits_vmem(bh, t, d, expect):
     """Beside q, k, v the backward holds do, two accumulators and six

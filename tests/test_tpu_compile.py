@@ -79,6 +79,8 @@ def _qkv(topo, shape, dtype=jnp.bfloat16):
     # The Qwen3-Next cell's attention layer: 16 heads of width 256 over
     # one sequence of 8192 tokens (2 rows a grid step fit VMEM).
     ((16, 8192, 256), "f32[16,16,1,512]"),
+    # The LFM2 cell's: 4 sequences x 32 heads of width 64 over 8192 tokens.
+    ((128, 8192, 64), "f32[128,16,1,512]"),
 ])
 def test_flash_forward_compiles(topo, shape, stats):
     """The forward at the tiles the kernel picks from the shapes (a VMEM
@@ -97,6 +99,9 @@ def test_flash_forward_compiles(topo, shape, stats):
     # a row's dq is 8 MB, so the dK/dV and the dQ kernel.
     ((128, 1024, 64), 2),
     ((16, 8192, 256), 3),
+    # The LFM2 cell's attention layer (width 64 at 8192 tokens): a row's dq
+    # is 2 MB, so two backward kernels here too.
+    ((128, 8192, 64), 3),
     # f32 operands: a whole dq does not fit beside 512 x 512 tiles.
     ((8, 1024, 64, "float32"), 3),
 ])
