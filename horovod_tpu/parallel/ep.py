@@ -30,6 +30,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import trace as _trace
 from ..common.compat import axis_size as _axis_size
+from ..ops import moe_combine as _combine
 from .mesh import DATA_AXIS, EXPERT_AXIS
 
 
@@ -198,8 +199,11 @@ def _held_groups(ids, first_expert: int, experts_held: int):
     local = ids.reshape(-1) - first_expert
     key = jnp.where((local >= 0) & (local < experts_held), local,
                     experts_held)
-    sizes = jnp.bincount(key, length=experts_held + 1)[:experts_held]
-    return key, sizes.astype(jnp.int32)
+    # a compare and a sum, not ``bincount``: that is a scatter-add of S * k
+    # ones, which the chip applies one after another (1.2 ms for 131072)
+    sizes = jnp.sum(key[:, None] == jnp.arange(experts_held), axis=0,
+                    dtype=jnp.int32)
+    return key, sizes
 
 
 def held_load(ids, *, first_expert: int, experts_held: int):
@@ -208,30 +212,6 @@ def held_load(ids, *, first_expert: int, experts_held: int):
     layer's grouped products compute), and the load of the busiest of them."""
     _, sizes = _held_groups(ids, first_expert, experts_held)
     return jnp.sum(sizes), jnp.max(sizes)
-
-
-def _tile_rows(x, scale, w_gate, w_up, w_down, order, sizes, top_k):
-    """One tile of the sorted (token, expert) pairs: gather the rows, three
-    grouped products over the tile's part of the ragged assignment, and the
-    weighted rows ``[rows, D]`` float32. ``sizes`` is each held expert's
-    count of rows in this tile."""
-    rows = order.shape[0]
-    with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
-        valid = jnp.arange(rows) < jnp.sum(sizes)
-        # Rows past the last group belong to no expert. A grouped product
-        # leaves whatever was in memory there (zeros on the CPU, anything on
-        # the chip), forward AND transposed, so both ends are masked: the
-        # rows' output below, and here their way back into x's gradient.
-        xs = jnp.where(valid[:, None], x[order // top_k], 0).astype(
-            w_gate.dtype)                                   # [rows, D]
-    with jax.named_scope(_trace.SCOPE_MOE_EXPERTS):
-        grouped = lambda a, w: lax.ragged_dot(
-            a, w, sizes, preferred_element_type=jnp.float32)
-        h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
-        ys = grouped(h.astype(w_down.dtype), w_down)        # [rows, D] f32
-    with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
-        weight = jnp.where(valid, scale[order], 0.0)
-        return jnp.where(valid[:, None], ys, 0.0) * weight[:, None]
 
 
 def _tile(i, order, starts, ends):
@@ -247,41 +227,103 @@ def _tiles_needed(order, ends):
     return (ends[-1] + order.shape[1] - 1) // order.shape[1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
-def _experts(x, scale, w_gate, w_up, w_down, order, starts, ends, top_k):
+def _tile_products(xs, w_gate, w_up, w_down, sizes):
+    """Three grouped products over a tile's part of the ragged assignment:
+    ``[rows, D]`` float32. Rows past the last group belong to no expert, and
+    a grouped product leaves whatever was in memory there (zeros on the CPU,
+    anything on the chip), forward AND transposed: nothing may read them.
+    The per-token sums below read the rows that :func:`_token_slots` names,
+    which are held pairs' rows and no others."""
+    with jax.named_scope(_trace.SCOPE_MOE_EXPERTS):
+        grouped = lambda a, w: lax.ragged_dot(
+            a, w, sizes, preferred_element_type=jnp.float32)
+        h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+        return grouped(h.astype(w_down.dtype), w_down)      # [rows, D] f32
+
+
+def _token_slots(pos, tile, rows, load, slots, weight=None):
+    """Where a tile's rows go, token-major. ``pos``: ``[S, k]``, every
+    pair's row in the sorted order. Returns ``(slot_pos [S, slots],
+    slot_weight [S, slots])``: per token its pairs that are held AND lie in
+    tile ``tile``, moved to the first slots in pair order: the pair's row
+    inside the tile, or -1 for a slot that holds none, and its weight (1
+    without ``weight``). ``slots`` is the most held pairs a token can
+    have."""
+    local = pos - tile * rows
+    mine = (local >= 0) & (local < jnp.minimum(rows, load - tile * rows))
+    rank = jnp.cumsum(mine, axis=1, dtype=jnp.int32) - 1
+    pick = mine[:, :, None] & (rank[:, :, None] == jnp.arange(slots))
+    slot_pos = jnp.max(jnp.where(pick, local[:, :, None], -1), axis=1)
+    if weight is None:
+        return slot_pos, (slot_pos >= 0).astype(jnp.float32)
+    return slot_pos, jnp.sum(jnp.where(pick, weight[:, :, None], 0.0), axis=1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
+def _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends, slots):
     """The held experts' part of the result for the sorted pairs ``order``
-    (``[tiles, rows]``): the first tile always, further tiles in a loop that
-    runs as far as this batch's load reaches. The loop's length is read on
-    the device, so it has no transpose of JAX's: the backward below walks
+    (``[tiles, rows]``; ``pos`` ``[S, k]`` is its inverse): the first tile
+    always, further tiles in a loop that runs as far as this batch's load
+    reaches. A tile's rows are gathered by pair, multiplied by group, and
+    summed per token by a gather-sum (``ops/moe_combine``): each token reads
+    its held pairs' rows, weighted, in pair order. The loop's length is read
+    on the device, so it has no transpose of JAX's: the backward below walks
     the same tiles again, one tile's rows alive at a time."""
-    def add_tile(i, y):
+    rows = order.shape[1]
+
+    def tile_sum(i):
         order_t, sizes_t = _tile(i, order, starts, ends)
-        ys = _tile_rows(x, scale, w_gate, w_up, w_down, order_t, sizes_t,
-                        top_k)
         with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
-            return y.at[order_t // top_k].add(ys)
+            xs = x[order_t // scale.shape[1]].astype(w_gate.dtype)
+        ys = _tile_products(xs, w_gate, w_up, w_down, sizes_t)
+        with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+            slot_pos, slot_w = _token_slots(
+                pos, i, rows, ends[-1], slots, scale)
+            return _combine.gather_sum(ys, slot_pos, slot_w)
 
-    y = add_tile(0, jnp.zeros(x.shape, jnp.float32))
-    return lax.fori_loop(1, _tiles_needed(order, ends), add_tile, y)
+    return lax.fori_loop(1, _tiles_needed(order, ends),
+                         lambda i, y: y + tile_sum(i), tile_sum(0))
 
 
-def _experts_fwd(x, scale, w_gate, w_up, w_down, order, starts, ends, top_k):
-    y = _experts(x, scale, w_gate, w_up, w_down, order, starts, ends, top_k)
-    return y, (x, scale, w_gate, w_up, w_down, order, starts, ends)
+def _experts_fwd(x, scale, w_gate, w_up, w_down, order, pos, starts, ends,
+                 slots):
+    y = _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends,
+                 slots)
+    return y, (x, scale, w_gate, w_up, w_down, order, pos, starts, ends)
 
 
-def _experts_bwd(top_k, res, dy):
-    x, scale, w_gate, w_up, w_down, order, starts, ends = res
+def _experts_bwd(slots, res, dy):
+    x, scale, w_gate, w_up, w_down, order, pos, starts, ends = res
+    rows, top_k = order.shape[1], scale.shape[1]
     f32 = lambda tree: jax.tree.map(lambda g: g.astype(jnp.float32), tree)
 
     def tile_grads(i):
         order_t, sizes_t = _tile(i, order, starts, ends)
-        _, vjp = jax.vjp(
-            lambda *a: _tile_rows(*a, order_t, sizes_t, top_k),
-            x, scale, w_gate, w_up, w_down)
         with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
-            rows = dy[order_t // top_k]
-        return f32(vjp(rows))
+            token = order_t // top_k
+            xs = x[token].astype(w_gate.dtype)
+        # the tile's forward again: its rows are in no residual
+        ys, vjp = jax.vjp(
+            lambda *a: _tile_products(*a, sizes_t), xs, w_gate, w_up, w_down)
+        with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+            # y[t] = sum over t's held pairs of weight * ys[row]: a row's
+            # cotangent is its token's dy times the pair's weight, the
+            # weight's is the row's product with dy. Rows past the load get
+            # weight 0 and hand the transposed products zeros.
+            valid = jnp.arange(rows) < jnp.sum(sizes_t)
+            weight = jnp.where(valid, scale.reshape(-1)[order_t], 0.0)
+            dy_rows = dy[token]
+            dweight = jnp.where(valid, jnp.sum(ys * dy_rows, axis=-1), 0.0)
+            dys = dy_rows * weight[:, None]
+        dxs, *dw = vjp(dys)
+        with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+            slot_pos, slot_one = _token_slots(pos, i, rows, ends[-1], slots)
+            dx = _combine.gather_sum(dxs.astype(jnp.float32), slot_pos,
+                                     slot_one)
+            # rows past the load carry 0, whatever pair they stand for
+            dscale = jnp.zeros(scale.size, jnp.float32).at[order_t].add(
+                dweight).reshape(scale.shape)
+        return (dx, dscale, *f32(dw))
 
     grads = lax.fori_loop(
         1, _tiles_needed(order, ends),
@@ -289,7 +331,7 @@ def _experts_bwd(top_k, res, dy):
         tile_grads(0))
     primals = (x, scale, w_gate, w_up, w_down)
     return tuple(g.astype(p.dtype) for g, p in zip(grads, primals)) + (
-        None, None, None)
+        None, None, None, None)
 
 
 _experts.defvjp(_experts_fwd, _experts_bwd)
@@ -334,7 +376,11 @@ def dropless_moe(
     fills twice over, and a loop computes as many tiles as this batch's
     load reaches (:func:`held_load`): balanced routing is one tile, and
     every token choosing only held experts is computed in full, tile by
-    tile."""
+    tile. A token's result is the sum of its held pairs' rows, each read
+    where the sort put it and weighted as it is added, in pair order
+    (``ops/moe_combine.gather_sum``): no row is scattered, no row past the
+    load is read, and the same sum with unit weights gives the tokens'
+    gradient from the transposed products' rows."""
     s_tokens, d_model = x.shape
     e_total = w_router.shape[-1]
     e_held = w_gate.shape[0]
@@ -342,26 +388,33 @@ def dropless_moe(
     rows = min(worst, _round_up(
         2 * s_tokens * top_k * e_held // e_total + 8 * e_held, 512))
     tiles = -(-worst // rows)
+    slots = min(top_k, e_held)      # the most held pairs a token can have
     if _trace.ACTIVE:
+        block = _combine.plan(s_tokens, d_model, jnp.float32)
         _trace.TAP.note_plan(
             moe_experts_total=e_total, moe_experts_held=e_held,
             moe_top_k=top_k, moe_tile_rows=rows, moe_tiles=tiles,
             moe_score=score, moe_select_bias=select_bias is not None,
+            moe_combine_kernel=block is not None,
+            moe_combine_block=block or 0, moe_combine_slots=slots,
         )
     with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
         weights, ids = route_top_k(
             x, w_router, top_k=top_k, norm_topk=norm_topk, score=score,
             select_bias=select_bias, norm_eps=norm_eps, scale=scale)
         key, sizes = _held_groups(ids, first_expert, e_held)
-        order = jnp.argsort(key, stable=True)[:worst].astype(jnp.int32)
-        order = jnp.pad(order, (0, tiles * rows - worst)).reshape(tiles, rows)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        pos = jnp.argsort(order).astype(jnp.int32)    # the sort's inverse
+        order = jnp.pad(order[:tiles * rows],
+                        (0, max(0, tiles * rows - order.size)))
         ends = jnp.cumsum(sizes)
     with jax.named_scope(_trace.SCOPE_MOE_EXPERTS):
         # cast once, outside the loop over tiles
         w_gate, w_up, w_down = (w.astype(dtype)
                                 for w in (w_gate, w_up, w_down))
-    return _experts(x, weights.reshape(-1), w_gate, w_up, w_down, order,
-                    ends - sizes, ends, top_k)
+    return _experts(x, weights, w_gate, w_up, w_down,
+                    order.reshape(tiles, rows), pos.reshape(ids.shape),
+                    ends - sizes, ends, slots)
 
 
 def _round_up(n: int, to: int) -> int:

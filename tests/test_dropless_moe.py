@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import moe_combine
 from horovod_tpu.parallel import ep
 
 S, D, E, F, K = 96, 16, 16, 8, 4
@@ -32,21 +33,20 @@ MODES = {
 modes = pytest.mark.parametrize("mode", list(MODES))
 
 
-def _route(x, wr, mode):
-    """The routing written out."""
+def _masked_loop(x, wr, wg, wu, wd, first, held, top_k, routing):
+    """The layer written out: the routing (``routing``: ``dropless_moe``'s
+    routing keywords, none for the softmax) and a loop over the held experts
+    with masks."""
     logits = jnp.matmul(x, wr, precision=jax.lax.Precision.HIGHEST)
-    if mode == "softmax":
-        w, ids = jax.lax.top_k(jax.nn.softmax(logits, -1), K)
-        return w / w.sum(-1, keepdims=True), ids
-    scores = jax.nn.sigmoid(logits)
-    _, ids = jax.lax.top_k(scores + BIAS, K)
-    w = jnp.take_along_axis(scores, ids, -1)
-    return 1.5 * (w / (w.sum(-1, keepdims=True) + 1e-6)), ids
-
-
-def _dense(x, wr, wg, wu, wd, first, held, mode="softmax"):
-    """The layer written out: a loop over the held experts with masks."""
-    w, ids = _route(x, wr, mode)
+    if not routing:
+        w, ids = jax.lax.top_k(jax.nn.softmax(logits, -1), top_k)
+        w = w / w.sum(-1, keepdims=True)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, ids = jax.lax.top_k(scores + routing["select_bias"], top_k)
+        w = jnp.take_along_axis(scores, ids, -1)
+        w = routing["scale"] * w / (w.sum(-1, keepdims=True)
+                                    + routing["norm_eps"])
     y = jnp.zeros_like(x)
     for e in range(first, first + held):
         mine = jnp.sum(jnp.where(ids == e, w, 0.0), -1)
@@ -55,11 +55,18 @@ def _dense(x, wr, wg, wu, wd, first, held, mode="softmax"):
     return y
 
 
-def _share(x, wr, wg, wu, wd, first, held, mode="softmax"):
+def _held_share(x, wr, wg, wu, wd, first, held, top_k, routing):
     sl = slice(first, first + held)
-    return ep.dropless_moe(x, wr, wg[sl], wu[sl], wd[sl], top_k=K,
-                           first_expert=first, dtype=jnp.float32,
-                           **MODES[mode])
+    return ep.dropless_moe(x, wr, wg[sl], wu[sl], wd[sl], top_k=top_k,
+                           first_expert=first, dtype=jnp.float32, **routing)
+
+
+def _dense(x, wr, wg, wu, wd, first, held, mode="softmax"):
+    return _masked_loop(x, wr, wg, wu, wd, first, held, K, MODES[mode])
+
+
+def _share(x, wr, wg, wu, wd, first, held, mode="softmax"):
+    return _held_share(x, wr, wg, wu, wd, first, held, K, MODES[mode])
 
 
 @modes
@@ -175,21 +182,337 @@ def test_selection_bias_takes_no_gradient_and_changes_the_choice():
     assert float(jnp.max(jnp.abs(layer(BIAS) - layer(None)))) > 1e-3
 
 
-def test_plan_notes_when_tracing_is_armed(monkeypatch):
+def _plan_notes(monkeypatch, traced):
+    """The plan notes ``traced()`` leaves with tracing armed."""
     from horovod_tpu import trace
 
     notes = {}
-
-    class Tap:
-        def note_plan(self, **kw):
-            notes.update(kw)
-
     monkeypatch.setattr(trace, "ACTIVE", True)
-    monkeypatch.setattr(trace, "TAP", Tap())
-    _share(*_weights(5), 0, 4)
+    monkeypatch.setattr(trace, "TAP", type("Tap", (), {
+        "note_plan": staticmethod(lambda **kw: notes.update(kw))})())
+    traced()
+    return notes
+
+
+def test_plan_notes_when_tracing_is_armed(monkeypatch):
+    notes = _plan_notes(monkeypatch, lambda: _share(*_weights(5), 0, 4))
     assert notes["moe_experts_total"] == E and notes["moe_experts_held"] == 4
     assert notes["moe_top_k"] == K
     assert notes["moe_tile_rows"] * notes["moe_tiles"] >= S * K
+    # width 16 is no whole float32 tile: the per-token sums are XLA gathers
+    assert notes["moe_combine_kernel"] is False
+    assert notes["moe_combine_block"] == 0 and notes["moe_combine_slots"] == K
     assert notes["moe_score"] == "softmax" and notes["moe_select_bias"] is False
-    _share(*_weights(5), 0, 4, "sigmoid_bias")
+    notes = _plan_notes(
+        monkeypatch, lambda: _share(*_weights(5), 0, 4, "sigmoid_bias"))
     assert notes["moe_score"] == "sigmoid" and notes["moe_select_bias"] is True
+
+
+# --------------------------------------------------------------------------
+# The per-token gather-sum in place of the scatter-add; the tile rule is the
+# parent's.
+# --------------------------------------------------------------------------
+
+# The two regimes the layer runs in, at rehearsal sizes: few experts with
+# many rows each (models/lfm2_moe.py: 8 of 32 held, top 4) and many experts
+# with few rows each (models/qwen3_next.py: 32 of 512 held, top 10).
+# (tokens, experts, held, top k, first held expert)
+REGIMES = {
+    "few_experts_many_rows": (256, 8, 2, 4, 2),
+    "many_experts_few_rows": (64, 64, 16, 10, 16),
+}
+# Loads of a share whose sorted pairs are cut into tiles: 1024 tokens, top 4,
+# the router sending every token to experts 0..3. A share of 4 of 64 experts
+# (tiles of 1024 rows) that holds all four computes 4096 pairs in four tiles;
+# of 16 experts, a share of experts 2..3 (tiles of 1536 rows) computes 2048
+# pairs in two, and a share that nobody chose computes nothing.
+# (experts, first held expert, held, rows a tile, tiles the load reaches, pairs)
+LOADS = {
+    "fourth_tile": (64, 0, 4, 1024, 4, 4096),
+    "second_tile": (16, 2, 2, 1536, 2, 2048),
+    "empty": (16, 8, 4, 2560, 0, 0),
+}
+
+
+def _routing(mode, e_total):
+    """``dropless_moe``'s routing keywords at ``e_total`` experts: none for
+    the softmax, and the sigmoid scores with a selection bias, the epsilon
+    and a scale that models/lfm2_moe.py passes."""
+    if mode == "softmax":
+        return {}
+    bias = np.random.default_rng(e_total).normal(size=e_total) * 0.05
+    return dict(score="sigmoid", select_bias=jnp.asarray(bias, jnp.float32),
+                norm_eps=1e-6, scale=1.5)
+
+
+def _layer_args(s_tokens, e_total, seed, all_choose=None, logit=40.0,
+                width=D):
+    rng = np.random.default_rng(seed)
+    arr = lambda *shape, scale=1.0: jnp.asarray(
+        rng.normal(size=shape) * scale, jnp.float32)
+    x, wr = arr(s_tokens, width), arr(width, e_total)
+    if all_choose:
+        x = jnp.abs(x)
+        bias = jnp.concatenate([jnp.full((all_choose,), logit),
+                                jnp.zeros(e_total - all_choose)])
+        wr = jnp.ones((width, 1)) * bias[None] / width
+    return (x, wr, arr(e_total, width, F, scale=0.3),
+            arr(e_total, width, F, scale=0.3),
+            arr(e_total, F, width, scale=0.3))
+
+
+def _scatter_add_layer(x, wr, wg, wu, wd, first, held, top_k, routing):
+    """The layer as the parent commit computed it, all sorted pairs in one
+    tile: rows gathered and masked past the load, grouped products, rows
+    masked and weighted, a scatter-add by token; gradients by JAX."""
+    sl = slice(first, first + held)
+    weights, ids = ep.route_top_k(x, wr, top_k=top_k, **routing)
+    key, sizes = ep._held_groups(ids, first, held)
+    order = jnp.argsort(key, stable=True)
+    valid = jnp.arange(order.size) < jnp.sum(sizes)
+    xs = jnp.where(valid[:, None], x[order // top_k], 0)
+    grouped = lambda a, w: jax.lax.ragged_dot(a, w, sizes)
+    ys = grouped(jax.nn.silu(grouped(xs, wg[sl])) * grouped(xs, wu[sl]),
+                 wd[sl])
+    weight = jnp.where(valid, weights.reshape(-1)[order], 0.0)
+    return jnp.zeros_like(x).at[order // top_k].add(
+        jnp.where(valid[:, None], ys, 0.0) * weight[:, None])
+
+
+def _layer_and_gradients(layer, args, first, held, top_k, routing):
+    fn = lambda *a: layer(*a, first, held, top_k, routing)
+    loss = lambda *a: jnp.sum(fn(*a) ** 2)
+    return (jax.jit(fn)(*args),
+            *jax.jit(jax.grad(loss, argnums=range(5)))(*args))
+
+
+NAMES = "y x router gate up down".split()
+
+
+def _assert_layer_and_gradients(args, first, held, top_k, routing):
+    """The layer and the gradients of x, router, weights and (through the
+    router, which the scale multiplies and the selection bias does not
+    reach) the routing weights equal the masked loop's and the parent's
+    scatter-add's."""
+    got = _layer_and_gradients(_held_share, args, first, held, top_k, routing)
+    for reference in (_masked_loop, _scatter_add_layer):
+        want = _layer_and_gradients(reference, args, first, held, top_k,
+                                    routing)
+        for name, a, b in zip(NAMES, got, want):
+            np.testing.assert_allclose(
+                a, b, atol=2e-5 * max(float(jnp.max(jnp.abs(b))), 1.0),
+                err_msg=f"{name} against {reference.__name__}")
+
+
+@modes
+@pytest.mark.parametrize("regime", list(REGIMES))
+def test_both_regimes_equal_the_masked_loop_and_the_scatter_add(regime, mode):
+    s_tokens, e_total, held, top_k, first = REGIMES[regime]
+    _assert_layer_and_gradients(_layer_args(s_tokens, e_total, 11), first,
+                                held, top_k, _routing(mode, e_total))
+
+
+@modes
+@pytest.mark.parametrize("load", list(LOADS))
+def test_loads_past_one_tile_and_an_empty_load(load, mode, monkeypatch):
+    """Every token choosing held experts overflows into a second tile and on
+    to a fourth, and a share nobody chose computes nothing: the loop over
+    tiles runs as far as the load reaches, forward and backward."""
+    e_total, first, held, rows, tiles_reached, pairs = LOADS[load]
+    # a sigmoid saturates at 40 and hands the router no gradient: at 4 the
+    # first experts still score over 0.85 against the others' half
+    args = _layer_args(1024, e_total, 12, all_choose=K,
+                       logit=40.0 if mode == "softmax" else 4.0)
+    routing = _routing(mode, e_total)
+    _, ids = ep.route_top_k(args[0], args[1], top_k=K, **routing)
+    got_pairs, _ = ep.held_load(ids, first_expert=first, experts_held=held)
+    assert int(got_pairs) == pairs
+    assert -(-pairs // rows) == tiles_reached
+    _assert_layer_and_gradients(args, first, held, K, routing)
+    y = _held_share(*args, first, held, K, routing)
+    if pairs:
+        assert float(jnp.min(jnp.max(jnp.abs(y), axis=-1))) > 0  # every token
+    else:
+        assert float(jnp.max(jnp.abs(y))) == 0.0
+    notes = _plan_notes(
+        monkeypatch, lambda: _held_share(*args, first, held, K, routing))
+    assert notes["moe_tile_rows"] == rows
+    assert notes["moe_tiles"] >= max(tiles_reached, 1)
+
+
+@pytest.mark.parametrize("tokens,top_k,held,total,rows,tiles,block", [
+    # models/qwen3_next.py in qwen3next-train-1chip
+    (8192, 10, 32, 512, 10752, 8, 256),
+    # models/lfm2_moe.py in lfm2moe-train-1chip
+    (32768, 4, 8, 32, 66048, 2, 256),
+])
+def test_the_cells_keep_their_tiles_and_take_the_kernel(
+        monkeypatch, tokens, top_k, held, total, rows, tiles, block):
+    """At both cells' shapes a tile is what balanced routing fills twice
+    over and eight rows an expert, in whole 512s, as before the gather-sum
+    came; the rows of width 2048 are whole float32 tiles, so the kernel
+    runs, 256 tokens a grid step, with as many slots as a token can hold
+    pairs."""
+    arr = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    notes = _plan_notes(monkeypatch, lambda: jax.eval_shape(
+        lambda *a: ep.dropless_moe(*a, top_k=top_k),
+        jax.ShapeDtypeStruct((tokens, 2048), jnp.bfloat16),
+        arr(2048, total), arr(held, 2048, 64), arr(held, 2048, 64),
+        arr(held, 64, 2048)))
+    assert (notes["moe_tile_rows"], notes["moe_tiles"]) == (rows, tiles)
+    assert rows == -(-(2 * tokens * top_k * held // total + 8 * held)
+                     // 512) * 512
+    assert notes["moe_combine_kernel"] is True
+    assert notes["moe_combine_block"] == block
+    assert notes["moe_combine_slots"] == min(top_k, held)
+
+
+def _scatter_add_reference(rows, pos, weight):
+    """``out[t] += weight[t, c] * rows[pos[t, c]]`` written as the plain
+    scatter-add over the named (token, slot) pairs, in numpy."""
+    out = np.zeros((pos.shape[0], rows.shape[1]), np.float64)
+    t, c = np.nonzero(pos >= 0)
+    np.add.at(out, t, weight[t, c, None].astype(np.float64)
+              * rows[pos[t, c]].astype(np.float64))
+    return out
+
+
+@pytest.mark.parametrize("width,form", [(16, "xla"), (64, "xla"),
+                                        (1024, "kernel"), (2048, "kernel")])
+@pytest.mark.parametrize("tokens,slots,fill", [
+    (64, 4, 0.3),      # some slots empty, some tokens with no pair at all
+    (24, 10, 0.06),    # most tokens hold nothing, as at 32 of 512 experts
+    (32, 4, 1.0),      # every pair held
+])
+def test_gather_sum_equals_a_scatter_add(width, form, tokens, slots, fill):
+    """Random ``pos`` with unnamed slots; the rows past the load are NaN, as
+    a grouped product may leave them on the chip, and must not reach the
+    output. Both forms against numpy and against each other: the XLA gathers
+    (widths that are no whole float32 tiles) and the Pallas kernel
+    (interpreted on the CPU)."""
+    assert (moe_combine.plan(tokens, width, jnp.float32) is None) == (
+        form == "xla")
+    rng = np.random.default_rng(tokens + width)
+    load, n_rows = 40, 56
+    rows = rng.normal(size=(n_rows, width)).astype(np.float32)
+    rows[load:] = np.nan
+    pos = rng.integers(0, load, (tokens, slots)).astype(np.int32)
+    pos[rng.random((tokens, slots)) > fill] = -1
+    if fill < 1:
+        pos[: tokens // 2, slots // 2:] = -1   # slots no token of a block fills
+        pos[3] = -1                            # a token with no held pair
+        assert (pos < 0).all(-1).any() and (pos >= 0).any()
+    else:
+        assert (pos >= 0).all()
+    weight = rng.normal(size=(tokens, slots)).astype(np.float32)
+    got = np.asarray(jax.jit(moe_combine.gather_sum)(rows, pos, weight))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, _scatter_add_reference(rows, pos, weight),
+                               atol=1e-5)
+    assert (got[(pos < 0).all(-1)] == 0).all()
+    # the same sum in the same order: the forms differ by a fused
+    # multiply-add's rounding at most
+    np.testing.assert_allclose(
+        got, jax.jit(moe_combine._xla)(rows, pos, weight), rtol=1e-5,
+        atol=1e-6)
+
+
+def test_gather_sum_of_other_rows_takes_the_gathers():
+    """bfloat16 rows, or a token count no block of eight divides, are not
+    the kernel's: the same sum as XLA gathers, in float32."""
+    assert moe_combine.plan(64, 1024, jnp.bfloat16) is None
+    assert moe_combine.plan(60, 1024, jnp.float32) is None
+    assert moe_combine.plan(64, 1024 + 128, jnp.float32) is None
+    assert moe_combine.plan(8192, 2048, jnp.float32) == 256
+    rows = jnp.arange(12 * 1024, dtype=jnp.bfloat16).reshape(12, 1024) / 64
+    pos = jnp.asarray([[0, 11], [5, -1], [-1, -1], [2, 2]], jnp.int32)
+    weight = jnp.asarray([[1, 2], [3, 4], [5, 6], [0.5, 0.25]], jnp.float32)
+    out = moe_combine.gather_sum(rows, pos, weight)
+    assert out.dtype == jnp.float32
+    np.testing.assert_allclose(out, _scatter_add_reference(
+        np.asarray(rows, np.float32), np.asarray(pos), np.asarray(weight)))
+
+
+def _poisoned_products(real):
+    """``ep._tile_products`` as the chip runs it: the rows past the last
+    group hold garbage (NaN here), forward and transposed."""
+    def poison(rows, sizes):
+        past = jnp.arange(rows.shape[0]) >= jnp.sum(sizes)
+        return jnp.where(past[:, None], jnp.nan, rows)
+
+    @jax.custom_vjp
+    def products(xs, w_gate, w_up, w_down, sizes):
+        return poison(real(xs, w_gate, w_up, w_down, sizes), sizes)
+
+    def fwd(xs, w_gate, w_up, w_down, sizes):
+        return (products(xs, w_gate, w_up, w_down, sizes),
+                (xs, w_gate, w_up, w_down, sizes))
+
+    def bwd(res, dys):
+        *primals, sizes = res
+        _, vjp = jax.vjp(lambda *a: real(*a, sizes), *primals)
+        dxs, *dw = vjp(dys)
+        return (poison(dxs, sizes), *dw, None)
+
+    products.defvjp(fwd, bwd)
+    return products
+
+
+@modes
+@pytest.mark.parametrize("width", [16, 1024])
+def test_garbage_past_the_load_reaches_nothing(monkeypatch, mode, width):
+    """NaN in every row past the load, in the products' result and in their
+    transposed result, changes neither the layer nor any gradient: the sums
+    read the rows that held pairs name and the masks that kept the rest
+    harmless are gone. Both forms of the sum; a load that fills one tile in
+    part and a second one in part."""
+    args = _layer_args(256, E, 13, all_choose=K, width=width,
+                       logit=40.0 if mode == "softmax" else 4.0)
+    first, held = 1, 3      # 768 of 1024 pairs held, tiles of 512 rows
+    routing = _routing(mode, E)
+    clean = _layer_and_gradients(_held_share, args, first, held, K, routing)
+    monkeypatch.setattr(ep, "_tile_products",
+                        _poisoned_products(ep._tile_products))
+    dirty = _layer_and_gradients(_held_share, args, first, held, K, routing)
+    assert float(jnp.max(jnp.abs(clean[0]))) > 0
+    for name, a, b in zip(NAMES, dirty, clean):
+        assert bool(jnp.isfinite(a).all()), name
+        # the poisoned layer is another program to XLA: a fusion's rounding
+        np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-6 * float(jnp.max(jnp.abs(b))),
+            err_msg=name)
+
+
+def _row_scatters(hlo: str, width: int):
+    """The ``scatter`` instructions of an HLO module's text whose result (the
+    operand they add into) is a float array with ``width`` last."""
+    found = []
+    for line in hlo.splitlines():
+        head, call, _ = line.partition(" scatter(")
+        if not call:
+            continue
+        result = head.split("=", 1)[1].strip()     # f32[96,64]{1,0}
+        dims = result[result.index("[") + 1:result.index("]")].split(",")
+        if result[0] in "fb" and dims[-1] == str(width):
+            found.append(line.strip())
+    return found
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("width", [64, 1024])
+def test_lowered_layer_scatters_no_rows(width, direction):
+    """Neither direction of the lowered layer holds a scatter into an array
+    of the model's width: ``y`` and ``dx`` are gather-sums (the scores'
+    gradient, a scatter into ``[S, experts]``, and the weights' cotangent, a
+    scalar scatter, stay). The check finds the parent's scatter-add."""
+    e_total = 32
+    args = _layer_args(S, e_total, 3, width=width)
+    if direction == "forward":
+        fn = lambda layer: lambda *a: layer(*a, 4, 4, K, {})
+    else:
+        fn = lambda layer: jax.grad(
+            lambda *a: jnp.sum(layer(*a, 4, 4, K, {}) ** 2),
+            argnums=range(5))
+    hlo = lambda layer: jax.jit(fn(layer)).lower(*args).as_text(dialect="hlo")
+    assert not _row_scatters(hlo(_held_share), width)
+    assert _row_scatters(hlo(_scatter_add_layer), width)

@@ -153,24 +153,37 @@ def test_gated_delta_rule_compiles(topo, compile_kernel, chunk, kernels):
     assert ("gdn_fwd" in text and "gdn_bwd" in text) == bool(kernels)
 
 
-def test_dropless_expert_layer_compiles(topo):
-    """The expert layer at the published widths (32 of 512 experts held,
-    top-10, 2048 -> 512 -> 2048) on 2048 tokens: the grouped products are
-    the chip's own ragged-dot kernel, and no [tokens, experts, capacity]
-    tensor is in the program."""
+@pytest.mark.parametrize("tokens,top_k,total,held,width", [
+    (8192, 10, 512, 32, 512),    # qwen3next-train-1chip: many experts, few rows
+    (32768, 4, 32, 8, 1792),     # lfm2moe-train-1chip: few experts, many rows
+])
+def test_dropless_expert_layer_compiles(topo, compile_kernel, tokens, top_k,
+                                        total, held, width):
+    """The expert layer at the two cells' shapes (32 of 512 experts held,
+    top-10, 2048 -> 512 -> 2048 on 8192 tokens; 8 of 32, top-4, 2048 -> 1792
+    -> 2048 on 32768), forward and backward: the grouped products are the
+    chip's own ragged-dot kernel, the per-token sums the gather-sum kernel
+    (once a direction), no [tokens, experts, capacity] tensor is in the
+    program and no row buffer is scatter-added."""
     from horovod_tpu.parallel.ep import dropless_moe
 
     one_chip = SingleDeviceSharding(topo.devices[0])
     arr = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
         shape, dtype, sharding=one_chip)
-    fwd = functools.partial(dropless_moe, top_k=10)
-    compiled = jax.jit(fwd).lower(
-        arr((2048, 2048), jnp.bfloat16), arr((2048, 512)),
-        arr((32, 2048, 512)), arr((32, 2048, 512)), arr((32, 512, 2048)),
+    loss = lambda *a: dropless_moe(*a, top_k=top_k).sum()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arr((tokens, 2048), jnp.bfloat16), arr((2048, total)),
+        arr((held, 2048, width)), arr((held, 2048, width)),
+        arr((held, width, 2048)),
     ).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    assert "[2048,512," not in text.replace(" ", "")  # tokens x experts x .
+    calls = [l for l in text.splitlines()
+             if "custom-call(" in l and "tpu_custom_call" in l]
+    assert sum("moe_combine" in l for l in calls) == 2
+    assert f"[{tokens},{total}," not in text.replace(" ", "")  # x capacity
+    scattered = [l for l in text.splitlines() if " scatter(" in l
+                 and ",2048]" in l.split(" scatter(")[0]]
+    assert not scattered
 
 
 def test_ring_block_compiles(topo):
