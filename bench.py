@@ -983,24 +983,11 @@ def run_lm_benchmark(args) -> int:
 
     # Per-step skew summary (docs/timeline.md "Step spans & straggler
     # attribution"): a single-controller bench has one host process, so
-    # cross-rank HOST skew is structurally zero here — the block still
-    # reports the local step-span distribution (trace tap when armed,
-    # else iteration-level timing), and a multi-process `hvdrun` round
-    # gets real skew via the driver's hvd_step_skew_seconds /
-    # hvd_straggler_total metrics and tools/trace_merge.py.
-    span_summary = _trace.step_summary()
-    if not span_summary.get("steps"):
-        per_step = sorted(dt / steps_per_iter for dt in iter_times)
-        span_summary = {
-            "steps": steps_per_iter * args.num_iters,
-            "p50_s": round(per_step[len(per_step) // 2], 6),
-            "p99_s": round(per_step[-1], 6),
-            "source": "iter-timing",
-        }
-    else:
-        span_summary["source"] = "trace-step-tap"
+    # cross-rank HOST skew is structurally zero here; a multi-process
+    # `hvdrun` round gets real skew via the driver's
+    # hvd_step_skew_seconds / hvd_straggler_total metrics and
+    # tools/trace_merge.py.
     step_skew = {
-        "step_spans": span_summary,
         "p50_skew_s": 0.0,
         "p99_skew_s": 0.0,
         "worst_rank": None,

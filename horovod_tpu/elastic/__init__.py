@@ -574,12 +574,12 @@ def _adopt_replan(ctx: _ElasticContext) -> None:
             "hvd_replan_adopt", cat="elastic",
             id=int(doc.get("id", 0)), gen=ctx.gen,
         )
-        # The new plan invalidates the noted correlation ids; the
-        # rebuilt step re-notes its own.
-        _trace.TAP.note_plan(
-            topo_algorithm=doc.get("config", {}).get("topo_algorithm"),
-            wire_dtype=doc.get("config", {}).get("wire_dtype"),
-        )
+    # The new plan invalidates the noted correlation ids; the
+    # rebuilt step re-notes its own.
+    _trace.note_plan(
+        topo_algorithm=doc.get("config", {}).get("topo_algorithm"),
+        wire_dtype=doc.get("config", {}).get("wire_dtype"),
+    )
     if _fault_injector.ACTIVE:
         _fault_injector.record_event(
             "driver", int(doc.get("id", 0)), "replan-adopt",

@@ -22,6 +22,10 @@ Typical use::
 
 from __future__ import annotations
 
+import time as _time
+
+_IMPORT_START = _time.perf_counter()  # before `import jax`: trace.note_import
+
 import contextlib as _contextlib
 import functools as _functools
 import logging
@@ -69,6 +73,10 @@ from ..parallel.mesh import (
 )
 
 _logger = logging.getLogger("horovod_tpu")
+
+# Before any program of this process is traced: the build ledger hears JAX's
+# trace, lower and compile-or-load events from here on (docs/timeline.md).
+_trace.install_build_listeners()
 
 # Compiled-mode users reach collectives through jit, never through hvd.init;
 # an explicit HOROVOD_XLA_PERF_PRESET must land in LIBTPU_INIT_ARGS before the
@@ -593,12 +601,11 @@ def _zero1_distributed_optimizer(
             "off/zero/warn"
         )
     knobs = dict(threshold_bytes=fusion_threshold_bytes, quantized=quantized)
-    if _trace.ACTIVE:
-        _trace.TAP.note_plan(
-            optimizer="DistributedOptimizer",
-            wire_dtype="int8" if quantized else "f32",
-            overlap=bool(overlap), zero1=True,
-        )
+    _trace.note_plan(
+        optimizer="DistributedOptimizer",
+        wire_dtype="int8" if quantized else "f32",
+        overlap=bool(overlap), zero1=True,
+    )
 
     def init_fn(params):
         return init_zero1_stream_state(
@@ -845,18 +852,17 @@ def DistributedOptimizer(  # noqa: N802 - API parity with hvd.DistributedOptimiz
         )
         _tuned_resolution["r"] = r
         return r
-    if _trace.ACTIVE:
-        # Step-span correlation ids for loops driven by this optimizer:
-        # the host-side step boundaries themselves come from wrap_step
-        # or the elastic commit seam (an optax transformation runs
-        # inside the caller's jit and has no host boundary of its own),
-        # but every step span they record carries this wire/overlap
-        # configuration. Disabled → not reached (NULL_TAP discipline).
-        _trace.TAP.note_plan(
-            optimizer="DistributedOptimizer",
-            wire_dtype="int8" if quantized else "f32",
-            overlap=bool(overlap),
-        )
+    # Step-span correlation ids for loops driven by this optimizer:
+    # the host-side step boundaries themselves come from wrap_step
+    # or the elastic commit seam (an optax transformation runs
+    # inside the caller's jit and has no host boundary of its own),
+    # but every step span they record carries this wire/overlap
+    # configuration.
+    _trace.note_plan(
+        optimizer="DistributedOptimizer",
+        wire_dtype="int8" if quantized else "f32",
+        overlap=bool(overlap),
+    )
 
     def init_fn(params):
         if _knobs(params, "init")["use_ef"]:
@@ -1790,3 +1796,6 @@ class GradientAccumulator:
 
     def should_reduce(self, step_count: int) -> bool:
         return (step_count + 1) % self.n == 0
+
+
+_trace.note_import(_IMPORT_START, _time.perf_counter())

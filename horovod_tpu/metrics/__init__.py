@@ -67,6 +67,19 @@ _CATALOG: Dict[str, str] = {
     "hvd_xla_cache_hits_total": "Compiled-collective cache hits",
     "hvd_xla_cache_misses_total": "Compiled-collective cache misses",
     "hvd_xla_compile_seconds": "Compiled-collective build time",
+    # The build ledger (trace/build.py; docs/timeline.md): JAX's own
+    # programs, beside the eager runtime's compiled collectives above.
+    "hvd_jit_compiles_total": "Programs traced, lowered, compiled or "
+                              "loaded by JAX (labeled by phase)",
+    "hvd_jit_compile_seconds_total": "Seconds JAX spent tracing, lowering "
+                                     "and compiling or loading (labeled "
+                                     "by phase)",
+    "hvd_jit_cache_hits_total": "Executables found in JAX's persistent "
+                                "compilation cache",
+    "hvd_jit_cache_misses_total": "Executables compiled and written to "
+                                  "JAX's persistent compilation cache",
+    "hvd_kernel_fallbacks_total": "Traced kernel call sites that took the "
+                                  "XLA form (labeled by op and reason)",
     "hvd_rpc_requests_total": "Control-plane RPCs issued",
     "hvd_rpc_retries_total": "Control-plane RPC retries (backoff fired)",
     "hvd_rpc_failures_total": "Control-plane RPCs failed after retries",

@@ -66,8 +66,7 @@ class ShortConv(nn.Module):
         bcu = _dense(3 * C, "in_proj", self.dtype, self.init_std)(x)
         kernel = self.param("conv", lambda k, s: {"kernel": _normal(
             self.init_std)(k, s, f32)}, (self.conv_kernel, C))["kernel"]
-        if _trace.ACTIVE:
-            _trace.TAP.note_plan(short_conv_taps=self.conv_kernel)
+        _trace.note_plan(short_conv_taps=self.conv_kernel)
         with jax.named_scope(_trace.SCOPE_SHORT_CONV):
             gate_in, gate_out, u = jnp.split(bcu, 3, axis=-1)
             # the first gate is a product of two bfloat16 projections and is

@@ -343,9 +343,8 @@ def expert_load(model, params, tokens):
         for i in range(model.cfg.n_layers)
         for ffn in inter.get(f"layer_{i}", {}).values() if "held_load" in ffn
     ])
-    if _trace.ACTIVE:
-        _trace.TAP.note_plan(
-            moe_pairs_held=[int(v) for v in load[:, 0]],
-            moe_largest_load=[int(v) for v in load[:, 1]],
-        )
+    _trace.note_plan(
+        moe_pairs_held=[int(v) for v in load[:, 0]],
+        moe_largest_load=[int(v) for v in load[:, 1]],
+    )
     return load

@@ -141,11 +141,10 @@ def record_axis_wire_bytes(
     ``hvd_axis_wire_bytes_total{axis,collective}`` (docs/metrics.md) plus
     a trace-tap plan note so step spans carry the split. This is what
     lets a composed DP x TP program report its DP and TP wire bytes
-    SEPARATELY (docs/parallelism.md "Per-axis attribution"). Must be
+    SEPARATELY (docs/parallelism.md "Per-axis attribution"); the note is
+    always recorded, the counter with metrics armed. Must be
     called inside the axis-binding trace (the axis size is read off the
-    live binding); no-op when neither metrics nor tracing is armed."""
-    if not (_metrics.ACTIVE or _trace.ACTIVE):
-        return
+    live binding)."""
     n = _axis_size_of(
         tuple(_axes_of(axis_name)) if isinstance(axis_name, (tuple, list))
         else axis_name
@@ -167,10 +166,9 @@ def record_axis_wire_bytes(
             "hvd_axis_wire_bytes_total", float(onwire),
             axis=label, collective=collective,
         )
-    if _trace.ACTIVE:
-        _trace.TAP.note_plan(
-            **{f"axis_wire_bytes:{label}:{collective}": int(onwire)}
-        )
+    _trace.note_plan(
+        **{f"axis_wire_bytes:{label}:{collective}": int(onwire)}
+    )
 
 
 def fused_allreduce(
@@ -201,13 +199,12 @@ def fused_allreduce(
         sum(l.size * dtype_size(dtype_from_array(l)) for l in leaves),
         axis_name, "allreduce", wire_dtype,
     )
-    if _trace.ACTIVE:
-        # Correlation ids for the fleet-trace step spans (trace-time,
-        # one note per compile): which fusion path reduced how many
-        # buckets this step.
-        _trace.TAP.note_plan(
-            fusion_path=label, fusion_buckets=len(buckets)
-        )
+    # Correlation ids for the fleet-trace step spans (trace-time,
+    # one note per compile): which fusion path reduced how many
+    # buckets this step.
+    _trace.note_plan(
+        fusion_path=label, fusion_buckets=len(buckets)
+    )
     if _metrics.ACTIVE:
         # Trace-time plan stats (one emission per compile, not per step).
         _metrics.TAP.set(
@@ -509,11 +506,10 @@ def fused_reduce_scatter(
         axis_name, "reduce_scatter",
         "int8" if quantized else "f32",
     )
-    if _trace.ACTIVE:
-        _trace.TAP.note_plan(
-            fusion_path=label, fusion_buckets=len(buckets),
-            zero1_reduction="reduce-scatter",
-        )
+    _trace.note_plan(
+        fusion_path=label, fusion_buckets=len(buckets),
+        zero1_reduction="reduce-scatter",
+    )
     if _metrics.ACTIVE:
         _metrics.TAP.set(
             "hvd_fusion_buckets", float(len(buckets)), path=label
@@ -751,12 +747,11 @@ def quantized_ef_allreduce(
         sum(l.size * dtype_size(dtype_from_array(l)) for l in leaves),
         axis_name, "allreduce", "int8",
     )
-    if _trace.ACTIVE:
-        # Correlation ids for the fleet-trace step spans (trace-time):
-        # the EF int8 wire reduced this many buckets under this label.
-        _trace.TAP.note_plan(
-            fusion_path=label, fusion_buckets=len(buckets)
-        )
+    # Correlation ids for the fleet-trace step spans (trace-time):
+    # the EF int8 wire reduced this many buckets under this label.
+    _trace.note_plan(
+        fusion_path=label, fusion_buckets=len(buckets)
+    )
     results: List[jax.Array | None] = [None] * len(leaves)
     residuals: List[jax.Array | None] = [None] * len(leaves)
     average = op == ReduceOp.AVERAGE

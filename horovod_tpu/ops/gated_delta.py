@@ -374,6 +374,18 @@ def _step_vmem_bytes(tiles, R, C, dk, dv, in_size, out_size):
     return 2 * tiles * max(fwd, bwd) + (30 * R * R + 20 * R * d) * 4
 
 
+def _refusal(T, C, dk, dv):
+    """Why the shapes are not the kernels' (the word the build ledger's
+    fallback record carries), or None where they are."""
+    if C & (C - 1) or C < 16:
+        return "chunk_not_power_of_two"
+    if dk % _LANES or dv % _LANES:
+        return "head_width_not_whole_lanes"
+    if T % max(C, _LANES):
+        return "sequence_not_whole_tiles"
+    return None
+
+
 def _plan(T, C, dk, dv, in_size, out_size):
     """``(tile, tiles a grid step)`` of the chunk-local kernels, or None
     where the shapes are not theirs: a chunk that is no power of two (or
@@ -382,7 +394,7 @@ def _plan(T, C, dk, dv, in_size, out_size):
     A step takes the largest divisor of the head's tiles, up to
     ``_PREF_TILES``, that fits the VMEM budget."""
     R = max(C, _LANES)
-    if C & (C - 1) or C < 16 or dk % _LANES or dv % _LANES or T % R:
+    if _refusal(T, C, dk, dv):
         return None
     for tiles in range(min(_PREF_TILES, T // R), 0, -1):
         if (T // R) % tiles == 0 and _step_vmem_bytes(
@@ -545,15 +557,14 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
     plan = None if initial_state is not None else _plan(
         T, C, dk, dv, max(x.dtype.itemsize for x in (q, k, v)),
         dtype.itemsize)
-    if _trace.ACTIVE:
-        R, tiles = plan or (0, 0)
-        _trace.TAP.note_plan(
-            gdn_chunk=C, gdn_heads=H, gdn_chunks=N,
-            gdn_kernel=plan is not None,
-            gdn_block_chunks=tiles * R // C,
-            gdn_grid_steps=B * H * T // (tiles * R) if plan else 0,
-            gdn_bwd_recomputes_inverse=False,
-        )
+    R, tiles = plan or (0, 0)
+    _trace.note_plan(
+        gdn_chunk=C, gdn_heads=H, gdn_chunks=N,
+        gdn_kernel=plan is not None,
+        gdn_block_chunks=tiles * R // C,
+        gdn_grid_steps=B * H * T // (tiles * R) if plan else 0,
+        gdn_bwd_recomputes_inverse=False,
+    )
 
     # [B, T, H] -> [B, H, N, C]
     by_chunk = lambda x: jnp.moveaxis(x.astype(f32), 1, 2).reshape(
@@ -561,6 +572,12 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = DEFAULT_CHUNK,
     G = jnp.cumsum(by_chunk(g), axis=-1)
     g_last = G[..., -1]                               # [B, H, N]
     if plan is None:
+        # the XLA form's backward is JAX's transpose of it: the one record
+        # stands for gdn_bwd too
+        _trace.note_fallback(
+            "gdn_fwd", "initial_state" if initial_state is not None
+            else _refusal(T, C, dk, dv) or "no_tile_fits_vmem",
+            batch=B, seq=T, heads=H, chunk=C, dk=dk, dv=dv)
         shared = lambda x: jnp.repeat(x, H // Hk, axis=2)
         local = _local_xla(shared(q), shared(k), v, G, by_chunk(beta), C,
                            dtype)

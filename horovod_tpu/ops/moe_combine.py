@@ -34,6 +34,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import trace as _trace
 from . import pallas_attention as _pa
 from .pallas_attention import _LANES, _VMEM_BUDGET
 
@@ -47,14 +48,25 @@ def plan(tokens: int, width: int, dtype) -> Optional[int]:
     is copied as ``width / 128`` sublanes of 128 lanes), a token count no
     block of whole sublanes divides. Two row buffers and the pipeline's two
     output blocks are ``4 * block * width`` float32."""
-    if (jnp.dtype(dtype) != jnp.float32 or width % (_SUBLANES * _LANES)
-            or tokens % _SUBLANES):
+    if _refusal(tokens, width, dtype):
         return None
     block = _PREF_TOKENS
     while block > _SUBLANES and (tokens % block
                                  or 16 * block * width > _VMEM_BUDGET):
         block //= 2
     return block if tokens % block == 0 else None
+
+
+def _refusal(tokens: int, width: int, dtype) -> Optional[str]:
+    """Why the shapes are not the kernel's (the word the build ledger's
+    fallback record carries), or None where they are."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return "rows_not_float32"
+    if width % (_SUBLANES * _LANES):
+        return "row_not_whole_tiles"
+    if tokens % _SUBLANES:
+        return "tokens_not_whole_blocks"
+    return None
 
 
 def _kernel(counts_ref, pos_s, tok_s, pos_v, w_v, rows_hbm, out_ref, buf, sem,
@@ -187,6 +199,11 @@ def gather_sum(rows: jax.Array, pos: jax.Array,
     block = plan(pos.shape[0], rows.shape[1], rows.dtype)
     weight = weight.astype(jnp.float32)
     if block is None:
+        _trace.note_fallback(
+            "moe_combine",
+            _refusal(pos.shape[0], rows.shape[1], rows.dtype)
+            or "tokens_not_whole_blocks",
+            tokens=pos.shape[0], slots=pos.shape[1], width=rows.shape[1])
         return _xla(rows, pos, weight)
     return _pallas(rows, pos, weight, block=block,
                    interpret=_pa._resolve_interpret(None),

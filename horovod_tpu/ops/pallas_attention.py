@@ -390,17 +390,15 @@ def _flash_call(q, k, v, delta, *, sm_scale, causal, block_q, block_k,
         bh, t_q, t_k, d, q.dtype.itemsize, out_dtype.itemsize,
         block_q, block_k,
     )
-    if _trace.ACTIVE:
-        # Trace-time, one note per compile (the fusion plan's discipline):
-        # what one grid step of this program's forward kernel is.
-        _trace.TAP.note_plan(
-            flash_block_q=block_q, flash_block_k=block_k,
-            flash_rows_per_step=rows,
-            flash_grid_steps=(bh // rows) * (t_q // block_q)
-            * (t_k // block_k),
-            flash_pairs_visited=round(
-                _pairs_visited(t_q, t_k, block_q, block_k, causal), 4),
-        )
+    # Trace-time, one note per compile (the fusion plan's discipline):
+    # what one grid step of this program's forward kernel is.
+    _trace.note_plan(
+        flash_block_q=block_q, flash_block_k=block_k,
+        flash_rows_per_step=rows,
+        flash_grid_steps=(bh // rows) * (t_q // block_q) * (t_k // block_k),
+        flash_pairs_visited=round(
+            _pairs_visited(t_q, t_k, block_q, block_k, causal), 4),
+    )
     plan = (bh, t_q, t_k, d, out_dtype, sm_scale, causal, block_q, block_k,
             rows, normalize, interpret)
     args = (jnp.asarray(delta, jnp.int32).reshape(1), q, k, v)
@@ -748,18 +746,17 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     t_k = k.shape[1]
     block_q, block_k, rows, one_pass = _plan_bwd(
         bh, t_q, t_k, d, q.dtype.itemsize, block_q, block_k)
-    if _trace.ACTIVE:
-        # Beside the forward's note: what one grid step of this program's
-        # backward is, which form runs, and the steps of all its kernels.
-        _trace.TAP.note_plan(
-            flash_bwd_block_q=block_q, flash_bwd_block_k=block_k,
-            flash_bwd_rows_per_step=rows,
-            flash_bwd_one_pass=one_pass,
-            flash_bwd_grid_steps=(1 if one_pass else 2) * (bh // rows)
-            * (t_q // block_q) * (t_k // block_k),
-            flash_bwd_pairs_visited=round(
-                _pairs_visited(t_q, t_k, block_q, block_k, causal), 4),
-        )
+    # Beside the forward's note: what one grid step of this program's
+    # backward is, which form runs, and the steps of all its kernels.
+    _trace.note_plan(
+        flash_bwd_block_q=block_q, flash_bwd_block_k=block_k,
+        flash_bwd_rows_per_step=rows,
+        flash_bwd_one_pass=one_pass,
+        flash_bwd_grid_steps=(1 if one_pass else 2) * (bh // rows)
+        * (t_q // block_q) * (t_k // block_k),
+        flash_bwd_pairs_visited=round(
+            _pairs_visited(t_q, t_k, block_q, block_k, causal), 4),
+    )
     # D_i = sum_j dO_ij O_ij (the softmax-jacobian row term), and the
     # statistics as the lane-dense rows the forward kernel writes.
     dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -832,6 +829,10 @@ def flash_attention_bthd(
             qf, kf, vf, causal=causal, sm_scale=scale, interpret=interpret,
         )
     else:
+        # the dense form's backward is JAX's transpose of it: the one record
+        # stands for both directions
+        _trace.note_fallback("attention", "no_block_divisor", t_q=T,
+                             t_k=k.shape[1], heads=H, head_dim=D)
         out = _dense_full(qf, kf, vf, causal, scale)
     return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
@@ -883,6 +884,9 @@ def _flash_block_vjp_fwd(q, k, v, delta, sm_scale, causal, block_q, block_k,
 def _flash_block_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res,
                          cts):
     q, k, v, delta = res
+    _trace.note_fallback("flash_bwd", "ring_block_recomputes_densely",
+                         bh=q.shape[0], t_q=q.shape[1], t_k=k.shape[1],
+                         head_dim=q.shape[2])
 
     def f(q, k, v):
         return _dense_block(q, k, v, delta, sm_scale, causal)

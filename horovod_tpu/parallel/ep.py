@@ -389,15 +389,14 @@ def dropless_moe(
         2 * s_tokens * top_k * e_held // e_total + 8 * e_held, 512))
     tiles = -(-worst // rows)
     slots = min(top_k, e_held)      # the most held pairs a token can have
-    if _trace.ACTIVE:
-        block = _combine.plan(s_tokens, d_model, jnp.float32)
-        _trace.TAP.note_plan(
-            moe_experts_total=e_total, moe_experts_held=e_held,
-            moe_top_k=top_k, moe_tile_rows=rows, moe_tiles=tiles,
-            moe_score=score, moe_select_bias=select_bias is not None,
-            moe_combine_kernel=block is not None,
-            moe_combine_block=block or 0, moe_combine_slots=slots,
-        )
+    block = _combine.plan(s_tokens, d_model, jnp.float32)
+    _trace.note_plan(
+        moe_experts_total=e_total, moe_experts_held=e_held,
+        moe_top_k=top_k, moe_tile_rows=rows, moe_tiles=tiles,
+        moe_score=score, moe_select_bias=select_bias is not None,
+        moe_combine_kernel=block is not None,
+        moe_combine_block=block or 0, moe_combine_slots=slots,
+    )
     with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
         weights, ids = route_top_k(
             x, w_router, top_k=top_k, norm_topk=norm_topk, score=score,

@@ -1358,15 +1358,14 @@ def record_plan(plan: Plan, where: str = "compositor") -> Plan:
     from .. import metrics as _metrics
     from .. import trace as _trace
 
-    if _trace.ACTIVE:
-        # Correlation ids for the fleet-trace step spans: the selected
-        # lowering algorithm + wire dtype ride every later step span so
-        # one trace links step → bucket → collective → hop.
-        _trace.TAP.note_plan(
-            topo_algorithm=plan.algorithm,
-            topo_collective=plan.collective,
-            wire_dtype=getattr(plan, "wire_dtype", "f32"),
-        )
+    # Correlation ids for the fleet-trace step spans: the selected
+    # lowering algorithm + wire dtype ride every later step span so
+    # one trace links step → bucket → collective → hop.
+    _trace.note_plan(
+        topo_algorithm=plan.algorithm,
+        topo_collective=plan.collective,
+        wire_dtype=getattr(plan, "wire_dtype", "f32"),
+    )
     if _metrics.ACTIVE:
         _metrics.TAP.set(
             "hvd_topo_plan_info", 1.0,

@@ -22,6 +22,12 @@ artifact:
   discipline) on guard abort, stall-ladder escalation, SIGTERM, and
   uncaught crashes, so "the last N seconds before death, all ranks,
   aligned" survives the process (``tools/trace_merge.py --postmortem``).
+- **Build ledger** (``build.py``): what this process traced, lowered and
+  compiled or loaded, by function, the plan notes of the fusion, topology
+  and kernel layers, and every kernel call site that took its XLA form.
+  ALWAYS on, the one exception to the discipline below: all of it happens
+  where a program is built, none of it where a step runs. Armed, the
+  compiles are ring events too and the notes ride every step span.
 
 Tap discipline — identical to ``fault/injector.py`` / ``metrics`` /
 ``guard``: with no trace knob set (the production default) the
@@ -48,6 +54,18 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
+
+from .build import (  # noqa: F401 (re-exported: the build ledger)
+    KERNELS,
+    _count,
+    build_ledger,
+    install_build_listeners,
+    note_fallback,
+    note_import,
+    note_plan,
+    plan_args,
+    reset_build_ledger,
+)
 
 logger = logging.getLogger("horovod_tpu.trace")
 
@@ -149,13 +167,6 @@ def _rank() -> int:
     return int(v) if v.isdigit() else 0
 
 
-def _count(name: str, value: float = 1.0, **labels) -> None:
-    from .. import metrics as _metrics
-
-    if _metrics.ACTIVE:
-        _metrics.TAP.inc(name, value, **labels)
-
-
 class TraceTap:
     """The live tap: a thread-safe bounded ring of span/event records
     plus the step ledger the straggler attribution feeds on.
@@ -178,9 +189,6 @@ class TraceTap:
         self._wrapped_steps = 0
         self._last_commit_t: Optional[float] = None
         self._commit_idx = 0
-        # Correlation ids noted at trace time by the fusion/compositor
-        # layers; stamped onto every step span (docs/timeline.md).
-        self._plan_args: Dict[str, Any] = {}
         # Clock-offset estimate vs the driver (recorded metadata, never
         # applied to timestamps).
         self.clock: Dict[str, Any] = {
@@ -241,7 +249,7 @@ class TraceTap:
             "dur": t1 - t0,
             "cat": "step",
             "tid": 0,
-            "args": {"step": idx, **self.plan_args(), **args},
+            "args": {"step": idx, **plan_args(), **args},
         }
         with self._lock:
             self._ring.append(rec)
@@ -273,36 +281,6 @@ class TraceTap:
             if not wrapped and last is not None:
                 self._steps.append((idx - 1, last, now))
 
-    def step_summary(self) -> Dict[str, Any]:
-        """Local step-span statistics (``bench.py`` report block)."""
-        with self._lock:
-            durs = sorted(t1 - t0 for _, t0, t1 in self._steps)
-        if not durs:
-            return {"steps": 0}
-
-        def pct(p: float) -> float:
-            return durs[min(int(p * (len(durs) - 1)), len(durs) - 1)]
-
-        return {
-            "steps": len(durs),
-            "p50_s": round(pct(0.50), 6),
-            "p99_s": round(pct(0.99), 6),
-        }
-
-    # ------------------------------------------------- correlation ids
-    def note_plan(self, **kw) -> None:
-        """Record the active plan/correlation ids (fusion bucket plan,
-        topo algorithm, wire dtype) — stamped onto every later step span
-        so one trace links step → bucket → collective → hop."""
-        with self._lock:
-            self._plan_args.update(
-                {k: v for k, v in kw.items() if v is not None}
-            )
-
-    def plan_args(self) -> Dict[str, Any]:
-        with self._lock:
-            return dict(self._plan_args)
-
     # ------------------------------------------------------- shipping
     def window(self) -> Dict[str, Any]:
         """The pushable/dumpable view of this rank's recent activity —
@@ -321,7 +299,7 @@ class TraceTap:
             "rank": self.rank,
             "gen": int(gen) if gen.isdigit() else 0,
             "clock": dict(self.clock),
-            "plan": self.plan_args(),
+            "plan": plan_args(),
             "events": events,
             "steps": steps,
         }
@@ -408,15 +386,6 @@ class _NullTraceTap:
 
     def commit_step(self, **args) -> None:
         pass
-
-    def step_summary(self) -> Dict[str, Any]:
-        return {"steps": 0}
-
-    def note_plan(self, **kw) -> None:
-        pass
-
-    def plan_args(self) -> Dict[str, Any]:
-        return {}
 
     def window(self) -> Dict[str, Any]:
         return {}
@@ -530,10 +499,6 @@ def flight_dump(reason: str) -> Optional[str]:
     if not ACTIVE:
         return None
     return TAP.flight_dump(reason)
-
-
-def step_summary() -> Dict[str, Any]:
-    return TAP.step_summary()
 
 
 # Re-exported for the driver/tools (lazy submodule import keeps worker

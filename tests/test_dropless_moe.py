@@ -182,20 +182,18 @@ def test_selection_bias_takes_no_gradient_and_changes_the_choice():
     assert float(jnp.max(jnp.abs(layer(BIAS) - layer(None)))) > 1e-3
 
 
-def _plan_notes(monkeypatch, traced):
-    """The plan notes ``traced()`` leaves with tracing armed."""
+def _plan_notes(traced):
+    """The plan notes ``traced()`` leaves in the build ledger, tracing off."""
     from horovod_tpu import trace
 
-    notes = {}
-    monkeypatch.setattr(trace, "ACTIVE", True)
-    monkeypatch.setattr(trace, "TAP", type("Tap", (), {
-        "note_plan": staticmethod(lambda **kw: notes.update(kw))})())
+    assert not trace.ACTIVE
+    trace.reset_build_ledger()
     traced()
-    return notes
+    return trace.plan_args()
 
 
-def test_plan_notes_when_tracing_is_armed(monkeypatch):
-    notes = _plan_notes(monkeypatch, lambda: _share(*_weights(5), 0, 4))
+def test_plan_notes_are_always_recorded():
+    notes = _plan_notes(lambda: _share(*_weights(5), 0, 4))
     assert notes["moe_experts_total"] == E and notes["moe_experts_held"] == 4
     assert notes["moe_top_k"] == K
     assert notes["moe_tile_rows"] * notes["moe_tiles"] >= S * K
@@ -203,8 +201,7 @@ def test_plan_notes_when_tracing_is_armed(monkeypatch):
     assert notes["moe_combine_kernel"] is False
     assert notes["moe_combine_block"] == 0 and notes["moe_combine_slots"] == K
     assert notes["moe_score"] == "softmax" and notes["moe_select_bias"] is False
-    notes = _plan_notes(
-        monkeypatch, lambda: _share(*_weights(5), 0, 4, "sigmoid_bias"))
+    notes = _plan_notes(lambda: _share(*_weights(5), 0, 4, "sigmoid_bias"))
     assert notes["moe_score"] == "sigmoid" and notes["moe_select_bias"] is True
 
 
@@ -314,7 +311,7 @@ def test_both_regimes_equal_the_masked_loop_and_the_scatter_add(regime, mode):
 
 @modes
 @pytest.mark.parametrize("load", list(LOADS))
-def test_loads_past_one_tile_and_an_empty_load(load, mode, monkeypatch):
+def test_loads_past_one_tile_and_an_empty_load(load, mode):
     """Every token choosing held experts overflows into a second tile and on
     to a fourth, and a share nobody chose computes nothing: the loop over
     tiles runs as far as the load reaches, forward and backward."""
@@ -334,8 +331,7 @@ def test_loads_past_one_tile_and_an_empty_load(load, mode, monkeypatch):
         assert float(jnp.min(jnp.max(jnp.abs(y), axis=-1))) > 0  # every token
     else:
         assert float(jnp.max(jnp.abs(y))) == 0.0
-    notes = _plan_notes(
-        monkeypatch, lambda: _held_share(*args, first, held, K, routing))
+    notes = _plan_notes(lambda: _held_share(*args, first, held, K, routing))
     assert notes["moe_tile_rows"] == rows
     assert notes["moe_tiles"] >= max(tiles_reached, 1)
 
@@ -347,14 +343,14 @@ def test_loads_past_one_tile_and_an_empty_load(load, mode, monkeypatch):
     (32768, 4, 8, 32, 66048, 2, 256),
 ])
 def test_the_cells_keep_their_tiles_and_take_the_kernel(
-        monkeypatch, tokens, top_k, held, total, rows, tiles, block):
+        tokens, top_k, held, total, rows, tiles, block):
     """At both cells' shapes a tile is what balanced routing fills twice
     over and eight rows an expert, in whole 512s, as before the gather-sum
     came; the rows of width 2048 are whole float32 tiles, so the kernel
     runs, 256 tokens a grid step, with as many slots as a token can hold
     pairs."""
     arr = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
-    notes = _plan_notes(monkeypatch, lambda: jax.eval_shape(
+    notes = _plan_notes(lambda: jax.eval_shape(
         lambda *a: ep.dropless_moe(*a, top_k=top_k),
         jax.ShapeDtypeStruct((tokens, 2048), jnp.bfloat16),
         arr(2048, total), arr(held, 2048, 64), arr(held, 2048, 64),
@@ -363,6 +359,8 @@ def test_the_cells_keep_their_tiles_and_take_the_kernel(
     assert rows == -(-(2 * tokens * top_k * held // total + 8 * held)
                      // 512) * 512
     assert notes["moe_combine_kernel"] is True
+    from horovod_tpu import trace
+    assert trace.build_ledger()["fallbacks"] == []   # no call site without it
     assert notes["moe_combine_block"] == block
     assert notes["moe_combine_slots"] == min(top_k, held)
 

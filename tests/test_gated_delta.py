@@ -261,19 +261,14 @@ def test_a_chunk_that_is_no_power_of_two_is_the_xla_form_s_to_refuse():
 
 
 @pytest.mark.parametrize("kernel", [True, False])
-def test_plan_notes_with_tracing_armed(kernel, monkeypatch):
+def test_plan_notes_are_always_recorded(kernel):
     from horovod_tpu import trace
 
-    notes = {}
-
-    class Tap:
-        def note_plan(self, **kw):
-            notes.update(kw)
-
-    monkeypatch.setattr(trace, "ACTIVE", True)
-    monkeypatch.setattr(trace, "TAP", Tap())
+    assert not trace.ACTIVE
+    trace.reset_build_ledger()
     args = _wide(1, 512, 1, 2, 128 if kernel else 16, 128)
     assert _uses_kernel(*args, chunk=64) == kernel
+    notes = trace.plan_args()
     assert notes["gdn_chunk"] == 64 and notes["gdn_chunks"] == 8
     assert notes["gdn_heads"] == 2
     assert notes["gdn_kernel"] is kernel

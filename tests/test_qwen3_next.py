@@ -219,25 +219,21 @@ def test_trains_through_make_train_step():
     assert np.isfinite(losses).all() and losses[-1] < losses[0] - 0.05
 
 
-def test_scopes_and_plan_notes(monkeypatch):
+def test_scopes_and_plan_notes():
     from horovod_tpu import trace
 
     cfg, model, params, tokens, labels = _setup(jnp.bfloat16)
-    notes = {}
-
-    class Tap:
-        def note_plan(self, **kw):
-            notes.update(kw)
-
-    monkeypatch.setattr(trace, "ACTIVE", True)
-    monkeypatch.setattr(trace, "TAP", Tap())
+    assert not trace.ACTIVE         # the notes are recorded all the same
+    trace.reset_build_ledger()
     text = jax.jit(jax.grad(_loss(model))).lower(
         params, tokens, labels).as_text(debug_info=True)
+    notes = trace.plan_args()
     for scope in trace.MODEL_SCOPES:
         assert scope in text, scope
     assert notes["gdn_chunk"] == 16 and notes["gdn_heads"] == 4
     assert notes["moe_experts_total"] == 8 and notes["moe_experts_held"] == 4
     load = np.asarray(qn.expert_load(model, params, tokens))
+    notes = trace.plan_args()
     assert load.shape == (4, 2)
     assert notes["moe_pairs_held"] == list(load[:, 0])
     assert notes["moe_largest_load"] == list(load[:, 1])

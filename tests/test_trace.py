@@ -31,9 +31,11 @@ def _clean_trace_state():
     """Every test starts and ends with both taps in their env-default
     state (inactive in the test environment)."""
     hvd_trace.reset()
+    hvd_trace.reset_build_ledger()
     hvd_metrics.reset()
     yield
     hvd_trace.reset()
+    hvd_trace.reset_build_ledger()
     hvd_metrics.reset()
 
 
@@ -47,7 +49,6 @@ def test_disabled_tap_is_shared_noop_singleton():
     hvd_trace.TAP.commit_step()
     hvd_trace.TAP.end_step(hvd_trace.TAP.begin_step())
     assert hvd_trace.TAP.window() == {}
-    assert hvd_trace.TAP.step_summary() == {"steps": 0}
     assert hvd_trace.flight_dump("nope") is None
 
 
@@ -123,7 +124,7 @@ def test_wrap_step_enters_the_profilers_step_annotation(monkeypatch):
 
 def test_wrap_step_records_spans_with_meta_and_plan_args():
     hvd_trace.install(True)
-    hvd_trace.TAP.note_plan(topo_algorithm="ring", wire_dtype="int8")
+    hvd_trace.note_plan(topo_algorithm="ring", wire_dtype="int8")
 
     calls = []
     step = hvd_trace.wrap_step(lambda x: calls.append(x), overlap=True)
@@ -139,7 +140,6 @@ def test_wrap_step_records_spans_with_meta_and_plan_args():
     assert spans[0]["args"]["topo_algorithm"] == "ring"
     assert spans[0]["args"]["wire_dtype"] == "int8"
     assert len(win["steps"]) == 2
-    assert hvd_trace.step_summary()["steps"] == 2
 
 
 def test_ring_is_bounded():
@@ -493,14 +493,14 @@ def test_distributed_optimizer_notes_plan_when_tracing():
 
     hvd_trace.install(True)
     hvdj.DistributedOptimizer(optax.sgd(0.1), quantized=True)
-    plan = hvd_trace.TAP.plan_args()
+    plan = hvd_trace.TAP.window()["plan"]
     assert plan["optimizer"] == "DistributedOptimizer"
     assert plan["wire_dtype"] == "int8"
 
 
 def test_flash_kernel_notes_its_plan_once_per_compile(monkeypatch):
     """The forward flash kernel's tile plan is a trace-time plan note: one
-    emission per compile when tracing is armed, none when it is not."""
+    emission per compile, tracing armed or not."""
     import functools
 
     import jax
@@ -512,13 +512,13 @@ def test_flash_kernel_notes_its_plan_once_per_compile(monkeypatch):
     fresh = lambda: jax.jit(functools.partial(pa.flash_attention, causal=True))
     fresh()(q, q, q)
     assert hvd_trace.TAP is hvd_trace.NULL_TAP
-    assert hvd_trace.TAP.plan_args() == {}
+    assert hvd_trace.plan_args()["flash_block_q"] == 64   # noted all the same
 
-    hvd_trace.install(True)
+    hvd_trace.reset_build_ledger()
     notes = []
-    note_plan = hvd_trace.TAP.note_plan
+    note_plan = hvd_trace.note_plan
     monkeypatch.setattr(
-        hvd_trace.TAP, "note_plan",
+        hvd_trace, "note_plan",
         lambda **kw: (notes.append(kw), note_plan(**kw)),
     )
     monkeypatch.setattr(pa, "_PREF_BLOCK", 16)
@@ -526,7 +526,7 @@ def test_flash_kernel_notes_its_plan_once_per_compile(monkeypatch):
     step(q, q, q)
     step(q, q, q)                       # the cached executable: no new note
     assert len(notes) == 1
-    assert hvd_trace.TAP.plan_args() == {
+    assert hvd_trace.plan_args() == {
         "flash_block_q": 16, "flash_block_k": 16, "flash_rows_per_step": 8,
         "flash_grid_steps": 16, "flash_pairs_visited": 0.625,
     }
@@ -544,11 +544,10 @@ def test_flash_backward_notes_its_plan_beside_the_forwards(monkeypatch):
 
     q = jnp.zeros((8, 64, 16), jnp.float32)
     attn = functools.partial(pa.flash_attention, causal=True)
-    hvd_trace.install(True)
     notes = []
-    note_plan = hvd_trace.TAP.note_plan
+    note_plan = hvd_trace.note_plan
     monkeypatch.setattr(
-        hvd_trace.TAP, "note_plan",
+        hvd_trace, "note_plan",
         lambda **kw: (notes.append(kw), note_plan(**kw)),
     )
     monkeypatch.setattr(pa, "_PREF_BLOCK", 16)
@@ -558,7 +557,7 @@ def test_flash_backward_notes_its_plan_beside_the_forwards(monkeypatch):
     grad(q, q, q)                       # the cached executable: no new note
     assert [sorted(n)[0] for n in notes] == ["flash_block_k",
                                              "flash_bwd_block_k"]
-    plan = hvd_trace.TAP.plan_args()
+    plan = hvd_trace.plan_args()
     assert {k: v for k, v in plan.items() if k.startswith("flash_bwd")} == {
         "flash_bwd_block_q": 16, "flash_bwd_block_k": 16,
         "flash_bwd_rows_per_step": 8,
