@@ -35,7 +35,7 @@ from flax import linen as nn
 
 from .. import trace as _trace
 from ..ops.pallas_attention import flash_attention_bthd
-from ..parallel.ep import dropless_moe, held_load, route_top_k
+from ..parallel.ep import _load_and_tiles, dropless_moe, route_top_k
 from .qwen3_next import (RMSNorm, _dense, _normal, causal_depthwise_conv,
                          expert_load, rotary)
 
@@ -168,8 +168,8 @@ class SparseMoe(nn.Module):
         flat = x.reshape(B * T, C)
         if self.is_mutable_collection("intermediates"):
             _, ids = route_top_k(flat, router, **routing)
-            self.sow("intermediates", "held_load", jnp.stack(held_load(
-                ids, first_expert=self.first_expert, experts_held=E)))
+            self.sow("intermediates", "held_load", _load_and_tiles(
+                ids, self.first_expert, E, self.n_experts))
         y = dropless_moe(
             flat, router, experts["gate"], experts["up"], experts["down"],
             first_expert=self.first_expert, dtype=self.dtype, **routing)

@@ -36,7 +36,7 @@ from flax import linen as nn
 from .. import trace as _trace
 from ..ops.gated_delta import DEFAULT_CHUNK, gated_delta_chunked
 from ..ops.pallas_attention import flash_attention_bthd
-from ..parallel.ep import dropless_moe, held_load, route_top_k
+from ..parallel.ep import _load_and_tiles, dropless_moe, route_top_k
 
 _normal = nn.initializers.normal
 
@@ -217,8 +217,8 @@ class SparseMoe(nn.Module):
         flat = x.reshape(B * T, C)
         if self.is_mutable_collection("intermediates"):
             _, ids = route_top_k(flat, router, top_k=self.top_k)
-            self.sow("intermediates", "held_load", jnp.stack(held_load(
-                ids, first_expert=self.first_expert, experts_held=E)))
+            self.sow("intermediates", "held_load", _load_and_tiles(
+                ids, self.first_expert, E, self.n_experts))
         y = dropless_moe(
             flat, router, experts["gate"], experts["up"], experts["down"],
             top_k=self.top_k, first_expert=self.first_expert,
@@ -329,12 +329,13 @@ class Qwen3NextLM(nn.Module):
 
 
 def expert_load(model, params, tokens):
-    """``[expert layers, 2]`` int32 for one batch of a model whose sparse
+    """``[expert layers, 3]`` int32 for one batch of a model whose sparse
     layers sow ``held_load`` (this one and ``models/lfm2_moe.py``): per
     expert layer, in layer order, the (token, expert) pairs that fall on the
-    experts held here (what the layer's grouped products compute) and the
-    busiest held expert's load. With tracing armed the numbers also go onto
-    every later step span as plan notes."""
+    experts held here (what the layer's grouped products compute), the
+    busiest held expert's load, and the tiles of rows the layer computes for
+    that load (1 where the first tile holds it; more says the batch reached
+    the overflow tiles). The numbers also go onto the plan notes."""
     _, state = model.apply({"params": params}, tokens,
                            mutable=["intermediates"])
     inter = state["intermediates"]
@@ -346,5 +347,6 @@ def expert_load(model, params, tokens):
     _trace.note_plan(
         moe_pairs_held=[int(v) for v in load[:, 0]],
         moe_largest_load=[int(v) for v in load[:, 1]],
+        moe_tiles_computed=[int(v) for v in load[:, 2]],
     )
     return load

@@ -214,17 +214,57 @@ def held_load(ids, *, first_expert: int, experts_held: int):
     return jnp.sum(sizes), jnp.max(sizes)
 
 
-def _tile(i, order, starts, ends):
-    """Tile ``i``'s pairs and its part of every group: rows
-    ``[i * rows, (i + 1) * rows)`` of the sorted pairs."""
-    rows = order.shape[1]
-    lo = i * rows
+_RIDGE_ROWS = 256   # rows an expert: see _tile_plan
+
+
+def _tile_plan(s_tokens: int, top_k: int, e_held: int, e_total: int):
+    """``(first, over, n_over)``: the rows of the first tile, of an overflow
+    tile, and how many overflow tiles the worst case (every token choosing
+    only held experts) needs behind the first; in whole 512s, a function of
+    the shapes and of nothing else. Which side is cheap depends on the rows
+    an expert gets. From some 240 rows an expert up (v5e) its products are
+    compute-bound and a row past the load costs what a row costs: the first
+    tile is what balanced routing gives the held experts and a quarter
+    more, an overflow tile an eighth of it (4096 rows an expert: 38% fewer
+    rows took 28 of 714 ms off the step, PERF.md section 6, PR 39). Under
+    that a product is bound by reading the expert's weights and a tile
+    costs that read however few its rows: tiles are as many rows as
+    balanced routing fills twice over (160 rows an expert: 38% fewer rows
+    saved 0.9 ms of 303, and a load that wanders to twice the balanced one
+    within a window met an overflow tile at more than that)."""
+    worst = s_tokens * min(top_k, e_held)
+    balanced = s_tokens * top_k * e_held // e_total
+    if balanced < _RIDGE_ROWS * e_held:
+        first = over = _round_up(2 * balanced + 8 * e_held, 512)
+    else:
+        first = _round_up(balanced + balanced // 4, 512)
+        over = _round_up(balanced // 8, 512)
+    first = min(worst, first)
+    return first, over, -(-(worst - first) // over)
+
+
+def _tiles_needed(load, first: int, over: int):
+    """The tiles a load of ``load`` sorted pairs computes: the first always,
+    and as many overflow tiles as hold what lies past it."""
+    return 1 + (jnp.maximum(load - first, 0) + over - 1) // over
+
+
+def _load_and_tiles(ids, first_expert: int, experts_held: int,
+                    experts_total: int):
+    """``[pairs, largest, tiles]`` int32 for one layer's choices ``ids``
+    (``[S, k]``): :func:`held_load`, and how many tiles :func:`dropless_moe`
+    computes for that load, by the arithmetic its loop's length uses."""
+    pairs, largest = held_load(ids, first_expert=first_expert,
+                               experts_held=experts_held)
+    first, over, _ = _tile_plan(*ids.shape, experts_held, experts_total)
+    return jnp.stack([pairs, largest, _tiles_needed(pairs, first, over)])
+
+
+def _tile(order, lo, rows: int, starts, ends):
+    """The tile of ``rows`` rows from row ``lo`` of the sorted pairs: its
+    pairs and its part of every group."""
     sizes = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo), 0)
-    return order[i], sizes
-
-
-def _tiles_needed(order, ends):
-    return (ends[-1] + order.shape[1] - 1) // order.shape[1]
+    return lax.dynamic_slice(order, (lo,), (rows,)), sizes
 
 
 def _tile_products(xs, w_gate, w_up, w_down, sizes):
@@ -241,16 +281,16 @@ def _tile_products(xs, w_gate, w_up, w_down, sizes):
         return grouped(h.astype(w_down.dtype), w_down)      # [rows, D] f32
 
 
-def _token_slots(pos, tile, rows, load, slots, weight=None):
+def _token_slots(pos, lo, rows: int, load, slots, weight=None):
     """Where a tile's rows go, token-major. ``pos``: ``[S, k]``, every
-    pair's row in the sorted order. Returns ``(slot_pos [S, slots],
-    slot_weight [S, slots])``: per token its pairs that are held AND lie in
-    tile ``tile``, moved to the first slots in pair order: the pair's row
-    inside the tile, or -1 for a slot that holds none, and its weight (1
-    without ``weight``). ``slots`` is the most held pairs a token can
-    have."""
-    local = pos - tile * rows
-    mine = (local >= 0) & (local < jnp.minimum(rows, load - tile * rows))
+    pair's row in the sorted order; the tile is the ``rows`` rows from row
+    ``lo``. Returns ``(slot_pos [S, slots], slot_weight [S, slots])``: per
+    token its pairs that are held AND lie in the tile, moved to the first
+    slots in pair order: the pair's row inside the tile, or -1 for a slot
+    that holds none, and its weight (1 without ``weight``). ``slots`` is the
+    most held pairs a token can have."""
+    local = pos - lo
+    mine = (local >= 0) & (local < jnp.minimum(rows, load - lo))
     rank = jnp.cumsum(mine, axis=1, dtype=jnp.int32) - 1
     pick = mine[:, :, None] & (rank[:, :, None] == jnp.arange(slots))
     slot_pos = jnp.max(jnp.where(pick, local[:, :, None], -1), axis=1)
@@ -259,46 +299,63 @@ def _token_slots(pos, tile, rows, load, slots, weight=None):
     return slot_pos, jnp.sum(jnp.where(pick, weight[:, :, None], 0.0), axis=1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9,))
-def _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends, slots):
-    """The held experts' part of the result for the sorted pairs ``order``
-    (``[tiles, rows]``; ``pos`` ``[S, k]`` is its inverse): the first tile
-    always, further tiles in a loop that runs as far as this batch's load
-    reaches. A tile's rows are gathered by pair, multiplied by group, and
-    summed per token by a gather-sum (``ops/moe_combine``): each token reads
-    its held pairs' rows, weighted, in pair order. The loop's length is read
-    on the device, so it has no transpose of JAX's: the backward below walks
-    the same tiles again, one tile's rows alive at a time."""
-    rows = order.shape[1]
+def _walk_tiles(tile, plan, load):
+    """``tile(lo, rows)`` of the first tile of ``plan`` (:func:`_tile_plan`)
+    and, added to it leaf by leaf, of every overflow tile that ``load``
+    sorted pairs reach. The loop's length is read on the device; its body is
+    the overflow tile's program, a second, smaller one than the first
+    tile's."""
+    first, over, n_over = plan
+    head = tile(0, first)
+    if not n_over:                  # the first tile holds the worst case
+        return head
+    return lax.fori_loop(
+        1, _tiles_needed(load, first, over),
+        lambda i, acc: jax.tree.map(
+            jnp.add, acc, tile(first + (i - 1) * over, over)),
+        head)
 
-    def tile_sum(i):
-        order_t, sizes_t = _tile(i, order, starts, ends)
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+def _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends, plan,
+             slots):
+    """The held experts' part of the result for the sorted pairs ``order``
+    (flat: the first tile's rows, then the overflow tiles', as ``plan`` of
+    :func:`_tile_plan` cuts them; ``pos`` ``[S, k]`` is its inverse): the
+    first tile always, overflow tiles in a loop that runs as far as this
+    batch's load reaches.
+    A tile's rows are gathered by pair, multiplied by group, and summed per
+    token by a gather-sum (``ops/moe_combine``): each token reads its held
+    pairs' rows, weighted, in pair order. The loop's length is read on the
+    device, so it has no transpose of JAX's: the backward below walks the
+    same tiles again, one tile's rows alive at a time."""
+    def tile_sum(lo, rows):
+        order_t, sizes_t = _tile(order, lo, rows, starts, ends)
         with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
             xs = x[order_t // scale.shape[1]].astype(w_gate.dtype)
         ys = _tile_products(xs, w_gate, w_up, w_down, sizes_t)
         with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
             slot_pos, slot_w = _token_slots(
-                pos, i, rows, ends[-1], slots, scale)
+                pos, lo, rows, ends[-1], slots, scale)
             return _combine.gather_sum(ys, slot_pos, slot_w)
 
-    return lax.fori_loop(1, _tiles_needed(order, ends),
-                         lambda i, y: y + tile_sum(i), tile_sum(0))
+    return _walk_tiles(tile_sum, plan, ends[-1])
 
 
 def _experts_fwd(x, scale, w_gate, w_up, w_down, order, pos, starts, ends,
-                 slots):
+                 plan, slots):
     y = _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends,
-                 slots)
+                 plan, slots)
     return y, (x, scale, w_gate, w_up, w_down, order, pos, starts, ends)
 
 
-def _experts_bwd(slots, res, dy):
+def _experts_bwd(plan, slots, res, dy):
     x, scale, w_gate, w_up, w_down, order, pos, starts, ends = res
-    rows, top_k = order.shape[1], scale.shape[1]
+    top_k = scale.shape[1]
     f32 = lambda tree: jax.tree.map(lambda g: g.astype(jnp.float32), tree)
 
-    def tile_grads(i):
-        order_t, sizes_t = _tile(i, order, starts, ends)
+    def tile_grads(lo, rows):
+        order_t, sizes_t = _tile(order, lo, rows, starts, ends)
         with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
             token = order_t // top_k
             xs = x[token].astype(w_gate.dtype)
@@ -317,7 +374,7 @@ def _experts_bwd(slots, res, dy):
             dys = dy_rows * weight[:, None]
         dxs, *dw = vjp(dys)
         with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
-            slot_pos, slot_one = _token_slots(pos, i, rows, ends[-1], slots)
+            slot_pos, slot_one = _token_slots(pos, lo, rows, ends[-1], slots)
             dx = _combine.gather_sum(dxs.astype(jnp.float32), slot_pos,
                                      slot_one)
             # rows past the load carry 0, whatever pair they stand for
@@ -325,10 +382,7 @@ def _experts_bwd(slots, res, dy):
                 dweight).reshape(scale.shape)
         return (dx, dscale, *f32(dw))
 
-    grads = lax.fori_loop(
-        1, _tiles_needed(order, ends),
-        lambda i, acc: jax.tree.map(jnp.add, acc, tile_grads(i)),
-        tile_grads(0))
+    grads = _walk_tiles(tile_grads, plan, ends[-1])
     primals = (x, scale, w_gate, w_up, w_down)
     return tuple(g.astype(p.dtype) for g, p in zip(grads, primals)) + (
         None, None, None, None)
@@ -372,11 +426,17 @@ def dropless_moe(
     The (token, expert) pairs held here are sorted by expert and multiplied
     by grouped products over the ragged assignment (``lax.ragged_dot``): no
     ``[tokens, experts, capacity]`` tensor exists. Shapes are static, so the
-    sorted pairs are cut into tiles of as many rows as balanced routing
-    fills twice over, and a loop computes as many tiles as this batch's
-    load reaches (:func:`held_load`): balanced routing is one tile, and
-    every token choosing only held experts is computed in full, tile by
-    tile. A token's result is the sum of its held pairs' rows, each read
+    sorted pairs are cut into a first tile, always computed, and overflow
+    tiles behind it, of which a loop computes as many as this batch's load
+    reaches (:func:`held_load`). Where an expert gets rows enough for its
+    products to be compute-bound the first tile is the rows balanced
+    routing fills and a quarter more and an overflow tile an eighth of
+    them, so the rows computed follow the load and a load one row past the
+    first tile costs one small tile; where it gets few, rows are cheap and
+    tiles are not, and every tile is as many rows as balanced routing fills
+    twice over (:func:`_tile_plan`). Every token choosing only held experts
+    is computed in full, tile by tile. A token's result is the sum of its held
+    pairs' rows, tile by tile, each read
     where the sort put it and weighted as it is added, in pair order
     (``ops/moe_combine.gather_sum``): no row is scattered, no row past the
     load is read, and the same sum with unit weights gives the tokens'
@@ -384,15 +444,14 @@ def dropless_moe(
     s_tokens, d_model = x.shape
     e_total = w_router.shape[-1]
     e_held = w_gate.shape[0]
-    worst = s_tokens * min(top_k, e_held)
-    rows = min(worst, _round_up(
-        2 * s_tokens * top_k * e_held // e_total + 8 * e_held, 512))
-    tiles = -(-worst // rows)
+    plan = first, over, n_over = _tile_plan(s_tokens, top_k, e_held, e_total)
+    rows_in_all = first + n_over * over
     slots = min(top_k, e_held)      # the most held pairs a token can have
     block = _combine.plan(s_tokens, d_model, jnp.float32)
     _trace.note_plan(
         moe_experts_total=e_total, moe_experts_held=e_held,
-        moe_top_k=top_k, moe_tile_rows=rows, moe_tiles=tiles,
+        moe_top_k=top_k, moe_tile_rows=first, moe_tiles=1 + n_over,
+        moe_overflow_rows=over,
         moe_score=score, moe_select_bias=select_bias is not None,
         moe_combine_kernel=block is not None,
         moe_combine_block=block or 0, moe_combine_slots=slots,
@@ -404,16 +463,15 @@ def dropless_moe(
         key, sizes = _held_groups(ids, first_expert, e_held)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         pos = jnp.argsort(order).astype(jnp.int32)    # the sort's inverse
-        order = jnp.pad(order[:tiles * rows],
-                        (0, max(0, tiles * rows - order.size)))
+        order = jnp.pad(order[:rows_in_all],
+                        (0, max(0, rows_in_all - order.size)))
         ends = jnp.cumsum(sizes)
     with jax.named_scope(_trace.SCOPE_MOE_EXPERTS):
         # cast once, outside the loop over tiles
         w_gate, w_up, w_down = (w.astype(dtype)
                                 for w in (w_gate, w_up, w_down))
-    return _experts(x, weights, w_gate, w_up, w_down,
-                    order.reshape(tiles, rows), pos.reshape(ids.shape),
-                    ends - sizes, ends, slots)
+    return _experts(x, weights, w_gate, w_up, w_down, order,
+                    pos.reshape(ids.shape), ends - sizes, ends, plan, slots)
 
 
 def _round_up(n: int, to: int) -> int:

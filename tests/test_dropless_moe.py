@@ -206,8 +206,8 @@ def test_plan_notes_are_always_recorded():
 
 
 # --------------------------------------------------------------------------
-# The per-token gather-sum in place of the scatter-add; the tile rule is the
-# parent's.
+# The per-token gather-sum in place of the scatter-add, over a first tile
+# sized to the expected load and small overflow tiles behind it.
 # --------------------------------------------------------------------------
 
 # The two regimes the layer runs in, at rehearsal sizes: few experts with
@@ -218,16 +218,35 @@ REGIMES = {
     "few_experts_many_rows": (256, 8, 2, 4, 2),
     "many_experts_few_rows": (64, 64, 16, 10, 16),
 }
-# Loads of a share whose sorted pairs are cut into tiles: 1024 tokens, top 4,
-# the router sending every token to experts 0..3. A share of 4 of 64 experts
-# (tiles of 1024 rows) that holds all four computes 4096 pairs in four tiles;
-# of 16 experts, a share of experts 2..3 (tiles of 1536 rows) computes 2048
-# pairs in two, and a share that nobody chose computes nothing.
-# (experts, first held expert, held, rows a tile, tiles the load reaches, pairs)
+# Loads of a share whose sorted pairs are cut into a first tile and overflow
+# tiles, top 4. The first three: 1024 tokens, the router sending every token
+# to experts 0..3; a share of 4 of 64 experts (64 rows an expert balanced:
+# tiles of twice the balanced load, 1024 rows) that holds all four computes
+# 4096 pairs in four tiles; of 16 experts (256 rows an expert: a first tile
+# of the balanced load and a quarter more, small overflow tiles), a share of
+# experts 2..3 computes 2048 pairs in a first tile of 1024 rows and two of
+# 512, and a share that nobody chose computes nothing (its first tile runs
+# empty). The
+# others: 4096 tokens over 16 experts, experts 0..3 held (balanced 4096
+# pairs: a first tile of 5120 rows, 22 overflow tiles of 512 for the worst
+# case), the router sending ``full`` tokens to experts 0..3 (four held pairs
+# each) and ``single`` tokens to expert 0 and three experts held elsewhere
+# (one held pair each), the rest to experts nobody holds here: the load is
+# ``4 * full + single`` pairs, to the row.
+# (tokens, experts, first held expert, held, rows of the first tile, of an
+#  overflow tile, tiles the load computes, pairs, (full, single) or None)
 LOADS = {
-    "fourth_tile": (64, 0, 4, 1024, 4, 4096),
-    "second_tile": (16, 2, 2, 1536, 2, 2048),
-    "empty": (16, 8, 4, 2560, 0, 0),
+    "fourth_tile": (1024, 64, 0, 4, 1024, 1024, 4, 4096, None),
+    "second_tile": (1024, 16, 2, 2, 1024, 512, 3, 2048, None),
+    "empty": (1024, 16, 8, 4, 1536, 512, 1, 0, None),
+    "one_row_under_the_first_tile": (4096, 16, 0, 4, 5120, 512, 1, 5119,
+                                     (1279, 3)),
+    "the_first_tile_exactly": (4096, 16, 0, 4, 5120, 512, 1, 5120, (1280, 0)),
+    "one_row_past_the_first_tile": (4096, 16, 0, 4, 5120, 512, 2, 5121,
+                                    (1280, 1)),
+    "ends_inside_the_fourth_overflow_tile": (4096, 16, 0, 4, 5120, 512, 5,
+                                             6858, (1714, 2)),
+    "worst_case": (4096, 16, 0, 4, 5120, 512, 23, 16384, (4096, 0)),
 }
 
 
@@ -256,6 +275,23 @@ def _layer_args(s_tokens, e_total, seed, all_choose=None, logit=40.0,
     return (x, wr, arr(e_total, width, F, scale=0.3),
             arr(e_total, width, F, scale=0.3),
             arr(e_total, F, width, scale=0.3))
+
+
+def _layer_args_of_load(s_tokens, e_total, seed, full, single, logit=40.0,
+                        width=D):
+    """:func:`_layer_args` with a router that sends the first ``full`` tokens
+    to experts 0..3, the next ``single`` to expert 0 and the last three
+    experts, and the rest to the last four: a token's first three features
+    name its group and the router reads nothing else."""
+    x, _, *experts = _layer_args(s_tokens, e_total, seed, width=width)
+    group = np.full(s_tokens, 2)
+    group[:full], group[full:full + single] = 0, 1
+    x = x.at[:, :3].set(jnp.asarray(np.eye(3)[group], jnp.float32))
+    chosen = np.zeros((3, e_total))
+    chosen[0, :K] = chosen[1, 0] = chosen[1, -3:] = chosen[2, -K:] = logit
+    wr = jnp.zeros((width, e_total)).at[:3].set(
+        jnp.asarray(chosen, jnp.float32))
+    return (x, wr, *experts)
 
 
 def _scatter_add_layer(x, wr, wg, wu, wd, first, held, top_k, routing):
@@ -312,52 +348,82 @@ def test_both_regimes_equal_the_masked_loop_and_the_scatter_add(regime, mode):
 @modes
 @pytest.mark.parametrize("load", list(LOADS))
 def test_loads_past_one_tile_and_an_empty_load(load, mode):
-    """Every token choosing held experts overflows into a second tile and on
-    to a fourth, and a share nobody chose computes nothing: the loop over
-    tiles runs as far as the load reaches, forward and backward."""
-    e_total, first, held, rows, tiles_reached, pairs = LOADS[load]
+    """Loads one row under the first tile, exactly at it, one row past it
+    (one overflow tile), several overflow tiles on and ending inside one, and
+    every token choosing held experts (the worst case); a share nobody chose
+    computes nothing: the loop over overflow tiles runs as far as the load
+    reaches, forward and backward."""
+    (s_tokens, e_total, first, held, first_rows, over_rows, tiles_computed,
+     pairs, chosen) = LOADS[load]
     # a sigmoid saturates at 40 and hands the router no gradient: at 4 the
     # first experts still score over 0.85 against the others' half
-    args = _layer_args(1024, e_total, 12, all_choose=K,
-                       logit=40.0 if mode == "softmax" else 4.0)
+    logit = 40.0 if mode == "softmax" else 4.0
+    args = (_layer_args(s_tokens, e_total, 12, all_choose=K, logit=logit)
+            if chosen is None else
+            _layer_args_of_load(s_tokens, e_total, 12, *chosen, logit=logit))
     routing = _routing(mode, e_total)
     _, ids = ep.route_top_k(args[0], args[1], top_k=K, **routing)
     got_pairs, _ = ep.held_load(ids, first_expert=first, experts_held=held)
     assert int(got_pairs) == pairs
-    assert -(-pairs // rows) == tiles_reached
+    assert ep._tile_plan(s_tokens, K, held, e_total)[:2] == (
+        first_rows, over_rows)
+    assert int(ep._tiles_needed(got_pairs, first_rows, over_rows)) == (
+        tiles_computed)
+    assert tiles_computed == 1 + -(-max(pairs - first_rows, 0) // over_rows)
     _assert_layer_and_gradients(args, first, held, K, routing)
     y = _held_share(*args, first, held, K, routing)
-    if pairs:
+    if chosen is None and pairs:
         assert float(jnp.min(jnp.max(jnp.abs(y), axis=-1))) > 0  # every token
+    elif chosen:
+        has_pair = jnp.arange(s_tokens) < sum(chosen)
+        assert bool(((jnp.max(jnp.abs(y), axis=-1) > 0) == has_pair).all())
     else:
         assert float(jnp.max(jnp.abs(y))) == 0.0
     notes = _plan_notes(lambda: _held_share(*args, first, held, K, routing))
-    assert notes["moe_tile_rows"] == rows
-    assert notes["moe_tiles"] >= max(tiles_reached, 1)
+    assert notes["moe_tile_rows"] == first_rows
+    assert notes["moe_overflow_rows"] == over_rows
+    # tiles enough for every token choosing only held experts
+    assert (first_rows + (notes["moe_tiles"] - 1) * over_rows
+            >= s_tokens * min(K, held))
+    assert notes["moe_tiles"] >= tiles_computed
 
 
-@pytest.mark.parametrize("tokens,top_k,held,total,rows,tiles,block", [
-    # models/qwen3_next.py in qwen3next-train-1chip
-    (8192, 10, 32, 512, 10752, 8, 256),
-    # models/lfm2_moe.py in lfm2moe-train-1chip
-    (32768, 4, 8, 32, 66048, 2, 256),
-])
-def test_the_cells_keep_their_tiles_and_take_the_kernel(
-        tokens, top_k, held, total, rows, tiles, block):
-    """At both cells' shapes a tile is what balanced routing fills twice
-    over and eight rows an expert, in whole 512s, as before the gather-sum
-    came; the rows of width 2048 are whole float32 tiles, so the kernel
-    runs, 256 tokens a grid step, with as many slots as a token can hold
-    pairs."""
+@pytest.mark.parametrize(
+    "tokens,top_k,held,total,first_rows,over_rows,tiles,block", [
+        # models/qwen3_next.py in qwen3next-train-1chip: balanced 5120 pairs,
+        # 160 rows an expert: rows are cheap and tiles are not
+        (8192, 10, 32, 512, 10752, 10752, 8, 256),
+        # models/lfm2_moe.py in lfm2moe-train-1chip: balanced 32768 pairs,
+        # 4096 rows an expert: the rows follow the load
+        (32768, 4, 8, 32, 40960, 4096, 23, 256),
+    ])
+def test_the_cells_tiles_follow_their_load_and_take_the_kernel(
+        tokens, top_k, held, total, first_rows, over_rows, tiles, block):
+    """At the LFM2 cell's shapes (4096 rows an expert, compute-bound) the
+    first tile is what balanced routing gives the held experts and a quarter
+    more and an overflow tile an eighth of it; at the hybrid cell's (160
+    rows an expert, bound by the weights' read) every tile is what balanced
+    routing fills twice over and eight rows an expert, as before PR 39; in
+    whole 512s, with overflow tiles enough for every token choosing only
+    held experts and no more; the rows of width 2048 are whole float32
+    tiles, so the kernel runs at both row counts, 256 tokens a grid step,
+    with as many slots as a token can hold pairs."""
     arr = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
     notes = _plan_notes(lambda: jax.eval_shape(
         lambda *a: ep.dropless_moe(*a, top_k=top_k),
         jax.ShapeDtypeStruct((tokens, 2048), jnp.bfloat16),
         arr(2048, total), arr(held, 2048, 64), arr(held, 2048, 64),
         arr(held, 64, 2048)))
-    assert (notes["moe_tile_rows"], notes["moe_tiles"]) == (rows, tiles)
-    assert rows == -(-(2 * tokens * top_k * held // total + 8 * held)
-                     // 512) * 512
+    assert (notes["moe_tile_rows"], notes["moe_overflow_rows"],
+            notes["moe_tiles"]) == (first_rows, over_rows, tiles)
+    balanced = tokens * top_k * held // total
+    worst = tokens * min(top_k, held)
+    whole = lambda n: -(-n // 512) * 512
+    assert (first_rows, over_rows) == (
+        (whole(balanced * 5 // 4), whole(balanced // 8))
+        if balanced // held >= 256 else (whole(2 * balanced + 8 * held),) * 2)
+    assert first_rows + (tiles - 1) * over_rows >= worst
+    assert first_rows + (tiles - 2) * over_rows < worst
     assert notes["moe_combine_kernel"] is True
     from horovod_tpu import trace
     assert trace.build_ledger()["fallbacks"] == []   # no call site without it
@@ -456,18 +522,35 @@ def _poisoned_products(real):
     return products
 
 
+# 512 tokens over 16 experts, top 4, by _layer_args_of_load.
+# (first held expert, held, (full, single), pairs, the tile plan)
+GARBAGE = {
+    # experts 1..3 held: 900 pairs, inside the first tile of 1024 rows; the
+    # overflow tile behind it is not reached
+    "inside_the_first_tile": (1, 3, (300, 0), 900, (1024, 1024, 1)),
+    # experts 0..3 held: 1603 pairs fill the first tile of 1536 rows and end
+    # inside the overflow tile
+    "ends_inside_an_overflow_tile": (0, 4, (400, 3), 1603, (1536, 1536, 1)),
+}
+
+
 @modes
+@pytest.mark.parametrize("load", list(GARBAGE))
 @pytest.mark.parametrize("width", [16, 1024])
-def test_garbage_past_the_load_reaches_nothing(monkeypatch, mode, width):
+def test_garbage_past_the_load_reaches_nothing(monkeypatch, mode, width, load):
     """NaN in every row past the load, in the products' result and in their
     transposed result, changes neither the layer nor any gradient: the sums
     read the rows that held pairs name and the masks that kept the rest
-    harmless are gone. Both forms of the sum; a load that fills one tile in
-    part and a second one in part."""
-    args = _layer_args(256, E, 13, all_choose=K, width=width,
-                       logit=40.0 if mode == "softmax" else 4.0)
-    first, held = 1, 3      # 768 of 1024 pairs held, tiles of 512 rows
+    harmless are gone. Both forms of the sum; a load that ends inside the
+    first tile, and one that fills it and ends inside an overflow tile."""
+    first, held, chosen, pairs, plan = GARBAGE[load]
+    args = _layer_args_of_load(512, E, 13, *chosen, width=width,
+                               logit=40.0 if mode == "softmax" else 4.0)
     routing = _routing(mode, E)
+    assert ep._tile_plan(512, K, held, E) == plan
+    _, ids = ep.route_top_k(args[0], args[1], top_k=K, **routing)
+    assert int(ep.held_load(ids, first_expert=first,
+                            experts_held=held)[0]) == pairs
     clean = _layer_and_gradients(_held_share, args, first, held, K, routing)
     monkeypatch.setattr(ep, "_tile_products",
                         _poisoned_products(ep._tile_products))

@@ -355,9 +355,64 @@ def test_scopes_and_plan_notes():
     assert notes["moe_experts_total"] == 8 and notes["moe_experts_held"] == 4
     load = np.asarray(lm.expert_load(model, params, tokens))
     notes = trace.plan_args()
-    assert load.shape == (4, 2)               # the four sparse layers
+    assert load.shape == (4, 3)               # the four sparse layers
     assert notes["moe_pairs_held"] == list(load[:, 0])
     assert notes["moe_largest_load"] == list(load[:, 1])
+    # a rehearsal's first tile holds its worst case: one tile, whatever falls
+    assert notes["moe_tiles_computed"] == list(load[:, 2]) == [1] * 4
     # uniform routing: about k * held / routed of B * T * k pairs, here half
     assert (load[:, 0] > 0.25 * B * T * 2).all()
     assert (load[:, 0] < 0.75 * B * T * 2).all()
+
+
+def test_expert_load_counts_the_tiles_a_batch_computes():
+    """``expert_load()`` reports, a sparse layer, the tiles of rows that
+    batch computes, by the arithmetic ``dropless_moe``'s loop uses: 1 where
+    the seeded router's load lies inside the first tile; with the selection
+    bias sending every token's first choice to a held expert the load passes
+    the first tile by part of an overflow tile, and with both choices held
+    it is the worst case."""
+    from horovod_tpu import trace
+
+    cfg, model, params, _, _ = _setup(jnp.float32)
+    tokens = jnp.asarray(np.random.default_rng(3).integers(
+        0, cfg["vocab_size"], (2, 1024)), jnp.int32)
+    s_tokens, top_k = tokens.size, cfg["num_experts_per_tok"]
+    held, total = cfg["num_experts"], cfg["num_experts_routed"]
+    first, over, n_over = ep._tile_plan(s_tokens, top_k, held, total)
+    assert (first, over, n_over) == (2560, 512, 3)    # balanced 2048 pairs
+
+    def biased(chosen):
+        """The parameters with a selection bias no score outweighs on the
+        experts ``chosen``."""
+        flat, tree = jax.tree_util.tree_flatten_with_path(params)
+        return jax.tree.unflatten(tree, [
+            x.at[jnp.asarray(chosen)].set(10.0) if chosen
+            and "expert_bias" in jax.tree_util.keystr(p) else x
+            for p, x in flat])
+
+    lo = cfg["first_expert_held"]
+    for chosen in ([], [lo], [lo, lo + 1]):
+        load = np.asarray(lm.expert_load(model, biased(chosen), tokens))
+        assert load.shape == (4, 3)
+        pairs, tiles = load[:, 0], load[:, 2]
+        assert list(tiles) == list(1 + -(-np.maximum(pairs - first, 0) // over))
+        assert list(tiles) == [int(ep._tiles_needed(p, first, over))
+                               for p in pairs]
+        assert trace.plan_args()["moe_tiles_computed"] == list(tiles)
+        if not chosen:      # the seeded router alone: within 35% of balanced
+            assert (tiles == 1).sum() >= 3 and (tiles <= 2).all(), load
+        elif len(chosen) == 1:      # 2048 pairs and about 3 / 7 of 2048 more
+            assert (pairs > first).all() and (tiles >= 2).all(), load
+            assert (tiles < 1 + n_over).all(), load
+        else:                       # every pair held: the worst case
+            assert (pairs == s_tokens * top_k).all(), load
+            assert (tiles == 1 + n_over).all(), load
+    # every note the layer and the counter leave is in the documents' list
+    with open(os.path.join(ROOT, "docs", "timeline.md")) as f:
+        listed = f.read()
+    for note in trace.plan_args():
+        if note.startswith("moe_"):
+            assert f"`{note}`" in listed, note
+    assert {"moe_overflow_rows", "moe_tiles_computed"} <= set(
+        trace.plan_args())

@@ -234,9 +234,11 @@ def test_scopes_and_plan_notes():
     assert notes["moe_experts_total"] == 8 and notes["moe_experts_held"] == 4
     load = np.asarray(qn.expert_load(model, params, tokens))
     notes = trace.plan_args()
-    assert load.shape == (4, 2)
+    assert load.shape == (4, 3)
     assert notes["moe_pairs_held"] == list(load[:, 0])
     assert notes["moe_largest_load"] == list(load[:, 1])
+    # a rehearsal's first tile holds its worst case: one tile, whatever falls
+    assert notes["moe_tiles_computed"] == list(load[:, 2]) == [1] * 4
     # uniform routing: about k * held / routed of B * T * k pairs, here half
     assert (load[:, 0] > 0.25 * B * T * 2).all()
     assert (load[:, 0] < 0.75 * B * T * 2).all()

@@ -153,18 +153,26 @@ def test_gated_delta_rule_compiles(topo, compile_kernel, chunk, kernels):
     assert ("gdn_fwd" in text and "gdn_bwd" in text) == bool(kernels)
 
 
-@pytest.mark.parametrize("tokens,top_k,total,held,width", [
-    (8192, 10, 512, 32, 512),    # qwen3next-train-1chip: many experts, few rows
-    (32768, 4, 32, 8, 1792),     # lfm2moe-train-1chip: few experts, many rows
+@pytest.mark.parametrize("tokens,top_k,total,held,width,parent_temp_gb", [
+    # qwen3next-train-1chip: many experts, few rows
+    (8192, 10, 512, 32, 512, 0.989),
+    # lfm2moe-train-1chip: few experts, many rows
+    (32768, 4, 32, 8, 1792, 4.133),
 ])
 def test_dropless_expert_layer_compiles(topo, compile_kernel, tokens, top_k,
-                                        total, held, width):
+                                        total, held, width, parent_temp_gb):
     """The expert layer at the two cells' shapes (32 of 512 experts held,
     top-10, 2048 -> 512 -> 2048 on 8192 tokens; 8 of 32, top-4, 2048 -> 1792
     -> 2048 on 32768), forward and backward: the grouped products are the
     chip's own ragged-dot kernel, the per-token sums the gather-sum kernel
-    (once a direction), no [tokens, experts, capacity] tensor is in the
-    program and no row buffer is scatter-added."""
+    (of a sum's gradient the backward alone is left: its first tile and the
+    overflow tiles' loop body, a row count each), no [tokens, experts,
+    capacity] tensor is in the program and no row buffer is scatter-added.
+    The program's scratch is no more than at PR 38's tile rule
+    (``parent_temp_gb``: the same compile there), and with a first tile
+    sized to the load, the LFM2 cell's, about half of it (the cells' whole
+    steps read 14.89 and 11.41 GB by ``memory_analysis()`` for 14.89 and
+    13.52)."""
     from horovod_tpu.parallel.ep import dropless_moe
 
     one_chip = SingleDeviceSharding(topo.devices[0])
@@ -184,6 +192,7 @@ def test_dropless_expert_layer_compiles(topo, compile_kernel, tokens, top_k,
     scattered = [l for l in text.splitlines() if " scatter(" in l
                  and ",2048]" in l.split(" scatter(")[0]]
     assert not scattered
+    assert compiled.memory_analysis().temp_size_in_bytes <= parent_temp_gb * 1e9
 
 
 def test_ring_block_compiles(topo):
