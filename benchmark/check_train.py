@@ -45,6 +45,13 @@ def _accumulate(acc, g):
     return jax.tree.map(jnp.add, acc, g)
 
 
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _divide(acc, n):
+    # n is an argument, not a constant of the program: a constant divisor XLA
+    # may turn into a product with its reciprocal, which rounds otherwise
+    return jax.tree.map(lambda x: x / n, acc)
+
+
 def _blocks(batch, rows):
     n = len(batch[0])
     return [tuple(x[i:i + rows] for x in batch) for i in range(0, n, rows)]
@@ -52,7 +59,16 @@ def _blocks(batch, rows):
 
 def reference_steps(ref_loss, fresh_params, batches, rows, opt, precision):
     """Drive the reference through ``batches`` (one optimizer step each).
-    Returns host numbers only; everything on the device is freed."""
+    Returns host numbers only; everything on the device is freed.
+
+    Beside the parameters and the optimizer's moments ONE gradient tree is
+    alive when a block's gradient is computed (a second, the sum so far, from
+    a step's second block on): the sum is donated to its successor and to the
+    mean, and no name holds a gradient past the update. Each hand-over waits
+    for the device, because memory for a program's results is taken when it
+    is enqueued, and what the program before it gives up is free only once
+    that one has run. The reference then costs what the program costs, 16
+    bytes a parameter, and a cell's size is not held down by its check."""
     grad_block = jax.jit(jax.value_and_grad(
         lambda p, b: ref_loss(p, b, precision)
     ))
@@ -61,16 +77,24 @@ def reference_steps(ref_loss, fresh_params, batches, rows, opt, precision):
     losses, first_grad = [], None
     for batch in batches:
         blocks = _blocks(batch, rows)
-        total, acc = 0.0, None
+        total, grads = 0.0, None
         for block in blocks:
             value, g = grad_block(params, block)
             total += float(value)
-            acc = g if acc is None else _accumulate(acc, g)
-        grads = jax.tree.map(lambda x: x / len(blocks), acc)
+            if grads is None:
+                grads = g
+            else:
+                grads = jax.block_until_ready(_accumulate(grads, g))
+            del g
+        if len(blocks) > 1:   # x / 1 is x
+            grads = _divide(grads, jnp.float32(len(blocks)))
         losses.append(total / len(blocks))
         if first_grad is None:
             first_grad = np.asarray(leaf_norms(grads))
         params, state = optim.update(opt, params, grads, state)
+        del grads
+        jax.block_until_ready(params)
+    del state
     update = np.asarray(diff_norms(params, fresh_params()))
     return {"losses": losses, "first_grad_norms": first_grad,
             "update_norms": update}

@@ -115,6 +115,14 @@ def train_ops_per_step(cfg, traffic, batch_per_chip) -> float:
     return 6.0 * matmul_params_per_token(cfg) * batch_per_chip * T + attn
 
 
+def attn_fwd_calls(cfg) -> int:
+    """The forward flash kernel's calls in ONE forward pass, one an attention
+    layer: what ``attn_fwd_cost`` is the least cost of. With recomputation on a step
+    runs the pass twice, and ``attn_fwd_roofline`` holds one pass's bound
+    against one pass's share of the kernel's time."""
+    return dims(cfg)["attn_layers"]
+
+
 def attn_fwd_cost(cfg, traffic, batch_per_chip):
     """Least operations and bytes of the forward flash kernel calls of one
     step on one chip (the attention layers): causal QK^T and PV over the

@@ -4,12 +4,12 @@ per step of the kernels' events. Median over the traced steps, chip 0.
 
 The events: custom calls named ``flash_bwd.<n>``, after the two backward
 ``pallas_call``s of ``ops/pallas_attention.py`` under the scope ``flash_bwd``
-(a dK/dV and a dQ kernel a layer). The first rule of ``scope_groups/*.json``
-that a ``pallas_call`` meets is ``attn_fwd``, so the GROUP ``attn_fwd`` holds
-these kernels beside the forward's and ``attn_bwd_ms.train`` only what lies
-round them; this reader goes by event name and opcode and holds the kernels
-alone. A program whose backward is no kernel (an XLA ``while``) has no such
-event, and the metric is left out.
+(one kernel a layer where a row's whole ``dq`` fits VMEM, a dK/dV and a dQ
+kernel where not). The group ``attn_bwd`` of ``scope_groups/*.json``, which
+``attn_bwd_ms.train`` reads, holds these kernels AND what lies round them;
+this reader goes by event name and opcode and holds the kernels alone. A
+program whose backward is no kernel (an XLA ``while``) has no such event, and
+the metric is left out.
 
 The least time: the larger of operations over peak FLOP/s and bytes over peak
 bytes/s, both twice the family's ``attn_fwd_cost``: the four products the
@@ -19,7 +19,7 @@ that redo QK^T and dP cannot pass 4/7 = 57%), and q, k, v, o, dO read and dq,
 dk, dv written once where the forward moves four such arrays.
 
 Also prints the line ``attn_bwd_kernels: {...}`` with the kernels' milliseconds
-per step, which is the number ``attn_bwd_ms.train`` no longer holds."""
+per step: the kernels' own part of ``attn_bwd_ms.train``."""
 
 import json
 import re
@@ -34,19 +34,9 @@ OPCODE = "custom-call"
 def kernel_ns(trace, opcodes, match):
     """``(median ns per step, calls per step)`` of the backward kernels on
     chip 0 of a plain or scoped trace; ``None`` where no launch holds one."""
-    planes = tr.device_planes(trace)
-    if not planes:
-        return None
-    per_step = []
-    for launch in tr.per_launch(planes[0], match):
-        mine = [e[2] for e in launch["ops"]
-                if KERNEL.search(e[0]) and opcodes.get(e[0]) == OPCODE]
-        if mine:
-            per_step.append((sum(mine), len(mine)))
-    if not per_step:
-        return None
-    return (tr.median([t for t, _ in per_step]),
-            tr.median([n for _, n in per_step]))
+    return tr.named_ops_ns(
+        trace, match,
+        lambda name: KERNEL.search(name) and opcodes.get(name) == OPCODE)
 
 
 def bound(run):

@@ -1,11 +1,23 @@
 """The forward flash kernel's share of its roofline: the least time the chip
-could take for one step's forward attention (the larger of operations over
-peak FLOP/s and bytes over peak bytes/s, from the family's count at the cell's
-shapes) over the summed device time per step of the kernel's events
-(the trace names them ``attention.<n>``, after the forward ``pallas_call`` of
-``ops/pallas_attention.py``; the backward is an XLA ``while`` and has no name
-of its own). Chip 0."""
+could take for ONE forward pass over the attention layers (the larger of
+operations over peak FLOP/s and bytes over peak bytes/s, from the family's
+``attn_fwd_cost`` at the cell's shapes) over the device time of one pass's
+calls of the kernel (the trace names them ``attention.<n>``, after the forward
+``pallas_call`` of ``ops/pallas_attention.py``; the backward's kernels are
+``flash_bwd.<n>`` and ``attn_bwd_roofline``'s). Chip 0.
 
+One pass's calls: the family says how many calls a pass makes
+(``attn_fwd_calls``: one an attention layer). A step with per-layer
+recomputation on runs the pass twice, so its events are two passes' and one
+pass's time is half their sum; held against the sum, as this reader held it
+until PR 40, one pass's bound could not pass 50% whatever the kernel did.
+Recomputed operations are still no required work: ``mfu.train`` does not count
+them.
+
+Also prints the line ``attn_fwd_kernel: {...}`` with the kernel's milliseconds
+and calls per step, the calls a pass makes and the passes that come to."""
+
+import json
 import re
 
 from benchmark import manifest
@@ -15,7 +27,7 @@ KERNEL = re.compile(r"^attention(\.\d+)?$")  # the forward kernel's name in the 
 
 
 def bound(run):
-    """``(least_seconds, which)`` for one step's forward kernel calls."""
+    """``(least_seconds, which)`` for one forward pass's kernel calls."""
     peak = manifest.peak_for(run.devices[0].device_kind)
     ops, nbytes = run.cell.family.attn_fwd_cost(
         run.cell.config, run.cell.traffic, run.counters["per_chip_batch"]
@@ -26,18 +38,27 @@ def bound(run):
                                    else "memory")
 
 
+def kernel_ns(trace, match):
+    """``(median ns per step, calls per step)`` of the forward kernel on chip
+    0; ``None`` where no launch holds one."""
+    return tr.named_ops_ns(trace, match, KERNEL.search)
+
+
 def compute(run):
-    if not hasattr(run.cell.family, "attn_fwd_cost"):
+    family = run.cell.family
+    if not (hasattr(family, "attn_fwd_cost")
+            and hasattr(family, "attn_fwd_calls")):
         return None
-    planes = tr.device_planes(run.device_trace)
-    if not planes:
+    found = kernel_ns(run.device_trace, run.launch_match())
+    if found is None:
         return None
-    per_step = []
-    for launch in tr.per_launch(planes[0], run.launch_match()):
-        t = sum(e[2] for e in launch["ops"] if KERNEL.search(e[0]))
-        if t:
-            per_step.append(t)
-    if not per_step:
-        return None
-    least, _ = bound(run)
-    return 100.0 * least / (tr.median(per_step) / 1e9)
+    ns, calls = found
+    per_pass = family.attn_fwd_calls(run.cell.config)
+    least, which = bound(run)
+    print("attn_fwd_kernel: " + json.dumps({
+        "kernel_ms": ns / 1e6, "calls_per_step": calls,
+        "calls_per_pass": per_pass, "passes": calls / per_pass,
+        "least_ms": least * 1e3, "bound": which,
+    }), flush=True)
+    # the time of as many calls as one pass makes
+    return 100.0 * least / (ns * per_pass / calls / 1e9)

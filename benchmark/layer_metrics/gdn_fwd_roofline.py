@@ -4,8 +4,9 @@ The least time the chip could take (the larger of operations over peak FLOP/s
 and bytes over peak bytes/s, from the family's ``gdn_fwd_cost`` at the cell's
 shapes) over the device time per step of the group ``gdn_scan_fwd`` of
 ``scope_groups/<family>.json``: the first forward pass alone (recomputed and
-transposed operations are not the forward's). Median over the traced steps,
-chip 0."""
+transposed operations are not the forward's), the chunk-local Pallas kernel's
+first call a layer (``gdn_fwd.<n>``) with the scan over chunks and the copies
+round it. Median over the traced steps, chip 0."""
 
 from benchmark import manifest, scope_reduce
 

@@ -4,14 +4,15 @@ summed durations of the custom calls named ``gdn_fwd.<n>`` and ``gdn_bwd.<n>``
 its backward), forward, recomputed and backward. Median over the traced
 steps, chip 0. A time, not a share of a roofline.
 
-Why by name: the kernels run under the scope ``gdn_scan``, but the first rule
-of ``scope_groups/qwen3_next.json`` that a ``pallas_call`` meets is
-``attn_fwd``, so the GROUP ``attn_fwd`` holds them beside the flash kernels and
-``gdn_ms.train`` and ``gdn_fwd_roofline`` only what lies round them (the scan
-over chunks, the convolution, the copies). This reader goes by event name and
-opcode, as ``attn_bwd_roofline`` reads ``flash_bwd.<n>``; the delta rule's
-time is ``gdn_ms.train`` plus this. A program whose chunk-local part is plain
-XLA has no such event, and the metric is left out.
+The kernels run under the scope ``gdn_scan``, so the groups ``gdn_scan_fwd``
+and ``gdn_scan_bwd`` of ``scope_groups/qwen3_next.json`` hold them beside the
+scan over chunks and the copies, and ``gdn_ms.train`` is the whole delta rule
+(from PR 31 to PR 39 a rule on every ``pallas_call`` sent them to ``attn_fwd``,
+and the layer's time was that metric plus this one). This reader goes by event
+name and opcode, as ``attn_bwd_roofline`` reads ``flash_bwd.<n>``, and is the
+kernels' OWN part of ``gdn_ms.train``: what a change to the kernels moves, and
+what a change to the scan round them does not. A program whose chunk-local
+part is plain XLA has no such event, and the metric is left out.
 
 Also prints the line ``gdn_kernels: {...}`` with each direction's milliseconds
 and calls per step."""

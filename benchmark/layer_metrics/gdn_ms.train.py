@@ -1,6 +1,8 @@
 """Device time per step of the Gated DeltaNet layers' own mechanism: the causal
 convolution (scope ``gdn_conv``) and the chunked delta rule (``gdn_scan``, no
-projection), forward, recomputed and backward. Median over the traced steps,
+projection: the chunk-local Pallas kernels, whose own part is
+``gdn_kernel_ms.train``, the scan over chunks and the copies), forward,
+recomputed and backward. Median over the traced steps,
 chip 0. Source: device trace, groups ``gdn_conv``, ``gdn_scan_fwd`` and
 ``gdn_scan_bwd`` of ``scope_groups/<family>.json``."""
 

@@ -5,14 +5,15 @@ them, weighted and summed, once forward for the layer's result and once
 backward for the tokens' gradient). Median over the traced steps, chip 0. A
 time, not a share of a roofline.
 
-Why by name: the kernel runs under the scope ``moe_route``, but the first rule
-of ``scope_groups/<family>.json`` that a ``pallas_call`` meets is
-``attn_fwd``, so the GROUP ``attn_fwd`` holds it beside the flash kernels and
-``moe_route_ms.train`` only what lies round it (the router, the sort, the row
-gathers, the slots' index arithmetic). This reader goes by event name and
-opcode, as ``gdn_kernel_ms.train`` reads the delta rule's kernels; routing's
-time is ``moe_route_ms.train`` plus this. A program that sums the rows by a
-scatter-add has no such event, and the metric is left out.
+The kernel runs under the scope ``moe_route``, so the group ``moe_route`` of
+``scope_groups/<family>.json`` holds it beside the router, the sort, the row
+gathers and the slots' index arithmetic, and ``moe_route_ms.train`` is the
+whole of routing (from PR 37 to PR 39 a rule on every ``pallas_call`` sent it
+to ``attn_fwd``, and routing's time was that metric plus this one). This
+reader goes by event name and opcode, as ``gdn_kernel_ms.train`` reads the
+delta rule's kernels, and is the kernel's OWN part of ``moe_route_ms.train``.
+A program that sums the rows by a scatter-add has no such event, and the
+metric is left out.
 
 Also prints the line ``moe_combine_kernel: {...}`` with the kernel's
 milliseconds and calls per step."""
@@ -30,19 +31,9 @@ OPCODE = "custom-call"
 def kernel_ns(trace, opcodes, match):
     """``(median ns per step, calls per step)`` of the kernel on chip 0 of a
     plain or scoped trace; ``None`` where no launch holds one."""
-    planes = tr.device_planes(trace)
-    if not planes:
-        return None
-    per_step = []
-    for launch in tr.per_launch(planes[0], match):
-        mine = [e[2] for e in launch["ops"]
-                if KERNEL.search(e[0]) and opcodes.get(e[0]) == OPCODE]
-        if mine:
-            per_step.append(mine)
-    if not per_step:
-        return None
-    return (tr.median([sum(s) for s in per_step]),
-            tr.median([len(s) for s in per_step]))
+    return tr.named_ops_ns(
+        trace, match,
+        lambda name: KERNEL.search(name) and opcodes.get(name) == OPCODE)
 
 
 def compute(run):

@@ -1,6 +1,7 @@
 """Device time per step of the optimizer update: the ``hvd_optimizer`` scope
-of the step builders, outside any ``hvd_exchange`` (a ``DistributedOptimizer``
-reduces again inside it: that is wire, not update) and any ``hvd_guard``.
+of the step builders, outside any ``hvd_exchange`` (an exchange a wrapper
+makes inside it, where the step could not open the wrapper, is wire, not
+update) and any ``hvd_guard``.
 Median over the traced steps, chip 0. Source: device trace, group
 ``optimizer`` of ``scope_groups/<family>.json``."""
 

@@ -234,10 +234,13 @@ def loss(params, batch, cfg, precision="highest"):
 
 
 def block_rows(cfg, per_chip_batch):
-    """Rows the loss may be computed on at a time. Two: the harness then
-    holds six float32 trees of 507.8 M at its later steps (parameters, two
-    moments, the sum, a stale gradient and the new one: 12.2 GB) beside 2.7
-    GB of scratch (3.0 GB in the int8 control); one row a block would leave
-    it a seventh tree and 15.6 GB in all (compiled for a described v5e,
-    PERF.md section 6, PR 32)."""
+    """Rows the loss may be computed on at a time. Two, sized by PR 32 for a
+    harness that held six float32 trees of 507.8 M at its later steps
+    (parameters, two moments, the sum, a stale gradient and the new one: 12.2
+    GB) beside 2.7 GB of scratch (3.0 GB in the int8 control). Since PR 40
+    ``check_train.reference_steps`` holds five at a step's second block
+    (parameters, two moments, the sum and the new gradient: 10.2 GB) and four
+    at its first; the two rows stay, because the numbers the limits were set
+    from were read with them (another blocking sums a step's gradient in
+    another order)."""
     return min(2, per_chip_batch)

@@ -7,10 +7,11 @@ A new process per run; it runs on the machine it is started on, fails (non-zero,
 no result line) when JAX's default device is not a TPU or there are fewer chips
 than the cell asks for, and prints as its last line one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` with
-``--trace 1``). With ``--trace 0`` the metrics are the cell's end-to-end metrics,
-with ``--trace 1`` its per-layer metrics. ``--rehearse-cpu`` runs the same files
-at their ``rehearsal`` sizes on the CPU; its line says ``"platform": "cpu"`` and
-is never a chip reading.
+``--trace 1``), then ``compared``: each number `correct` compared beside its
+limit, which are also the last lines on standard error. With ``--trace 0`` the
+metrics are the cell's end-to-end metrics, with ``--trace 1`` its per-layer
+metrics. ``--rehearse-cpu`` runs the same files at their ``rehearsal`` sizes on
+the CPU; its line says ``"platform": "cpu"`` and is never a chip reading.
 """
 
 from __future__ import annotations
@@ -21,6 +22,7 @@ _PROCESS_START = time.perf_counter()
 
 import argparse
 import json
+import math
 import os
 import re
 import shutil
@@ -132,6 +134,11 @@ def _compile_cache():
     return path
 
 
+def _plain(value):
+    """A number as JSON holds it; a non-finite one by its name."""
+    return value if math.isfinite(value) else repr(value)
+
+
 def _layer_metrics(ctx):
     from benchmark import manifest
 
@@ -210,7 +217,17 @@ def main(argv=None):
         for name, metric in line["metrics"].items():
             print(f"{name}: {metric['value']} {metric['unit']}", flush=True)
     line["device"] = device
+    # each number compared beside its limit: last in the result's line, and
+    # the last lines on standard error (what a refused run leaves behind)
+    line["compared"] = {
+        row["number"]: {"value": _plain(row["value"]), "limit": row["limit"]}
+        for row in rows
+    }
     print(json.dumps(line), flush=True)
+    for row in rows:
+        print(f"compared {row['number']} {row['value']!r} limit "
+              f"{row['limit']!r} {'within' if row['within'] else 'OVER'}",
+              file=sys.stderr, flush=True)
     return 0
 
 

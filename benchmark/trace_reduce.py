@@ -27,8 +27,15 @@ OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench:"
+# A collective by its opcode, or by the name XLA gives the instruction: the
+# opcode's own (`all-reduce.<n>`) or, where the exchange reduces ONE array, the
+# JAX primitive's (`psum.<n>`: a one-leaf bucket's all-reduce, two of the
+# fourteen in gpt2m-train-dp4 and a quarter of the wire's time; PERF.md
+# section 3). Those two are the names read in that cell's trace; what JAX calls
+# the other collectives' instructions no cell has shown yet.
 COLLECTIVE = re.compile(
-    r"^(all-reduce|reduce-scatter|all-gather|collective-permute|all-to-all)"
+    r"^(all-reduce|reduce-scatter|all-gather|collective-permute|all-to-all"
+    r"|psum)"
 )
 
 
@@ -239,6 +246,25 @@ def collective_times(launch):
     ))
     merged = merge(as_intervals(coll))
     return sum(e[2] for e in coll), total(subtract(merged, rest))
+
+
+def named_ops_ns(trace, match, mine):
+    """``(median ns per step, median calls per step)`` of the ops of chip 0
+    whose instruction name ``mine`` accepts (a kernel is told by its name:
+    ``attention.<n>``, ``flash_bwd.<n>``, ``moe_combine.<n>``), over the
+    steady launches that hold one; ``None`` where none does."""
+    planes = device_planes(trace)
+    if not planes:
+        return None
+    per_step = []
+    for launch in per_launch(planes[0], match):
+        found = [e[2] for e in launch["ops"] if mine(e[0])]
+        if found:
+            per_step.append((sum(found), len(found)))
+    if not per_step:
+        return None
+    return (median([t for t, _ in per_step]),
+            median([n for _, n in per_step]))
 
 
 def top_ops(trace, window_match=None, skip=1, n=10):

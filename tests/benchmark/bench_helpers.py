@@ -14,7 +14,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 # tests/conftest.py.)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
-CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+CONTRACT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+                 "compared"}
 
 
 def rehearse(cell, seed=3, seconds=1.5, trace=0, timeout=300):

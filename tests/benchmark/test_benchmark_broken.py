@@ -72,3 +72,27 @@ def test_part_of_the_batch_left_out_is_not_correct(monkeypatch, capsys):
     monkeypatch.setattr(gpt_dense, "build_train", broken)
     line = run_in_process(capsys, ["--workload", "gpt2m-train-1chip"] + ARGS)
     assert line["correct"] is False
+
+
+def test_every_run_says_each_number_compared_beside_its_limit(monkeypatch,
+                                                              capsys):
+    """Last in the result's line under a key of its own, and as the last
+    lines on standard error: what a refused run leaves in the record."""
+    import json
+
+    from benchmark import run
+
+    _loosen(monkeypatch, TRAIN_LIMITS)
+    assert run.main(["--workload", "gpt2m-train-1chip"] + ARGS) == 0
+    said = capsys.readouterr()
+    line = json.loads([l for l in said.out.splitlines() if l.strip()][-1])
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == set(TRAIN_LIMITS)
+    for name, pair in line["compared"].items():
+        assert pair["limit"] == TRAIN_LIMITS[name]
+        assert pair["value"] <= pair["limit"]
+    last = [l for l in said.err.splitlines() if l.strip()][-len(TRAIN_LIMITS):]
+    assert [l.split()[1] for l in last] == list(TRAIN_LIMITS)
+    assert all(l.startswith("compared ") and l.endswith(" within")
+               for l in last)
+    assert run._plain(float("nan")) == "nan" and run._plain(0.5) == 0.5

@@ -1,9 +1,10 @@
 """``gdn_kernel_ms.train`` reads the delta rule's chunk-local kernels by event
 name and opcode: on a hand-made trace whose answer can be worked out on paper,
 and on a trace with no such event (the parent's side of a comparison: the
-metric is left out). The last tests pin why it goes by name: the groups of
-``scope_groups/qwen3_next.json`` send a kernel under ``gdn_scan`` to
-``attn_fwd``."""
+metric is left out). The last tests hold what it is the kernels' own part
+of: since PR 40 the groups of ``scope_groups/qwen3_next.json`` leave a kernel
+under ``gdn_scan`` to the delta rule's groups (from PR 31 to PR 39 a rule on
+every ``pallas_call`` sent it to ``attn_fwd``)."""
 
 import types
 
@@ -81,12 +82,15 @@ def test_compute_returns_none_without_a_trace(tmp_path):
 
 
 def test_metric_is_declared_for_the_qwen3_next_cell_alone():
+    # "alone" of the cells this PR knew: a later cell with a delta rule may
+    # join the list, and a later metric may stand behind this one
     per_layer = manifest.load_manifest()["per_layer"]
-    assert per_layer[-1] == {
+    entry = next(m for m in per_layer if m["name"] == NAME)
+    assert "qwen3next-train-1chip" in entry.pop("workloads")
+    assert entry == {
         "name": NAME, "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "Delta rule",
         "moves": "train_samples_per_s_per_chip",
-        "workloads": ["qwen3next-train-1chip"],
     }
     layers = {m["layer"] for m in per_layer if m["name"].startswith("gdn_")}
     assert layers == {"Delta rule"}
@@ -99,21 +103,20 @@ BWD = ("jit(step)/hvd_loss_grad/transpose(jvp(Qwen3NextLM))/layer_0/"
 
 
 @pytest.mark.parametrize("opcode,path,group", [
-    # TODAY'S FACT, for the `benchmark` PR that mends the rule order to
-    # change: the first rule a pallas_call meets is attn_fwd, whatever scope
-    # it was written under, so gdn_ms.train and gdn_fwd_roofline do not hold
-    # the kernels' time (and a by-name reader does)
-    ("custom-call", FWD + "gdn_fwd/pallas_call", "attn_fwd"),
-    ("custom-call", BWD + "gdn_fwd/pallas_call", "attn_fwd"),
-    ("custom-call", BWD + "gdn_bwd/pallas_call", "attn_fwd"),
-    ("custom-call", FWD + "pallas_call", "attn_fwd"),
-    # what lies round them stays the delta rule's
+    # a kernel is its scope's: the first pass's call is the forward's, the
+    # recomputed call and the backward kernel the backward's, so gdn_ms.train
+    # holds the kernels' time and gdn_fwd_roofline the first call's
+    ("custom-call", FWD + "gdn_fwd/pallas_call", "gdn_scan_fwd"),
+    ("custom-call", BWD + "gdn_fwd/pallas_call", "gdn_scan_bwd"),
+    ("custom-call", BWD + "gdn_bwd/pallas_call", "gdn_scan_bwd"),
+    ("custom-call", FWD + "pallas_call", "gdn_scan_fwd"),
+    # and so is what lies round them
     ("while", FWD + "while/body/dot_general", "gdn_scan_fwd"),
     ("fusion", FWD + "cumsum", "gdn_scan_fwd"),
     ("copy", FWD + "gdn_fwd/pallas_call", "gdn_scan_fwd"),
     ("while", BWD + "while/body/dot_general", "gdn_scan_bwd"),
     ("fusion", BWD + "reduce_sum", "gdn_scan_bwd"),
 ])
-def test_a_kernel_under_gdn_scan_falls_to_the_group_attn_fwd(opcode, path,
-                                                             group):
+def test_a_kernel_under_gdn_scan_falls_to_the_delta_rules_groups(opcode, path,
+                                                                 group):
     assert sr.group_of(QWEN.rules, opcode, path) == group

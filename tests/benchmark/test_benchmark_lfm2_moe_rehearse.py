@@ -26,6 +26,6 @@ def test_rehearsal_comes_out_correct(trace):
                        for name in line["metrics"])
     else:
         assert set(line) == CONTRACT_KEYS
-        assert set(line["metrics"]) == {"train_samples_per_s_per_chip",
+        assert set(line["metrics"]) >= {"train_samples_per_s_per_chip",
                                         "setup_s"}
     assert '"number": "first_grad_norm"' in out

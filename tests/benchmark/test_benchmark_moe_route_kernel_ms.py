@@ -2,8 +2,10 @@
 event name and opcode: on a hand-made trace whose answer can be worked out on
 paper, on a scoped trace recorded on the chip (``benchmark/testdata/``), and
 on a trace with no such event (the parent's side of a comparison: the metric
-is left out). The last tests pin why it goes by name: the groups of both
-expert families send a kernel under ``moe_route`` to ``attn_fwd``."""
+is left out). The last tests hold what it is the kernel's own part of: since
+PR 40 the groups of both expert families leave a kernel under ``moe_route`` to
+``moe_route`` (from PR 37 to PR 39 a rule on every ``pallas_call`` sent it to
+``attn_fwd``)."""
 
 import gzip
 import json
@@ -139,21 +141,20 @@ BWD = ("jit(step)/hvd_loss_grad/transpose(jvp({lm}))/layer_2/{ffn}/"
     ("lfm2_moe", "Lfm2MoeLM", "feed_forward"),
 ])
 @pytest.mark.parametrize("opcode,path,group", [
-    # TODAY'S FACT, for the `benchmark` PR that mends the rule order to
-    # change: the first rule a pallas_call meets is attn_fwd, whatever scope
-    # it was written under, so moe_route_ms.train does not hold the kernel's
-    # time (and a by-name reader does)
-    ("custom-call", FWD + "moe_combine/pallas_call", "attn_fwd"),
-    ("custom-call", BWD + "moe_combine/pallas_call", "attn_fwd"),
-    ("custom-call", FWD + "pallas_call", "attn_fwd"),
-    # what lies round it stays routing's
+    # a kernel is its scope's, so moe_route_ms.train holds the kernel's time
+    ("custom-call", FWD + "moe_combine/pallas_call", "moe_route"),
+    ("custom-call", FWD + "jit(_pallas)/moe_combine/pallas_call",
+     "moe_route"),
+    ("custom-call", BWD + "moe_combine/pallas_call", "moe_route"),
+    ("custom-call", FWD + "pallas_call", "moe_route"),
+    # and so is what lies round it
     ("fusion", FWD + "gather", "moe_route"),
     ("copy", FWD + "moe_combine/pallas_call", "moe_route"),
     ("fusion", FWD + "reshape", "moe_route"),
     ("fusion", BWD + "reduce_sum", "moe_route"),
     ("sort", FWD + "jit(argsort)/sort", "moe_route"),
 ])
-def test_a_kernel_under_moe_route_falls_to_the_group_attn_fwd(
+def test_a_kernel_under_moe_route_falls_to_the_group_moe_route(
         family, lm, ffn, opcode, path, group):
     rules = sr.Groups(family).rules
     assert sr.group_of(rules, opcode, path.format(lm=lm, ffn=ffn)) == group

@@ -119,13 +119,18 @@ def test_configuration_file_states_every_published_size(cell):
 
 
 def test_manifest_entries_of_the_cell(cell):
-    assert cell.entry == cell.manifest["workloads"][-1]
+    # the entry is there, with these keys; where it stands in the list, and
+    # what later PRs appended behind it, is not this test's to hold
+    assert cell.manifest["workloads"].count(cell.entry) == 1
+    assert cell.entry == {
+        "name": CELL, "config": CONFIG, "traffic": "lm-train-t8192",
+        "chips": 1, "why": cell.entry["why"]}
     assert cell.chips == 1 and cell.options["mesh"] == {"data": 1}
     assert len(cell.entry["why"]) <= 200
     assert cell.traffic["seq_len"] == 8192
     assert cell.traffic["per_chip_batch"] == 1
-    assert [m["name"] for m in cell.end_to_end()] == [
-        "train_samples_per_s_per_chip", "setup_s"]
+    assert {"train_samples_per_s_per_chip", "setup_s"} <= {
+        m["name"] for m in cell.end_to_end()}
     names = {m["name"] for m in cell.per_layer()}
     assert {"gdn_ms.train", "gdn_fwd_roofline", "moe_route_ms.train",
             "moe_experts_ms.train", "moe_experts_roofline",
@@ -135,9 +140,9 @@ def test_manifest_entries_of_the_cell(cell):
     assert not any(n.startswith("grad_") for n in names)   # no wire here
     for name in names:
         assert hasattr(manifest.load_reader(name), "compute")
-    # the cells that were there are first, as they were
-    assert [w["name"] for w in cell.manifest["workloads"][:2]] == [
-        "gpt2m-train-1chip", "gpt2m-train-dp4"]
+    # the cells that were there are there still
+    assert {"gpt2m-train-1chip", "gpt2m-train-dp4"} <= {
+        w["name"] for w in cell.manifest["workloads"]}
     limits = cell.options["limits"]
     assert set(limits) == {"loss_rel", "first_grad_norm", "update_norm",
                            "nonfinite_losses"}

@@ -11,6 +11,6 @@ def test_dp4_cell_rehearses_on_four_virtual_devices():
     assert set(line) == CONTRACT_KEYS
     assert line["device"]["platform"] == "cpu" and line["device"]["count"] == 4
     assert line["failed"] == 0 and line["attempted"] > 0
-    assert set(line["metrics"]) == {"train_samples_per_s_per_chip", "setup_s"}
+    assert set(line["metrics"]) >= {"train_samples_per_s_per_chip", "setup_s"}
     for m in line["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0

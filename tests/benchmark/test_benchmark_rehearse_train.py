@@ -31,4 +31,4 @@ def test_cell_added_as_data_runs_with_no_edit(tmp_path, monkeypatch, capsys):
                                    "--trace", "0", "--rehearse-cpu"])
     assert set(line) == CONTRACT_KEYS
     assert line["correct"] is True and line["attempted"] > 0
-    assert set(line["metrics"]) == {"train_samples_per_s_per_chip", "setup_s"}
+    assert set(line["metrics"]) >= {"train_samples_per_s_per_chip", "setup_s"}

@@ -5,6 +5,7 @@ can be worked out on paper, and on the small trace recorded on the chip that
 import gzip
 import json
 import os
+import types
 
 import pytest
 
@@ -109,3 +110,63 @@ def test_recorded_chip_trace_reduces_to_known_numbers():
                  if e[0].startswith("attention"))
     assert kernel == want["kernel_ns_first_launch"]
     assert 100e6 < kernel < 140e6  # 24 forward kernel calls, ~5 ms each
+
+
+@pytest.mark.parametrize("name,is_one", [
+    # XLA names the instruction after its opcode, or after the JAX primitive
+    # where the exchange reduces one array: both are the wire (PR 40; before
+    # it the second was read as no collective, a quarter of the wire's time)
+    ("all-reduce.12", True), ("all-reduce", True), ("psum.221", True),
+    ("psum", True), ("all-reduce-start.3", True), ("reduce-scatter.1", True),
+    ("all-gather", True), ("collective-permute.2", True), ("all-to-all", True),
+    ("fusion.3", False), ("reduce-window.101", False), ("copy-start.4", False),
+    ("custom-call.3:tpu_custom_call", False), ("reduce.7", False),
+])
+def test_a_collective_is_known_by_either_name(name, is_one):
+    assert tr.is_collective(name) is is_one
+
+
+def test_the_wire_readers_hold_a_one_leaf_buckets_all_reduce():
+    """``grad_collective_ms`` and ``grad_collective_exposed_ms`` on the
+    hand-made trace with a ``psum.<n>`` of 6 ms in each launch's idle tail
+    (70-76): 20 + 6 summed, 10 + 6 exposed."""
+    t = _trace()
+    for plane in tr.device_planes(t):
+        ops = tr.line_events(plane, tr.OPS_LINE)
+        ops += [["psum.9", i * 100 * MS + 70 * MS, 6 * MS] for i in range(3)]
+        plane["lines"][1]["events"] = ops
+    run = types.SimpleNamespace(
+        device_trace=t, launch_match=lambda: lambda n: n.startswith("jit_step"))
+    assert manifest.load_reader("grad_collective_ms").compute(run) == 26.0
+    assert manifest.load_reader(
+        "grad_collective_exposed_ms").compute(run) == 16.0
+
+
+SCOPED = os.path.join(manifest.HERE, "testdata", "lm_train_dp4_scoped.json.gz")
+
+
+@pytest.mark.skipif(not os.path.exists(SCOPED), reason="no recorded trace")
+def test_recorded_four_chip_trace_reads_the_whole_wire():
+    """Chip 0 of the four-chip cell as PR 23 recorded it (the gradients were
+    reduced twice then: 28 all-reduces a step, four of them ``psum.<n>``). By
+    name the reader now finds what the opcodes say is there: 56.45 ms a step,
+    where the opcode's own name alone gave 42.01."""
+    with gzip.open(SCOPED, "rt") as f:
+        scoped = json.load(f)
+    opcodes = scoped["opcodes"]
+    by_opcode = {n for n, op in opcodes.items() if op.startswith("all-reduce")}
+    assert {n for n in opcodes if tr.is_collective(n)} == by_opcode
+    assert len(by_opcode) == 28
+    assert sum(n.startswith("psum.") for n in by_opcode) == 4
+    launches = tr.per_launch(tr.device_planes(scoped)[0],
+                             lambda n: n.startswith("jit_step("))
+    assert len(launches) == 3
+    for launch in launches:
+        summed, exposed = tr.collective_times(launch)
+        named = sum(e[2] for e in launch["ops"]
+                    if e[0].startswith("all-reduce"))
+        assert summed == sum(e[2] for e in launch["ops"]
+                             if e[0] in by_opcode)
+        assert 56.4 * MS < summed < 56.5 * MS
+        assert 41.9 * MS < named < 42.1 * MS
+        assert exposed == summed   # synchronous, after the backward
