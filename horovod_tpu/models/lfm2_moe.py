@@ -133,7 +133,10 @@ class SparseMoe(nn.Module):
     ``expert_bias``, the weight by the score without it; this device's
     ``experts_held`` of them computed without dropping a token. No shared
     expert. ``expert_bias`` is a float32 leaf whose gradient is exactly zero
-    (the published balancing update of it is not part of the model)."""
+    (the published balancing update of it is not part of the model).
+    ``norm_eps`` stands under the chosen weights' sum (LFM2's by default).
+    ``models/xing4.py`` routes through this layer too, with its shared
+    expert beside it."""
 
     n_experts: int
     experts_held: int
@@ -143,6 +146,7 @@ class SparseMoe(nn.Module):
     norm_topk: bool = True
     routed_scale: float = 1.0
     use_expert_bias: bool = True
+    norm_eps: float = NORM_EPS
     init_std: float = 0.02
     dtype: Any = jnp.bfloat16
 
@@ -163,8 +167,8 @@ class SparseMoe(nn.Module):
             "down": init(jax.random.fold_in(k, 2), (E, F, C), f32),
         })
         routing = dict(top_k=self.top_k, norm_topk=self.norm_topk,
-                       score="sigmoid", select_bias=bias, norm_eps=NORM_EPS,
-                       scale=self.routed_scale)
+                       score="sigmoid", select_bias=bias,
+                       norm_eps=self.norm_eps, scale=self.routed_scale)
         flat = x.reshape(B * T, C)
         if self.is_mutable_collection("intermediates"):
             _, ids = route_top_k(flat, router, **routing)

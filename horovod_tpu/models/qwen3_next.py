@@ -65,12 +65,15 @@ def _dense(features, name, dtype, std):
                     kernel_init=_normal(std))
 
 
-def rotary(x, positions, *, rotary_dim: int, theta: float):
+def rotary(x, positions, *, rotary_dim: int, theta: float, inv_freq=None):
     """Half-rotation rotary positions on the first ``rotary_dim`` of the last
-    axis; ``x``: ``[B, T, H, D]``, ``positions``: ``[B, T]``. float32."""
+    axis; ``x``: ``[B, T, H, D]``, ``positions``: ``[B, T]``. float32.
+    ``inv_freq`` (``[rotary_dim / 2]``) replaces ``theta``'s frequencies
+    where a model rescales them."""
     half = rotary_dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0
-                         / rotary_dim)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0
+                             / rotary_dim)
     angle = positions.astype(jnp.float32)[..., None] * inv_freq  # [B, T, half]
     cos = jnp.cos(angle)[:, :, None, :]
     sin = jnp.sin(angle)[:, :, None, :]

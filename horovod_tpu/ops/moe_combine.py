@@ -20,7 +20,10 @@ kernel's loops run as many times as rows are named: a layer whose tokens hold
 few of their ``k`` choices pays for the slots in use.
 :func:`plan` takes the shapes: rows of whole lanes in float32 and a token
 count that blocks divide; other shapes take the same sum as one XLA gather a
-slot (``_xla``: no scatter either).
+slot (``_xla``: no scatter either). A row that is whole lanes and not whole
+``(8, 128)`` tiles (3584 is 28 sublanes) is laid out on a pitch of whole
+tiles, so that every copy starts and ends on a tile's edge; a row of whole
+tiles is its own pitch and is handed over as it is.
 """
 
 from __future__ import annotations
@@ -42,17 +45,25 @@ _SUBLANES = 8
 _PREF_TOKENS = 256           # tokens a grid step, at most
 
 
+def _pitch(width: int) -> int:
+    """Sublanes from one row to the next in the layout the kernel copies
+    from: the row's ``width / 128`` sublanes, in whole tiles of eight."""
+    return -(-(width // _LANES) // _SUBLANES) * _SUBLANES
+
+
 def plan(tokens: int, width: int, dtype) -> Optional[int]:
     """Tokens a grid step, or ``None`` where the kernel does not run: rows
-    that are not float32, a width that is not whole ``(8, 128)`` tiles (a row
-    is copied as ``width / 128`` sublanes of 128 lanes), a token count no
-    block of whole sublanes divides. Two row buffers and the pipeline's two
-    output blocks are ``4 * block * width`` float32."""
+    that are not float32, a width that is not whole lanes (a row is copied as
+    ``width / 128`` sublanes of 128 lanes, on a pitch of whole tiles), a
+    token count no block of whole sublanes divides. Two row buffers at the
+    pitch and the pipeline's two output blocks are float32: ``16 * block *
+    width`` bytes where a row is whole tiles."""
     if _refusal(tokens, width, dtype):
         return None
+    row_bytes = 8 * (width + _pitch(width) * _LANES)
     block = _PREF_TOKENS
     while block > _SUBLANES and (tokens % block
-                                 or 16 * block * width > _VMEM_BUDGET):
+                                 or block * row_bytes > _VMEM_BUDGET):
         block //= 2
     return block if tokens % block == 0 else None
 
@@ -62,26 +73,27 @@ def _refusal(tokens: int, width: int, dtype) -> Optional[str]:
     fallback record carries), or None where they are."""
     if jnp.dtype(dtype) != jnp.float32:
         return "rows_not_float32"
-    if width % (_SUBLANES * _LANES):
-        return "row_not_whole_tiles"
+    if width % _LANES:
+        return "row_not_whole_lanes"
     if tokens % _SUBLANES:
         return "tokens_not_whole_blocks"
     return None
 
 
 def _kernel(counts_ref, pos_s, tok_s, pos_v, w_v, rows_hbm, out_ref, buf, sem,
-            *, block: int, slots: int, chunks: int):
-    """One block of tokens. ``rows_hbm`` is the rows as ``[R * chunks,
-    128]``: a row is ``chunks`` whole sublanes, contiguous in HBM and in the
-    buffer alike, so one copy moves it; the sum reads the buffer back eight
-    tokens a time with a sublane stride of ``chunks``, which is one lane
-    chunk of eight rows as the output block holds them."""
+            *, block: int, slots: int, chunks: int, pitch: int):
+    """One block of tokens. ``rows_hbm`` is the rows as ``[R * pitch,
+    128]``: a row is ``chunks`` sublanes at the head of ``pitch`` whole
+    ones, contiguous in HBM and in the buffer alike, so one copy moves it;
+    the sum reads the buffer back eight tokens a time with a sublane stride
+    of ``pitch``, which is one lane chunk of eight rows as the output block
+    holds them."""
     i = pl.program_id(0)
 
     def row_copy(c, p, t):
         return pltpu.make_async_copy(
-            rows_hbm.at[pl.ds(pl.multiple_of(p * chunks, chunks), chunks)],
-            buf.at[c % 2, pl.ds(pl.multiple_of(t * chunks, chunks), chunks)],
+            rows_hbm.at[pl.ds(pl.multiple_of(p * pitch, pitch), pitch)],
+            buf.at[c % 2, pl.ds(pl.multiple_of(t * pitch, pitch), pitch)],
             sem.at[c % 2])
 
     def start_slot(c):
@@ -106,8 +118,8 @@ def _kernel(counts_ref, pos_s, tok_s, pos_v, w_v, rows_hbm, out_ref, buf, sem,
             w = w_v[pl.ds(t0, _SUBLANES), c:c + 1]
             for j in range(chunks):
                 lanes = slice(j * _LANES, (j + 1) * _LANES)
-                rows = buf[c % 2, pl.ds(t0 * chunks + j, _SUBLANES,
-                                        stride=chunks), :]
+                rows = buf[c % 2, pl.ds(t0 * pitch + j, _SUBLANES,
+                                        stride=pitch), :]
                 out_ref[pl.ds(t0, _SUBLANES), lanes] += jnp.where(
                     named, w * rows, 0.0)
             return carry
@@ -133,8 +145,11 @@ def _pallas(rows, pos, weight, *, block: int, interpret: bool, vma):
     lowered (half a second of Python) at every call, in every process."""
     tokens, slots = pos.shape
     width = rows.shape[1]
-    chunks = width // _LANES
+    chunks, pitch = width // _LANES, _pitch(width)
     blocks = tokens // block
+    if pitch != chunks:   # whatever fills a row's last tile is never summed
+        rows = jnp.pad(rows.reshape(-1, chunks, _LANES),
+                       ((0, 0), (0, pitch - chunks), (0, 0)))
     named = (pos >= 0).reshape(blocks, block, slots).swapaxes(1, 2)
     counts = jnp.sum(named, axis=2, dtype=jnp.int32).reshape(-1)
     # per block and slot, the tokens that name a row, in token order: the
@@ -158,12 +173,13 @@ def _pallas(rows, pos, weight, *, block: int, interpret: bool, vma):
         ],
         out_specs=pl.BlockSpec((block, width), by_block),
         scratch_shapes=[
-            pltpu.VMEM((2, block * chunks, _LANES), jnp.float32),
+            pltpu.VMEM((2, block * pitch, _LANES), jnp.float32),
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, block=block, slots=slots, chunks=chunks),
+        functools.partial(_kernel, block=block, slots=slots, chunks=chunks,
+                          pitch=pitch),
         out_shape=jax.ShapeDtypeStruct((tokens, width), jnp.float32, vma=vma),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(

@@ -46,6 +46,13 @@ written once. :func:`_plan_bwd` picks tiles, rows and form from the shapes.
 The ring block's VJP recomputes its single [T, T/n] block densely (the
 same memory class as the forward block it differentiates).
 
+Queries and keys share one width and the values (with the output and its
+cotangent) may have another: a latent-attention head has keys of 192 (128
+from the low-rank part, 64 rotary) and values of 128. Plans and VMEM counts
+take both widths (``d_v``, the keys' width where it is not given), and no
+operand is padded in HBM to make them equal; at equal widths plans, programs
+and results are what one width gave. The ring block stays at one width.
+
 Interpret mode runs the same kernels on the CPU backend (the tests'
 virtual mesh): it is chosen when the caller asks for it or when the
 default backend is ``cpu``, and refused on a TPU backend — there the
@@ -160,7 +167,7 @@ def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref,
                 rows: int, normalize: bool):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
-    d = q_ref.shape[-1]
+    d = v_ref.shape[-1]     # the accumulator's and the output's width
 
     @pl.when(ki == 0)
     def _init():
@@ -237,17 +244,24 @@ def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref,
         lax.fori_loop(0, rows, one, None)
 
 
-def _step_vmem_bytes(rows, block_q, block_k, d, in_size, out_size):
+def _lane_padded(d):
+    """A minor dim as VMEM holds it: whole 128-lane tiles."""
+    return -(-d // _LANES) * _LANES
+
+
+def _step_vmem_bytes(rows, block_q, block_k, d, in_size, out_size, d_v=None):
     """What one grid step keeps in VMEM: the pipeline's two buffers of
-    every q/k/v/o block (a minor dim under 128 lanes is padded to them),
-    the f32 accumulator and statistics, and the [Bq, Bk] f32 temporaries
-    of one row of ``bh`` (scores, probabilities, the mask's bias, the PV
-    operand)."""
-    dl = -(-d // _LANES) * _LANES
-    blocks = 2 * rows * dl * (
-        (block_q + 2 * block_k) * in_size + block_q * out_size
+    every q/k/v/o block (a minor dim is padded to whole 128-lane tiles; q
+    and k are ``d`` wide, v and o ``d_v``), the f32 accumulator and
+    statistics, and the [Bq, Bk] f32 temporaries of one row of ``bh``
+    (scores, probabilities, the mask's bias, the PV operand)."""
+    dl = _lane_padded(d)
+    vl = dl if d_v is None else _lane_padded(d_v)
+    blocks = 2 * rows * (
+        (block_q + block_k) * dl * in_size + block_k * vl * in_size
+        + block_q * vl * out_size
     )
-    scratch = rows * block_q * (dl + 2 * _LANES) * 4
+    scratch = rows * block_q * (vl + 2 * _LANES) * 4
     return blocks + scratch + 4 * block_q * block_k * 4
 
 
@@ -280,13 +294,13 @@ def _fit_plan(bh, t_q, t_k, block_q, block_k, step_bytes):
     return bq, bk, rows
 
 
-def _plan(bh, t_q, t_k, d, in_size, out_size, block_q, block_k):
+def _plan(bh, t_q, t_k, d, in_size, out_size, block_q, block_k, d_v=None):
     """``(block_q, block_k, rows)`` of one forward call
     (:func:`_fit_plan` with the forward's VMEM count)."""
     return _fit_plan(
         bh, t_q, t_k, block_q, block_k,
         lambda rows, bq, bk: _step_vmem_bytes(
-            rows, bq, bk, d, in_size, out_size),
+            rows, bq, bk, d, in_size, out_size, d_v),
     )
 
 
@@ -303,11 +317,12 @@ def _pairs_visited(t_q, t_k, block_q, block_k, causal):
     return seen / (n_q * n_k)
 
 
-def _forward(bh, t_q, t_k, d, out_dtype, sm_scale, causal, block_q, block_k,
-             rows, normalize, interpret, vma):
+def _forward(bh, t_q, t_k, d, d_v, out_dtype, sm_scale, causal, block_q,
+             block_k, rows, normalize, interpret, vma):
     """The forward ``pallas_call`` at one plan, as a function of
     ``(delta[1] int32, q, k, v)`` returning ``(o, m, l)`` with the
-    statistics as ``[bh, t_q / block_q, 1, block_q]``."""
+    statistics as ``[bh, t_q / block_q, 1, block_q]``; q and k are ``d``
+    wide, v and o ``d_v``."""
     n_q, n_k = t_q // block_q, t_k // block_k
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
@@ -332,15 +347,15 @@ def _forward(bh, t_q, t_k, d, out_dtype, sm_scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((rows, block_q, d), q_index),
             pl.BlockSpec((rows, block_k, d), kv_index),
-            pl.BlockSpec((rows, block_k, d), kv_index),
+            pl.BlockSpec((rows, block_k, d_v), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((rows, block_q, d), q_index),
+            pl.BlockSpec((rows, block_q, d_v), q_index),
             pl.BlockSpec((rows, 1, 1, block_q), stat_index),
             pl.BlockSpec((rows, 1, 1, block_q), stat_index),
         ],
         scratch_shapes=[
-            pltpu.VMEM((rows, block_q, d), jnp.float32),
+            pltpu.VMEM((rows, block_q, d_v), jnp.float32),
             pltpu.VMEM((rows, block_q, _LANES), jnp.float32),
             pltpu.VMEM((rows, block_q, _LANES), jnp.float32),
         ],
@@ -351,7 +366,7 @@ def _forward(bh, t_q, t_k, d, out_dtype, sm_scale, causal, block_q, block_k,
     return pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_q, d), out_dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_q, d_v), out_dtype, vma=vma),
             stat, stat,
         ],
         grid_spec=grid_spec,
@@ -371,8 +386,8 @@ def _forward_jaxpr(mesh, in_dtypes, *plan):
     the same ``pallas_call`` under the caller's own name stack. ``mesh``
     is the abstract mesh of the caller's context: avals carry it, so a
     jaxpr is kept per context."""
-    bh, t_q, t_k, d = plan[:4]
-    shapes = ((1,), (bh, t_q, d), (bh, t_k, d), (bh, t_k, d))
+    bh, t_q, t_k, d, d_v = plan[:5]
+    shapes = ((1,), (bh, t_q, d), (bh, t_k, d), (bh, t_k, d_v))
     return jax.make_jaxpr(_forward(*plan, vma=frozenset()))(*(
         jax.ShapeDtypeStruct(shape, dtype)
         for shape, dtype in zip(shapes, (jnp.int32,) + in_dtypes)
@@ -384,11 +399,11 @@ def _flash_call(q, k, v, delta, *, sm_scale, causal, block_q, block_k,
     """Run the forward kernel; returns (o, m, l) with m/l of shape
     [bh, t_q] (row max / softmax denominator in the online recurrence)."""
     bh, t_q, d = q.shape
-    t_k = k.shape[1]
+    t_k, d_v = v.shape[1:]
     out_dtype = jnp.dtype(out_dtype)
     block_q, block_k, rows = _plan(
         bh, t_q, t_k, d, q.dtype.itemsize, out_dtype.itemsize,
-        block_q, block_k,
+        block_q, block_k, d_v,
     )
     # Trace-time, one note per compile (the fusion plan's discipline):
     # what one grid step of this program's forward kernel is.
@@ -399,8 +414,8 @@ def _flash_call(q, k, v, delta, *, sm_scale, causal, block_q, block_k,
         flash_pairs_visited=round(
             _pairs_visited(t_q, t_k, block_q, block_k, causal), 4),
     )
-    plan = (bh, t_q, t_k, d, out_dtype, sm_scale, causal, block_q, block_k,
-            rows, normalize, interpret)
+    plan = (bh, t_q, t_k, d, d_v, out_dtype, sm_scale, causal, block_q,
+            block_k, rows, normalize, interpret)
     args = (jnp.asarray(delta, jnp.int32).reshape(1), q, k, v)
     vma = _vma(q, k, v)
     if vma:   # typed per mesh axis: traced where the axes are bound
@@ -595,7 +610,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
         dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
-def _bwd_step_vmem_bytes(rows, block_q, block_k, d, in_size, whole_t_q=0):
+def _bwd_step_vmem_bytes(rows, block_q, block_k, d, in_size, whole_t_q=0,
+                         d_v=None):
     """What one grid step of the backward keeps in VMEM: the pipeline's two
     buffers of the q, do, k, v blocks, of the ``lse`` and ``D`` rows (a
     ``[1, Bq]`` f32 row fills 8 sublanes) and of the outputs, the f32
@@ -604,13 +620,16 @@ def _bwd_step_vmem_bytes(rows, block_q, block_k, d, in_size, whole_t_q=0):
     MXU operands cast from them). With ``whole_t_q`` the one-pass kernel,
     which holds dK/dV's buffers, a ``dq`` of that many queries and ``ds``
     transposed; without, the larger of the dK/dV and the dQ kernel (the
-    latter with its two statistic columns)."""
-    dl = -(-d // _LANES) * _LANES
-    held = dl * (2 * in_size + 4)    # an output's two buffers and its f32 sum
+    latter with its two statistic columns). q, k, ``dq`` and ``dk`` are
+    ``d`` wide; v, ``do`` and ``dv`` are ``d_v``."""
+    dl = _lane_padded(d)
+    vl = dl if d_v is None else _lane_padded(d_v)
+    # an output's two buffers and its f32 sum, a row of it
+    held, held_v = (w * (2 * in_size + 4) for w in (dl, vl))
     inputs = 2 * rows * (
-        2 * (block_q + block_k) * dl * in_size + 2 * 8 * block_q * 4
+        (block_q + block_k) * (dl + vl) * in_size + 2 * 8 * block_q * 4
     )
-    dkv = 2 * rows * block_k * held
+    dkv = rows * block_k * (held + held_v)
     if whole_t_q:
         return (inputs + dkv + rows * whole_t_q * held
                 + 7 * block_q * block_k * 4)
@@ -618,28 +637,30 @@ def _bwd_step_vmem_bytes(rows, block_q, block_k, d, in_size, whole_t_q=0):
     return inputs + max(dkv, dq) + 6 * block_q * block_k * 4
 
 
-def _plan_bwd(bh, t_q, t_k, d, in_size, block_q, block_k):
+def _plan_bwd(bh, t_q, t_k, d, in_size, block_q, block_k, d_v=None):
     """``(block_q, block_k, rows, one_pass)`` of one backward call:
     :func:`_plan`'s rule with the backward's own VMEM count gives the two
     kernels' tiles; where some rows' whole ``dq`` fits beside them at those
     tiles, the one-pass kernel runs instead (5 products a pair for 7)."""
     bq, bk, rows = _fit_plan(
         bh, t_q, t_k, block_q, block_k,
-        lambda rows, bq, bk: _bwd_step_vmem_bytes(rows, bq, bk, d, in_size),
+        lambda rows, bq, bk: _bwd_step_vmem_bytes(
+            rows, bq, bk, d, in_size, 0, d_v),
     )
     for r in range(rows, 0, -1):
         if bh % r == 0 and _bwd_step_vmem_bytes(
-                r, bq, bk, d, in_size, t_q) <= _VMEM_BUDGET:
+                r, bq, bk, d, in_size, t_q, d_v) <= _VMEM_BUDGET:
             return bq, bk, r, True
     return bq, bk, rows, False
 
 
-def _backward(bh, t_q, t_k, d, dtypes, sm_scale, causal, block_q, block_k,
-              rows, one_pass, interpret, vma):
+def _backward(bh, t_q, t_k, d, d_v, dtypes, sm_scale, causal, block_q,
+              block_k, rows, one_pass, interpret, vma):
     """The backward ``pallas_call``s at one plan (the one-pass kernel, or
     the dK/dV and the dQ kernel), as a function of ``(q, k, v, do, lse,
     D)`` returning ``(dq, dk, dv)``; ``lse`` and ``D`` are
-    ``[bh, t_q / block_q, 1, block_q]`` f32."""
+    ``[bh, t_q / block_q, 1, block_q]`` f32; q and k are ``d`` wide, v and
+    ``do`` ``d_v``."""
     n_q, n_k = t_q // block_q, t_k // block_k
     static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
                   block_k=block_k, rows=rows)
@@ -661,33 +682,37 @@ def _backward(bh, t_q, t_k, d, dtypes, sm_scale, causal, block_q, block_k,
     def specs(q_of, k_of):
         """The six inputs' specs from where a grid step's Q and K/V blocks
         lie."""
-        q_spec = pl.BlockSpec(
-            (rows, block_q, d), lambda b, x, y: (b, q_of(x, y), 0))
-        k_spec = pl.BlockSpec(
-            (rows, block_k, d), lambda b, x, y: (b, k_of(x, y), 0))
+        q_spec, do_spec = (pl.BlockSpec(
+            (rows, block_q, w), lambda b, x, y: (b, q_of(x, y), 0))
+            for w in (d, d_v))
+        k_spec, v_spec = (pl.BlockSpec(
+            (rows, block_k, w), lambda b, x, y: (b, k_of(x, y), 0))
+            for w in (d, d_v))
         stat = pl.BlockSpec(
             (rows, 1, 1, block_q), lambda b, x, y: (b, q_of(x, y), 0, 0))
-        return [q_spec, k_spec, k_spec, q_spec, stat, stat]
+        return [q_spec, k_spec, v_spec, do_spec, stat, stat]
 
     dq_dtype, dk_dtype, dv_dtype = dtypes
     dq_shape = jax.ShapeDtypeStruct((bh, t_q, d), dq_dtype, vma=vma)
-    kv_spec = pl.BlockSpec((rows, block_k, d), lambda b, j, i: (b, j, 0))
-    kv_acc = pltpu.VMEM((rows, block_k, d), jnp.float32)
+    dk_spec, dv_spec = (pl.BlockSpec(
+        (rows, block_k, w), lambda b, j, i: (b, j, 0)) for w in (d, d_v))
+    dk_acc, dv_acc = (pltpu.VMEM((rows, block_k, w), jnp.float32)
+                      for w in (d, d_v))
     # With one_pass a third output and accumulator: the rows' whole dq,
     # resident across both inner axes (so neither is parallel).
     dkv = pl.pallas_call(
         functools.partial(_dkv_kernel, one_pass=one_pass, **static),
         out_shape=[
             jax.ShapeDtypeStruct((bh, t_k, d), dk_dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, t_k, d), dv_dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, t_k, d_v), dv_dtype, vma=vma),
         ] + [dq_shape] * one_pass,
         grid=(bh // rows, n_k, n_q),
         in_specs=specs(lambda j, i: lax.max(i, first_q(j)),
                        lambda j, i: j),
-        out_specs=[kv_spec, kv_spec] + [
+        out_specs=[dk_spec, dv_spec] + [
             pl.BlockSpec((rows, t_q, d), lambda b, j, i: (b, 0, 0))
         ] * one_pass,
-        scratch_shapes=[kv_acc, kv_acc] + [
+        scratch_shapes=[dk_acc, dv_acc] + [
             pltpu.VMEM((rows, t_q, d), jnp.float32)] * one_pass,
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "arbitrary" if one_pass else "parallel", "arbitrary",
@@ -725,12 +750,12 @@ def _backward_jaxpr(mesh, *plan):
     :func:`_forward_jaxpr` keeps the forward: the kernel bodies of a layer
     would otherwise be traced 24 times a program. ``do`` has the dtype of
     the output it is the cotangent of, which is q's."""
-    bh, t_q, t_k, d, (q_dtype, k_dtype, v_dtype), _, _, block_q = plan[:8]
+    bh, t_q, t_k, d, d_v, (q_dtype, k_dtype, v_dtype), _, _, block_q = plan[:9]
     stat = ((bh, t_q // block_q, 1, block_q), jnp.float32)
     return jax.make_jaxpr(_backward(*plan, vma=frozenset()))(*(
         jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
             ((bh, t_q, d), q_dtype), ((bh, t_k, d), k_dtype),
-            ((bh, t_k, d), v_dtype), ((bh, t_q, d), q_dtype), stat, stat,
+            ((bh, t_k, d_v), v_dtype), ((bh, t_q, d_v), q_dtype), stat, stat,
         )
     ))
 
@@ -743,9 +768,9 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     HBM."""
     q, k, v, o, lse = res
     bh, t_q, d = q.shape
-    t_k = k.shape[1]
+    t_k, d_v = v.shape[1:]
     block_q, block_k, rows, one_pass = _plan_bwd(
-        bh, t_q, t_k, d, q.dtype.itemsize, block_q, block_k)
+        bh, t_q, t_k, d, q.dtype.itemsize, block_q, block_k, d_v)
     # Beside the forward's note: what one grid step of this program's
     # backward is, which form runs, and the steps of all its kernels.
     _trace.note_plan(
@@ -762,8 +787,8 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     stat_shape = (bh, t_q // block_q, 1, block_q)
     args = (q, k, v, do, lse.reshape(stat_shape), dd.reshape(stat_shape))
-    plan = (bh, t_q, t_k, d, (q.dtype, k.dtype, v.dtype), sm_scale, causal,
-            block_q, block_k, rows, one_pass, interpret)
+    plan = (bh, t_q, t_k, d, d_v, (q.dtype, k.dtype, v.dtype), sm_scale,
+            causal, block_q, block_k, rows, one_pass, interpret)
     vma = _vma(*args)
     if vma:   # typed per mesh axis: traced where the axes are bound
         return _backward(*plan, vma=vma)(*args)
@@ -772,6 +797,13 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+def _check_widths(q, k):
+    if q.shape[-1] != k.shape[-1]:
+        raise ValueError(
+            f"queries are {q.shape[-1]} wide and keys {k.shape[-1]}: they "
+            "share one width (only the values may have another)")
 
 
 def flash_attention(
@@ -787,7 +819,9 @@ def flash_attention(
 ) -> jax.Array:
     """Fused attention over ``[..., T, D]`` (leading dims fold into one
     batch x heads grid axis). Differentiable; the backward is kernels too,
-    which recompute the probabilities per block pair.
+    which recompute the probabilities per block pair. ``v`` may have
+    another width than ``q`` and ``k`` (which share one); the output has
+    ``v``'s.
 
     ``interpret=None`` interprets on the CPU backend only, so the same
     code runs in tests on the virtual CPU mesh.
@@ -795,15 +829,16 @@ def flash_attention(
     if q.ndim < 3:
         raise ValueError("expected [..., T, D] with at least one batch dim")
     interpret = _resolve_interpret(interpret)
+    _check_widths(q, k)
     lead = q.shape[:-2]
     t_q, d = q.shape[-2:]
-    t_k = k.shape[-2]
+    t_k, d_v = v.shape[-2:]
     scale = sm_scale if sm_scale is not None else d ** -0.5
     qf = q.reshape((-1, t_q, d))
     kf = k.reshape((-1, t_k, d))
-    vf = v.reshape((-1, t_k, d))
+    vf = v.reshape((-1, t_k, d_v))
     out = _flash(qf, kf, vf, scale, causal, block_q, block_k, interpret)
-    return out.reshape(*lead, t_q, d)
+    return out.reshape(*lead, t_q, d_v)
 
 
 def flash_attention_bthd(
@@ -819,9 +854,12 @@ def flash_attention_bthd(
     signature (``models/transformer.py``): fold heads into the kernel's
     batch axis, run the fused kernel, unfold. Sequence lengths the kernel's
     block constraint rejects (prime/odd T) take a dense fallback instead of
-    raising, so the default attention accepts any shape."""
+    raising, so the default attention accepts any shape. ``v`` may have
+    another head width than ``q`` and ``k``."""
+    _check_widths(q, k)
     B, T, H, D = q.shape
-    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, x.shape[1], D)
+    Dv = v.shape[-1]
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(B * H, *x.shape[1::2])
     qf, kf, vf = fold(q), fold(k), fold(v)
     scale = sm_scale if sm_scale is not None else D ** -0.5
     if flashable(T, k.shape[1]):
@@ -832,9 +870,10 @@ def flash_attention_bthd(
         # the dense form's backward is JAX's transpose of it: the one record
         # stands for both directions
         _trace.note_fallback("attention", "no_block_divisor", t_q=T,
-                             t_k=k.shape[1], heads=H, head_dim=D)
+                             t_k=k.shape[1], heads=H, head_dim=D,
+                             **({"v_head_dim": Dv} if Dv != D else {}))
         out = _dense_full(qf, kf, vf, causal, scale)
-    return out.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    return out.reshape(B, H, T, Dv).transpose(0, 2, 1, 3)
 
 
 # --------------------------------------------------------------------------
@@ -916,7 +955,13 @@ def flash_attention_block(
     float scalar giving the K block's global sequence offset minus Q's
     (traced — ring steps compute it from ``lax.axis_index``). Returns
     ``(o_unnormalized_f32, m, l)`` for the caller's online-softmax merge
-    (``parallel/ring_attention.py``)."""
+    (``parallel/ring_attention.py``). One width: no caller of the ring has
+    values of another width than its keys."""
+    if not q.shape[-1] == k.shape[-1] == v.shape[-1]:
+        raise ValueError(
+            "the ring block takes q, k and v of one width; got "
+            f"{q.shape[-1]}, {k.shape[-1]}, {v.shape[-1]} (flash_attention "
+            "takes values of another width)")
     interpret = _resolve_interpret(interpret)
     delta = jnp.asarray(delta, jnp.float32)
     return _flash_block(q, k, v, delta, sm_scale, causal, block_q, block_k,

@@ -110,6 +110,10 @@ SCOPE_MOE_SHARED = "moe_shared"
 # six, which benchmark/scope_groups/qwen3_next.json lists one for one.
 SCOPE_SHORT_CONV = "short_conv"    # the two gates and the taps, no projection
 SCOPE_GQA_ATTN = "gqa_attn"
+# The layers of models/xing4.py (XING4_SCOPES below): the latent-attention
+# mixer outside the flash kernels, and the two stream mixes of a layer.
+SCOPE_LATENT_ATTN = "latent_attn"  # low-rank products, norms, rotary, k, W_o
+SCOPE_HC_MIX = "hc_mix"            # mixing matrices, read and write of streams
 MODEL_SCOPES = (
     SCOPE_GDN_CONV,
     SCOPE_GDN_SCAN,
@@ -123,6 +127,13 @@ LFM2_SCOPES = (
     SCOPE_GQA_ATTN,
     SCOPE_MOE_ROUTE,
     SCOPE_MOE_EXPERTS,
+)
+XING4_SCOPES = (
+    SCOPE_LATENT_ATTN,
+    SCOPE_HC_MIX,
+    SCOPE_MOE_ROUTE,
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_SHARED,
 )
 STEP_SCOPES = (
     SCOPE_LOSS_GRAD,

@@ -188,7 +188,7 @@ def test_delta_rule_records_a_fallback_a_call_site_and_none_for_the_kernel():
                                      "chunk": 64, "dk": 16, "dv": 128}
 
 
-def test_gather_sum_records_a_fallback_where_a_row_is_no_whole_tile():
+def test_gather_sum_records_a_fallback_where_a_row_is_no_whole_lanes():
     from horovod_tpu.ops.moe_combine import gather_sum
 
     def traced(width):
@@ -200,7 +200,7 @@ def test_gather_sum_records_a_fallback_where_a_row_is_no_whole_tile():
     assert hvd_trace.build_ledger()["fallbacks"] == []
     traced(64)
     assert hvd_trace.build_ledger()["fallbacks"] == [{
-        "op": "moe_combine", "reason": "row_not_whole_tiles",
+        "op": "moe_combine", "reason": "row_not_whole_lanes",
         "shape": {"tokens": 16, "slots": 2, "width": 64}}]
 
 

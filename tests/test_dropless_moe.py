@@ -442,7 +442,8 @@ def _scatter_add_reference(rows, pos, weight):
 
 
 @pytest.mark.parametrize("width,form", [(16, "xla"), (64, "xla"),
-                                        (1024, "kernel"), (2048, "kernel")])
+                                        (1024, "kernel"), (2048, "kernel"),
+                                        (384, "kernel"), (3584, "kernel")])
 @pytest.mark.parametrize("tokens,slots,fill", [
     (64, 4, 0.3),      # some slots empty, some tokens with no pair at all
     (24, 10, 0.06),    # most tokens hold nothing, as at 32 of 512 experts
@@ -452,8 +453,10 @@ def test_gather_sum_equals_a_scatter_add(width, form, tokens, slots, fill):
     """Random ``pos`` with unnamed slots; the rows past the load are NaN, as
     a grouped product may leave them on the chip, and must not reach the
     output. Both forms against numpy and against each other: the XLA gathers
-    (widths that are no whole float32 tiles) and the Pallas kernel
-    (interpreted on the CPU)."""
+    (widths that are no whole lanes) and the Pallas kernel (interpreted on
+    the CPU), at rows of whole float32 tiles and at rows laid out on a pitch
+    of whole tiles (384 is 3 sublanes of 8, 3584 is 28 of 32: the fourth
+    model's width)."""
     assert (moe_combine.plan(tokens, width, jnp.float32) is None) == (
         form == "xla")
     rng = np.random.default_rng(tokens + width)
@@ -486,8 +489,10 @@ def test_gather_sum_of_other_rows_takes_the_gathers():
     the kernel's: the same sum as XLA gathers, in float32."""
     assert moe_combine.plan(64, 1024, jnp.bfloat16) is None
     assert moe_combine.plan(60, 1024, jnp.float32) is None
-    assert moe_combine.plan(64, 1024 + 128, jnp.float32) is None
+    assert moe_combine.plan(64, 1024 + 64, jnp.float32) is None
     assert moe_combine.plan(8192, 2048, jnp.float32) == 256
+    # 28 sublanes on a pitch of 32: two buffers of 4096 and two blocks of 3584
+    assert moe_combine.plan(8192, 3584, jnp.float32) == 128
     rows = jnp.arange(12 * 1024, dtype=jnp.bfloat16).reshape(12, 1024) / 64
     pos = jnp.asarray([[0, 11], [5, -1], [-1, -1], [2, 2]], jnp.int32)
     weight = jnp.asarray([[1, 2], [3, 4], [5, 6], [0.5, 0.25]], jnp.float32)

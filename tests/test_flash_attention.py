@@ -109,6 +109,68 @@ def test_grad_matches_dense(monkeypatch, one_pass, causal, dtype, shape):
             np.asarray(a, np.float32), np.asarray(b, np.float32), **tol)
 
 
+# (bh, t, d_qk, d_v, block, _PREF_BLOCK): values of another width than the
+# keys, as a latent-attention head has them, and equal widths beside them.
+_TWO_WIDTHS = {
+    # the Xing4.0 head itself, tiles of 64 standing for 512
+    "192/128": (2, 256, 192, 128, None, 64),
+    "24/16": (4, 64, 24, 16, 16, None),
+    "16/24": (4, 64, 16, 24, 16, None),     # values wider than the keys
+    "128/128": (2, 128, 128, 128, None, 64),
+}
+
+
+@pytest.mark.parametrize("shape", list(_TWO_WIDTHS))
+@pytest.mark.parametrize("one_pass", [True, False],
+                         ids=["one-pass", "two-kernels"])
+def test_two_widths_match_dense(monkeypatch, one_pass, shape):
+    """Forward, dq, dk and dv at keys of one width and values of another,
+    in both backward forms, against the dense form's own gradient, causal,
+    at the softmax scale a caller passes."""
+    bh, t, d, d_v, block, pref = _TWO_WIDTHS[shape]
+    if pref is not None:
+        monkeypatch.setattr(pa, "_PREF_BLOCK", pref)
+    plan_bwd = pa._plan_bwd
+    monkeypatch.setattr(
+        pa, "_plan_bwd", lambda *a: plan_bwd(*a)[:3] + (one_pass,))
+    rng = np.random.RandomState(5)
+    mk = lambda w: jnp.asarray(rng.randn(bh, t, w).astype(np.float32) * 0.5)
+    q, k, v, w = mk(d), mk(d), mk(d_v), mk(d_v)
+    scale = 0.14468
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v) * w)
+
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, sm_scale=scale, block_q=block, block_k=block)
+    dense = lambda q, k, v: pa._dense_full(q, k, v, True, scale)
+    out = flash(q, k, v)
+    assert out.shape == (bh, t, d_v)
+    np.testing.assert_allclose(out, dense(q, k, v), rtol=2e-4, atol=2e-5)
+    gf = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+    gd = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for a, b, width in zip(gf, gd, (d, d, d_v)):
+        assert a.shape == (bh, t, width)
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_two_widths_through_the_bthd_adapter_and_the_checks():
+    rng = np.random.RandomState(2)
+    mk = lambda w: jnp.asarray(rng.randn(2, 32, 3, w).astype(np.float32))
+    q, k, v = mk(24), mk(24), mk(16)
+    out = flash_attention_bthd(q, k, v, causal=True, sm_scale=0.2)
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(6, 32, -1)
+    want = pa._dense_full(fold(q), fold(k), fold(v), True, 0.2)
+    np.testing.assert_allclose(
+        out, want.reshape(2, 3, 32, 16).transpose(0, 2, 1, 3),
+        rtol=2e-4, atol=2e-5)
+    # queries and keys share one width; the ring block takes one for all
+    with pytest.raises(ValueError, match="share one width"):
+        flash_attention(fold(q), fold(v), fold(v))
+    with pytest.raises(ValueError, match="ring block"):
+        flash_attention_block(fold(q), fold(k), fold(v), 0.0, sm_scale=0.2)
+
+
 def test_grad_under_checked_shard_map():
     """Inside a vma-checked shard_map the kernels' outputs are typed
     varying over the axes their inputs vary over (traced in place, not

@@ -80,3 +80,46 @@ def test_backward_plan_keeps_the_callers_blocks_and_halves_its_own():
     # f32 operands at width 256: a 512 x 512 step of one row overruns
     assert pa._bwd_step_vmem_bytes(1, 512, 512, 256, 4) > pa._VMEM_BUDGET
     assert pa._plan_bwd(16, 2048, 2048, 256, 4, None, None)[:2] == (256, 256)
+
+
+def test_plans_at_keys_of_192_and_values_of_128():
+    """The Xing4.0 cell: [32 heads, 8192, 192 | 128] bf16. In VMEM a 192-wide
+    block takes 256 lanes and a 128-wide one 128: two rows a forward step
+    where keys AND values of 192 would leave one, and one row a backward
+    step, in two kernels (a row's dq is 8192 x 256 lanes of f32, 8 MB)."""
+    bq, bk, rows = pa._plan(32, 8192, 8192, 192, 2, 2, None, None, 128)
+    assert (bq, bk, rows) == (512, 512, 2)
+    count = lambda r, d_v: pa._step_vmem_bytes(r, bq, bk, 192, 2, 2, d_v)
+    assert count(2, 128) <= pa._VMEM_BUDGET < count(4, 128)
+    assert pa._plan(32, 8192, 8192, 192, 2, 2, None, None)[2] == 2
+    assert count(2, 128) < count(2, None) == count(2, 192)
+    assert pa._plan_bwd(32, 8192, 8192, 192, 2, None, None, 128) == (
+        512, 512, 1, False)
+    held = pa._bwd_step_vmem_bytes(1, 512, 512, 192, 2, 0, 128)
+    assert held <= pa._VMEM_BUDGET
+    assert held < pa._bwd_step_vmem_bytes(1, 512, 512, 192, 2)
+    assert pa._bwd_step_vmem_bytes(2, 512, 512, 192, 2, 0, 128) \
+        > pa._VMEM_BUDGET
+    assert pa._pairs_visited(8192, 8192, bq, bk, True) == pytest.approx(
+        (16 * 17 / 2) / 256)
+
+
+@pytest.mark.parametrize("bh,t,d,in_size", [
+    (128, 1024, 64, 2), (16, 8192, 256, 2), (128, 8192, 64, 2),
+    (64, 2048, 128, 2), (64, 2048, 256, 4), (8, 1024, 64, 4),
+])
+def test_equal_widths_plan_as_one_width_did(bh, t, d, in_size):
+    """``d_v`` left out, or given as the keys' width, counts and plans what
+    the one-width kernels counted and planned (the pins above are those)."""
+    one = pa._plan(bh, t, t, d, in_size, in_size, None, None)
+    assert pa._plan(bh, t, t, d, in_size, in_size, None, None, d) == one
+    bq, bk, rows = one
+    dl = -(-d // 128) * 128
+    assert pa._step_vmem_bytes(rows, bq, bk, d, in_size, in_size, d) == (
+        2 * rows * dl * ((bq + 2 * bk) * in_size + bq * in_size)
+        + rows * bq * (dl + 2 * 128) * 4 + 4 * bq * bk * 4)
+    back = pa._plan_bwd(bh, t, t, d, in_size, None, None)
+    assert pa._plan_bwd(bh, t, t, d, in_size, None, None, d) == back
+    for whole in (0, t):
+        assert pa._bwd_step_vmem_bytes(1, bq, bk, d, in_size, whole, d) == (
+            pa._bwd_step_vmem_bytes(1, bq, bk, d, in_size, whole))

@@ -66,8 +66,12 @@ def _compile(jitted, *avals):
 
 
 def _qkv(topo, shape, dtype=jnp.bfloat16):
+    """q, k and v of ``[bh, t, d]``, or ``[bh, t, d, d_v]`` for values of
+    another width."""
     one_chip = SingleDeviceSharding(topo.devices[0])
-    return (jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip),) * 3
+    arr = lambda width: jax.ShapeDtypeStruct(
+        shape[:2] + (width,), dtype, sharding=one_chip)
+    return arr(shape[2]), arr(shape[2]), arr(shape[-1])
 
 
 @pytest.mark.parametrize("shape,stats", [
@@ -81,6 +85,8 @@ def _qkv(topo, shape, dtype=jnp.bfloat16):
     ((16, 8192, 256), "f32[16,16,1,512]"),
     # The LFM2 cell's: 4 sequences x 32 heads of width 64 over 8192 tokens.
     ((128, 8192, 64), "f32[128,16,1,512]"),
+    # The Xing4.0 cell's: 32 heads with keys of 192 and values of 128.
+    ((32, 8192, 192, 128), "f32[32,16,1,512]"),
 ])
 def test_flash_forward_compiles(topo, shape, stats):
     """The forward at the tiles the kernel picks from the shapes (a VMEM
@@ -104,6 +110,8 @@ def test_flash_forward_compiles(topo, shape, stats):
     ((128, 8192, 64), 3),
     # f32 operands: a whole dq does not fit beside 512 x 512 tiles.
     ((8, 1024, 64, "float32"), 3),
+    # The Xing4.0 cell's latent-attention layer: keys of 192, values of 128.
+    ((32, 8192, 192, 128), 3),
 ])
 def test_flash_backward_compiles(topo, shape, kernels):
     """The backward at the tiles and the form ``_plan_bwd`` picks: the
@@ -115,10 +123,11 @@ def test_flash_backward_compiles(topo, shape, kernels):
         out = pa.flash_attention(q, k, v, causal=True, interpret=False)
         return out.astype(jnp.float32).sum()
 
-    dtype = jnp.dtype(shape[3]) if len(shape) == 4 else jnp.bfloat16
+    f32 = isinstance(shape[-1], str)
     compiled = _compile(
         jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
-        *_qkv(topo, shape[:3], dtype))
+        *_qkv(topo, shape[:3] if f32 else shape,
+              jnp.dtype(shape[3]) if f32 else jnp.bfloat16))
     text = compiled.as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") == kernels
     assert " while(" not in text
@@ -276,3 +285,49 @@ def test_composed_step_compiles_on_2x2(topo, compile_kernel):
         _placed(batch, mesh, jax.tree.map(lambda _: P("data"), batch)),
     )
     assert "all-reduce" in compiled.as_text()
+
+
+def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
+    """The Xing4.0 cell's whole step (``hvd.make_train_step`` over
+    ``Xing4LM`` at the configuration's sizes: 759.3 M parameters, one
+    8192-token sequence) for the described chip: the flash kernels at 192 /
+    128 are in it, one forward and two backward a layer and the forward
+    again under recomputation, and parameters, AdamW's moments, gradients and
+    scratch come to no more than 16.0 GB by the compiler's own count."""
+    import os
+    import sys
+
+    from horovod_tpu import trace as hvd_trace
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import manifest, weights
+
+    cell = manifest.Cell(manifest.load_manifest(), "xing4-train-1chip")
+    cfg, traffic = cell.config, cell.traffic
+    assert (traffic["seq_len"], traffic["per_chip_batch"]) == (8192, 1)
+    mesh = hvdj.build_mesh({"data": 1}, devices=topo.devices[:1])
+    rep, dat = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    params = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, jnp.float32, sharding=rep),
+        cell.family.param_spec(cfg), is_leaf=weights.is_leaf)
+    step, tx = cell.family.build_train(cfg, traffic, {}, mesh)
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+        jax.eval_shape(tx.init, params))
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=dat)
+    hvd_trace.reset_build_ledger()
+    compiled = _compile(step, params, state, (tokens, tokens))
+    layers = cfg["num_hidden_layers"]
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") >= 4 * layers
+    # the sparse layers' per-token sums are the gather-sum kernel at this
+    # model's 28 sublanes a row, and no call site fell back to XLA
+    assert sum("moe_combine" in l for l in text.splitlines()
+               if "custom-call(" in l) >= 3 * (layers - 1)
+    assert hvd_trace.build_ledger()["fallbacks"] == []
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 12.1e9 < held <= 16.0e9, held
