@@ -42,12 +42,14 @@ import numpy as np
 from flax import linen as nn
 
 from .. import trace as _trace
+from ..ops import stream_mix
 from ..ops.pallas_attention import flash_attention_bthd
+from ..ops.stream_mix import sinkhorn
 from .lfm2_moe import DenseMlp, SparseMoe, _norm
 from .qwen3_next import _dense, _normal, expert_load, rotary
 
-__all__ = ["Xing4Config", "Xing4LM", "expert_load", "softmax_scale",
-           "yarn_inv_freq"]
+__all__ = ["Xing4Config", "Xing4LM", "expert_load", "sinkhorn",
+           "softmax_scale", "yarn_inv_freq"]
 
 
 def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
@@ -123,15 +125,6 @@ class LatentAttention(nn.Module):
             return dense(C, "o_proj")(a.reshape(B, T, H * dv))
 
 
-def sinkhorn(m, iters: int, eps: float):
-    """``iters`` rounds of rows then columns on ``m`` (``[n, n, ...]``, row
-    index first): each divides by the sum plus ``eps``."""
-    for _ in range(iters):
-        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
-        m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
-    return m
-
-
 class StreamMix(nn.Module):
     """One sublayer's read and write of the ``n`` residual streams
     (manifold-constrained hyper-connections): from the token's whole
@@ -140,7 +133,8 @@ class StreamMix(nn.Module):
     ``post`` (``[n]``, twice a sigmoid) spreads its output over them, and
     ``res`` (``[n, n]``, the exponential of a clipped map, Sinkhorn-
     normalised) mixes the streams among themselves. :meth:`pre` before the
-    sublayer, :meth:`post` after it. The maps are stream-major
+    sublayer, :meth:`post` after it, both through ``ops/stream_mix.py``: one
+    pass over the streams a call and direction. The maps are stream-major
     (``[n, B, T]``, ``[n, n, B, T]``): a token's 4 x 4 matrix is sixteen
     planes, not a tile of four sublanes."""
 
@@ -155,41 +149,21 @@ class StreamMix(nn.Module):
         self.b = self.param("b", nn.initializers.zeros, (2 * n + n * n,), f32)
 
     def pre(self, streams):
-        """``streams [n, B, T, C] -> (h [B, T, C], (post, res))``."""
+        """``streams [n, B, T, C] -> (h [B, T, C], maps)``; ``maps`` is what
+        :meth:`post` takes: ``post``, ``res`` and the streams as the op
+        carries them to its second half."""
         c = self.cfg
-        n, f32 = c.hc_mult, jnp.float32
-        _, B, T, C = streams.shape
-        with jax.named_scope(_trace.SCOPE_HC_MIX):
-            xf = streams.astype(f32)
-            inv_rms = jax.lax.rsqrt(jnp.mean(xf * xf, axis=(0, 3)) + c.eps)
-            # x~ @ phi with the token's scalar taken out of the product,
-            # a stream's rows of phi at a time: no relayout of the streams
-            phi = self.phi.reshape(n, C, -1)
-            maps = sum(jnp.einsum("btc,cm->mbt", xf[i], phi[i],
-                                  precision=jax.lax.Precision.HIGHEST)
-                       for i in range(n)) * inv_rms
-            alpha = jnp.repeat(self.alpha, np.array([n, n, n * n]),
-                               total_repeat_length=2 * n + n * n)
-            maps = alpha[:, None, None] * maps + self.b[:, None, None]
-            pre = jax.nn.sigmoid(maps[:n])
-            post = 2.0 * jax.nn.sigmoid(maps[n:2 * n])
-            res = sinkhorn(
-                jnp.exp(jnp.clip(maps[2 * n:], *c.hc_clamp)).reshape(
-                    n, n, B, T), c.hc_sinkhorn_iters, c.hc_eps)
-            h = sum(pre[i][..., None] * xf[i]
-                    for i in range(n)).astype(streams.dtype)
-        return h, (post, res)
+        h, *maps = stream_mix.pre(
+            streams, self.phi, self.alpha, self.b, stream_mix.Spec(
+                c.eps, c.hc_eps, tuple(c.hc_clamp), c.hc_sinkhorn_iters))
+        return h, tuple(maps)
 
     def post(self, streams, y, maps):
-        """``X'[i] = sum_j res[i, j] X[j] + post[i] y``."""
-        post, res = maps
-        with jax.named_scope(_trace.SCOPE_HC_MIX):
-            xf, yf = streams.astype(jnp.float32), y.astype(jnp.float32)
-            n = streams.shape[0]
-            return jnp.stack([
-                (sum(res[i, j][..., None] * xf[j] for j in range(n))
-                 + post[i][..., None] * yf).astype(streams.dtype)
-                for i in range(n)])
+        """``X'[i] = sum_j res[i, j] X[j] + post[i] y``. The streams are read
+        through ``maps``, where :meth:`pre` left them: their cotangent then
+        reaches its backward as an argument and is added to in place."""
+        post, res, streams = maps
+        return stream_mix.post(streams, y, post, res)
 
 
 class DecoderLayer(nn.Module):

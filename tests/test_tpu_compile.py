@@ -204,6 +204,51 @@ def test_dropless_expert_layer_compiles(topo, compile_kernel, tokens, top_k,
     assert compiled.memory_analysis().temp_size_in_bytes <= parent_temp_gb * 1e9
 
 
+def test_stream_mix_kernels_compile(topo, compile_kernel):
+    """One stream mix at the Xing4.0 cell's shape (four bfloat16 streams of
+    8192 tokens x 3584), forward and backward: the four kernels pass the
+    chip's compiler at the tile and under the scoped VMEM limit that
+    ``plan`` counts for them (the transposes of the maps' planes, the
+    sublane slices of four, the contractions of 128 + 32 and of 128 tokens,
+    the cotangent accumulated in place), no product at ``HIGHEST`` is left
+    in the program and ``phi``'s gradient is float32."""
+    from horovod_tpu import trace as hvd_trace
+    from horovod_tpu.ops import stream_mix as sm
+
+    n, B, T, C = 4, 1, 8192, 3584
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    arr = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    spec = sm.Spec(1e-6, 1e-6, (-30.0, 30.0), 20)
+    tile = sm.plan(n, T, C, jnp.bfloat16)
+    assert tile and sm._tile_bytes(n, tile, C) <= sm._VMEM_CEILING
+
+    def loss(streams, y, phi, alpha, b):
+        h, post, res, carried = sm.pre(streams, phi, alpha, b, spec)
+        out = sm.post(carried, y + h, post, res)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    hvd_trace.reset_build_ledger()
+    compiled = _compile(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))),
+        arr((n, B, T, C), jnp.bfloat16), arr((B, T, C), jnp.bfloat16),
+        arr((n * C, 24)), arr((3,)), arr((24,)))
+    assert hvd_trace.build_ledger()["fallbacks"] == []
+    assert hvd_trace.plan_args()["hc_mix_tile"] == tile
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines()
+             if "custom-call(" in l and "tpu_custom_call" in l]
+    for name in ("hc_mix_pre", "hc_mix_post", "hc_mix_post_bwd",
+                 "hc_mix_pre_bwd"):
+        assert sum(f"/{name}/" in l for l in calls) == 1, name
+    assert "f32[14336,24]" in text.replace(" ", "")       # d_phi
+    assert "algorithm=dot_bf16_bf16_f32_x6" not in text
+    assert "operand_precision={highest" not in text
+    # the streams' float32 copies are gone from the mix (the loss has its own)
+    assert not [l for l in text.splitlines() if "hc_mix" in l
+                and "f32[4,1,8192,3584]" in l.replace(" ", "")]
+
+
 def test_ring_block_compiles(topo):
     """One ring step of T=1024 over four ranks: [B*H, T/4, D] blocks and a
     traced offset, forward and backward."""
@@ -327,6 +372,12 @@ def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
     assert sum("moe_combine" in l for l in text.splitlines()
                if "custom-call(" in l) >= 3 * (layers - 1)
     assert hvd_trace.build_ledger()["fallbacks"] == []
+    # ten stream mixes: both halves forward, `pre` again under recomputation
+    # and both transposed, by the kernels
+    mixes = [l for l in text.splitlines()
+             if "custom-call(" in l and "/hc_mix/" in l]
+    assert len(mixes) >= 5 * 2 * layers
+    assert hvd_trace.plan_args()["hc_mix_tile"] == 128
     m = compiled.memory_analysis()
     held = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
