@@ -1,5 +1,8 @@
 """Model zoo: the reference's headline benchmark families re-implemented
-as idiomatic flax modules (bfloat16 compute, fp32 state, NHWC)."""
+as idiomatic flax modules (bfloat16 compute, fp32 state, NHWC), and five
+language models trained through ``hvd.make_train_step`` (``docs/models.md``):
+``transformer.TransformerLM``, ``qwen3_next.Qwen3NextLM``,
+``lfm2_moe.Lfm2MoeLM``, ``xing4.Xing4LM`` and ``keye_vl.KeyeVLLM``."""
 
 from __future__ import annotations
 

@@ -192,7 +192,8 @@ class GatedDeltaNet(nn.Module):
 class SparseMoe(nn.Module):
     """Softmax top-k routing over ``n_experts``, this device's
     ``experts_held`` of them computed without dropping a token, plus the
-    shared expert behind its sigmoid gate."""
+    shared expert behind its sigmoid gate (none where ``shared_dim`` is 0:
+    ``models/keye_vl.py``)."""
 
     n_experts: int
     experts_held: int
@@ -227,6 +228,8 @@ class SparseMoe(nn.Module):
             top_k=self.top_k, first_expert=self.first_expert,
             norm_topk=self.norm_topk, dtype=self.dtype,
         ).reshape(B, T, C)
+        if not self.shared_dim:   # a model without a shared expert
+            return y.astype(self.dtype)
         with jax.named_scope(_trace.SCOPE_MOE_SHARED):
             dense = lambda n, name: _dense(n, name, self.dtype, self.init_std)
             h = (jax.nn.silu(dense(self.shared_dim, "shared_gate_proj")(x))

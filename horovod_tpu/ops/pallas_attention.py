@@ -164,7 +164,7 @@ def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref,
                 o_ref, m_out_ref, l_out_ref,
                 acc_ref, m_ref, l_ref, *,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
-                rows: int, normalize: bool):
+                rows: int, normalize: bool, sel=None):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     d = v_ref.shape[-1]     # the accumulator's and the output's width
@@ -201,7 +201,11 @@ def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref,
 
         lax.fori_loop(0, rows, one, None)
 
-    if causal:
+    if sel is not None:
+        # A selection names its pairs one by one (the causal rule is part of
+        # it): a block pair with none is neither fetched nor computed.
+        _on_selected_pairs(update, sel, qi, ki, q_axis=0)
+    elif causal:
         # Global positions: q at q_pos, k at k_pos + delta, where delta is
         # the (dynamic) offset of the K block's sequence origin relative to
         # Q's — 0 for self-attention, src*T - rank*T inside ring attention.
@@ -242,6 +246,14 @@ def _fwd_kernel(delta_ref, q_ref, k_ref, v_ref,
             return carry
 
         lax.fori_loop(0, rows, one, None)
+
+
+def _fwd_kernel_sel(fetch_ref, q_ref, k_ref, v_ref, sel_ref, *refs, heads,
+                    **static):
+    """The forward kernel under a selection: the table of block pairs comes
+    first (scalar prefetch), the selection's tile after v."""
+    _fwd_kernel(None, q_ref, k_ref, v_ref, *refs, causal=False,
+                sel=(fetch_ref, sel_ref, heads, static["rows"]), **static)
 
 
 def _lane_padded(d):
@@ -485,9 +497,29 @@ def _on_visible_pairs(update, causal, qi, ki, block_q, block_k, q_axis):
         update(jnp.where(rel >= first_k, 0.0, 2 * _NEG_INF))
 
 
+def _on_selected_pairs(update, sel, qi, ki, q_axis):
+    """Run ``update(bias)`` for this (q block, k block) pair if the selection
+    holds a pair of it. ``sel`` is ``(table, tile, heads, rows)``: the table
+    names, for every step of the grid's inner axis, the block that axis
+    fetches (:func:`_fetch_table`: the step's own where the pair holds a
+    selected pair, the last such before it otherwise, so that nothing is
+    fetched for a pair that is not computed); the int8 tile is 1 where the
+    query attends to the key, laid out as the score tile is (``q_axis`` the
+    axis that runs over the queries)."""
+    table, tile, heads, rows = sel
+    batch = lax.div(pl.program_id(0) * rows, heads)
+    outer, inner = (qi, ki) if q_axis == 0 else (ki, qi)
+    at = (batch * pl.num_programs(1) + outer) * pl.num_programs(2) + inner
+
+    @pl.when(table[at] == inner)
+    def _selected():
+        # 0 where selected, far under any running max or logsumexp where not
+        update((tile[0].astype(jnp.float32) - 1.0) * (-2 * _NEG_INF))
+
+
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, *outs,
                 sm_scale: float, causal: bool, block_q: int, block_k: int,
-                rows: int, one_pass: bool):
+                rows: int, one_pass: bool, sel=None):
     """dK and dV of one K/V block: the Q axis is the innermost grid axis
     and ``dk``/``dv`` stay in f32 scratch across it. Scores are held
     transposed, ``[block_k, block_q]``: the lane-dense ``lse`` and ``D``
@@ -546,7 +578,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, *outs,
 
         lax.fori_loop(0, rows, one, None)
 
-    _on_visible_pairs(update, causal, qi, ki, block_q, block_k, q_axis=1)
+    if sel is not None:
+        _on_selected_pairs(update, sel, qi, ki, q_axis=1)
+    else:
+        _on_visible_pairs(update, causal, qi, ki, block_q, block_k, q_axis=1)
 
     @pl.when(last_q)
     def _finalize():
@@ -561,7 +596,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, *outs,
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                dq_ref, dq_acc, lse_col, dd_col, *,
                sm_scale: float, causal: bool, block_q: int, block_k: int,
-               rows: int):
+               rows: int, sel=None):
     """dQ of one Q block: the K/V axis is the innermost grid axis, ``dq``
     stays in f32 scratch across it and is written once. The lane-dense
     ``lse`` and ``D`` rows are turned into lane-replicated columns once a
@@ -603,11 +638,31 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
         lax.fori_loop(0, rows, one, None)
 
-    _on_visible_pairs(update, causal, qi, ki, block_q, block_k, q_axis=0)
+    if sel is not None:
+        _on_selected_pairs(update, sel, qi, ki, q_axis=0)
+    else:
+        _on_visible_pairs(update, causal, qi, ki, block_q, block_k, q_axis=0)
 
     @pl.when(ki == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+
+def _dkv_kernel_sel(fetch_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
+                    sel_ref, *outs, heads, **static):
+    """The dK/dV kernel under a selection (its tile transposed, as the scores
+    are held)."""
+    _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, *outs,
+                causal=False, one_pass=False,
+                sel=(fetch_ref, sel_ref, heads, static["rows"]), **static)
+
+
+def _dq_kernel_sel(fetch_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
+                   sel_ref, *outs, heads, **static):
+    """The dQ kernel under a selection."""
+    _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, *outs,
+               causal=False, sel=(fetch_ref, sel_ref, heads, static["rows"]),
+               **static)
 
 
 def _bwd_step_vmem_bytes(rows, block_q, block_k, d, in_size, whole_t_q=0,
@@ -799,6 +854,190 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
+# --------------------------------------------------------------------------
+# Attention under a selection: each query names its keys.
+# --------------------------------------------------------------------------
+
+def _fetch_table(selection, block_r, block_c):
+    """int32 ``[B * n_r * n_c]``: for every block pair of ``selection``
+    (int8 ``[B, R, C]``, tiles of ``block_r x block_c``) the column block a
+    kernel's inner axis fetches at that step: the step's own where the tile
+    holds a selected pair (the pair is computed exactly then), else the last
+    such block before it, and before the row's first the first. A step that
+    computes nothing so names the block the pipeline already holds."""
+    B, R, C = selection.shape
+    n_r, n_c = R // block_r, C // block_c
+    held = selection.reshape(B, n_r, block_r, n_c, block_c).max(
+        axis=(2, 4)) != 0
+    own = jnp.where(held, jnp.arange(n_c, dtype=jnp.int32), -1)
+    last = lax.cummax(own, axis=2)
+    first = jnp.argmax(held, axis=2).astype(jnp.int32)[..., None]
+    return jnp.where(last >= 0, last, first).reshape(-1)
+
+
+def _call_sel(kernel, out_shape, grid, in_specs, out_specs, scratch,
+              interpret):
+    return pl.pallas_call(
+        kernel, out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )
+
+
+def _sel_specs(heads, rows, n_outer, n_inner, q_inner, block_q, block_k, d,
+               d_v, stats):
+    """The specs of q, k, v (and ``do``, ``lse``, ``D`` with ``stats``) and
+    of the selection's tile for a grid ``(bh / rows, outer, inner)`` whose
+    inner axis walks the Q blocks (``q_inner``: the dK/dV kernel, the tile
+    transposed) or the K/V blocks, fetching what the table names."""
+    batch = lambda g: lax.div(g * rows, heads)
+
+    def fetched(g, x, y, table):
+        return table[(batch(g) * n_outer + x) * n_inner + y]
+
+    if q_inner:
+        q_of, k_of = fetched, lambda g, x, y, table: x
+        tile = (1, block_k, block_q)
+    else:
+        q_of, k_of = lambda g, x, y, table: x, fetched
+        tile = (1, block_q, block_k)
+    spec = lambda rows_, w, of: pl.BlockSpec(
+        (rows, rows_, w), lambda g, x, y, t: (g, of(g, x, y, t), 0))
+    stat = pl.BlockSpec((rows, 1, 1, block_q),
+                        lambda g, x, y, t: (g, q_of(g, x, y, t), 0, 0))
+    sel = pl.BlockSpec(tile, lambda g, x, y, t: (
+        batch(g), x, fetched(g, x, y, t)))
+    qkv = [spec(block_q, d, q_of), spec(block_k, d, k_of),
+           spec(block_k, d_v, k_of)]
+    if not stats:
+        return qkv + [sel]
+    return qkv + [spec(block_q, d_v, q_of), stat, stat, sel]
+
+
+def _plan_sel(heads, t_q, t_k, block_q, block_k, step_bytes):
+    """:func:`_fit_plan` for a call under a selection: the rows of a grid
+    step are heads of ONE batch row (they share the selection's tile), and
+    the tile is counted: its int8 buffers, two, and the float32 bias made of
+    it."""
+    return _fit_plan(
+        heads, t_q, t_k, block_q, block_k,
+        lambda rows, bq, bk: step_bytes(rows, bq, bk) + (2 + 4) * bq * bk)
+
+
+def _flash_sel_call(q, k, v, selection, sm_scale, heads, block_q, block_k,
+                    interpret):
+    """The forward kernel under ``selection``; ``(o, lse)``."""
+    bh, t_q, d = q.shape
+    t_k, d_v = v.shape[1:]
+    size = q.dtype.itemsize
+    block_q, block_k, rows = _plan_sel(
+        heads, t_q, t_k, block_q, block_k,
+        lambda r, bq, bk: _step_vmem_bytes(r, bq, bk, d, size, size, d_v))
+    n_q, n_k = t_q // block_q, t_k // block_k
+    _trace.note_plan(
+        flash_block_q=block_q, flash_block_k=block_k,
+        flash_rows_per_step=rows, flash_selection=True,
+        flash_grid_steps=(bh // rows) * n_q * n_k,
+    )
+    vma = _vma(q, k, v, selection)
+    static = dict(sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+                  rows=rows, normalize=True)
+    stat = jax.ShapeDtypeStruct((bh, n_q, 1, block_q), jnp.float32, vma=vma)
+    o, m, l = _call_sel(
+        functools.partial(_fwd_kernel_sel, heads=heads, **static),
+        [jax.ShapeDtypeStruct((bh, t_q, d_v), q.dtype, vma=vma), stat, stat],
+        (bh // rows, n_q, n_k),
+        _sel_specs(heads, rows, n_q, n_k, False, block_q, block_k, d, d_v,
+                   stats=False),
+        [pl.BlockSpec((rows, block_q, d_v), lambda g, i, j, t: (g, i, 0)),
+         pl.BlockSpec((rows, 1, 1, block_q), lambda g, i, j, t: (g, i, 0, 0)),
+         pl.BlockSpec((rows, 1, 1, block_q), lambda g, i, j, t: (g, i, 0, 0))],
+        [pltpu.VMEM((rows, block_q, d_v), jnp.float32),
+         pltpu.VMEM((rows, block_q, _LANES), jnp.float32),
+         pltpu.VMEM((rows, block_q, _LANES), jnp.float32)],
+        interpret,
+    )(_fetch_table(selection, block_q, block_k), q, k, v, selection)
+    m, l = m.reshape(bh, t_q), l.reshape(bh, t_q)
+    return o, m + jnp.log(jnp.where(l == 0.0, 1.0, l))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_sel(q, k, v, selection, sm_scale, heads, block_q, block_k,
+               interpret):
+    return _flash_sel_call(q, k, v, selection, sm_scale, heads, block_q,
+                           block_k, interpret)
+
+
+def _flash_sel_vjp_fwd(q, k, v, selection, sm_scale, heads, block_q, block_k,
+                       interpret):
+    o, lse = _flash_sel_call(q, k, v, selection, sm_scale, heads, block_q,
+                             block_k, interpret)
+    return (o, lse), (q, k, v, o, lse, selection)
+
+
+@jax.named_scope(SCOPE_FLASH_BWD)
+def _flash_sel_vjp_bwd(sm_scale, heads, block_q, block_k, interpret, res,
+                       cts):
+    """The backward under a selection: the dK/dV and the dQ kernel (a row's
+    whole ``dq`` beside the tiles is for short sequences, where a selection
+    is the causal rule), the first on the selection transposed once in HBM,
+    as it holds its scores. The logsumexp is a statistic: its cotangent is
+    not used."""
+    q, k, v, o, lse, selection = res
+    do, _ = cts
+    bh, t_q, d = q.shape
+    t_k, d_v = v.shape[1:]
+    block_q, block_k, rows = _plan_sel(
+        heads, t_q, t_k, block_q, block_k,
+        lambda r, bq, bk: _bwd_step_vmem_bytes(
+            r, bq, bk, d, q.dtype.itemsize, 0, d_v))
+    n_q, n_k = t_q // block_q, t_k // block_k
+    _trace.note_plan(
+        flash_bwd_block_q=block_q, flash_bwd_block_k=block_k,
+        flash_bwd_rows_per_step=rows, flash_bwd_one_pass=False,
+        flash_bwd_grid_steps=2 * (bh // rows) * n_q * n_k,
+    )
+    dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    stat_shape = (bh, n_q, 1, block_q)
+    args = (q, k, v, do, lse.reshape(stat_shape), dd.reshape(stat_shape))
+    vma = _vma(*args, selection)
+    static = dict(sm_scale=sm_scale, block_q=block_q, block_k=block_k,
+                  rows=rows)
+    transposed = jnp.swapaxes(selection, 1, 2)
+    dk, dv = _call_sel(
+        functools.partial(_dkv_kernel_sel, heads=heads, **static),
+        [jax.ShapeDtypeStruct((bh, t_k, d), k.dtype, vma=vma),
+         jax.ShapeDtypeStruct((bh, t_k, d_v), v.dtype, vma=vma)],
+        (bh // rows, n_k, n_q),
+        _sel_specs(heads, rows, n_k, n_q, True, block_q, block_k, d, d_v,
+                   stats=True),
+        [pl.BlockSpec((rows, block_k, w), lambda g, j, i, t: (g, j, 0))
+         for w in (d, d_v)],
+        [pltpu.VMEM((rows, block_k, w), jnp.float32) for w in (d, d_v)],
+        interpret,
+    )(_fetch_table(transposed, block_k, block_q), *args, transposed)
+    dq = _call_sel(
+        functools.partial(_dq_kernel_sel, heads=heads, **static),
+        jax.ShapeDtypeStruct((bh, t_q, d), q.dtype, vma=vma),
+        (bh // rows, n_q, n_k),
+        _sel_specs(heads, rows, n_q, n_k, False, block_q, block_k, d, d_v,
+                   stats=True),
+        pl.BlockSpec((rows, block_q, d), lambda g, i, j, t: (g, i, 0)),
+        [pltpu.VMEM((rows, block_q, d), jnp.float32),
+         pltpu.VMEM((rows, block_q, _LANES), jnp.float32),
+         pltpu.VMEM((rows, block_q, _LANES), jnp.float32)],
+        interpret,
+    )(_fetch_table(selection, block_q, block_k), *args, selection)
+    return dq, dk, dv, None
+
+
+_flash_sel.defvjp(_flash_sel_vjp_fwd, _flash_sel_vjp_bwd)
+
+
 def _check_widths(q, k):
     if q.shape[-1] != k.shape[-1]:
         raise ValueError(
@@ -816,12 +1055,23 @@ def flash_attention(
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
-) -> jax.Array:
+    selection: Optional[jax.Array] = None,
+):
     """Fused attention over ``[..., T, D]`` (leading dims fold into one
     batch x heads grid axis). Differentiable; the backward is kernels too,
     which recompute the probabilities per block pair. ``v`` may have
     another width than ``q`` and ``k`` (which share one); the output has
     ``v``'s.
+
+    ``selection`` (int8 ``[B, T_q, T_k]``, for q, k, v of ``[B, H, T, D]``)
+    is 1 where a query attends to a key and holds the causal rule itself
+    (``causal`` is not read): each head's softmax runs over its query's
+    selected keys alone, forward and backward, and a block pair with no
+    selected pair is neither fetched nor computed. The result is then ``(out,
+    lse)``, ``lse`` (float32 ``[B, H, T_q]``) the logsumexp of each query's
+    selected scores, a statistic without a gradient. No gradient reaches the
+    selection. Without one the call is what it was: the same plans and
+    programs.
 
     ``interpret=None`` interprets on the CPU backend only, so the same
     code runs in tests on the virtual CPU mesh.
@@ -837,6 +1087,15 @@ def flash_attention(
     qf = q.reshape((-1, t_q, d))
     kf = k.reshape((-1, t_k, d))
     vf = v.reshape((-1, t_k, d_v))
+    if selection is not None:
+        if q.ndim != 4 or selection.shape != (lead[0], t_q, t_k):
+            raise ValueError(
+                f"a selection is [B, T_q, T_k] for q of [B, H, T_q, D]; got "
+                f"{selection.shape} for q of {q.shape}")
+        out, lse = _flash_sel(qf, kf, vf, selection.astype(jnp.int8), scale,
+                              lead[1], block_q, block_k, interpret)
+        return (out.reshape(*lead, t_q, d_v),
+                lax.stop_gradient(lse).reshape(*lead, t_q))
     out = _flash(qf, kf, vf, scale, causal, block_q, block_k, interpret)
     return out.reshape(*lead, t_q, d_v)
 

@@ -114,6 +114,11 @@ SCOPE_GQA_ATTN = "gqa_attn"
 # mixer outside the flash kernels, and the two stream mixes of a layer.
 SCOPE_LATENT_ATTN = "latent_attn"  # low-rank products, norms, rotary, k, W_o
 SCOPE_HC_MIX = "hc_mix"            # mixing matrices, read and write of streams
+# The layers of models/keye_vl.py (KEYE_SCOPES below: gqa_attn is its
+# attention outside the kernels, as LFM2's): the indexer in front of
+# attention (ops/sparse_index.py) and the objective that trains it.
+SCOPE_SPARSE_INDEX = "sparse_index"  # projections, scores, k-th value, mask
+SCOPE_SPARSE_INDEX_LOSS = "sparse_index_loss"  # head-summed p, KL, backward
 MODEL_SCOPES = (
     SCOPE_GDN_CONV,
     SCOPE_GDN_SCAN,
@@ -134,6 +139,13 @@ XING4_SCOPES = (
     SCOPE_MOE_ROUTE,
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_SHARED,
+)
+KEYE_SCOPES = (
+    SCOPE_GQA_ATTN,
+    SCOPE_SPARSE_INDEX,
+    SCOPE_SPARSE_INDEX_LOSS,
+    SCOPE_MOE_ROUTE,
+    SCOPE_MOE_EXPERTS,
 )
 STEP_SCOPES = (
     SCOPE_LOSS_GRAD,

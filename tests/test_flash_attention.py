@@ -421,3 +421,210 @@ def test_ring_attention_lowers_for_tpu_target():
     text = lowered.as_text()
     assert "tpu_custom_call" in text          # the Mosaic flash block
     assert "collective_permute" in text        # the K/V rotation
+
+
+# --------------------------------------------------------------------------
+# Attention under a selection: each query names its keys.
+# --------------------------------------------------------------------------
+
+def _selection(B, T, seed=0, share=0.3):
+    """A random selection under the causal rule, every query keeping itself,
+    with whole block pairs empty (the table of block pairs has to skip them)
+    and one batch row different from the other."""
+    rng = np.random.RandomState(seed)
+    causal = np.tril(np.ones((T, T), bool))
+    sel = (rng.rand(B, T, T) < share) & causal | np.eye(T, dtype=bool)
+    sel[0, T // 2:, T // 4:T // 2] = False     # an empty band below the diagonal
+    sel[-1, 3 * T // 4:, :T // 4] = False
+    return jnp.asarray(sel)
+
+
+def _dense_selected(q, k, v, sel, scale):
+    """``(out, lse, head-summed probabilities)`` of q, k, v ``[B, H, T, D]``
+    under ``sel`` ``[B, T, T]``, densely."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
+    s = jnp.where(sel[:, None], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return (jnp.einsum("bhqk,bhkd->bhqd", p, v),
+            jax.nn.logsumexp(s, axis=-1), jnp.sum(p, axis=1))
+
+
+@pytest.mark.parametrize("dtype,blocks,tol", [
+    (jnp.float32, (32, 32), 2e-5), (jnp.float32, (None, None), 2e-5),
+    (jnp.float32, (16, 64), 2e-5), (jnp.bfloat16, (32, 32), 3e-2),
+])
+def test_selection_matches_dense_masked(dtype, blocks, tol):
+    """Forward, logsumexp, dq, dk, dv and the head-summed probabilities (what
+    the indexer's objective is trained toward: from the returned logsumexp,
+    ``sum_h exp(q_h . k_h * scale - lse_h)`` on the selected keys) against
+    the dense masked form; float32 gaps are rounding (2e-5 of unit-size
+    values), bfloat16 gaps the operands' 2^-9."""
+    B, H, T, D = 2, 4, 128, 16
+    rng = np.random.RandomState(1)
+    q, k, v, ct = (jnp.asarray(rng.randn(B, H, T, D) * 0.5, dtype)
+                   for _ in range(4))
+    sel = _selection(B, T)
+    bq, bk = blocks
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, sm_scale=0.25, selection=sel.astype(jnp.int8), block_q=bq,
+        block_k=bk)
+    f32 = lambda a: a.astype(jnp.float32)
+    want, want_lse, want_p = _dense_selected(f32(q), f32(k), f32(v), sel, 0.25)
+    out, lse = flash(q, k, v)
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    assert lse.shape == (B, H, T)
+    np.testing.assert_allclose(f32(out), want, atol=tol)
+    np.testing.assert_allclose(lse, want_lse, atol=tol)
+    s = jnp.einsum("bhqd,bhkd->bhqk", f32(q), f32(k)) * 0.25
+    summed = jnp.sum(jnp.where(sel[:, None], jnp.exp(s - lse[..., None]), 0.0),
+                     axis=1)
+    np.testing.assert_allclose(summed, want_p, atol=H * tol)
+    np.testing.assert_allclose(summed.sum(-1), H, rtol=10 * tol)
+
+    got = jax.grad(lambda *a: jnp.sum(f32(flash(*a)[0]) * f32(ct)),
+                   argnums=(0, 1, 2))(q, k, v)
+    ref = jax.grad(lambda *a: jnp.sum(
+        _dense_selected(*a, sel, 0.25)[0] * f32(ct)), argnums=(0, 1, 2))(
+        f32(q), f32(k), f32(v))
+    for a, b in zip(got, ref):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(f32(a), b, atol=4 * tol)
+
+
+def test_selection_of_every_causal_key_is_causal_attention():
+    """A selection that keeps every causal key is the causal kernel's
+    function (another program: the mask is read, not made)."""
+    q, k, v = (x.reshape(1, 4, 32, 16) for x in _qkv_bhtd())
+    sel = jnp.tril(jnp.ones((1, 32, 32), jnp.int8))
+    out, _ = flash_attention(q, k, v, selection=sel)
+    np.testing.assert_allclose(out, flash_attention(q, k, v, causal=True),
+                               atol=2e-6)
+    with pytest.raises(ValueError, match="selection is"):
+        flash_attention(q[0], k[0], v[0], selection=sel)
+
+
+def test_tied_scores_choose_the_lower_position():
+    """The selection the kernels are fed: of equal scores the lower position
+    is kept, as ``jax.lax.top_k`` keeps it, and attention then runs over
+    exactly those keys. All the indexer's scores equal (its queries zero):
+    every query keeps its first ``top_k`` keys."""
+    from horovod_tpu.ops.sparse_index import select_top_k
+
+    B, T, J, D, K = 1, 64, 2, 8, 4
+    rng = np.random.RandomState(2)
+    k_i = jnp.asarray(rng.randn(B, T, D), jnp.float32)
+    w = jnp.ones((B, T, J), jnp.float32)
+    sel, lse_i = select_top_k(jnp.zeros((B, T, J, D)), k_i, w, top_k=K,
+                              kernel=False)
+    first = np.tril(np.ones((T, T), bool)) & (np.arange(T)[None, :] < K)
+    np.testing.assert_array_equal(np.asarray(sel[0]) != 0, first)
+    np.testing.assert_allclose(lse_i[0], np.log(np.minimum(np.arange(T) + 1,
+                                                           K)), atol=1e-6)
+    q, k, v = (jnp.asarray(rng.randn(B, 2, T, 16), jnp.float32)
+               for _ in range(3))
+    out, _ = flash_attention(q, k, v, selection=sel)
+    want, _, _ = _dense_selected(q, k, v, jnp.asarray(first)[None], 0.25)
+    np.testing.assert_allclose(out, want, atol=2e-6)
+
+
+# What ``jax.grad(flash_attention(q, k, v, causal=True))`` in bfloat16 planned
+# and which kernels it called at the commit before the selection came
+# (8e5d92d), at three cells' shapes ``(bh, t, d, d_v)``: the plan notes are
+# this file's arithmetic and the kernels' names this file's functions, so
+# neither moves with the toolchain. A PR that changes the causal kernels on
+# purpose records its own.
+def _parent_plan(grid, bwd_grid, visited, rows, one_pass):
+    return {"flash_block_q": 512, "flash_block_k": 512,
+            "flash_rows_per_step": rows, "flash_grid_steps": grid,
+            "flash_pairs_visited": visited, "flash_bwd_block_q": 512,
+            "flash_bwd_block_k": 512, "flash_bwd_rows_per_step": 1,
+            "flash_bwd_one_pass": one_pass, "flash_bwd_grid_steps": bwd_grid,
+            "flash_bwd_pairs_visited": visited}
+
+
+_TWO_KERNELS = {"_fwd_kernel": 1, "_dkv_kernel": 1, "_dq_kernel": 1}
+_PARENT_PROGRAMS = {
+    (8, 1024, 64, 64): (_parent_plan(8, 32, 0.75, 4, True),
+                        {"_fwd_kernel": 1, "_dkv_kernel": 1}),
+    (16, 8192, 256, 256): (_parent_plan(2048, 8192, 0.5312, 2, False),
+                           _TWO_KERNELS),
+    (32, 8192, 192, 128): (_parent_plan(4096, 16384, 0.5312, 2, False),
+                           _TWO_KERNELS),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_PARENT_PROGRAMS))
+def test_selection_none_keeps_the_parents_programs(shape):
+    """Without a selection the call is what it was before the selection came:
+    the parent's plan, note for note, the parent's kernels and no other, no
+    int8 operand anywhere, and the same program, kernels included, whether
+    the keyword is left out or given as None (one process, one toolchain)."""
+    import re
+    from collections import Counter
+    from functools import partial
+
+    from horovod_tpu import trace
+
+    bh, t, d, d_v = shape
+    q = jax.ShapeDtypeStruct((bh, t, d), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((bh, t, d_v), jnp.bfloat16)
+
+    def lowered(**keyword):
+        attn = partial(flash_attention, causal=True, interpret=False,
+                       **keyword)
+        grad = jax.jit(jax.grad(
+            lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)))
+        trace.reset_build_ledger()
+        text = grad.trace(q, q, v).lower(
+            lowering_platforms=("tpu",)).as_text()
+        notes = {k: v for k, v in trace.plan_args().items()
+                 if k.startswith("flash")}
+        return text, notes
+
+    text, notes = lowered()
+    plan, kernels = _PARENT_PROGRAMS[shape]
+    assert notes == plan
+    assert Counter(re.findall(r'kernel_name = "(\w+)"', text)) == kernels
+    assert "i8" not in text.split("backend_config")[0]
+    assert lowered(selection=None) == (text, notes)
+
+
+def test_selection_kernels_lower_for_tpu_target():
+    """The three kernels under a selection at the new cell's shape (32 heads
+    of 128 over 16384 positions, an int8 selection): they serialize for
+    Mosaic with the table of block pairs as scalar prefetch, two heads a grid
+    step (the selection's tile is counted), and the plan says so."""
+    from functools import partial
+
+    from horovod_tpu import trace
+
+    q = jax.ShapeDtypeStruct((1, 32, 16384, 128), jnp.bfloat16)
+    sel = jax.ShapeDtypeStruct((1, 16384, 16384), jnp.int8)
+    attn = partial(flash_attention, interpret=False)
+    trace.reset_build_ledger()
+    grad = jax.jit(jax.grad(
+        lambda q, k, v, sel: attn(q, k, v, selection=sel)[0].astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)))
+    text = grad.trace(q, q, q, sel).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 3
+    notes = trace.plan_args()
+    assert notes["flash_selection"] is True
+    assert (notes["flash_block_q"], notes["flash_block_k"],
+            notes["flash_rows_per_step"]) == (512, 512, 2)
+    assert (notes["flash_bwd_block_q"], notes["flash_bwd_block_k"],
+            notes["flash_bwd_one_pass"]) == (512, 512, False)
+
+
+def test_fetch_table_names_the_block_the_pipeline_holds():
+    """A step of the inner axis names its own block where the pair holds a
+    selected pair, the last such before it where not, and before the row's
+    first the first: nothing is fetched for a pair that is not computed."""
+    held = np.array([[0, 1, 0, 0, 1, 0],
+                     [1, 0, 0, 0, 0, 0],
+                     [0, 0, 0, 0, 0, 1]], bool)
+    sel = jnp.asarray(np.kron(held, np.eye(4, dtype=np.int8)))[None]
+    np.testing.assert_array_equal(
+        pa._fetch_table(sel, 4, 4).reshape(3, 6),
+        [[1, 1, 1, 1, 4, 4], [0, 0, 0, 0, 0, 0], [5, 5, 5, 5, 5, 5]])

@@ -382,3 +382,54 @@ def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
     held = (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert 12.1e9 < held <= 16.0e9, held
+
+
+def test_sparse_selection_kernels_compile(topo, compile_kernel):
+    """The Keye-VL cell's five kernels at its shape (one 16384-token
+    sequence, 32 query / 4 key-value heads of 128, an indexer of 16 heads of
+    64 over one key head, top 2048) pass the chip's compiler: the selection
+    (int8 mask stores, ordered int32 keys in 8 MB of VMEM scratch, dynamic
+    loops over the causal chunks, a scoped limit over Mosaic's default), the
+    flash kernels under the selection (an int8 tile and a scalar-prefetched
+    table, forward and both backward), and the objective with its gradient
+    (all 32 heads inside a grid step, the keys' whole gradient resident)."""
+    from horovod_tpu import trace as hvd_trace
+    from horovod_tpu.ops import sparse_index as si
+
+    B, T, H, KV, D, J, Di, K = 1, 16384, 32, 4, 128, 16, 64, 2048
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    bf16 = jnp.bfloat16
+    arr = lambda shape, dtype=bf16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+
+    def loss(x, kv, q_i, k_i, w):
+        q, k, v = x, kv, kv
+        selection, lse_i = si.select_top_k(q_i, k_i, w, top_k=K)
+        heads_first = lambda a: a.transpose(0, 2, 1, 3)
+        kr, vr = (heads_first(jnp.repeat(a, H // KV, axis=2)) for a in (k, v))
+        out, lse = pa.flash_attention(heads_first(q), kr, vr,
+                                      selection=selection)
+        kl = si.index_kl(jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
+                         lse, selection, q_i, k_i, w, lse_i,
+                         sm_scale=D ** -0.5)
+        return jnp.sum(out.astype(jnp.float32)) + kl
+
+    hvd_trace.reset_build_ledger()
+    compiled = _compile(
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))),
+        arr((B, T, H, D)), arr((B, T, KV, D)), arr((B, T, J, Di)),
+        arr((B, T, Di)), arr((B, T, J), jnp.float32))
+    assert hvd_trace.build_ledger()["fallbacks"] == []
+    notes = hvd_trace.plan_args()
+    assert notes["sparse_index_kernel"] and notes["sparse_index_loss_kernel"]
+    assert notes["flash_selection"] and notes["flash_rows_per_step"] == 2
+    text = compiled.as_text()
+    calls = [l for l in text.splitlines()
+             if "custom-call(" in l and "tpu_custom_call" in l]
+    for name, count in (("sparse_index_select", 1), ("sparse_index_kl", 1),
+                        ("flash_bwd", 2)):
+        assert sum(name in l for l in calls) == count, name
+    assert len(calls) == 5
+    # no float32 [T, T] stands in HBM; the selection is int8
+    flat = text.replace(" ", "")
+    assert "f32[1,16384,16384]" not in flat and "s8[1,16384,16384]" in flat
