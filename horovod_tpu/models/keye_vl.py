@@ -41,8 +41,9 @@ from flax import linen as nn
 
 from .. import trace as _trace
 from ..ops.pallas_attention import flash_attention, flashable
-from ..ops.sparse_index import KL_RESIDUALS, index_kl, select_top_k
+from ..ops.sparse_index import index_kl, select_top_k
 from .qwen3_next import RMSNorm, SparseMoe, _dense, _normal, expert_load, rotary
+from .recompute import remat_layer
 
 __all__ = ["KeyeVLConfig", "KeyeVLLM", "lm_loss", "expert_load"]
 
@@ -223,12 +224,10 @@ class KeyeVLLM(nn.Module):
         x = nn.Embed(c.vocab_size, c.d_model, dtype=c.dtype,
                      embedding_init=_normal(c.init_std),
                      name="embed_tokens")(tokens)
-        # a layer is recomputed in its backward, all but the objective's
-        # gradient, which its one walk leaves beside the value
-        layer = nn.remat(
-            DecoderLayer,
-            policy=jax.checkpoint_policies.save_only_these_names(KL_RESIDUALS),
-        ) if c.remat else DecoderLayer
+        # a layer is recomputed in its backward, all but what its kernels
+        # named: the objective's gradient, which its one walk leaves beside
+        # the value, and the attention's result and logsumexp
+        layer = remat_layer(DecoderLayer) if c.remat else DecoderLayer
         index_loss = jnp.zeros((), jnp.float32)
         for i in range(c.n_layers):
             x, kl = layer(cfg=c, name=f"layer_{i}")(x, positions)

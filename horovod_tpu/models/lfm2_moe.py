@@ -38,6 +38,7 @@ from ..ops.pallas_attention import flash_attention_bthd
 from ..parallel.ep import _load_and_tiles, dropless_moe, route_top_k
 from .qwen3_next import (RMSNorm, _dense, _normal, causal_depthwise_conv,
                          expert_load, rotary)
+from .recompute import remat_layer
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeLM", "expert_load"]
 
@@ -302,7 +303,7 @@ class Lfm2MoeLM(nn.Module):
                          embedding_init=_normal(c.init_std),
                          name="embed_tokens")
         x = embed(tokens)
-        layer = nn.remat(DecoderLayer) if c.remat else DecoderLayer
+        layer = remat_layer(DecoderLayer) if c.remat else DecoderLayer
         for i, kind in enumerate(c.layer_types):
             x = layer(cfg=c, kind=kind, dense=i < c.n_dense_layers,
                       name=f"layer_{i}")(x, positions)

@@ -37,6 +37,7 @@ from .. import trace as _trace
 from ..ops.gated_delta import DEFAULT_CHUNK, gated_delta_chunked
 from ..ops.pallas_attention import flash_attention_bthd
 from ..parallel.ep import _load_and_tiles, dropless_moe, route_top_k
+from .recompute import remat_layer
 
 _normal = nn.initializers.normal
 
@@ -325,7 +326,7 @@ class Qwen3NextLM(nn.Module):
         x = nn.Embed(c.vocab_size, c.d_model, dtype=c.dtype,
                      embedding_init=_normal(c.init_std),
                      name="embed_tokens")(tokens)
-        layer = nn.remat(DecoderLayer) if c.remat else DecoderLayer
+        layer = remat_layer(DecoderLayer) if c.remat else DecoderLayer
         for i in range(c.n_layers):
             x = layer(cfg=c, attention=c.is_attention(i),
                       name=f"layer_{i}")(x, positions)

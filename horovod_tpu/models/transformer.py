@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..ops.pallas_attention import flash_attention_bthd
+from .recompute import remat_layer
 
 
 class Attention(nn.Module):
@@ -112,9 +113,7 @@ class TransformerLM(nn.Module):
         pos_emb = nn.Embed(self.max_len, self.d_model,
                            dtype=self.dtype, name="pos_embeddings")(positions)
         x = tok_emb + pos_emb
-        block = Block
-        if self.remat:
-            block = nn.remat(Block)
+        block = remat_layer(Block) if self.remat else Block
         for i in range(self.n_layers):
             x = block(
                 d_model=self.d_model, n_heads=self.n_heads,

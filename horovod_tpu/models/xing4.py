@@ -47,6 +47,7 @@ from ..ops.pallas_attention import flash_attention_bthd
 from ..ops.stream_mix import sinkhorn
 from .lfm2_moe import DenseMlp, SparseMoe, _norm
 from .qwen3_next import _dense, _normal, expert_load, rotary
+from .recompute import remat_layer
 
 __all__ = ["Xing4Config", "Xing4LM", "expert_load", "sinkhorn",
            "softmax_scale", "yarn_inv_freq"]
@@ -275,7 +276,7 @@ class Xing4LM(nn.Module):
         # the embedding is copied into the streams; they are summed in
         # front of the final norm
         streams = jnp.broadcast_to(x, (c.hc_mult,) + x.shape)
-        layer = nn.remat(DecoderLayer) if c.remat else DecoderLayer
+        layer = remat_layer(DecoderLayer) if c.remat else DecoderLayer
         for i in range(c.n_layers):
             streams = layer(cfg=c, dense=i < c.n_dense_layers,
                             name=f"layer_{i}")(streams, positions)

@@ -67,11 +67,18 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import trace as _trace
 from ..trace import SCOPE_FLASH_BWD
+
+# What the forward kernel leaves for its backward, by name: a caller's
+# recomputation that keeps these names (``models/recompute.remat_layer``)
+# runs the forward kernel once a step, where an unnamed residual is computed
+# again. Outside a ``jax.checkpoint`` a name is the identity.
+FLASH_RESIDUALS = ("flash_o", "flash_lse")
 
 _NEG_INF = -1e30
 _LANES = 128  # TPU lane width: the running max and sum are kept lane-
@@ -455,6 +462,10 @@ def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     return o
 
 
+def _named_residuals(o, lse):
+    return tuple(map(checkpoint_name, (o, lse), FLASH_RESIDUALS))
+
+
 def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     o, m, l = _flash_call(
         q, k, v, 0, sm_scale=sm_scale, causal=causal, block_q=block_q,
@@ -462,6 +473,7 @@ def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         out_dtype=q.dtype,
     )
     lse = m + jnp.log(jnp.where(l == 0.0, 1.0, l))   # [bh, tq]
+    o, lse = _named_residuals(o, lse)
     return o, (q, k, v, o, lse)
 
 
@@ -974,8 +986,8 @@ def _flash_sel(q, k, v, selection, sm_scale, heads, block_q, block_k,
 
 def _flash_sel_vjp_fwd(q, k, v, selection, sm_scale, heads, block_q, block_k,
                        interpret):
-    o, lse = _flash_sel_call(q, k, v, selection, sm_scale, heads, block_q,
-                             block_k, interpret)
+    o, lse = _named_residuals(*_flash_sel_call(
+        q, k, v, selection, sm_scale, heads, block_q, block_k, interpret))
     return (o, lse), (q, k, v, o, lse, selection)
 
 

@@ -559,7 +559,7 @@ def _index_kl_fwd(q, k, lse, selection, q_i, k_i, w, lse_i, sm_scale, rows,
                        rows, kernel, with_grads=True)
     # as a kernel's backward leaves them: in the dtypes of what they are the
     # cotangents of; NAMED, so that a caller's recomputation can keep them
-    # (``jax.checkpoint_policies.save_only_these_names(KL_RESIDUALS)``: some
+    # (``models/recompute.remat_layer`` keeps every such name: some
     # 36 MB a layer at 16384 positions) and the walk runs once a step, where
     # an unnamed residual is recomputed and the first pass walks for the
     # value alone

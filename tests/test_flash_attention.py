@@ -628,3 +628,115 @@ def test_fetch_table_names_the_block_the_pipeline_holds():
     np.testing.assert_array_equal(
         pa._fetch_table(sel, 4, 4).reshape(3, 6),
         [[1, 1, 1, 1, 4, 4], [0, 0, 0, 0, 0, 0], [5, 5, 5, 5, 5, 5]])
+
+
+# --------------------------------------------------------------------------
+# The forward kernel's result and logsumexp are named residuals.
+# --------------------------------------------------------------------------
+
+_STACK_LAYERS = 3
+
+# Compile options under which two programs of the same arithmetic give the
+# same bits: each operation alone and in the dtype the program states. Left to
+# itself XLA keeps a bfloat16 intermediate wider where a fusion allows,
+# contracts float32 products and sums, and draws its fusions anew round a kept
+# array.
+AS_STATED = {"xla_allow_excess_precision": False,
+             "xla_disable_hlo_passes": "fusion"}
+
+
+def _stack(selection=None, wrap=lambda layer: layer):
+    """``loss(x, w)`` over a stack of attention layers on ``x [B, H, T, D]``,
+    each layer handed to ``wrap`` (a ``jax.checkpoint``, or nothing)."""
+    @wrap
+    def layer(x, w):
+        q, k, v = (x * w[i] for i in range(3))
+        if selection is None:
+            return x + flash_attention(q, k, v, causal=True)
+        out, lse = flash_attention(q, k, v, selection=selection)
+        return x + out + lse[..., None].astype(x.dtype)
+
+    def loss(x, w):
+        for i in range(_STACK_LAYERS):
+            x = layer(x, w[i])
+        return jnp.sum(x.astype(jnp.float32) ** 2)
+    return loss
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("selected", [False, True],
+                         ids=["causal", "selection"])
+def test_a_recomputation_that_keeps_the_named_residuals(monkeypatch, selected,
+                                                        dtype):
+    """Under ``jax.checkpoint`` with the models' policy (``models/recompute
+    .KEEPS``) a stack of layers lowered for the chip holds ONE forward kernel
+    a layer, under plain ``jax.checkpoint`` two (first pass and
+    recomputation), and the backward kernels once either way; the two
+    programs' gradients are the same bits (interpreted, ``AS_STATED``)."""
+    import re
+    from collections import Counter
+    from functools import partial
+
+    from horovod_tpu.models.recompute import KEEPS
+
+    B, H, T, D = 1, 2, 32, 16
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(B, H, T, D), dtype)
+    w = jnp.asarray(1 + 0.1 * rng.randn(_STACK_LAYERS, 3, D), dtype)
+    selection = _selection(B, T) if selected else None
+    keeps = jax.checkpoint_policies.save_only_these_names(*KEEPS)
+    # (made anew a use: a checkpoint holds the kernels as it first traced them)
+    stack = lambda policy: _stack(
+        selection, partial(jax.checkpoint, policy=policy))
+    fwd, bwd = (("_fwd_kernel_sel", ("_dkv_kernel_sel", "_dq_kernel_sel"))
+                if selected else ("_fwd_kernel", ("_dkv_kernel",)))
+
+    def forward_calls(policy):
+        with monkeypatch.context() as m:
+            m.setattr(pa, "_resolve_interpret", lambda interpret: False)
+            text = jax.jit(jax.grad(stack(policy))).trace(x, w).lower(
+                lowering_platforms=("tpu",)).as_text()
+        calls = Counter(re.findall(r'kernel_name = "(\w+)"', text))
+        assert {k: n for k, n in calls.items() if k != fwd} == dict.fromkeys(
+            bwd, _STACK_LAYERS)
+        return calls[fwd]
+
+    assert forward_calls(keeps) == _STACK_LAYERS
+    assert forward_calls(None) == 2 * _STACK_LAYERS
+    # one program holds both gradients (what the two share is computed once)
+    kept, plain = jax.jit(lambda x, w: tuple(
+        jax.grad(stack(policy), argnums=(0, 1))(x, w)
+        for policy in (keeps, None))
+    ).lower(x, w).compile(compiler_options=AS_STATED)(x, w)
+    for a, b in zip(kept, plain):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert float(jnp.abs(a.astype(jnp.float32)).max()) > 0
+
+
+@pytest.mark.parametrize("selected", [False, True],
+                         ids=["causal", "selection"])
+def test_outside_a_checkpoint_a_name_lowers_to_nothing(monkeypatch, selected):
+    """A name is the identity where nothing recomputes: the gradient of the
+    same stack with no ``jax.checkpoint`` round its layers lowers for the chip
+    to the text it lowers to with the names taken out of the module."""
+    import re
+
+    args = (jax.ShapeDtypeStruct((1, 2, 256, 64), jnp.bfloat16),
+            jax.ShapeDtypeStruct((_STACK_LAYERS, 3, 64), jnp.bfloat16))
+    if selected:
+        args += (jax.ShapeDtypeStruct((1, 256, 256), jnp.int8),)
+    monkeypatch.setattr(pa, "_resolve_interpret", lambda interpret: False)
+    grad = jax.jit(jax.grad(lambda x, w, *selection: _stack(*selection)(x, w),
+                            argnums=(0, 1)))
+    texts = []
+    for name in (pa.checkpoint_name, lambda x, name: x):
+        monkeypatch.setattr(pa, "checkpoint_name", name)
+        # one call site, since a kernel's module holds where it was called
+        # from; a helper function's running number follows what the process
+        # lowered before
+        texts.append(re.sub(r"(@[A-Za-z_]+)_\d+", r"\1", grad.trace(
+            *args).lower(lowering_platforms=("tpu",)).as_text()))
+    kernels = 3 if selected else 2   # a short causal backward is one kernel
+    assert texts[0].count("tpu_custom_call") == kernels * _STACK_LAYERS
+    assert texts[0] == texts[1]

@@ -25,6 +25,7 @@ in float32, and a model without ``L_I`` leaves the indexer's leaves without
 a gradient (a gap of 0.015 to 1 against the float32 limit of 3e-5)."""
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -58,11 +59,11 @@ B, T = 2, 128
 INDEXER = "['indexer']"
 
 
-def _setup(dtype, seed=11, **over):
-    cfg = {**CFG, **over}
-    model = km.KeyeVLLM(dataclasses.replace(
-        family.model_config(cfg), dtype=dtype))
-    params = make_params(family.param_spec(cfg), seed)
+@functools.lru_cache(maxsize=None)
+def _inputs(seed):
+    """Weights and a batch of ``CFG`` from ``seed``: made once a process (the
+    tests share them; the one test whose step donates its input copies)."""
+    params = make_params(family.param_spec(CFG), seed)
     # norm weights start at one and the indexer's LayerNorm bias at zero: move
     # every vector off its initial value so that a leaf the program ignores
     # shows
@@ -72,8 +73,8 @@ def _setup(dtype, seed=11, **over):
               for (_, x), k in zip(flat, keys)]
     params = jax.tree.unflatten(tree, leaves)
     rng = np.random.default_rng(seed)
-    tokens = jnp.asarray(rng.integers(0, cfg["vocab_size"], (B, T)), jnp.int32)
-    labels = jnp.asarray(rng.integers(0, cfg["vocab_size"], (B, T)), jnp.int32)
+    tokens = jnp.asarray(rng.integers(0, CFG["vocab_size"], (B, T)), jnp.int32)
+    labels = jnp.asarray(rng.integers(0, CFG["vocab_size"], (B, T)), jnp.int32)
     # an image of 5 x 7 patches in the middle of the text: three DIFFERENT rows
     t = np.arange(T)
     image = (t >= 40) & (t < 75)
@@ -84,7 +85,13 @@ def _setup(dtype, seed=11, **over):
                               np.where(t >= 75, t - 28, t))])
     positions = jnp.asarray(np.broadcast_to(rows[:, None], (3, B, T)),
                             jnp.int32)
-    return cfg, model, params, tokens, labels, positions
+    return params, tokens, labels, positions
+
+
+def _setup(dtype, seed=11):
+    model = km.KeyeVLLM(dataclasses.replace(
+        family.model_config(CFG), dtype=dtype))
+    return (CFG, model) + _inputs(seed)
 
 
 def _gaps(got, want):
@@ -117,16 +124,19 @@ def test_parameter_tree_is_the_benchmarks_spec():
 ])
 def test_program_equals_reference(dtype, logit_tol, grad_tol):
     cfg, model, params, tokens, labels, positions = _setup(dtype)
-    want, want_index = reference.logits(params, tokens, cfg,
-                                        positions=positions)
-    got, got_index = model.apply({"params": params}, tokens, positions)
+    # (every whole-model call of this file is one jitted program: run
+    # operation by operation the same tests took three times as long)
+    want, want_index = jax.jit(lambda p: reference.logits(
+        p, tokens, cfg, positions=positions))(params)
+    got, got_index = jax.jit(lambda p: model.apply(
+        {"params": p}, tokens, positions))(params)
     assert got.dtype == jnp.float32
     gap = lambda a: float(jnp.linalg.norm(a - want) / jnp.linalg.norm(want))
     assert gap(got) <= logit_tol
     # the selection binds: attention over every causal key is another model,
     # and the comparison says so (45% in float32)
-    dense, _ = reference.logits(params, tokens, cfg, positions=positions,
-                                selected=False)
+    dense, _ = jax.jit(lambda p: reference.logits(
+        p, tokens, cfg, positions=positions, selected=False))(params)
     assert gap(dense) > 2 * 0.15
 
     batch = (tokens, labels, positions)
@@ -155,7 +165,8 @@ def _value_and_grad(terms, params):
         lm, index = terms(p)
         return lm + index, (lm, index)
 
-    (_, pair), grads = jax.value_and_grad(total, has_aux=True)(params)
+    (_, pair), grads = jax.jit(
+        jax.value_and_grad(total, has_aux=True))(params)
     return pair, grads
 
 
@@ -166,22 +177,27 @@ def test_the_two_terms_feed_disjoint_leaves():
     float32 tolerance refuses by four orders."""
     cfg, model, params, tokens, labels, positions = _setup(jnp.float32)
     batch = (tokens, labels, positions)
-    for terms in (
-        lambda p: km.lm_loss(model, p, batch, terms=True),
+
+    def each_terms_gradient(terms):
+        """``(d L_LM / d params, d L_I / d params)``, one program."""
+        return jax.jit(lambda p: tuple(
+            jax.grad(lambda q, i=i: terms(q)[i])(p) for i in (0, 1)))(params)
+
+    program = each_terms_gradient(
+        lambda p: km.lm_loss(model, p, batch, terms=True))
+    plain = each_terms_gradient(
         lambda p: reference.loss_terms(p, (tokens, labels), cfg,
-                                       positions=positions),
-    ):
+                                       positions=positions))
+    for grads in (program, plain):
         for term, indexer_reads in ((0, False), (1, True)):
-            g = jax.grad(lambda p: terms(p)[term])(params)
-            for path, x in jax.tree_util.tree_leaves_with_path(g):
+            for path, x in jax.tree_util.tree_leaves_with_path(grads[term]):
                 reads = float(jnp.max(jnp.abs(x))) > 0
                 mine = INDEXER in jax.tree_util.keystr(path)
                 assert reads == (mine == indexer_reads), (
                     term, jax.tree_util.keystr(path))
-    g_ref = jax.grad(lambda p: sum(reference.loss_terms(
-        p, (tokens, labels), cfg, positions=positions)))(params)
-    without = jax.grad(
-        lambda p: km.lm_loss(model, p, batch, terms=True)[0])(params)
+    # (each leaf is fed by one term: the sum's gradient is the two trees' sum)
+    g_ref = jax.tree.map(jnp.add, *plain)
+    without = program[0]
     gaps = {path: gap for path, gap, _ in _gaps(without, g_ref)}
     # (a leaf's gap is taken against its norm or the median leaf's, whichever
     # is larger: a missing gradient reads its norm over that, 0.015 to 1 here)
@@ -250,8 +266,9 @@ def test_trains_through_make_train_step():
     tx = hvd.DistributedOptimizer(optax.adamw(3e-3))
     step = hvd.make_train_step(
         lambda p, batch: km.lm_loss(model, p, batch), tx, mesh)
+    start = jax.tree.map(np.asarray, params)
+    params = jax.tree.map(jnp.asarray, start)  # the step donates its input
     state = tx.init(params)
-    start = jax.tree.map(np.asarray, params)   # the step donates its input
     losses = []
     for _ in range(8):
         params, state, loss = step(params, state, (tokens, labels))
@@ -282,6 +299,8 @@ def test_scopes_and_plan_notes():
     assert notes["sparse_index_pairs_selected"] == B * 1928
     assert notes["sparse_index_pairs_causal"] == B * 8256
     assert notes["flash_selection"] is True
+    assert notes["layer_recompute_keeps"] == (
+        "flash_o", "flash_lse", "sparse_index_kl_grads")
     assert notes["moe_experts_total"] == 8 and notes["moe_experts_held"] == 4
     assert notes["sparse_index_kernel"] is True
     assert notes["sparse_index_loss_kernel"] is True
@@ -293,12 +312,14 @@ def test_scopes_and_plan_notes():
 
 
 def test_the_objective_walks_once_a_layer_and_step(monkeypatch):
-    """A layer is recomputed in its backward, all but the objective's
-    gradient: its kernel's one walk leaves the value and the gradient, the
-    layer's recomputation policy keeps the (named) gradient, and a step holds
-    ONE ``sparse_index_kl`` call a layer; the selection and the forward flash
-    kernel run twice (first pass and recomputation), the backward flash
-    kernels once. Counted in the step lowered for the chip."""
+    """A layer is recomputed in its backward, all but what its kernels named
+    (``models/recompute.KEEPS``): the objective's one walk leaves the value
+    and the gradient and the forward flash kernel its result and logsumexp,
+    the layer's recomputation keeps them, and a step holds ONE
+    ``sparse_index_kl`` and ONE ``_fwd_kernel_sel`` call a layer (two until
+    PR 44); the selection runs twice (first pass and recomputation: its mask
+    is not kept), the backward flash kernels once. Counted in the step lowered
+    for the chip."""
     import re
     from collections import Counter
 
@@ -313,7 +334,7 @@ def test_the_objective_walks_once_a_layer_and_step(monkeypatch):
     layers = cfg["num_hidden_layers"]
     assert calls == {
         "sparse_index_kl": layers, "sparse_index_select": 2 * layers,
-        "_fwd_kernel_sel": 2 * layers, "_dkv_kernel_sel": layers,
+        "_fwd_kernel_sel": layers, "_dkv_kernel_sel": layers,
         "_dq_kernel_sel": layers}
 
 

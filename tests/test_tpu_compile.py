@@ -336,8 +336,8 @@ def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
     """The Xing4.0 cell's whole step (``hvd.make_train_step`` over
     ``Xing4LM`` at the configuration's sizes: 759.3 M parameters, one
     8192-token sequence) for the described chip: the flash kernels at 192 /
-    128 are in it, one forward and two backward a layer and the forward
-    again under recomputation, and parameters, AdamW's moments, gradients and
+    128 are in it, one forward and two backward a layer (the layer's
+    recomputation keeps the forward's named result), and parameters, AdamW's moments, gradients and
     scratch come to no more than 16.0 GB by the compiler's own count."""
     import os
     import sys
