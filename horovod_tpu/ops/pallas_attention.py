@@ -38,11 +38,13 @@ masked pairs neither fetched nor computed), fed the saved logsumexp and
 blocks of one K/V block with ``dk`` and ``dv`` in VMEM and works on
 transposed ``[block_k, block_q]`` scores, so the statistics broadcast
 along sublanes. Where some rows' WHOLE ``dq`` fits in VMEM beside its
-tiles (T 1024 at d 64: 0.5 MB a row) that kernel accumulates ``dq`` in the
-same walk, 5 products a pair; where it does not (T 8192 at d 256: 8 MB) a
-dQ kernel walks the K/V blocks of one Q block with ``dq`` in VMEM, and the
-two recompute the scores and ``dp`` (7 products). Either way ``dq`` is
-written once. :func:`_plan_bwd` picks tiles, rows and form from the shapes.
+tiles (T 1024 at d 64: 0.5 MB a row in float32; T 8192 at d 256 and T 16384
+at d 128: 8 MB, under a scoped limit the call raises) that kernel
+accumulates ``dq`` in the same walk, 5 products a pair; where it does not
+(T 32768 at d 256: 32 MB) a dQ kernel walks the K/V blocks of one Q block
+with ``dq`` in VMEM, and the two recompute the scores and ``dp`` (7
+products). Either way ``dq`` is written once. :func:`_plan_bwd` picks
+tiles, rows and form from the shapes, under a selection too.
 The ring block's VJP recomputes its single [T, T/n] block densely (the
 same memory class as the forward block it differentiates).
 
@@ -86,7 +88,16 @@ _LANES = 128  # TPU lane width: the running max and sum are kept lane-
               # flash kernel keeps them.
 _PREF_BLOCK = 512           # block_q / block_k where the caller names none
 _PREF_ROWS = 8              # rows of bh one grid step takes, at most
-_VMEM_BUDGET = 12 * 2 ** 20  # under Mosaic's 16 MiB default scoped limit
+# What a grid step may count where its call asks the compiler for nothing:
+# under Mosaic's 16 MiB DEFAULT scoped limit, which is the compiler's and not
+# the chip's (a v5e core has 128 MiB of VMEM).
+_VMEM_BUDGET = 12 * 2 ** 20
+# What the one-pass backward's step may count with some rows' whole ``dq``
+# beside its tiles, and the scoped limit its call then asks for: a quarter
+# over the count for the compiler's own temporaries, half the core's VMEM
+# (``ops/sparse_index.py`` asks for as much, and for 56 MiB).
+_WHOLE_DQ_BUDGET = 48 * 2 ** 20
+_WHOLE_DQ_VMEM = 64 * 2 ** 20
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -663,9 +674,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 def _dkv_kernel_sel(fetch_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
                     sel_ref, *outs, heads, **static):
     """The dK/dV kernel under a selection (its tile transposed, as the scores
-    are held)."""
+    are held), in either form."""
     _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, *outs,
-                causal=False, one_pass=False,
+                causal=False,
                 sel=(fetch_ref, sel_ref, heads, static["rows"]), **static)
 
 
@@ -685,10 +696,12 @@ def _bwd_step_vmem_bytes(rows, block_q, block_k, d, in_size, whole_t_q=0,
     accumulators, and the [Bq, Bk] temporaries of one row of ``bh``
     (scores, probabilities, ``dp``, ``ds``, the mask's bias in f32 and the
     MXU operands cast from them). With ``whole_t_q`` the one-pass kernel,
-    which holds dK/dV's buffers, a ``dq`` of that many queries and ``ds``
-    transposed; without, the larger of the dK/dV and the dQ kernel (the
-    latter with its two statistic columns). q, k, ``dq`` and ``dk`` are
-    ``d`` wide; v, ``do`` and ``dv`` are ``d_v``."""
+    which holds dK/dV's buffers, a ``dq`` of that many queries (its f32 sum
+    and the two buffers of the output block: 8 MB a row at T 8192 x 128
+    lanes in bf16) and ``ds`` transposed; without, the larger of the dK/dV
+    and the dQ kernel (the latter with its two statistic columns). q, k,
+    ``dq`` and ``dk`` are ``d`` wide; v, ``do`` and ``dv`` are ``d_v``. A
+    selection's tile is counted by the plan (:func:`_sel_tile_bytes`)."""
     dl = _lane_padded(d)
     vl = dl if d_v is None else _lane_padded(d_v)
     # an output's two buffers and its f32 sum, a row of it
@@ -704,30 +717,55 @@ def _bwd_step_vmem_bytes(rows, block_q, block_k, d, in_size, whole_t_q=0,
     return inputs + max(dkv, dq) + 6 * block_q * block_k * 4
 
 
-def _plan_bwd(bh, t_q, t_k, d, in_size, block_q, block_k, d_v=None):
-    """``(block_q, block_k, rows, one_pass)`` of one backward call:
-    :func:`_plan`'s rule with the backward's own VMEM count gives the two
-    kernels' tiles; where some rows' whole ``dq`` fits beside them at those
-    tiles, the one-pass kernel runs instead (5 products a pair for 7)."""
-    bq, bk, rows = _fit_plan(
-        bh, t_q, t_k, block_q, block_k,
-        lambda rows, bq, bk: _bwd_step_vmem_bytes(
-            rows, bq, bk, d, in_size, 0, d_v),
-    )
-    for r in range(rows, 0, -1):
-        if bh % r == 0 and _bwd_step_vmem_bytes(
-                r, bq, bk, d, in_size, t_q, d_v) <= _VMEM_BUDGET:
-            return bq, bk, r, True
-    return bq, bk, rows, False
+def _sel_tile_bytes(block_q, block_k):
+    """What a selection's tile adds to a grid step: its int8 buffers, two,
+    and the float32 bias made of it."""
+    return (2 + 4) * block_q * block_k
+
+
+def _plan_bwd(bh, t_q, t_k, d, in_size, block_q, block_k, d_v=None,
+              sel_heads=None):
+    """``(block_q, block_k, rows, one_pass, step_bytes)`` of one backward
+    call: :func:`_plan`'s rule with the backward's own VMEM count gives the
+    two kernels' tiles and rows under ``_VMEM_BUDGET``; where some of those
+    rows' whole ``dq`` fits beside the tiles, the one-pass kernel runs
+    instead (5 products a pair for 7): under ``_VMEM_BUDGET`` as far as that
+    goes (short sequences: the call asks for no limit), else under
+    ``_WHOLE_DQ_BUDGET`` (T 8192 and 16384: :func:`_vmem_limit` raises the
+    call's scoped limit). ``step_bytes`` is the count of the step that runs.
+    Under a selection (``sel_heads``) the rows of a step are heads of ONE
+    batch row, as in :func:`_plan_sel`, and the selection's tile is counted."""
+    over = sel_heads or bh
+
+    def count(rows, bq, bk, whole_t_q=0):
+        tile = _sel_tile_bytes(bq, bk) if sel_heads else 0
+        return tile + _bwd_step_vmem_bytes(
+            rows, bq, bk, d, in_size, whole_t_q, d_v)
+
+    bq, bk, rows = _fit_plan(over, t_q, t_k, block_q, block_k, count)
+    for budget in (_VMEM_BUDGET, _WHOLE_DQ_BUDGET):
+        for r in range(rows, 0, -1):
+            held = count(r, bq, bk, t_q)
+            if over % r == 0 and held <= budget:
+                return bq, bk, r, True, held
+    return bq, bk, rows, False, count(rows, bq, bk)
+
+
+def _vmem_limit(step_bytes):
+    """The scoped VMEM limit a backward call asks for: none where its step
+    counts no more than ``_VMEM_BUDGET`` (the compiler's default holds it,
+    and the call lowers as it did before there was a second budget)."""
+    return _WHOLE_DQ_VMEM if step_bytes > _VMEM_BUDGET else None
 
 
 def _backward(bh, t_q, t_k, d, d_v, dtypes, sm_scale, causal, block_q,
-              block_k, rows, one_pass, interpret, vma):
+              block_k, rows, one_pass, step_bytes, interpret, vma):
     """The backward ``pallas_call``s at one plan (the one-pass kernel, or
     the dK/dV and the dQ kernel), as a function of ``(q, k, v, do, lse,
     D)`` returning ``(dq, dk, dv)``; ``lse`` and ``D`` are
     ``[bh, t_q / block_q, 1, block_q]`` f32; q and k are ``d`` wide, v and
-    ``do`` ``d_v``."""
+    ``do`` ``d_v``. ``step_bytes`` is the plan's count of a grid step: what
+    the first call's scoped VMEM limit follows."""
     n_q, n_k = t_q // block_q, t_k // block_k
     static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
                   block_k=block_k, rows=rows)
@@ -781,9 +819,12 @@ def _backward(bh, t_q, t_k, d, d_v, dtypes, sm_scale, causal, block_q,
         ] * one_pass,
         scratch_shapes=[dk_acc, dv_acc] + [
             pltpu.VMEM((rows, t_q, d), jnp.float32)] * one_pass,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=(
-            "parallel", "arbitrary" if one_pass else "parallel", "arbitrary",
-        )),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "arbitrary" if one_pass else "parallel",
+                "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(step_bytes),
+        ),
         interpret=interpret,
     )
     dq = None if one_pass else pl.pallas_call(
@@ -827,6 +868,21 @@ def _backward_jaxpr(mesh, *plan):
     ))
 
 
+def _note_bwd_plan(bh, t_q, t_k, block_q, block_k, rows, one_pass,
+                   step_bytes, **more):
+    """The backward's plan notes: tiles, rows, which form runs, the grid
+    steps of all its kernels and the VMEM its step counts."""
+    _trace.note_plan(
+        flash_bwd_block_q=block_q, flash_bwd_block_k=block_k,
+        flash_bwd_rows_per_step=rows,
+        flash_bwd_one_pass=one_pass,
+        flash_bwd_grid_steps=(1 if one_pass else 2) * (bh // rows)
+        * (t_q // block_q) * (t_k // block_k),
+        flash_bwd_vmem_mb=round(step_bytes / 2 ** 20, 1),
+        **more,
+    )
+
+
 @jax.named_scope(SCOPE_FLASH_BWD)
 def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     """Flash backward: the kernels of :func:`_backward` on the residuals
@@ -836,16 +892,12 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     q, k, v, o, lse = res
     bh, t_q, d = q.shape
     t_k, d_v = v.shape[1:]
-    block_q, block_k, rows, one_pass = _plan_bwd(
+    block_q, block_k, rows, one_pass, step_bytes = _plan_bwd(
         bh, t_q, t_k, d, q.dtype.itemsize, block_q, block_k, d_v)
     # Beside the forward's note: what one grid step of this program's
     # backward is, which form runs, and the steps of all its kernels.
-    _trace.note_plan(
-        flash_bwd_block_q=block_q, flash_bwd_block_k=block_k,
-        flash_bwd_rows_per_step=rows,
-        flash_bwd_one_pass=one_pass,
-        flash_bwd_grid_steps=(1 if one_pass else 2) * (bh // rows)
-        * (t_q // block_q) * (t_k // block_k),
+    _note_bwd_plan(
+        bh, t_q, t_k, block_q, block_k, rows, one_pass, step_bytes,
         flash_bwd_pairs_visited=round(
             _pairs_visited(t_q, t_k, block_q, block_k, causal), 4),
     )
@@ -855,7 +907,7 @@ def _flash_vjp_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     stat_shape = (bh, t_q // block_q, 1, block_q)
     args = (q, k, v, do, lse.reshape(stat_shape), dd.reshape(stat_shape))
     plan = (bh, t_q, t_k, d, d_v, (q.dtype, k.dtype, v.dtype), sm_scale,
-            causal, block_q, block_k, rows, one_pass, interpret)
+            causal, block_q, block_k, rows, one_pass, step_bytes, interpret)
     vma = _vma(*args)
     if vma:   # typed per mesh axis: traced where the axes are bound
         return _backward(*plan, vma=vma)(*args)
@@ -888,14 +940,15 @@ def _fetch_table(selection, block_r, block_c):
 
 
 def _call_sel(kernel, out_shape, grid, in_specs, out_specs, scratch,
-              interpret):
+              interpret, semantics=("parallel", "parallel", "arbitrary"),
+              vmem_limit=None):
     return pl.pallas_call(
         kernel, out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=semantics, vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )
 
@@ -933,11 +986,11 @@ def _sel_specs(heads, rows, n_outer, n_inner, q_inner, block_q, block_k, d,
 def _plan_sel(heads, t_q, t_k, block_q, block_k, step_bytes):
     """:func:`_fit_plan` for a call under a selection: the rows of a grid
     step are heads of ONE batch row (they share the selection's tile), and
-    the tile is counted: its int8 buffers, two, and the float32 bias made of
-    it."""
+    the tile is counted."""
     return _fit_plan(
         heads, t_q, t_k, block_q, block_k,
-        lambda rows, bq, bk: step_bytes(rows, bq, bk) + (2 + 4) * bq * bk)
+        lambda rows, bq, bk: step_bytes(rows, bq, bk)
+        + _sel_tile_bytes(bq, bk))
 
 
 def _flash_sel_call(q, k, v, selection, sm_scale, heads, block_q, block_k,
@@ -994,47 +1047,57 @@ def _flash_sel_vjp_fwd(q, k, v, selection, sm_scale, heads, block_q, block_k,
 @jax.named_scope(SCOPE_FLASH_BWD)
 def _flash_sel_vjp_bwd(sm_scale, heads, block_q, block_k, interpret, res,
                        cts):
-    """The backward under a selection: the dK/dV and the dQ kernel (a row's
-    whole ``dq`` beside the tiles is for short sequences, where a selection
-    is the causal rule), the first on the selection transposed once in HBM,
-    as it holds its scores. The logsumexp is a statistic: its cotangent is
-    not used."""
+    """The backward under a selection, in the form :func:`_plan_bwd` picks
+    with the selection's tile counted: where some heads' whole ``dq`` fits
+    beside the tiles, the one-pass kernel alone, on the selection transposed
+    once in HBM, as it holds its scores; where not, that kernel for dK/dV and
+    the dQ kernel on the selection as it came. The logsumexp is a statistic:
+    its cotangent is not used."""
     q, k, v, o, lse, selection = res
     do, _ = cts
     bh, t_q, d = q.shape
     t_k, d_v = v.shape[1:]
-    block_q, block_k, rows = _plan_sel(
-        heads, t_q, t_k, block_q, block_k,
-        lambda r, bq, bk: _bwd_step_vmem_bytes(
-            r, bq, bk, d, q.dtype.itemsize, 0, d_v))
+    block_q, block_k, rows, one_pass, step_bytes = _plan_bwd(
+        bh, t_q, t_k, d, q.dtype.itemsize, block_q, block_k, d_v,
+        sel_heads=heads)
     n_q, n_k = t_q // block_q, t_k // block_k
-    _trace.note_plan(
-        flash_bwd_block_q=block_q, flash_bwd_block_k=block_k,
-        flash_bwd_rows_per_step=rows, flash_bwd_one_pass=False,
-        flash_bwd_grid_steps=2 * (bh // rows) * n_q * n_k,
-    )
+    _note_bwd_plan(bh, t_q, t_k, block_q, block_k, rows, one_pass,
+                   step_bytes)
     dd = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     stat_shape = (bh, n_q, 1, block_q)
     args = (q, k, v, do, lse.reshape(stat_shape), dd.reshape(stat_shape))
     vma = _vma(*args, selection)
     static = dict(sm_scale=sm_scale, block_q=block_q, block_k=block_k,
                   rows=rows)
+    dq_shape = jax.ShapeDtypeStruct((bh, t_q, d), q.dtype, vma=vma)
     transposed = jnp.swapaxes(selection, 1, 2)
-    dk, dv = _call_sel(
-        functools.partial(_dkv_kernel_sel, heads=heads, **static),
+    # With one_pass a third output and accumulator, as in :func:`_backward`.
+    dk, dv, *whole_dq = _call_sel(
+        functools.partial(_dkv_kernel_sel, heads=heads, one_pass=one_pass,
+                          **static),
         [jax.ShapeDtypeStruct((bh, t_k, d), k.dtype, vma=vma),
-         jax.ShapeDtypeStruct((bh, t_k, d_v), v.dtype, vma=vma)],
+         jax.ShapeDtypeStruct((bh, t_k, d_v), v.dtype, vma=vma)]
+        + [dq_shape] * one_pass,
         (bh // rows, n_k, n_q),
         _sel_specs(heads, rows, n_k, n_q, True, block_q, block_k, d, d_v,
                    stats=True),
         [pl.BlockSpec((rows, block_k, w), lambda g, j, i, t: (g, j, 0))
-         for w in (d, d_v)],
-        [pltpu.VMEM((rows, block_k, w), jnp.float32) for w in (d, d_v)],
+         for w in (d, d_v)] + [
+            pl.BlockSpec((rows, t_q, d), lambda g, j, i, t: (g, 0, 0))
+        ] * one_pass,
+        [pltpu.VMEM((rows, block_k, w), jnp.float32) for w in (d, d_v)] + [
+            pltpu.VMEM((rows, t_q, d), jnp.float32)] * one_pass,
         interpret,
+        # the whole dq is resident across both inner axes: neither is parallel
+        semantics=("parallel", "arbitrary" if one_pass else "parallel",
+                   "arbitrary"),
+        vmem_limit=_vmem_limit(step_bytes),
     )(_fetch_table(transposed, block_k, block_q), *args, transposed)
+    if one_pass:
+        return whole_dq[0], dk, dv, None
     dq = _call_sel(
         functools.partial(_dq_kernel_sel, heads=heads, **static),
-        jax.ShapeDtypeStruct((bh, t_q, d), q.dtype, vma=vma),
+        dq_shape,
         (bh // rows, n_q, n_k),
         _sel_specs(heads, rows, n_q, n_k, False, block_q, block_k, d, d_v,
                    stats=True),

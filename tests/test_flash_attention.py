@@ -48,6 +48,18 @@ def test_forward_matches_dense(causal, blocks):
     )
 
 
+def _force_form(monkeypatch, one_pass):
+    """Make ``_plan_bwd`` give this form at the tiles, rows and count it
+    plans (interpreted, a count limits nothing)."""
+    plan_bwd = pa._plan_bwd
+
+    def forced(*a, **kw):
+        plan = plan_bwd(*a, **kw)
+        return plan[:3] + (one_pass,) + plan[4:]
+
+    monkeypatch.setattr(pa, "_plan_bwd", forced)
+
+
 # (bh, t_q, t_k, d, block_q, block_k, _PREF_BLOCK): what the two backward
 # kernels must get right beside the plain case.
 _GRAD_SHAPES = {
@@ -81,10 +93,8 @@ def test_grad_matches_dense(monkeypatch, one_pass, causal, dtype, shape):
     bh, t_q, t_k, d, bq, bk, pref = _GRAD_SHAPES[shape]
     if pref is not None:
         monkeypatch.setattr(pa, "_PREF_BLOCK", pref)
-    plan_bwd = pa._plan_bwd
-    assert plan_bwd(bh, t_q, t_k, d, 4, bq, bk)[3]
-    monkeypatch.setattr(
-        pa, "_plan_bwd", lambda *a: plan_bwd(*a)[:3] + (one_pass,))
+    assert pa._plan_bwd(bh, t_q, t_k, d, 4, bq, bk)[3]
+    _force_form(monkeypatch, one_pass)
     rng = np.random.RandomState(11)
     mk = lambda t: jnp.asarray(
         rng.randn(bh, t, d).astype(np.float32) * 0.5).astype(dtype)
@@ -130,9 +140,7 @@ def test_two_widths_match_dense(monkeypatch, one_pass, shape):
     bh, t, d, d_v, block, pref = _TWO_WIDTHS[shape]
     if pref is not None:
         monkeypatch.setattr(pa, "_PREF_BLOCK", pref)
-    plan_bwd = pa._plan_bwd
-    monkeypatch.setattr(
-        pa, "_plan_bwd", lambda *a: plan_bwd(*a)[:3] + (one_pass,))
+    _force_form(monkeypatch, one_pass)
     rng = np.random.RandomState(5)
     mk = lambda w: jnp.asarray(rng.randn(bh, t, w).astype(np.float32) * 0.5)
     q, k, v, w = mk(d), mk(d), mk(d_v), mk(d_v)
@@ -377,7 +385,8 @@ def test_kernel_lowers_for_tpu_target():
 
 @pytest.mark.parametrize("shape,kernels", [
     ((128, 1024, 64), 2),      # forward + the one-pass backward
-    ((16, 8192, 256), 3),      # forward + dK/dV + dQ
+    ((16, 8192, 256), 2),      # the same under a raised scoped limit
+    ((16, 32768, 256), 3),     # forward + dK/dV + dQ: a row's dq is 32 MB
 ])
 def test_backward_kernels_lower_for_tpu_target(shape, kernels):
     """The backward at the benchmark cells' own shapes, bf16: the forward
@@ -394,6 +403,9 @@ def test_backward_kernels_lower_for_tpu_target(shape, kernels):
     text = grad.trace(q, q, q).lower(lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") == kernels
     assert "stablehlo.while" not in text
+    # a limit is asked for exactly where the step counts over the default's
+    # budget: the one-pass kernel at T 8192
+    assert text.count("scoped_memory_configs") == (shape[1] == 8192)
 
 
 def test_ring_attention_lowers_for_tpu_target():
@@ -491,6 +503,79 @@ def test_selection_matches_dense_masked(dtype, blocks, tol):
         np.testing.assert_allclose(f32(a), b, atol=4 * tol)
 
 
+# (B, heads, key/value heads, T, D, block_q, block_k, _PREF_BLOCK): what the
+# backward under a selection must get right in both forms.
+_SEL_GRAD_SHAPES = {
+    # 4 x 4 block pairs; the four heads of a batch row share a grid step
+    # and the selection's tile, two of them a key/value head
+    "gqa-rows": (2, 4, 2, 64, 16, 16, 16, None),
+    "bq<bk": (2, 4, 2, 64, 16, 16, 32, None),
+    "bq>bk": (2, 2, 1, 64, 16, 32, 16, None),
+    # the cell's head width, the kernel's own tiles (16 standing for 512)
+    "d128": (1, 2, 2, 64, 128, None, None, 16),
+}
+
+
+def _sparse_selection(B, T, block):
+    """:func:`_selection` with, in the last batch row, a query block whose
+    only pairs lie in ONE K block (its own: every table entry of that row
+    names the same block) beside the empty bands (pairs the table skips)."""
+    sel = np.array(_selection(B, T, seed=3))
+    sel[-1, block:2 * block, :block] = False
+    assert not sel[0, T // 2:, T // 4:T // 2].any()
+    return jnp.asarray(sel)
+
+
+@pytest.mark.parametrize(
+    "shape,dtype",
+    [(shape, jnp.float32) for shape in _SEL_GRAD_SHAPES]
+    + [("gqa-rows", jnp.bfloat16)],
+    ids=lambda v: v if isinstance(v, str) else jnp.dtype(v).name)
+@pytest.mark.parametrize("one_pass", [True, False],
+                         ids=["one-pass", "two-kernels"])
+def test_selection_grad_matches_dense(monkeypatch, one_pass, shape, dtype):
+    """dq, dk and dv of ``flash_attention(..., selection=)`` in both backward
+    forms against JAX's own gradient of the dense masked reference, under a
+    cotangent that is not uniform, the keys and values repeated over their
+    query heads inside the loss (so a key/value head's gradient is its
+    heads' sum). Shapes this small always plan the one-pass kernel, so the
+    form is forced either way."""
+    B, H, KV, T, D, bq, bk, pref = _SEL_GRAD_SHAPES[shape]
+    if pref is not None:
+        monkeypatch.setattr(pa, "_PREF_BLOCK", pref)
+    assert pa._plan_bwd(B * H, T, T, D, 4, bq, bk, sel_heads=H)[3]
+    _force_form(monkeypatch, one_pass)
+    rng = np.random.RandomState(13)
+    mk = lambda heads: jnp.asarray(
+        rng.randn(B, heads, T, D).astype(np.float32) * 0.5).astype(dtype)
+    q, k, v = mk(H), mk(KV), mk(KV)
+    w = mk(H).astype(jnp.float32)
+    sel = _sparse_selection(B, T, bq or pref)
+    scale = D ** -0.5
+    spread = lambda a: jnp.repeat(a, H // KV, axis=1)
+
+    def loss_flash(q, k, v):
+        out, _ = flash_attention(
+            q, spread(k), spread(v), selection=sel.astype(jnp.int8),
+            block_q=bq, block_k=bk)
+        return jnp.sum(out.astype(jnp.float32) * w)
+
+    def loss_dense(q, k, v):
+        f32 = lambda a: a.astype(jnp.float32)
+        out = _dense_selected(f32(q), f32(spread(k)), f32(spread(v)), sel,
+                              scale)[0]
+        return jnp.sum(out.astype(dtype).astype(jnp.float32) * w)
+
+    gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    tol = dict(rtol=1e-4, atol=1e-5) if dtype == jnp.float32 else dict(
+        rtol=2e-2, atol=2e-2)
+    for a, b in zip(gf, gd):
+        assert a.dtype == dtype and float(jnp.abs(b).max()) > 0
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), **tol)
+
+
 def test_selection_of_every_causal_key_is_causal_attention():
     """A selection that keeps every causal key is the causal kernel's
     function (another program: the mask is read, not made)."""
@@ -532,24 +617,30 @@ def test_tied_scores_choose_the_lower_position():
 # (8e5d92d), at three cells' shapes ``(bh, t, d, d_v)``: the plan notes are
 # this file's arithmetic and the kernels' names this file's functions, so
 # neither moves with the toolchain. A PR that changes the causal kernels on
-# purpose records its own.
-def _parent_plan(grid, bwd_grid, visited, rows, one_pass):
+# purpose records its own: PR 45 gave the backward at T 8192 the one-pass
+# form (half the grid steps, no dQ kernel), every plan the bytes its step
+# counts, and left T 1024 and every forward as they were; T 32768 at width
+# 256 stands for the two kernels, planned as before.
+def _parent_plan(grid, bwd_grid, visited, rows, one_pass, vmem_mb):
     return {"flash_block_q": 512, "flash_block_k": 512,
             "flash_rows_per_step": rows, "flash_grid_steps": grid,
             "flash_pairs_visited": visited, "flash_bwd_block_q": 512,
             "flash_bwd_block_k": 512, "flash_bwd_rows_per_step": 1,
             "flash_bwd_one_pass": one_pass, "flash_bwd_grid_steps": bwd_grid,
-            "flash_bwd_pairs_visited": visited}
+            "flash_bwd_pairs_visited": visited,
+            "flash_bwd_vmem_mb": vmem_mb}
 
 
+_ONE_PASS = {"_fwd_kernel": 1, "_dkv_kernel": 1}
 _TWO_KERNELS = {"_fwd_kernel": 1, "_dkv_kernel": 1, "_dq_kernel": 1}
 _PARENT_PROGRAMS = {
-    (8, 1024, 64, 64): (_parent_plan(8, 32, 0.75, 4, True),
-                        {"_fwd_kernel": 1, "_dkv_kernel": 1}),
-    (16, 8192, 256, 256): (_parent_plan(2048, 8192, 0.5312, 2, False),
-                           _TWO_KERNELS),
-    (32, 8192, 192, 128): (_parent_plan(4096, 16384, 0.5312, 2, False),
-                           _TWO_KERNELS),
+    (8, 1024, 64, 64): (_parent_plan(8, 32, 0.75, 4, True, 10.1), _ONE_PASS),
+    (16, 8192, 256, 256): (_parent_plan(2048, 4096, 0.5312, 2, True, 27.1),
+                           _ONE_PASS),
+    (32, 8192, 192, 128): (_parent_plan(4096, 8192, 0.5312, 2, True, 26.1),
+                           _ONE_PASS),
+    (16, 32768, 256, 256): (
+        _parent_plan(32768, 131072, 0.5078, 2, False, 10.1), _TWO_KERNELS),
 }
 
 
@@ -591,10 +682,13 @@ def test_selection_none_keeps_the_parents_programs(shape):
 
 
 def test_selection_kernels_lower_for_tpu_target():
-    """The three kernels under a selection at the new cell's shape (32 heads
+    """The kernels under a selection at the Keye-VL cell's shape (32 heads
     of 128 over 16384 positions, an int8 selection): they serialize for
     Mosaic with the table of block pairs as scalar prefetch, two heads a grid
-    step (the selection's tile is counted), and the plan says so."""
+    step (the selection's tile is counted), the backward ONE kernel with two
+    heads' whole dq (2 x 16 MB) under a raised scoped limit, and the plan
+    says so; at four times the length a head's dq does not fit and the dQ
+    kernel is built."""
     from functools import partial
 
     from horovod_tpu import trace
@@ -608,13 +702,26 @@ def test_selection_kernels_lower_for_tpu_target():
             jnp.float32).sum(), argnums=(0, 1, 2)))
     text = grad.trace(q, q, q, sel).lower(
         lowering_platforms=("tpu",)).as_text()
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 2
+    assert text.count("scoped_memory_configs") == 1
     notes = trace.plan_args()
     assert notes["flash_selection"] is True
     assert (notes["flash_block_q"], notes["flash_block_k"],
             notes["flash_rows_per_step"]) == (512, 512, 2)
     assert (notes["flash_bwd_block_q"], notes["flash_bwd_block_k"],
-            notes["flash_bwd_one_pass"]) == (512, 512, False)
+            notes["flash_bwd_rows_per_step"], notes["flash_bwd_one_pass"],
+            notes["flash_bwd_grid_steps"], notes["flash_bwd_vmem_mb"]) == (
+        512, 512, 2, True, 16 * 32 * 32, 44.6)
+    long_q = jax.ShapeDtypeStruct((1, 32, 65536, 128), jnp.bfloat16)
+    long_sel = jax.ShapeDtypeStruct((1, 65536, 65536), jnp.int8)
+    trace.reset_build_ledger()
+    text = grad.trace(long_q, long_q, long_q, long_sel).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "scoped_memory_configs" not in text
+    notes = trace.plan_args()
+    assert (notes["flash_bwd_one_pass"], notes["flash_bwd_grid_steps"]) == (
+        False, 2 * 16 * 128 * 128)
 
 
 def test_fetch_table_names_the_block_the_pipeline_holds():
@@ -689,7 +796,8 @@ def test_a_recomputation_that_keeps_the_named_residuals(monkeypatch, selected,
     # (made anew a use: a checkpoint holds the kernels as it first traced them)
     stack = lambda policy: _stack(
         selection, partial(jax.checkpoint, policy=policy))
-    fwd, bwd = (("_fwd_kernel_sel", ("_dkv_kernel_sel", "_dq_kernel_sel"))
+    # (sequences this short plan the one-pass backward, selection or none)
+    fwd, bwd = (("_fwd_kernel_sel", ("_dkv_kernel_sel",))
                 if selected else ("_fwd_kernel", ("_dkv_kernel",)))
 
     def forward_calls(policy):
@@ -737,6 +845,6 @@ def test_outside_a_checkpoint_a_name_lowers_to_nothing(monkeypatch, selected):
         # lowered before
         texts.append(re.sub(r"(@[A-Za-z_]+)_\d+", r"\1", grad.trace(
             *args).lower(lowering_platforms=("tpu",)).as_text()))
-    kernels = 3 if selected else 2   # a short causal backward is one kernel
-    assert texts[0].count("tpu_custom_call") == kernels * _STACK_LAYERS
+    # a short backward is one kernel, under a selection too
+    assert texts[0].count("tpu_custom_call") == 2 * _STACK_LAYERS
     assert texts[0] == texts[1]

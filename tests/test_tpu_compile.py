@@ -100,25 +100,31 @@ def test_flash_forward_compiles(topo, shape, stats):
 @pytest.mark.parametrize("shape,kernels", [
     ((96, 1024, 64), 2),
     # The cells' own calls. GPT-2-medium's 8 sequences x 16 heads: the
-    # forward and the one-pass backward, a row's whole dq in VMEM. The
-    # Qwen3-Next attention layer's 16 heads of width 256 over 8192 tokens:
-    # a row's dq is 8 MB, so the dK/dV and the dQ kernel.
+    # forward and the one-pass backward, a row's whole dq in VMEM under the
+    # compiler's default limit. The Qwen3-Next attention layer's 16 heads of
+    # width 256 over 8192 tokens: a row's dq is 8 MB in f32 and as much in
+    # the output block's buffers, one pass under the limit the call raises.
     ((128, 1024, 64), 2),
-    ((16, 8192, 256), 3),
-    # The LFM2 cell's attention layer (width 64 at 8192 tokens): a row's dq
-    # is 2 MB, so two backward kernels here too.
-    ((128, 8192, 64), 3),
-    # f32 operands: a whole dq does not fit beside 512 x 512 tiles.
-    ((8, 1024, 64, "float32"), 3),
+    ((16, 8192, 256), 2),
+    # The LFM2 cell's attention layer (width 64 at 8192 tokens): two rows'
+    # dq a step, 2 MB each in f32.
+    ((128, 8192, 64), 2),
+    # f32 operands: a whole dq fits beside 512 x 512 tiles over the default.
+    ((8, 1024, 64, "float32"), 2),
     # The Xing4.0 cell's latent-attention layer: keys of 192, values of 128.
-    ((32, 8192, 192, 128), 3),
+    ((32, 8192, 192, 128), 2),
+    # The Keye-VL cell's shape without its selection: two rows' dq, 2 x 16 MB.
+    ((32, 16384, 128), 2),
+    # A row's dq is 32 MB in f32 alone: the dK/dV and the dQ kernel.
+    ((16, 32768, 256), 3),
 ])
 def test_flash_backward_compiles(topo, shape, kernels):
     """The backward at the tiles and the form ``_plan_bwd`` picks: the
     chip's compiler takes its VMEM (accumulators, the [Bq, Bk] f32
-    temporaries, the whole-dq block or the statistic columns), the
-    transposed product and the row-to-column transposes, and the gradient
-    holds kernels and no loop."""
+    temporaries, the whole-dq block, up to 43 MB a step under the scoped
+    limit the call asks for, or the statistic columns), the transposed
+    product and the row-to-column transposes, and the gradient holds
+    kernels and no loop."""
     def loss(q, k, v):
         out = pa.flash_attention(q, k, v, causal=True, interpret=False)
         return out.astype(jnp.float32).sum()
@@ -336,8 +342,9 @@ def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
     """The Xing4.0 cell's whole step (``hvd.make_train_step`` over
     ``Xing4LM`` at the configuration's sizes: 759.3 M parameters, one
     8192-token sequence) for the described chip: the flash kernels at 192 /
-    128 are in it, one forward and two backward a layer (the layer's
-    recomputation keeps the forward's named result), and parameters, AdamW's moments, gradients and
+    128 are in it, one forward and one backward a layer (the layer's
+    recomputation keeps the forward's named result; a head's whole dq is
+    held in VMEM), and parameters, AdamW's moments, gradients and
     scratch come to no more than 16.0 GB by the compiler's own count."""
     import os
     import sys
@@ -367,6 +374,8 @@ def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
     layers = cfg["num_hidden_layers"]
     text = compiled.as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") >= 4 * layers
+    assert sum("flash_bwd" in l for l in text.splitlines()
+               if "custom-call(" in l and "tpu_custom_call" in l) == layers
     # the sparse layers' per-token sums are the gather-sum kernel at this
     # model's 28 sublanes a row, and no call site fell back to XLA
     assert sum("moe_combine" in l for l in text.splitlines()
@@ -385,13 +394,14 @@ def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
 
 
 def test_sparse_selection_kernels_compile(topo, compile_kernel):
-    """The Keye-VL cell's five kernels at its shape (one 16384-token
+    """The Keye-VL cell's four kernels at its shape (one 16384-token
     sequence, 32 query / 4 key-value heads of 128, an indexer of 16 heads of
     64 over one key head, top 2048) pass the chip's compiler: the selection
     (int8 mask stores, ordered int32 keys in 8 MB of VMEM scratch, dynamic
     loops over the causal chunks, a scoped limit over Mosaic's default), the
     flash kernels under the selection (an int8 tile and a scalar-prefetched
-    table, forward and both backward), and the objective with its gradient
+    table, the forward and the ONE backward kernel, two heads' whole dq in 45
+    MB of VMEM a step), and the objective with its gradient
     (all 32 heads inside a grid step, the keys' whole gradient resident)."""
     from horovod_tpu import trace as hvd_trace
     from horovod_tpu.ops import sparse_index as si
@@ -423,13 +433,15 @@ def test_sparse_selection_kernels_compile(topo, compile_kernel):
     notes = hvd_trace.plan_args()
     assert notes["sparse_index_kernel"] and notes["sparse_index_loss_kernel"]
     assert notes["flash_selection"] and notes["flash_rows_per_step"] == 2
+    assert (notes["flash_bwd_rows_per_step"], notes["flash_bwd_one_pass"],
+            notes["flash_bwd_vmem_mb"]) == (2, True, 44.6)
     text = compiled.as_text()
     calls = [l for l in text.splitlines()
              if "custom-call(" in l and "tpu_custom_call" in l]
     for name, count in (("sparse_index_select", 1), ("sparse_index_kl", 1),
-                        ("flash_bwd", 2)):
+                        ("flash_bwd", 1)):
         assert sum(name in l for l in calls) == count, name
-    assert len(calls) == 5
+    assert len(calls) == 4
     # no float32 [T, T] stands in HBM; the selection is int8
     flat = text.replace(" ", "")
     assert "f32[1,16384,16384]" not in flat and "s8[1,16384,16384]" in flat

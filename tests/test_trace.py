@@ -564,6 +564,7 @@ def test_flash_backward_notes_its_plan_beside_the_forwards(monkeypatch):
         # a whole dq of 8 rows fits: one kernel, 1 x 4 q blocks x 4 k blocks
         "flash_bwd_one_pass": True,
         "flash_bwd_grid_steps": 16, "flash_bwd_pairs_visited": 0.625,
+        "flash_bwd_vmem_mb": 1.6,      # what that step counts in VMEM
     }
     assert plan["flash_grid_steps"] == 16
 
