@@ -31,7 +31,7 @@ import time
 import urllib.request
 from concurrent.futures import ThreadPoolExecutor
 
-# The shipped full sizes (bench.py's GPT-2-small-class LM, the ResNet-50
+# The shipped full sizes (a GPT-2-small-class LM, the ResNet-50
 # configuration of the one old chip record). Tests pass smaller ones.
 LM = dict(d_model=768, n_heads=12, n_layers=12, vocab=32768, seq=1024,
           batch=8, steps=5)

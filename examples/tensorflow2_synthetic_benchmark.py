@@ -4,7 +4,7 @@ on random data, ``DistributedGradientTape``, img/sec averaged over timed
 iterations, optional fp16 compression and Adasum).
 
 The recommended high-throughput path on TPU is the JAX compiled mode
-(see ``examples/jax_resnet50_synthetic_benchmark.py`` / ``bench.py``);
+(see ``examples/jax_resnet50_synthetic_benchmark.py``);
 this script exists for reference-CLI parity and TF-binding validation.
 
 Run:  python -m horovod_tpu.run -np 2 python \

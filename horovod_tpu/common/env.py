@@ -146,8 +146,8 @@ HOROVOD_TP_OVERLAP_CHUNKS = "HOROVOD_TP_OVERLAP_CHUNKS"
 HOROVOD_TUNED_FILE = "HOROVOD_TUNED_FILE"
 # Fleet-simulation calibration (docs/simulation.md): path to a
 # ``calibration.json`` fitted by ``tools/fleet_sim.py --calibrate`` from
-# merged trace data. The simulator, the tuner's cost objectives
-# (``tune(calibration=...)``), and bench's sim block read it when their
+# merged trace data. The simulator and the tuner's cost objectives
+# (``tune(calibration=...)``) read it when their
 # ``calibration`` argument is left unset and apply the per-hop constants
 # IF the interconnect-model signature (hop ladder) matches; a mismatch
 # warns loudly and runs on generation defaults. sim/calibrate.py reads
@@ -327,7 +327,7 @@ def applied_perf_preset() -> dict | None:
 
 def configure_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache for a script that runs
-    on the chip (chip_smoke.py, bench.py call this first thing; the
+    on the chip (chip_smoke.py calls this first thing; the
     library never does on import) and return the directory in use.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and

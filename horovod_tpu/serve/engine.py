@@ -149,7 +149,7 @@ class ServeEngine:
         self._completions_since = 0
         # Chaos observability (asserted by tools/serve_smoke.py).
         self.requeues = 0
-        # Occupancy accounting (bench.py --serve reports the mean).
+        # Occupancy accounting (batched_requests / batches is the mean).
         self.batches = 0
         self.batched_requests = 0
         # Decode launches, and the slots that held a live request in them:

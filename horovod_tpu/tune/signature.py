@@ -74,8 +74,8 @@ def mesh_axes_hash(sig: Optional[Dict]) -> str:
     """16-hex key over ONLY the mesh component of a signature — what
     lets a consumer say WHY a match failed: same program pinned on a
     different mesh (axes hash differs) vs a different program entirely.
-    ``bench.py`` refuses ``--quantized --tuned`` when this half differs
-    (the wire-dtype verdict is a function of the mesh's hop ladder)."""
+    The wire-dtype verdict is a function of the mesh's hop ladder, so a
+    tuning pinned on another mesh says nothing about ``quantized``."""
     body = (sig or {}).get("mesh") or {}
     blob = json.dumps(body, sort_keys=True, separators=(",", ":"))
     return hashlib.sha256(blob.encode()).hexdigest()[:16]

@@ -16,6 +16,7 @@ limits are 2% and 15%, which a dropped layer, a wrong mask, a wrong head
 grouping or a left-out bias exceeds by far."""
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -52,10 +53,12 @@ OPT = {"name": "adamw", "learning_rate": 3e-4, "b1": 0.9, "b2": 0.999,
 B, T = 2, 64
 
 
-def _setup(dtype, seed=11, **over):
-    cfg = {**CFG, **over}
-    model = lm.Lfm2MoeLM(dataclasses.replace(
-        family.model_config(cfg), dtype=dtype))
+@functools.lru_cache(maxsize=None)
+def _inputs(seed, over=()):
+    """Weights and a batch of ``CFG`` with ``over`` from ``seed``: made once
+    a process (the tests share them; the one test whose step donates its
+    input copies)."""
+    cfg = {**CFG, **dict(over)}
     params = make_params(family.param_spec(cfg), seed)
     # norm weights start at one: move every vector off its initial value so
     # that a leaf the program ignores shows (the bias keeps its own draw)
@@ -68,6 +71,13 @@ def _setup(dtype, seed=11, **over):
     rng = np.random.default_rng(seed)
     tokens = jnp.asarray(rng.integers(0, cfg["vocab_size"], (B, T)), jnp.int32)
     labels = jnp.asarray(rng.integers(0, cfg["vocab_size"], (B, T)), jnp.int32)
+    return cfg, params, tokens, labels
+
+
+def _setup(dtype, seed=11, **over):
+    cfg, params, tokens, labels = _inputs(seed, tuple(sorted(over.items())))
+    model = lm.Lfm2MoeLM(dataclasses.replace(
+        family.model_config(cfg), dtype=dtype))
     return cfg, model, params, tokens, labels
 
 
@@ -77,6 +87,17 @@ def _loss(model):
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, labels).mean()
     return f
+
+
+def _jit(f, *args):
+    """``f(*args)`` as ONE compiled program (run operation by operation the
+    whole-model tests of this file took twice as long) that rounds where the
+    operation-by-operation run does: no wider bfloat16 intermediates inside
+    a fusion, so the tolerances measured on that run hold. It runs once, so
+    LLVM's expensive passes cost more than they save (same bits without)."""
+    return jax.jit(f).lower(*args).compile(compiler_options={
+        "xla_allow_excess_precision": False,
+        "xla_llvm_disable_expensive_passes": True})(*args)
 
 
 def test_parameter_tree_is_the_benchmarks_spec():
@@ -115,15 +136,15 @@ def test_layers_follow_the_list_of_kinds():
 ])
 def test_program_equals_reference(dtype, logit_tol, grad_tol):
     cfg, model, params, tokens, labels = _setup(dtype)
-    want = reference.logits(params, tokens, cfg)
-    got = model.apply({"params": params}, tokens)
+    want = _jit(lambda p: reference.logits(p, tokens, cfg), params)
+    got = _jit(lambda p: model.apply({"params": p}, tokens), params)
     assert got.dtype == jnp.float32
     spread = float(jnp.max(want) - jnp.min(want))
     assert float(jnp.max(jnp.abs(got - want))) <= logit_tol * spread
 
-    l_ref, g_ref = jax.value_and_grad(
-        lambda p: reference.loss(p, (tokens, labels), cfg))(params)
-    l, g = jax.value_and_grad(_loss(model))(params, tokens, labels)
+    l_ref, g_ref = _jit(jax.value_and_grad(
+        lambda p: reference.loss(p, (tokens, labels), cfg)), params)
+    l, g = _jit(jax.value_and_grad(_loss(model)), params, tokens, labels)
     assert abs(float(l) - float(l_ref)) <= logit_tol * abs(float(l_ref))
     flat_ref = jax.tree_util.tree_leaves_with_path(g_ref)
     norms = [float(jnp.linalg.norm(x)) for _, x in flat_ref]
@@ -160,11 +181,13 @@ def test_three_adamw_steps_equal_the_reference():
     p, state = params, tx.init(params)
     q = jax.tree.map(jnp.copy, params)
     ref_state = optim.init(OPT, q)
+    grad = jax.jit(jax.grad(_loss(model)))
+    grad_ref = jax.jit(jax.grad(lambda w, batch: reference.loss(w, batch, cfg)))
     for batch in batches:
-        g = jax.grad(_loss(model))(p, *batch)
+        g = grad(p, *batch)
         updates, state = tx.update(g, state, p)
         p = optax.apply_updates(p, updates)
-        g_ref = jax.grad(lambda w: reference.loss(w, batch, cfg))(q)
+        g_ref = grad_ref(q, batch)
         q, ref_state = optim.update(OPT, q, g_ref, ref_state)
     flat = jax.tree_util.tree_leaves_with_path(params)
     for (path, w0), a, b in zip(flat, jax.tree.leaves(p), jax.tree.leaves(q)):
@@ -285,8 +308,8 @@ def test_head_is_the_embeddings_transpose():
     np.testing.assert_allclose(model.apply({"params": params}, tokens), want,
                                atol=1e-5)
     # the table's gradient holds both uses: the lookup's rows and the head's
-    g = jax.grad(_loss(model))(params, tokens, labels)["embed_tokens"][
-        "embedding"]
+    g = _jit(jax.grad(_loss(model)), params, tokens, labels)[
+        "embed_tokens"]["embedding"]
     unseen = np.setdiff1d(np.arange(cfg["vocab_size"]), np.asarray(tokens))
     assert unseen.size and float(jnp.min(jnp.linalg.norm(
         g[unseen], axis=-1))) > 0            # rows no token looked up
@@ -329,6 +352,7 @@ def test_trains_through_make_train_step():
     tx = hvd.DistributedOptimizer(optax.adamw(3e-3))
     loss_fn = lambda p, batch: _loss(model)(p, *batch)
     step = hvd.make_train_step(loss_fn, tx, mesh)
+    params = jax.tree.map(jnp.copy, params)       # the step donates them
     state = tx.init(params)
     losses = []
     for _ in range(8):

@@ -2,6 +2,10 @@
 reference's headline benchmark families (ResNet / VGG-16 / Inception V3,
 ``docs/benchmarks.rst:13-14`` upstream)."""
 
+import os
+import runpy
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -64,3 +68,19 @@ def test_bf16_compute_policy():
 def test_get_model_unknown_name():
     with pytest.raises(ValueError):
         get_model("alexnet")
+
+
+def test_synthetic_benchmark_example_runs(monkeypatch, capsys):
+    """``examples/jax_resnet50_synthetic_benchmark.py`` at a tiny size on the
+    virtual CPU mesh: warm-up, timed iterations, upstream's last lines."""
+    script = os.path.join(os.path.dirname(__file__), os.pardir, "examples",
+                          "jax_resnet50_synthetic_benchmark.py")
+    monkeypatch.setattr(sys, "argv", [
+        script, "--model", "resnet18", "--batch-size", "2", "--image-size",
+        "32", "--num-warmup-batches", "1", "--num-batches-per-iter", "1",
+        "--num-iters", "2"])
+    runpy.run_path(script, run_name="__main__")
+    out = capsys.readouterr().out
+    n = len(jax.devices())
+    assert "Iter #1:" in out and "Img/sec per chip:" in out
+    assert f"Total img/sec on {n} chip(s):" in out

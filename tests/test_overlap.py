@@ -491,3 +491,38 @@ def test_overlap_metrics_gauges():
         assert "hvd_fusion_bucket_bytes" in snap
     finally:
         metrics.reset()
+
+
+def test_overlap_schedule_parser():
+    """The HLO-schedule parser of tools/tpu_profile_overlap.py (phase B):
+    async pairs are matched by operand name including TUPLE-typed
+    (variadic) forms — a miss there would turn real latency hiding into a
+    false 'no overlap' verdict — and compute between start/done is counted
+    across tuple-shaped fusions."""
+    import importlib.util
+    import os
+
+    repo = os.path.join(os.path.dirname(__file__), os.pardir)
+    spec = importlib.util.spec_from_file_location(
+        "tpo", os.path.join(repo, "tools", "tpu_profile_overlap.py")
+    )
+    tpo = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tpo)
+
+    hlo = "\n".join([
+        "ENTRY %main {",
+        "  %p0 = f32[8]{0} parameter(0)",
+        "  %ars = (f32[64]{0}, f32[32]{0}) all-reduce-start(%g1, %g2), "
+        "replica_groups={{0,1}}",
+        "  %f.1 = (f32[64]{0}, f32[8]{0}) fusion(%p0), kind=kLoop",
+        "  %conv = f32[1,8,8,64]{3,2,1,0} convolution(%x, %k), window={}",
+        "  %ard = (f32[64]{0}, f32[32]{0}) all-reduce-done(%ars)",
+        "  %sync = f32[64]{0} all-reduce(%f.1), replica_groups={{0,1}}",
+        "  %gte = f32[64]{0} get-tuple-element(%ard), index=0",
+        "}",
+    ])
+    stats = tpo._schedule_overlap_stats(hlo)
+    assert stats["async_all_reduce_pairs"] == 1, stats
+    assert stats["compute_ops_overlapped_per_pair"] == [2], stats
+    assert stats["pairs_with_overlap"] == 1, stats
+    assert stats["sync_all_reduce_count"] == 1, stats

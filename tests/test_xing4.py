@@ -17,6 +17,7 @@ the experts' leaves); the limits are 6% and 30%, which a dropped mix, a wrong
 mask or left-out rotary keys exceed by far (they read 1)."""
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -57,10 +58,12 @@ CFG = {
 B, T = 2, 64
 
 
-def _setup(dtype, seed=11, **over):
-    cfg = {**CFG, **over}
-    model = xm.Xing4LM(dataclasses.replace(
-        family.model_config(cfg), dtype=dtype))
+@functools.lru_cache(maxsize=None)
+def _inputs(seed, over=()):
+    """Weights and a batch of ``CFG`` with ``over`` from ``seed``: made once
+    a process (the tests share them; the one test whose step donates its
+    input copies)."""
+    cfg = {**CFG, **dict(over)}
     params = make_params(family.param_spec(cfg), seed)
     # norm weights and alpha start at one: move every vector off its initial
     # value so that a leaf the program ignores shows (the drawn ones keep
@@ -76,6 +79,13 @@ def _setup(dtype, seed=11, **over):
     rng = np.random.default_rng(seed)
     tokens = jnp.asarray(rng.integers(0, cfg["vocab_size"], (B, T)), jnp.int32)
     labels = jnp.asarray(rng.integers(0, cfg["vocab_size"], (B, T)), jnp.int32)
+    return cfg, params, tokens, labels
+
+
+def _setup(dtype, seed=11, **over):
+    cfg, params, tokens, labels = _inputs(seed, tuple(sorted(over.items())))
+    model = xm.Xing4LM(dataclasses.replace(
+        family.model_config(cfg), dtype=dtype))
     return cfg, model, params, tokens, labels
 
 
@@ -85,6 +95,17 @@ def _loss(model):
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, labels).mean()
     return f
+
+
+def _jit(f, *args):
+    """``f(*args)`` as ONE compiled program (run operation by operation the
+    whole-model tests of this file took twice as long) that rounds where the
+    operation-by-operation run does: no wider bfloat16 intermediates inside
+    a fusion, so the tolerances measured on that run hold. It runs once, so
+    LLVM's expensive passes cost more than they save (same bits without)."""
+    return jax.jit(f).lower(*args).compile(compiler_options={
+        "xla_allow_excess_precision": False,
+        "xla_llvm_disable_expensive_passes": True})(*args)
 
 
 def test_parameter_tree_is_the_benchmarks_spec():
@@ -107,15 +128,15 @@ def test_parameter_tree_is_the_benchmarks_spec():
 ])
 def test_program_equals_reference(dtype, logit_tol, grad_tol):
     cfg, model, params, tokens, labels = _setup(dtype)
-    want = reference.logits(params, tokens, cfg)
-    got = model.apply({"params": params}, tokens)
+    want = _jit(lambda p: reference.logits(p, tokens, cfg), params)
+    got = _jit(lambda p: model.apply({"params": p}, tokens), params)
     assert got.dtype == jnp.float32
     spread = float(jnp.max(want) - jnp.min(want))
     assert float(jnp.max(jnp.abs(got - want))) <= logit_tol * spread
 
-    l_ref, g_ref = jax.value_and_grad(
-        lambda p: reference.loss(p, (tokens, labels), cfg))(params)
-    l, g = jax.value_and_grad(_loss(model))(params, tokens, labels)
+    l_ref, g_ref = _jit(jax.value_and_grad(
+        lambda p: reference.loss(p, (tokens, labels), cfg)), params)
+    l, g = _jit(jax.value_and_grad(_loss(model)), params, tokens, labels)
     assert abs(float(l) - float(l_ref)) <= logit_tol * abs(float(l_ref))
     flat_ref = jax.tree_util.tree_leaves_with_path(g_ref)
     norms = [float(jnp.linalg.norm(x)) for _, x in flat_ref]
@@ -249,7 +270,7 @@ def test_the_first_mix_of_identical_streams_ignores_its_read_and_res_maps():
     why that leaf's Adam update compares badly: benchmark/cells), and real
     from the next mix on."""
     cfg, model, params, tokens, labels = _setup(jnp.float32)
-    g = jax.grad(_loss(model))(params, tokens, labels)
+    g = _jit(jax.grad(_loss(model)), params, tokens, labels)
     size = lambda phi, cols: float(jnp.abs(phi[:, cols]).mean())
     first, later = g["layer_0"]["attn_hc"]["phi"], g["layer_0"]["ffn_hc"]["phi"]
     post = slice(4, 8)
@@ -269,6 +290,7 @@ def test_trains_through_make_train_step():
     tx = hvd.DistributedOptimizer(optax.adamw(3e-3))
     loss_fn = lambda p, batch: _loss(model)(p, *batch)
     step = hvd.make_train_step(loss_fn, tx, mesh)
+    params = jax.tree.map(jnp.copy, params)       # the step donates them
     state = tx.init(params)
     losses = []
     for _ in range(8):

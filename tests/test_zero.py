@@ -124,8 +124,8 @@ def test_quantized_convergence_tracks_fp32(mesh):
     """End-to-end convergence evidence: the
     int8-wire and int8+ZeRO-1 training curves must track full-precision
     DP — asserted on the final loss after real optimization steps, not a
-    per-call error bound. The committed 300-step artifact is
-    BENCH_CONVERGENCE_CPU.json; this CI version runs fewer steps."""
+    per-call error bound. ``utils/convergence.run`` defaults to 300
+    steps; this CI version runs fewer."""
     from horovod_tpu.utils import convergence
 
     result = convergence.run(steps=40, record_every=10)

@@ -75,9 +75,10 @@ def _transformer_params(seq_len: int, d_model: int, n_heads: int,
                         n_layers: int, vocab: int):
     """A fp32 TransformerLM program's params avals (dense attention so
     no Pallas trace is needed). The defaults mirror the structural
-    profiler's phase-B program; pass the bench's dims (e.g. ``--layers
+    profiler's phase-B program; pass your model's dims (e.g. ``--layers
     12 --d-model 768 --vocab 32768 --seq-len 1024``) to emit a tuning
-    whose signature matches ``bench.py --model transformer``."""
+    whose signature matches the step that will load it
+    (``make_train_step(..., tuned="tuned.json")``)."""
     import jax
     import jax.numpy as jnp
 
@@ -135,8 +136,8 @@ def _measure_fn_for(args, params_aval):
 
     if args.program != "mlp3":
         raise SystemExit(
-            "--measure currently supports --program mlp3 (the "
-            "transformer program's measured path is bench.py --tuned)"
+            "--measure currently supports --program mlp3 (time the "
+            "transformer program through make_train_step(tuned=...))"
         )
     mesh = build_mesh()
     n = len(jax.devices())

@@ -32,8 +32,8 @@ silently wrong.
 alpha-beta constants from measured collective samples into a
 signature-keyed ``calibration.json`` (hop-ladder staleness discipline,
 like ``tuned.json``). Consumed here via ``--calibration``, by the tuner
-(``tools/autotune_compiled.py --calibration``), and by bench's ``sim``
-block / ``HOROVOD_CALIBRATION_FILE``.
+(``tools/autotune_compiled.py --calibration``), and through
+``HOROVOD_CALIBRATION_FILE``.
 
 No accelerator needed: jax is imported only for the shared
 ``plan_layer_groups`` partition, never a backend — runs on any box.

@@ -7,7 +7,7 @@ compute" — was asserted, not shown, and the backward window (~8 ms) and
 ICI budget (100 GB/s) were uncited. This tool replaces assumption with
 evidence on the hardware that IS reachable (one chip):
 
-Phase A (measured, single chip): build the exact bench.py ResNet-50 DP
+Phase A (measured, single chip): build a ResNet-50 DP
 step, then time three jitted programs — forward loss only, forward +
 backward (value_and_grad), and the full step (grads + fused psum +
 optimizer) — giving a MEASURED backward window `t_grad - t_fwd`; capture
@@ -48,7 +48,7 @@ HLO for schedule interleaving, and reports per program:
    many compute ops are in NEITHER its operand nor its user cone (the
    compute a latency-hiding scheduler may run during the transfer).
 
-Writes PROFILE_OVERLAP_PHASEB_default.json / _overlap.json; with
+Writes one PROFILE_OVERLAP_PHASEB_<variant>.json a variant (git-ignored); with
 `--assert-overlap` exits nonzero unless the overlap build of BOTH
 programs shows independent_all_reduce_groups >= 3 and
 pairs_with_overlap > 0 (the `make overlap-smoke` CI gate).
