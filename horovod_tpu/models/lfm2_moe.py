@@ -27,7 +27,7 @@ token ``taps - 1 - j`` back.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Tuple
+from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,13 +79,16 @@ class ShortConv(nn.Module):
 
 class GroupedQueryAttention(nn.Module):
     """Grouped-query causal softmax attention with per-head q/k norms and
-    rotary positions over the whole head."""
+    rotary positions over the whole head; without the norms where
+    ``qk_norm`` is off and without positions where ``positions`` is None
+    (``models/nemotron_h.py``: its state-space layers carry position)."""
 
     n_heads: int
     n_kv_heads: int
     head_dim: int
     rope_theta: float = 1e6
     eps: float = 1e-5
+    qk_norm: bool = True
     init_std: float = 0.02
     dtype: Any = jnp.bfloat16
 
@@ -98,11 +101,13 @@ class GroupedQueryAttention(nn.Module):
             q = dense(H * D, "q_proj")(x).reshape(B, T, H, D)
             k = dense(KV * D, "k_proj")(x).reshape(B, T, KV, D)
             v = dense(KV * D, "v_proj")(x).reshape(B, T, KV, D)
-            q = _norm(self.eps, jnp.float32, "q_layernorm")(q)
-            k = _norm(self.eps, jnp.float32, "k_layernorm")(k)
-            rot = dict(rotary_dim=D, theta=self.rope_theta)
-            q = rotary(q, positions, **rot).astype(self.dtype)
-            k = rotary(k, positions, **rot).astype(self.dtype)
+            if self.qk_norm:
+                q = _norm(self.eps, jnp.float32, "q_layernorm")(q)
+                k = _norm(self.eps, jnp.float32, "k_layernorm")(k)
+            if positions is not None:
+                rot = dict(rotary_dim=D, theta=self.rope_theta)
+                q = rotary(q, positions, **rot).astype(self.dtype)
+                k = rotary(k, positions, **rot).astype(self.dtype)
             # each key/value head serves H / KV query heads
             k = jnp.repeat(k, H // KV, axis=2)
             v = jnp.repeat(v, H // KV, axis=2)
@@ -137,7 +142,10 @@ class SparseMoe(nn.Module):
     (the published balancing update of it is not part of the model).
     ``norm_eps`` stands under the chosen weights' sum (LFM2's by default).
     ``models/xing4.py`` routes through this layer too, with its shared
-    expert beside it."""
+    expert beside it, and ``models/nemotron_h.py`` with ``gated`` off: an
+    expert is then ``down(activation(up x))`` and holds no ``gate`` leaf, and
+    ``shared_dim`` gives the layer a shared expert of that form and that
+    width (``shared_up_proj``, ``shared_down_proj``), added unweighted."""
 
     n_experts: int
     experts_held: int
@@ -148,6 +156,9 @@ class SparseMoe(nn.Module):
     routed_scale: float = 1.0
     use_expert_bias: bool = True
     norm_eps: float = NORM_EPS
+    gated: bool = True
+    activation: Callable = jax.nn.silu
+    shared_dim: int = 0
     init_std: float = 0.02
     dtype: Any = jnp.bfloat16
 
@@ -157,13 +168,18 @@ class SparseMoe(nn.Module):
         f32 = jnp.float32
         init = _normal(self.init_std)
         E, F = self.experts_held, self.expert_dim
+        if self.shared_dim and self.gated:
+            raise ValueError("the layer's own shared expert has no gate; a "
+                             "gated one stands beside the layer "
+                             "(models/xing4.py)")
         router = self.param("router", lambda k, s: {"kernel": init(k, s, f32)},
                             (C, self.n_experts))["kernel"]
         bias = (self.param("expert_bias", nn.initializers.zeros,
                            (self.n_experts,), f32)
                 if self.use_expert_bias else None)
         experts = self.param("experts", lambda k: {
-            "gate": init(jax.random.fold_in(k, 0), (E, C, F), f32),
+            **({"gate": init(jax.random.fold_in(k, 0), (E, C, F), f32)}
+               if self.gated else {}),
             "up": init(jax.random.fold_in(k, 1), (E, C, F), f32),
             "down": init(jax.random.fold_in(k, 2), (E, F, C), f32),
         })
@@ -176,9 +192,17 @@ class SparseMoe(nn.Module):
             self.sow("intermediates", "held_load", _load_and_tiles(
                 ids, self.first_expert, E, self.n_experts))
         y = dropless_moe(
-            flat, router, experts["gate"], experts["up"], experts["down"],
-            first_expert=self.first_expert, dtype=self.dtype, **routing)
-        return y.reshape(B, T, C).astype(self.dtype)
+            flat, router, experts.get("gate"), experts["up"], experts["down"],
+            first_expert=self.first_expert, activation=self.activation,
+            dtype=self.dtype, **routing).reshape(B, T, C)
+        if self.shared_dim:
+            with jax.named_scope(_trace.SCOPE_MOE_SHARED):
+                # every chip of the group computes the shared expert alike
+                dense = lambda n, name: _dense(
+                    n, name, self.dtype, self.init_std)
+                h = self.activation(dense(self.shared_dim, "shared_up_proj")(x))
+                y = y + dense(C, "shared_down_proj")(h)
+        return y.astype(self.dtype)
 
 
 class DecoderLayer(nn.Module):

@@ -267,9 +267,16 @@ def _tile(order, lo, rows: int, starts, ends):
     return lax.dynamic_slice(order, (lo,), (rows,)), sizes
 
 
-def _tile_products(xs, w_gate, w_up, w_down, sizes):
-    """Three grouped products over a tile's part of the ragged assignment:
-    ``[rows, D]`` float32. Rows past the last group belong to no expert, and
+def relu_squared(x):
+    """``relu(x) ** 2``: the activation of an expert without a gate."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def _tile_products(xs, w_gate, w_up, w_down, sizes, activation):
+    """The grouped products over a tile's part of the ragged assignment,
+    ``down(activation(gate x) * up x)`` or, for experts without a gate
+    (``w_gate`` None), ``down(activation(up x))``: ``[rows, D]`` float32.
+    Rows past the last group belong to no expert, and
     a grouped product leaves whatever was in memory there (zeros on the CPU,
     anything on the chip), forward AND transposed: nothing may read them.
     The per-token sums below read the rows that :func:`_token_slots` names,
@@ -277,7 +284,10 @@ def _tile_products(xs, w_gate, w_up, w_down, sizes):
     with jax.named_scope(_trace.SCOPE_MOE_EXPERTS):
         grouped = lambda a, w: lax.ragged_dot(
             a, w, sizes, preferred_element_type=jnp.float32)
-        h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+        if w_gate is None:
+            h = activation(grouped(xs, w_up))
+        else:
+            h = activation(grouped(xs, w_gate)) * grouped(xs, w_up)
         return grouped(h.astype(w_down.dtype), w_down)      # [rows, D] f32
 
 
@@ -316,9 +326,9 @@ def _walk_tiles(tile, plan, load):
         head)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
 def _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends, plan,
-             slots):
+             slots, activation):
     """The held experts' part of the result for the sorted pairs ``order``
     (flat: the first tile's rows, then the overflow tiles', as ``plan`` of
     :func:`_tile_plan` cuts them; ``pos`` ``[S, k]`` is its inverse): the
@@ -332,8 +342,8 @@ def _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends, plan,
     def tile_sum(lo, rows):
         order_t, sizes_t = _tile(order, lo, rows, starts, ends)
         with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
-            xs = x[order_t // scale.shape[1]].astype(w_gate.dtype)
-        ys = _tile_products(xs, w_gate, w_up, w_down, sizes_t)
+            xs = x[order_t // scale.shape[1]].astype(w_up.dtype)
+        ys = _tile_products(xs, w_gate, w_up, w_down, sizes_t, activation)
         with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
             slot_pos, slot_w = _token_slots(
                 pos, lo, rows, ends[-1], slots, scale)
@@ -343,13 +353,13 @@ def _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends, plan,
 
 
 def _experts_fwd(x, scale, w_gate, w_up, w_down, order, pos, starts, ends,
-                 plan, slots):
+                 plan, slots, activation):
     y = _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends,
-                 plan, slots)
+                 plan, slots, activation)
     return y, (x, scale, w_gate, w_up, w_down, order, pos, starts, ends)
 
 
-def _experts_bwd(plan, slots, res, dy):
+def _experts_bwd(plan, slots, activation, res, dy):
     x, scale, w_gate, w_up, w_down, order, pos, starts, ends = res
     top_k = scale.shape[1]
     f32 = lambda tree: jax.tree.map(lambda g: g.astype(jnp.float32), tree)
@@ -358,10 +368,11 @@ def _experts_bwd(plan, slots, res, dy):
         order_t, sizes_t = _tile(order, lo, rows, starts, ends)
         with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
             token = order_t // top_k
-            xs = x[token].astype(w_gate.dtype)
+            xs = x[token].astype(w_up.dtype)
         # the tile's forward again: its rows are in no residual
         ys, vjp = jax.vjp(
-            lambda *a: _tile_products(*a, sizes_t), xs, w_gate, w_up, w_down)
+            lambda *a: _tile_products(*a, sizes_t, activation),
+            xs, w_gate, w_up, w_down)
         with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
             # y[t] = sum over t's held pairs of weight * ys[row]: a row's
             # cotangent is its token's dy times the pair's weight, the
@@ -384,7 +395,8 @@ def _experts_bwd(plan, slots, res, dy):
 
     grads = _walk_tiles(tile_grads, plan, ends[-1])
     primals = (x, scale, w_gate, w_up, w_down)
-    return tuple(g.astype(p.dtype) for g, p in zip(grads, primals)) + (
+    # an expert without a gate has no gate's gradient: None, like its weight
+    return jax.tree.map(lambda g, p: g.astype(p.dtype), grads, primals) + (
         None, None, None, None)
 
 
@@ -394,7 +406,7 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 def dropless_moe(
     x: jax.Array,
     w_router: jax.Array,
-    w_gate: jax.Array,
+    w_gate: Optional[jax.Array],
     w_up: jax.Array,
     w_down: jax.Array,
     *,
@@ -405,6 +417,7 @@ def dropless_moe(
     select_bias=None,
     norm_eps: float = 0.0,
     scale: float = 1.0,
+    activation: Callable = jax.nn.silu,
     dtype=jnp.bfloat16,
 ) -> jax.Array:
     """This device's experts' part of a top-k MoE feed-forward, no token
@@ -417,8 +430,11 @@ def dropless_moe(
     experts by :func:`route_top_k` (softmax and top-k by default, weights
     normalised over all k when ``norm_topk``; ``score``, ``select_bias``,
     ``norm_eps`` and ``scale`` are its), and the sum ``sum_k w_k *
-    down_e(silu(gate_e(x)) * up_e(x))`` runs over the chosen experts that
-    are held here; what the
+    down_e(activation(gate_e(x)) * up_e(x))`` runs over the chosen experts that
+    are held here (``w_gate`` None: experts without a gate, ``down_e(
+    activation(up_e(x)))``, two grouped products a tile and two weights
+    through the backward; :func:`relu_squared` is such a model's
+    ``activation``); what the
     others would add belongs to their owners (across an ``expert`` axis the
     exchange that brings their tokens here is ROADMAP's debt; on one chip
     the layer runs without it). Returns ``[S, D]`` float32.
@@ -443,7 +459,7 @@ def dropless_moe(
     gradient from the transposed products' rows."""
     s_tokens, d_model = x.shape
     e_total = w_router.shape[-1]
-    e_held = w_gate.shape[0]
+    e_held = w_up.shape[0]
     plan = first, over, n_over = _tile_plan(s_tokens, top_k, e_held, e_total)
     rows_in_all = first + n_over * over
     slots = min(top_k, e_held)      # the most held pairs a token can have
@@ -453,6 +469,7 @@ def dropless_moe(
         moe_top_k=top_k, moe_tile_rows=first, moe_tiles=1 + n_over,
         moe_overflow_rows=over,
         moe_score=score, moe_select_bias=select_bias is not None,
+        moe_gated=w_gate is not None,
         moe_combine_kernel=block is not None,
         moe_combine_block=block or 0, moe_combine_slots=slots,
     )
@@ -468,10 +485,11 @@ def dropless_moe(
         ends = jnp.cumsum(sizes)
     with jax.named_scope(_trace.SCOPE_MOE_EXPERTS):
         # cast once, outside the loop over tiles
-        w_gate, w_up, w_down = (w.astype(dtype)
-                                for w in (w_gate, w_up, w_down))
+        w_gate, w_up, w_down = jax.tree.map(
+            lambda w: w.astype(dtype), (w_gate, w_up, w_down))
     return _experts(x, weights, w_gate, w_up, w_down, order,
-                    pos.reshape(ids.shape), ends - sizes, ends, plan, slots)
+                    pos.reshape(ids.shape), ends - sizes, ends, plan, slots,
+                    activation)
 
 
 def _round_up(n: int, to: int) -> int:

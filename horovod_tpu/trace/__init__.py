@@ -119,6 +119,13 @@ SCOPE_HC_MIX = "hc_mix"            # mixing matrices, read and write of streams
 # attention (ops/sparse_index.py) and the objective that trains it.
 SCOPE_SPARSE_INDEX = "sparse_index"  # projections, scores, k-th value, mask
 SCOPE_SPARSE_INDEX_LOSS = "sparse_index_loss"  # head-summed p, KL, backward
+# The state-space layers of models/nemotron_h.py (NEMOTRON_H_SCOPES below:
+# its attention and expert layers enter gqa_attn and the moe_ scopes): the
+# mixer's projections and gated norm, and inside it the convolution and the
+# selective scan (ops/ssd.py), which ssm_ms.train reads.
+SCOPE_SSM_MIXER = "ssm_mixer"
+SCOPE_SSM_CONV = "ssm_conv"        # taps, bias, silu; no projection
+SCOPE_SSM_SCAN = "ssm_scan"        # decays, chunk products, chunk pass, D x
 MODEL_SCOPES = (
     SCOPE_GDN_CONV,
     SCOPE_GDN_SCAN,
@@ -146,6 +153,15 @@ KEYE_SCOPES = (
     SCOPE_SPARSE_INDEX_LOSS,
     SCOPE_MOE_ROUTE,
     SCOPE_MOE_EXPERTS,
+)
+NEMOTRON_H_SCOPES = (
+    SCOPE_SSM_MIXER,
+    SCOPE_SSM_CONV,
+    SCOPE_SSM_SCAN,
+    SCOPE_GQA_ATTN,
+    SCOPE_MOE_ROUTE,
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_SHARED,
 )
 STEP_SCOPES = (
     SCOPE_LOSS_GRAD,

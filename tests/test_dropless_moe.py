@@ -509,22 +509,26 @@ def _poisoned_products(real):
         past = jnp.arange(rows.shape[0]) >= jnp.sum(sizes)
         return jnp.where(past[:, None], jnp.nan, rows)
 
-    @jax.custom_vjp
-    def products(xs, w_gate, w_up, w_down, sizes):
-        return poison(real(xs, w_gate, w_up, w_down, sizes), sizes)
+    def with_activation(xs, w_gate, w_up, w_down, sizes, activation):
+        @jax.custom_vjp
+        def products(xs, w_gate, w_up, w_down, sizes):
+            return poison(real(xs, w_gate, w_up, w_down, sizes, activation),
+                          sizes)
 
-    def fwd(xs, w_gate, w_up, w_down, sizes):
-        return (products(xs, w_gate, w_up, w_down, sizes),
-                (xs, w_gate, w_up, w_down, sizes))
+        def fwd(xs, w_gate, w_up, w_down, sizes):
+            return (products(xs, w_gate, w_up, w_down, sizes),
+                    (xs, w_gate, w_up, w_down, sizes))
 
-    def bwd(res, dys):
-        *primals, sizes = res
-        _, vjp = jax.vjp(lambda *a: real(*a, sizes), *primals)
-        dxs, *dw = vjp(dys)
-        return (poison(dxs, sizes), *dw, None)
+        def bwd(res, dys):
+            *primals, sizes = res
+            _, vjp = jax.vjp(lambda *a: real(*a, sizes, activation), *primals)
+            dxs, *dw = vjp(dys)
+            return (poison(dxs, sizes), *dw, None)
 
-    products.defvjp(fwd, bwd)
-    return products
+        products.defvjp(fwd, bwd)
+        return products(xs, w_gate, w_up, w_down, sizes)
+
+    return with_activation
 
 
 # 512 tokens over 16 experts, top 4, by _layer_args_of_load.
@@ -602,3 +606,233 @@ def test_lowered_layer_scatters_no_rows(width, direction):
     hlo = lambda layer: jax.jit(fn(layer)).lower(*args).as_text(dialect="hlo")
     assert not _row_scatters(hlo(_held_share), width)
     assert _row_scatters(hlo(_scatter_add_layer), width)
+
+
+# --------------------------------------------------------------------------
+# Experts without a gate (``w_gate`` None, models/nemotron_h.py): two grouped
+# products a tile and ``relu(.)^2`` between; with a gate, the parent's program.
+# --------------------------------------------------------------------------
+
+def _ungated_loop(x, wr, wu, wd, first, held, top_k, routing):
+    """The layer written out for experts ``down(relu(up x)^2)``: the
+    sigmoid-and-bias routing and a dense loop over the held experts."""
+    scores = jax.nn.sigmoid(jnp.matmul(x, wr,
+                                       precision=jax.lax.Precision.HIGHEST))
+    _, ids = jax.lax.top_k(scores + routing["select_bias"], top_k)
+    w = jnp.take_along_axis(scores, ids, -1)
+    w = routing["scale"] * w / (w.sum(-1, keepdims=True)
+                                + routing["norm_eps"])
+    y = jnp.zeros_like(x)
+    for e in range(first, first + held):
+        mine = jnp.sum(jnp.where(ids == e, w, 0.0), -1)
+        y = y + mine[:, None] * (jnp.square(jax.nn.relu(x @ wu[e])) @ wd[e])
+    return y
+
+
+def _ungated_share(x, wr, wu, wd, first, held, top_k, routing):
+    sl = slice(first, first + held)
+    return ep.dropless_moe(x, wr, None, wu[sl], wd[sl], top_k=top_k,
+                           first_expert=first, activation=ep.relu_squared,
+                           dtype=jnp.float32, **routing)
+
+
+@pytest.mark.parametrize("load", ["second_tile", "empty",
+                                  "ends_inside_the_fourth_overflow_tile"])
+def test_ungated_experts_equal_a_dense_loop(load):
+    """Values and the gradients of x, the router and both weights against
+    the dense loop, under loads that the router makes uneven: three tiles,
+    none, and a load that ends inside the fourth overflow tile. float32
+    operands, so the tolerance is float32's, as above."""
+    (s_tokens, e_total, first, held, first_rows, over_rows, tiles_computed,
+     pairs, chosen) = LOADS[load]
+    x, wr, _, wu, wd = (
+        _layer_args(s_tokens, e_total, 14, all_choose=K, logit=4.0)
+        if chosen is None else
+        _layer_args_of_load(s_tokens, e_total, 14, *chosen, logit=4.0))
+    routing = _routing("sigmoid_bias", e_total)
+    _, ids = ep.route_top_k(x, wr, top_k=K, **routing)
+    got_pairs, _ = ep.held_load(ids, first_expert=first, experts_held=held)
+    assert int(got_pairs) == pairs
+    assert int(ep._tiles_needed(got_pairs, first_rows, over_rows)) == (
+        tiles_computed)
+    both = []
+    for layer in (_ungated_share, _ungated_loop):
+        fn = lambda *a: layer(*a, first, held, K, routing)
+        loss = lambda *a: jnp.sum(fn(*a) ** 2)
+        both.append((jax.jit(fn)(x, wr, wu, wd), *jax.jit(
+            jax.grad(loss, argnums=range(4)))(x, wr, wu, wd)))
+    for name, a, b in zip("y x router up down".split(), *both):
+        np.testing.assert_allclose(
+            a, b, atol=2e-5 * max(float(jnp.max(jnp.abs(b))), 1.0),
+            err_msg=name)
+    assert (float(jnp.max(jnp.abs(both[0][0]))) > 0) == bool(pairs)
+    notes = _plan_notes(lambda: _ungated_share(x, wr, wu, wd, first, held, K,
+                                               routing))
+    assert notes["moe_gated"] is False
+    assert _plan_notes(lambda: _share(*_weights(5), 0, 4))["moe_gated"] is True
+
+
+def _parent_dropless_moe():
+    """``dropless_moe`` as the commit before the ungated form wrote it (PR 46,
+    31dec6b): three grouped products a tile and three weights through the
+    custom VJP, over the helpers that commit left unchanged. The names are
+    the program's, so that nothing in a lowered module tells the two apart
+    but what they compute."""
+    import functools
+
+    from horovod_tpu import trace as _trace
+    from horovod_tpu.ops import moe_combine as _combine
+
+    def _tile_products(xs, w_gate, w_up, w_down, sizes):
+        with jax.named_scope(_trace.SCOPE_MOE_EXPERTS):
+            grouped = lambda a, w: jax.lax.ragged_dot(
+                a, w, sizes, preferred_element_type=jnp.float32)
+            h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+            return grouped(h.astype(w_down.dtype), w_down)
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10))
+    def _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends,
+                 plan, slots):
+        def tile_sum(lo, rows):
+            order_t, sizes_t = ep._tile(order, lo, rows, starts, ends)
+            with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+                xs = x[order_t // scale.shape[1]].astype(w_gate.dtype)
+            ys = _tile_products(xs, w_gate, w_up, w_down, sizes_t)
+            with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+                slot_pos, slot_w = ep._token_slots(
+                    pos, lo, rows, ends[-1], slots, scale)
+                return _combine.gather_sum(ys, slot_pos, slot_w)
+
+        return ep._walk_tiles(tile_sum, plan, ends[-1])
+
+    def _experts_fwd(x, scale, w_gate, w_up, w_down, order, pos, starts, ends,
+                     plan, slots):
+        y = _experts(x, scale, w_gate, w_up, w_down, order, pos, starts, ends,
+                     plan, slots)
+        return y, (x, scale, w_gate, w_up, w_down, order, pos, starts, ends)
+
+    def _experts_bwd(plan, slots, res, dy):
+        x, scale, w_gate, w_up, w_down, order, pos, starts, ends = res
+        top_k = scale.shape[1]
+        f32 = lambda tree: jax.tree.map(lambda g: g.astype(jnp.float32), tree)
+
+        def tile_grads(lo, rows):
+            order_t, sizes_t = ep._tile(order, lo, rows, starts, ends)
+            with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+                token = order_t // top_k
+                xs = x[token].astype(w_gate.dtype)
+            ys, vjp = jax.vjp(
+                lambda *a: _tile_products(*a, sizes_t),
+                xs, w_gate, w_up, w_down)
+            with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+                valid = jnp.arange(rows) < jnp.sum(sizes_t)
+                weight = jnp.where(valid, scale.reshape(-1)[order_t], 0.0)
+                dy_rows = dy[token]
+                dweight = jnp.where(valid, jnp.sum(ys * dy_rows, axis=-1), 0.0)
+                dys = dy_rows * weight[:, None]
+            dxs, *dw = vjp(dys)
+            with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+                slot_pos, slot_one = ep._token_slots(pos, lo, rows, ends[-1],
+                                                     slots)
+                dx = _combine.gather_sum(dxs.astype(jnp.float32), slot_pos,
+                                         slot_one)
+                dscale = jnp.zeros(scale.size, jnp.float32).at[order_t].add(
+                    dweight).reshape(scale.shape)
+            return (dx, dscale, *f32(dw))
+
+        grads = ep._walk_tiles(tile_grads, plan, ends[-1])
+        primals = (x, scale, w_gate, w_up, w_down)
+        return tuple(g.astype(p.dtype) for g, p in zip(grads, primals)) + (
+            None, None, None, None)
+
+    _experts.defvjp(_experts_fwd, _experts_bwd)
+
+    def dropless_moe(x, w_router, w_gate, w_up, w_down, *, top_k,
+                     first_expert=0, norm_topk=True, score="softmax",
+                     select_bias=None, norm_eps=0.0, scale=1.0,
+                     dtype=jnp.bfloat16):
+        s_tokens, d_model = x.shape
+        e_total = w_router.shape[-1]
+        e_held = w_gate.shape[0]
+        plan = first, over, n_over = ep._tile_plan(s_tokens, top_k, e_held,
+                                                   e_total)
+        rows_in_all = first + n_over * over
+        slots = min(top_k, e_held)
+        with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
+            weights, ids = ep.route_top_k(
+                x, w_router, top_k=top_k, norm_topk=norm_topk, score=score,
+                select_bias=select_bias, norm_eps=norm_eps, scale=scale)
+            key, sizes = ep._held_groups(ids, first_expert, e_held)
+            order = jnp.argsort(key, stable=True).astype(jnp.int32)
+            pos = jnp.argsort(order).astype(jnp.int32)
+            order = jnp.pad(order[:rows_in_all],
+                            (0, max(0, rows_in_all - order.size)))
+            ends = jnp.cumsum(sizes)
+        with jax.named_scope(_trace.SCOPE_MOE_EXPERTS):
+            w_gate, w_up, w_down = (w.astype(dtype)
+                                    for w in (w_gate, w_up, w_down))
+        return _experts(x, weights, w_gate, w_up, w_down, order,
+                        pos.reshape(ids.shape), ends - sizes, ends, plan,
+                        slots)
+
+    return dropless_moe
+
+
+# The expert layer at the four expert cells' shapes: tokens a step, width,
+# experts, held, top k, expert width, and the routing their models pass.
+_SIGMOID = dict(score="sigmoid", bias=True, norm_eps=1e-6, scale=1.0)
+CELL_SHAPES = {
+    "qwen3next-train-1chip": (8192, 2048, 512, 32, 10, 512, {}),
+    "lfm2moe-train-1chip": (32768, 2048, 32, 8, 4, 1792, _SIGMOID),
+    "xing4-train-1chip": (8192, 3584, 64, 8, 4, 1024,
+                          {**_SIGMOID, "norm_eps": 1e-20, "scale": 2.0}),
+    "keyevl-train-1chip": (16384, 2048, 128, 16, 8, 768, {}),
+}
+_KERNEL_BODY = __import__("re").compile(r'backend_config = "[^"]*"')
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHAPES))
+def test_with_a_gate_the_lowered_program_is_the_parents(cell, monkeypatch):
+    """At an expert cell's shapes the layer and its gradients lower, for the
+    chip, to the module the parent's three-product layer lowers to. The
+    gather-sum kernel's serialized body is left out of the comparison: it
+    embeds the source lines of its callers, which moved."""
+    from horovod_tpu.ops import pallas_attention as pa
+
+    monkeypatch.setattr(pa, "_resolve_interpret", lambda interpret: False)
+    tokens, d, e_total, held, top_k, f, routing = CELL_SHAPES[cell]
+    routing = dict(routing)
+    biased = routing.pop("bias", False)
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    def lowered(layer):
+        def loss(x, wr, wg, wu, wd, bias):
+            more = {"select_bias": bias} if biased else {}
+            return layer(x, wr, wg, wu, wd, top_k=top_k, **routing,
+                         **more).sum()
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).trace(
+            jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16), f32(d, e_total),
+            f32(held, d, f), f32(held, d, f), f32(held, f, d),
+            f32(e_total)).lower(lowering_platforms=("tpu",)).as_text()
+        assert "tpu_custom_call" in text and "ragged_dot" in text
+        return _KERNEL_BODY.sub("", text)
+
+    assert lowered(ep.dropless_moe) == lowered(_parent_dropless_moe())
+
+
+@modes
+def test_with_a_gate_the_gradients_are_the_parents_to_the_bit(mode):
+    args = _weights(21)
+    sl = slice(4, 12)
+    routing = MODES[mode]
+
+    def grads(layer):
+        loss = lambda x, wr, wg, wu, wd: jnp.sum(layer(
+            x, wr, wg[sl], wu[sl], wd[sl], top_k=K, first_expert=4,
+            **routing) ** 2)
+        return jax.jit(jax.value_and_grad(loss, argnums=range(5)))(*args)
+
+    (l, g), (l0, g0) = grads(ep.dropless_moe), grads(_parent_dropless_moe())
+    assert float(l) == float(l0) and float(l) > 0
+    for name, a, b in zip(NAMES[1:], g, g0):
+        np.testing.assert_array_equal(a, b, err_msg=name)

@@ -44,6 +44,18 @@ def test_plan_at_head_width_64_and_8192_tokens_is_the_gpt2_plan():
         (16 * 17 / 2) / 256)
 
 
+def test_plan_at_head_width_128_and_8192_tokens_over_64_rows():
+    # the Nemotron-H cell: [2 sequences x 32 heads, 8192, 128] bf16 in and
+    # out: the GPT-2 tiles, four rows a step, 4096 grid steps; one pass of
+    # the backward with two rows' whole dq (test below)
+    bq, bk, rows = pa._plan(64, 8192, 8192, 128, 2, 2, None, None)
+    assert (bq, bk, rows) == (512, 512, 4)
+    assert pa._step_vmem_bytes(rows, bq, bk, 128, 2, 2) <= pa._VMEM_BUDGET
+    assert pa._step_vmem_bytes(8, bq, bk, 128, 2, 2) > pa._VMEM_BUDGET
+    assert pa._plan_bwd(64, 8192, 8192, 128, 2, None, None) == (
+        512, 512, 2, True, 28442624)
+
+
 @pytest.mark.parametrize("d,in_size,expect", [
     (64, 4, (512, 512, 2)),      # f32 operands at the old width
     (128, 2, (512, 512, 4)),
@@ -94,6 +106,9 @@ def test_backward_plan_at_t_1024_is_the_parents(bh, t, d, expect):
     (32, 8192, 192, 128, None, 1, 26.06),
     # the Keye-VL cell: heads of ONE batch row, the selection's tile counted
     (32, 16384, 128, None, 32, 2, 44.62),
+    # the Nemotron-H cell: 2 sequences x 32 heads of 128 (2 key/value heads
+    # repeated 16 times): a row's dq is 4 MB and 2 x 2 MB of output block
+    (64, 8192, 128, None, None, 2, 27.12),
     # d 64 at T 4096, and what the selection's shape plans without one
     (16, 4096, 64, None, None, 2, 19.12),
     (32, 16384, 128, None, None, 2, 43.12),

@@ -338,14 +338,11 @@ def test_composed_step_compiles_on_2x2(topo, compile_kernel):
     assert "all-reduce" in compiled.as_text()
 
 
-def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
-    """The Xing4.0 cell's whole step (``hvd.make_train_step`` over
-    ``Xing4LM`` at the configuration's sizes: 759.3 M parameters, one
-    8192-token sequence) for the described chip: the flash kernels at 192 /
-    128 are in it, one forward and one backward a layer (the layer's
-    recomputation keeps the forward's named result; a head's whole dq is
-    held in VMEM), and parameters, AdamW's moments, gradients and
-    scratch come to no more than 16.0 GB by the compiler's own count."""
+def _compile_cell_step(topo, name, shape):
+    """A benchmark cell's whole step (``hvd.make_train_step`` over the
+    family's model at the configuration's sizes and the traffic's ``(seq_len,
+    per_chip_batch)``, which has to be ``shape``) compiled for the described
+    chip from shapes alone; the build ledger holds that compile's notes."""
     import os
     import sys
 
@@ -356,9 +353,10 @@ def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
         sys.path.insert(0, root)
     from benchmark import manifest, weights
 
-    cell = manifest.Cell(manifest.load_manifest(), "xing4-train-1chip")
+    cell = manifest.Cell(manifest.load_manifest(), name)
     cfg, traffic = cell.config, cell.traffic
-    assert (traffic["seq_len"], traffic["per_chip_batch"]) == (8192, 1)
+    seq, batch = shape
+    assert (traffic["seq_len"], traffic["per_chip_batch"]) == shape
     mesh = hvdj.build_mesh({"data": 1}, devices=topo.devices[:1])
     rep, dat = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
     params = jax.tree.map(
@@ -368,9 +366,23 @@ def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
     state = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
         jax.eval_shape(tx.init, params))
-    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=dat)
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=dat)
     hvd_trace.reset_build_ledger()
-    compiled = _compile(step, params, state, (tokens, tokens))
+    return cell, _compile(step, params, state, (tokens, tokens))
+
+
+def test_xing4_step_compiles_under_16_gb(topo, compile_kernel):
+    """The Xing4.0 cell's whole step (``hvd.make_train_step`` over
+    ``Xing4LM`` at the configuration's sizes: 759.3 M parameters, one
+    8192-token sequence) for the described chip: the flash kernels at 192 /
+    128 are in it, one forward and one backward a layer (the layer's
+    recomputation keeps the forward's named result; a head's whole dq is
+    held in VMEM), and parameters, AdamW's moments, gradients and
+    scratch come to no more than 16.0 GB by the compiler's own count."""
+    from horovod_tpu import trace as hvd_trace
+
+    cell, compiled = _compile_cell_step(topo, "xing4-train-1chip", (8192, 1))
+    cfg = cell.config
     layers = cfg["num_hidden_layers"]
     text = compiled.as_text()
     assert text.count("custom_call_target=\"tpu_custom_call\"") >= 4 * layers
@@ -445,3 +457,35 @@ def test_sparse_selection_kernels_compile(topo, compile_kernel):
     # no float32 [T, T] stands in HBM; the selection is int8
     flat = text.replace(" ", "")
     assert "f32[1,16384,16384]" not in flat and "s8[1,16384,16384]" in flat
+
+
+def test_nemotron_h_step_compiles_under_15_gb(topo, compile_kernel):
+    """The Nemotron-H cell's whole step (``hvd.make_train_step`` over
+    ``NemotronHLM`` at the configuration's sizes: 667.0 M parameters, two
+    8192-token sequences) for the described chip: the flash kernels at
+    width 128 over 64 rows are in it, one forward and one backward for the
+    one attention layer; the four chunked scans are XLA (no kernel, so no
+    fallback); and parameters, AdamW's moments, gradients and scratch come to
+    no more than the 15.0 GB that let the cell take two sequences (14.8 by
+    the compiler's own count; 15.6 without the scan's own checkpoint)."""
+    from horovod_tpu import trace as hvd_trace
+
+    _, compiled = _compile_cell_step(topo, "nemotronh-train-1chip", (8192, 2))
+    calls = [l for l in compiled.as_text().splitlines()
+             if "custom-call(" in l and "tpu_custom_call" in l]
+    assert sum("flash_bwd" in l for l in calls) == 1
+    # the four expert layers' per-token sums are the gather-sum kernel
+    assert sum("moe_combine" in l for l in calls) >= 3 * 4
+    assert hvd_trace.build_ledger()["fallbacks"] == []
+    notes = hvd_trace.plan_args()
+    assert (notes["ssm_heads"], notes["ssm_head_dim"], notes["ssm_state"],
+            notes["ssm_groups"], notes["ssm_chunk"], notes["ssm_chunks"],
+            notes["ssm_kernel"]) == (64, 64, 128, 8, 128, 64, False)
+    assert notes["moe_gated"] is False and notes["moe_tile_rows"] == 7680
+    assert (notes["flash_rows_per_step"], notes["flash_grid_steps"],
+            notes["flash_bwd_rows_per_step"], notes["flash_bwd_one_pass"]) == (
+        4, 4096, 2, True)
+    m = compiled.memory_analysis()
+    held = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 14.0e9 < held <= 15.0e9, held
