@@ -41,7 +41,7 @@ import optax
 from flax import linen as nn
 
 from .. import trace as _trace
-from ..ops.ssd import DEFAULT_CHUNK, ssd_chunked
+from ..ops.ssd import DEFAULT_CHUNK, ssd_chunked_packed
 from ..parallel.ep import relu_squared
 from .lfm2_moe import GroupedQueryAttention, SparseMoe, _norm
 from .qwen3_next import _dense, _normal, causal_depthwise_conv, expert_load
@@ -100,12 +100,10 @@ class Mamba2Mixer(nn.Module):
                     causal_depthwise_conv(xbc.astype(f32), conv["kernel"])
                     + conv["bias"]).astype(self.dtype)
             with jax.named_scope(_trace.SCOPE_SSM_SCAN):
-                y, _ = ssd_chunked(
-                    xbc[..., :inner].reshape(b, T, H, P),
-                    jax.nn.softplus(dt + dt_bias), -jnp.exp(a_log),
-                    xbc[..., inner:inner + bc].reshape(b, T, G, N),
-                    xbc[..., inner + bc:].reshape(b, T, G, N), skip,
-                    chunk=self.chunk, dtype=self.dtype)
+                # x, B and C where the convolution left them, side by side
+                y, _ = ssd_chunked_packed(
+                    xbc, jax.nn.softplus(dt + dt_bias), -jnp.exp(a_log), skip,
+                    groups=G, state=N, chunk=self.chunk, dtype=self.dtype)
             # the gate first, then the norm over each group's channels
             y = (y.reshape(b, T, inner) * jax.nn.silu(z.astype(f32))
                  ).reshape(b, T, G, inner // G)

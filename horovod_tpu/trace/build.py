@@ -30,7 +30,7 @@ CAPACITY = 4096
 # fallback record says which of them a call site did without.
 KERNELS = ("attention", "flash_bwd", "gdn_fwd", "gdn_bwd", "moe_combine",
            "hc_mix_pre", "hc_mix_post", "hc_mix_post_bwd", "hc_mix_pre_bwd",
-           "sparse_index_select", "sparse_index_kl")
+           "sparse_index_select", "sparse_index_kl", "ssd_fwd", "ssd_bwd")
 
 _TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"

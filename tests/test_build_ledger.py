@@ -224,7 +224,7 @@ def test_a_fallback_names_a_kernel_of_the_device_trace():
     assert hvd_trace.KERNELS == (
         "attention", "flash_bwd", "gdn_fwd", "gdn_bwd", "moe_combine",
         "hc_mix_pre", "hc_mix_post", "hc_mix_post_bwd", "hc_mix_pre_bwd",
-        "sparse_index_select", "sparse_index_kl")
+        "sparse_index_select", "sparse_index_kl", "ssd_fwd", "ssd_bwd")
     with pytest.raises(ValueError, match="no kernel"):
         hvd_trace.note_fallback("softmax", "whatever")
     assert hvd_trace.build_ledger()["fallbacks"] == []

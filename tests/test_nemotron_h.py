@@ -289,14 +289,19 @@ def test_scopes_and_plan_notes():
     assert "ssm_mixer/ssm_conv" in text and "ssm_mixer/ssm_scan" in text
     assert {k: v for k, v in notes.items() if k.startswith("ssm_")} == {
         "ssm_heads": 4, "ssm_head_dim": 8, "ssm_state": 16, "ssm_groups": 2,
-        "ssm_chunk": 16, "ssm_chunks": 4, "ssm_kernel": False}
+        "ssm_chunk": 16, "ssm_chunks": 4, "ssm_kernel": False,
+        "ssm_grid_steps": 0, "ssm_vmem_mb": 0.0}
     assert notes["moe_gated"] is False and notes["moe_score"] == "sigmoid"
     assert notes["moe_select_bias"] is True
     assert notes["moe_experts_total"] == 8 and notes["moe_experts_held"] == 4
-    # the scan has no kernel to fall back from; at width 64 the gather-sum
-    # takes its XLA form, as in the other models' small tests
-    assert {f["op"] for f in trace.build_ledger()["fallbacks"]} == {
-        "moe_combine"}
+    # heads of 8 over a state of 16 fill no lane: the scan takes its XLA
+    # form, one record a Mamba-2 layer; at width 64 the gather-sum takes its
+    # own, as in the other models' small tests
+    fallbacks = trace.build_ledger()["fallbacks"]
+    assert {f["op"] for f in fallbacks} == {"moe_combine", "ssd_fwd"}
+    scans = [f for f in fallbacks if f["op"] == "ssd_fwd"]
+    assert len(scans) == model.cfg.pattern.count("M")
+    assert {f["reason"] for f in scans} == {"heads_not_whole_lanes"}
     load = np.asarray(nm.expert_load(model, params, tokens))
     assert load.shape == (2, 3)               # the two expert layers
     assert trace.plan_args()["moe_pairs_held"] == list(load[:, 0])
