@@ -8,6 +8,8 @@ exercises every collective's numerics over a real 8-way mesh in one process.
 """
 
 import os
+import shutil
+import tempfile
 
 # Must happen before the first JAX backend initialization.
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -22,6 +24,30 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
+
+# One compile cache a run, empty at its start and removed at its end, which
+# the run's xdist workers share: the same small programs are compiled over
+# and over, by one test after another and by six workers side by side (an
+# interpreted kernel called eagerly is traced anew and compiled again each
+# call), and the suite's time is CPU seconds of compiling (ROADMAP D16).
+# The workers are the controller's children, so its pid names the directory.
+# Where JAX_COMPILATION_CACHE_DIR is set, that is the cache and stays.
+_RUN_CACHE = None
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _worker = "PYTEST_XDIST_WORKER" in os.environ
+    _RUN_CACHE = os.path.join(
+        tempfile.gettempdir(), "horovod_tpu_tests_jax_cache_"
+        f"{os.getppid() if _worker else os.getpid()}")
+    if not _worker:
+        shutil.rmtree(_RUN_CACHE, ignore_errors=True)
+    jax.config.update("jax_compilation_cache_dir", _RUN_CACHE)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+
+def pytest_unconfigure(config):
+    if _RUN_CACHE and not hasattr(config, "workerinput"):
+        shutil.rmtree(_RUN_CACHE, ignore_errors=True)
 
 
 @pytest.fixture(scope="session")

@@ -236,3 +236,37 @@ def test_tensorflow_keras_alias_module():
     assert htk.callbacks is hk.callbacks
     assert htk.load_model is hk.load_model
     assert htk.elastic.KerasState is hk.elastic.KerasState
+
+
+def test_the_run_keeps_one_compile_cache():
+    """``tests/conftest.py``: one compile cache a run, named by the pid of
+    the process that started it so that xdist's workers, its children, share
+    it, and holding every program whatever it cost to compile; none is made
+    where ``JAX_COMPILATION_CACHE_DIR`` names one. A program compiled a
+    second time is read back from it."""
+    import conftest
+    import jax
+
+    from horovod_tpu import trace
+
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        assert conftest._RUN_CACHE is None
+        return
+    worker = "PYTEST_XDIST_WORKER" in os.environ
+    assert conftest._RUN_CACHE.endswith(
+        f"_{os.getppid() if worker else os.getpid()}")
+    assert jax.config.jax_compilation_cache_dir == conftest._RUN_CACHE
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+
+    def run_cache_probe(x):
+        return jnp.cos(x) * 3
+
+    trace.install_build_listeners()       # what importing the program does
+    jax.jit(run_cache_probe)(jnp.ones(13))
+    assert any("run_cache_probe" in entry
+               for entry in os.listdir(conftest._RUN_CACHE))
+    jax.clear_caches()                    # what the next worker starts with
+    hits = trace.build_ledger()["cache"]["cache_hits"]
+    jax.jit(run_cache_probe)(jnp.ones(13))
+    assert trace.build_ledger()["cache"]["cache_hits"] > hits

@@ -20,8 +20,10 @@ state of whole lanes), interpreted on the CPU. The second half holds the
 kernels against the rule and against the XLA form, values, final state and
 all six gradients. In float32 the kernels and the XLA form are the same
 products in the same order but for the sums inside a product: measured gaps
-1e-7 of a value and 8e-6 of a gradient's largest element against the XLA
-form (limits 2e-6 and 1e-4), and the rule's own bands against the rule."""
+2.3e-7 of a value and 2.4e-5 of a gradient's largest element against the XLA
+form (limits 2e-6 and 1e-4), and the rule's own bands against the rule.
+Every form's values and gradients are computed as one compiled program each
+(``_values``, ``_grads``), which is how a model calls them."""
 
 import functools
 
@@ -54,6 +56,12 @@ def _inputs(seed=0, heads=H, groups=G, length=T):
     return _wide(b, length, heads, P, groups, N, seed)
 
 
+def _values(fn, args, **kw):
+    # one compiled program, as a model calls it (operation by operation each
+    # of the form's products, scans and kernels is compiled apart: 2 s a call)
+    return jax.jit(lambda *a: fn(*a, **kw))(*args)
+
+
 def _grads(fn, args, **kw):
     # a nonlinear readout of y and of the final state, so that every
     # cotangent differs; one compiled program (operation by operation the
@@ -82,7 +90,7 @@ def test_chunked_equals_recurrent_in_float32(chunk, heads, groups):
     is padded with tokens of dt = 0) and that hold all of it (128); groups
     shared by 2, 1 and 6 heads."""
     args, (y0, S0), grads0 = _recurrent(0, heads, groups)
-    y1, S1 = ssd_chunked(*args, chunk=chunk, dtype=jnp.float32)
+    y1, S1 = _values(ssd_chunked, args, chunk=chunk, dtype=jnp.float32)
     assert y1.shape == (b, T, heads, P) and y1.dtype == jnp.float32
     assert S1.shape == (b, heads, P, N)
     top = float(jnp.max(jnp.abs(y0)))
@@ -100,7 +108,7 @@ def test_chunked_equals_recurrent_in_float32(chunk, heads, groups):
 
 def test_bfloat16_products_stay_inside_their_band():
     args, (y0, _), grads0 = _recurrent(3)
-    y1, _ = ssd_chunked(*args, chunk=16)
+    y1, _ = _values(ssd_chunked, args, chunk=16)
     assert float(jnp.max(jnp.abs(y1 - y0))) <= 0.02 * float(
         jnp.max(jnp.abs(y0)))
     for g0, g1 in zip(grads0, _grads(ssd_chunked, args, chunk=16)):
@@ -116,7 +124,8 @@ def test_a_head_that_forgets_within_a_chunk_is_exact():
     A = jnp.full_like(A, -40.0)
     dt = jnp.ones_like(dt)
     y0, _ = jax.jit(ssd_recurrent)(x, dt, A, B, C, D)
-    y1, _ = ssd_chunked(x, dt, A, B, C, D, chunk=16, dtype=jnp.float32)
+    y1, _ = _values(ssd_chunked, (x, dt, A, B, C, D), chunk=16,
+                    dtype=jnp.float32)
     assert bool(jnp.all(jnp.isfinite(y1)))
     np.testing.assert_allclose(y1, y0, atol=2e-5 * float(jnp.max(jnp.abs(y0))))
     g = _grads(ssd_chunked, (x, dt, A, B, C, D), chunk=16, dtype=jnp.float32)
@@ -128,10 +137,10 @@ def test_groups_are_read_and_not_repeated():
     repeated to the heads by hand the result is the same to the bit, and the
     lowered chunked form holds no ``[.., H, N]`` copy of B or C."""
     x, dt, A, B, C, D = _inputs(seed=7)
-    shared = ssd_chunked(x, dt, A, B, C, D, chunk=16, dtype=jnp.float32)[0]
+    kw = dict(chunk=16, dtype=jnp.float32)
+    shared = _values(ssd_chunked, (x, dt, A, B, C, D), **kw)[0]
     rep = lambda m: jnp.repeat(m, H // G, axis=2)
-    own = ssd_chunked(x, dt, A, rep(B), rep(C), D, chunk=16,
-                      dtype=jnp.float32)[0]
+    own = _values(ssd_chunked, (x, dt, A, rep(B), rep(C), D), **kw)[0]
     np.testing.assert_allclose(shared, own, atol=1e-5)
     text = jax.jit(lambda *a: ssd_chunked(*a, chunk=16)[0]).lower(
         x, dt, A, B, C, D).as_text()
@@ -160,11 +169,12 @@ def test_plan_notes():
 # --------------------------------------------------------------------------
 # The chunked form as Pallas kernels (interpreted here).
 
-def _xla_form(monkeypatch, *args, **kw):
-    """``ssd_chunked`` with the plan refusing every shape."""
+def _xla_form(monkeypatch, via, args, **kw):
+    """``via`` (``_values`` or ``_grads``) of ``ssd_chunked`` with the plan
+    refusing every shape."""
     with monkeypatch.context() as m:
         m.setattr(ssd, "_plan", lambda *a: None)
-        return ssd_chunked(*args, **kw)
+        return via(ssd_chunked, args, **kw)
 
 
 def _uses_kernel(*args, **kw):
@@ -185,22 +195,14 @@ KERNEL_CASES = {
 }
 
 
-@functools.lru_cache(maxsize=None)
-def _rule(case, seed):
-    """``(args, (y, S), gradients)`` of the rule token by token at a
-    kernel case's shapes, once a process."""
-    args = _wide(*KERNEL_CASES[case][:6], seed=seed)
-    return args, jax.jit(ssd_recurrent)(*args), _grads(ssd_recurrent, args)
-
-
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
 def test_kernel_equals_recurrent_and_xla_form(case, monkeypatch):
-    chunk = KERNEL_CASES[case][6]
-    args, rule, _ = _rule(case, 11)
-    kw = dict(chunk=chunk, dtype=jnp.float32)
+    args = _wide(*KERNEL_CASES[case][:6], seed=11)
+    kw = dict(chunk=KERNEL_CASES[case][6], dtype=jnp.float32)
     assert _uses_kernel(*args, **kw)
-    got = ssd_chunked(*args, **kw)
-    xla = _xla_form(monkeypatch, *args, **kw)
+    rule = _values(ssd_recurrent, args)
+    got = _values(ssd_chunked, args, **kw)
+    xla = _xla_form(monkeypatch, _values, args, **kw)
     for name, a, r, x in zip(("y", "state"), got, rule, xla):
         assert a.shape == r.shape and a.dtype == jnp.float32, name
         top = float(jnp.max(jnp.abs(r)))
@@ -210,13 +212,11 @@ def test_kernel_equals_recurrent_and_xla_form(case, monkeypatch):
 
 @pytest.mark.parametrize("case", sorted(KERNEL_CASES))
 def test_kernel_gradients_equal_recurrent_and_xla_form(case, monkeypatch):
-    chunk = KERNEL_CASES[case][6]
-    args, _, rule = _rule(case, 12)
-    kw = dict(chunk=chunk, dtype=jnp.float32)
+    args = _wide(*KERNEL_CASES[case][:6], seed=12)
+    kw = dict(chunk=KERNEL_CASES[case][6], dtype=jnp.float32)
+    rule = _grads(ssd_recurrent, args)
     got = _grads(ssd_chunked, args, **kw)
-    with monkeypatch.context() as m:
-        m.setattr(ssd, "_plan", lambda *a: None)
-        xla = _grads(ssd_chunked, args, **kw)
+    xla = _xla_form(monkeypatch, _grads, args, **kw)
     for name, a, r, x in zip(("x", "dt", "A", "B", "C", "D"), got, rule, xla):
         assert a.shape == r.shape, name
         top = float(jnp.max(jnp.abs(r)))
@@ -241,12 +241,12 @@ def test_kernel_in_bfloat16_rounds_where_the_xla_form_does(case, monkeypatch):
     args = _wide(*shape[:6], seed=13, dtype=jnp.bfloat16)
     kw = dict(chunk=shape[6])
     assert _uses_kernel(*args, **kw)
-    rule_y, rule_s = jax.jit(ssd_recurrent)(*args)
+    rule_y, rule_s = _values(ssd_recurrent, args)
     rule_g = _grads(ssd_recurrent, args)
-    got, got_g = ssd_chunked(*args, **kw), _grads(ssd_chunked, args, **kw)
-    with monkeypatch.context() as m:
-        m.setattr(ssd, "_plan", lambda *a: None)
-        xla, xla_g = ssd_chunked(*args, **kw), _grads(ssd_chunked, args, **kw)
+    got, got_g = _values(ssd_chunked, args, **kw), _grads(ssd_chunked, args,
+                                                          **kw)
+    xla = _xla_form(monkeypatch, _values, args, **kw)
+    xla_g = _xla_form(monkeypatch, _grads, args, **kw)
     f32 = lambda a: a.astype(jnp.float32)
     top = lambda a: float(jnp.max(jnp.abs(f32(a))))
     norm = lambda a: float(jnp.linalg.norm(f32(a)))
@@ -272,7 +272,7 @@ def test_a_head_that_forgets_within_a_chunk_is_exact_through_the_kernel():
     kw = dict(chunk=16, dtype=jnp.float32)
     assert _uses_kernel(x, dt, A, B, C, D, **kw)
     y0, _ = jax.jit(ssd_recurrent)(x, dt, A, B, C, D)
-    y1, _ = ssd_chunked(x, dt, A, B, C, D, **kw)
+    y1, _ = _values(ssd_chunked, (x, dt, A, B, C, D), **kw)
     assert bool(jnp.all(jnp.isfinite(y1)))
     np.testing.assert_allclose(y1, y0, atol=2e-5 * float(jnp.max(jnp.abs(y0))))
     g = _grads(ssd_chunked, (x, dt, A, B, C, D), **kw)
@@ -341,7 +341,7 @@ def test_refused_shapes_fall_back_with_their_record(why):
                                "chunk": min(chunk, T)}
     assert trace.plan_args()["ssm_kernel"] is False
     if not heavy:
-        y, S = ssd_chunked(*args, chunk=chunk, dtype=jnp.float32)
+        y, S = _values(ssd_chunked, args, chunk=chunk, dtype=jnp.float32)
         y0, S0 = jax.jit(ssd_recurrent)(*args)
         assert float(jnp.max(jnp.abs(y - y0))) <= 2e-5 * float(
             jnp.max(jnp.abs(y0)))
