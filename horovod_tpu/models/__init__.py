@@ -1,9 +1,9 @@
 """Model zoo: the reference's headline benchmark families re-implemented
-as idiomatic flax modules (bfloat16 compute, fp32 state, NHWC), and six
+as idiomatic flax modules (bfloat16 compute, fp32 state, NHWC), and seven
 language models trained through ``hvd.make_train_step`` (``docs/models.md``):
 ``transformer.TransformerLM``, ``qwen3_next.Qwen3NextLM``,
-``lfm2_moe.Lfm2MoeLM``, ``xing4.Xing4LM``, ``keye_vl.KeyeVLLM`` and
-``nemotron_h.NemotronHLM``."""
+``lfm2_moe.Lfm2MoeLM``, ``xing4.Xing4LM``, ``keye_vl.KeyeVLLM``,
+``nemotron_h.NemotronHLM`` and ``ling.LingLM``."""
 
 from __future__ import annotations
 

@@ -145,7 +145,9 @@ class SparseMoe(nn.Module):
     expert beside it, and ``models/nemotron_h.py`` with ``gated`` off: an
     expert is then ``down(activation(up x))`` and holds no ``gate`` leaf, and
     ``shared_dim`` gives the layer a shared expert of that form and that
-    width (``shared_up_proj``, ``shared_down_proj``), added unweighted."""
+    width (``shared_up_proj``, ``shared_down_proj``), added unweighted.
+    ``models/ling.py`` limits the choice to the best ``topk_group`` of
+    ``n_group`` groups of experts (``parallel/ep.route_top_k``)."""
 
     n_experts: int
     experts_held: int
@@ -159,6 +161,8 @@ class SparseMoe(nn.Module):
     gated: bool = True
     activation: Callable = jax.nn.silu
     shared_dim: int = 0
+    n_group: int = 1
+    topk_group: int = 1
     init_std: float = 0.02
     dtype: Any = jnp.bfloat16
 
@@ -185,7 +189,8 @@ class SparseMoe(nn.Module):
         })
         routing = dict(top_k=self.top_k, norm_topk=self.norm_topk,
                        score="sigmoid", select_bias=bias,
-                       norm_eps=self.norm_eps, scale=self.routed_scale)
+                       norm_eps=self.norm_eps, scale=self.routed_scale,
+                       n_group=self.n_group, topk_group=self.topk_group)
         flat = x.reshape(B * T, C)
         if self.is_mutable_collection("intermediates"):
             _, ids = route_top_k(flat, router, **routing)

@@ -1,6 +1,6 @@
 """What a layer that is recomputed in its backward keeps.
 
-The six language models wrap their layer in :func:`remat_layer`. A layer's
+The seven language models wrap their layer in :func:`remat_layer`. A layer's
 activations are computed again in its backward, all but the residuals an op
 has NAMED because computing them again is a kernel call and keeping them is
 little: the forward flash kernel's result and logsumexp (``[B, T, H * d_v]``

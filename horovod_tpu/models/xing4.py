@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -88,9 +88,18 @@ class LatentAttention(nn.Module):
     low-rank bottleneck with a norm, keys and values from ONE normed latent a
     token, a rotary part of the query heads and one rotary key head shared
     by them all. The flash kernels take keys ``qk_nope_dim + qk_rope_dim``
-    wide and values ``v_head_dim`` wide, neither padded to the other."""
+    wide and values ``v_head_dim`` wide, neither padded to the other.
 
-    cfg: Any  # Xing4Config
+    ``models/ling.py`` runs this module too, by three fields of its
+    configuration: ``q_lora_rank`` None (queries by one product,
+    ``q_proj``, no bottleneck and no norm), ``rope_interleave`` (the rotary
+    pairs are NEIGHBOURS, element ``2 i`` with ``2 i + 1``: the rope part is
+    sorted into its even and its odd elements before the half rotation, in
+    the queries and the key alike, which leaves every score what the
+    rotation in place gives) and ``head_gate`` (``g_proj``: one sigmoid gate
+    a head on the attention's output, in front of ``o_proj``)."""
+
+    cfg: Any  # Xing4Config or models/ling.py's LingConfig
 
     @nn.compact
     def __call__(self, x, positions):
@@ -100,9 +109,13 @@ class LatentAttention(nn.Module):
         dense = lambda n, name: _dense(n, name, c.dtype, c.init_std)
         _trace.note_plan(attn_qk_width=dn + dr, attn_v_width=dv)
         with jax.named_scope(_trace.SCOPE_LATENT_ATTN):
-            c_q = _norm(c.eps, c.dtype, "q_a_layernorm")(
-                dense(c.q_lora_rank, "q_a_proj")(x))
-            q = dense(H * (dn + dr), "q_b_proj")(c_q).reshape(B, T, H, dn + dr)
+            if c.q_lora_rank is None:
+                q = dense(H * (dn + dr), "q_proj")(x)
+            else:
+                c_q = _norm(c.eps, c.dtype, "q_a_layernorm")(
+                    dense(c.q_lora_rank, "q_a_proj")(x))
+                q = dense(H * (dn + dr), "q_b_proj")(c_q)
+            q = q.reshape(B, T, H, dn + dr)
             kv_a = dense(c.kv_lora_rank + dr, "kv_a_proj")(x)
             c_kv = _norm(c.eps, c.dtype, "kv_a_layernorm")(
                 kv_a[..., :c.kv_lora_rank])
@@ -110,10 +123,15 @@ class LatentAttention(nn.Module):
                 B, T, H, dn + dv)
             rot = dict(rotary_dim=dr, theta=c.rope_theta,
                        inv_freq=jnp.asarray(c.inv_freq()))
+            # neighbours sorted into halves, or the halves as they are
+            pairs = (lambda r: jnp.concatenate(
+                [r[..., 0::2], r[..., 1::2]], -1)) if c.rope_interleave else (
+                    lambda r: r)
             # cos and sin carry mscale / mscale_all_dim
-            q_rope = rotary(q[..., dn:], positions, **rot) * c.rope_mscale()
-            k_rope = rotary(kv_a[:, :, None, c.kv_lora_rank:], positions,
+            q_rope = rotary(pairs(q[..., dn:]), positions,
                             **rot) * c.rope_mscale()
+            k_rope = rotary(pairs(kv_a[:, :, None, c.kv_lora_rank:]),
+                            positions, **rot) * c.rope_mscale()
             q = jnp.concatenate([q[..., :dn], q_rope.astype(c.dtype)], -1)
             # the one rotary key head serves every query head
             k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
@@ -123,6 +141,10 @@ class LatentAttention(nn.Module):
             with jax.named_scope("attention"):
                 a = flash_attention_bthd(q, k, kv[..., dn:], causal=True,
                                          sm_scale=c.softmax_scale())
+            if c.head_gate:
+                gate = jax.nn.sigmoid(dense(H, "g_proj")(x).astype(
+                    jnp.float32))
+                a = (a * gate[..., None]).astype(c.dtype)
             return dense(C, "o_proj")(a.reshape(B, T, H * dv))
 
 
@@ -212,7 +234,7 @@ class Xing4Config:
     n_dense_layers: int = 2
     d_model: int = 3584
     n_heads: int = 32
-    q_lora_rank: int = 768
+    q_lora_rank: Optional[int] = 768
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
@@ -224,6 +246,8 @@ class Xing4Config:
     rope_beta_slow: float = 1.0
     rope_mscale_value: float = 1.0
     rope_mscale_all_dim: float = 1.0
+    rope_interleave: bool = False
+    head_gate: bool = False
     dense_dim: int = 9216
     n_experts: int = 64
     experts_held: int = 64

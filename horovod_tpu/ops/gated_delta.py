@@ -75,8 +75,9 @@ _UNROLL = 4        # tiles the kernels' loop takes an iteration
 def gated_delta_recurrent(q, k, v, g, beta, initial_state=None):
     """The rule token by token, float32. ``q``, ``k``: ``[B, T, H, d_k]``;
     ``v``: ``[B, T, H, d_v]``; ``g`` (log decay, <= 0) and ``beta``:
-    ``[B, T, H]``. Returns ``(o [B, T, H, d_v], final state [B, H, d_k, d_v])``.
-    """
+    ``[B, T, H]``; a ``g`` of ``[B, T, H, d_k]`` decays every key channel by
+    its own number (``ops/kda.py``'s rule). Returns ``(o [B, T, H, d_v],
+    final state [B, H, d_k, d_v])``."""
     f32 = jnp.float32
     B, T, H, dk = q.shape
     dv = v.shape[-1]
@@ -85,7 +86,8 @@ def gated_delta_recurrent(q, k, v, g, beta, initial_state=None):
 
     def step(S, x):
         q_t, k_t, v_t, g_t, b_t = x
-        S = S * jnp.exp(g_t)[..., None, None]
+        decay = jnp.exp(g_t)
+        S = S * decay.reshape(decay.shape + (1,) * (S.ndim - decay.ndim))
         d_t = b_t[..., None] * (
             v_t - jnp.einsum("bhkv,bhk->bhv", S, k_t, precision=_HIGHEST))
         S = S + k_t[..., :, None] * d_t[..., None, :]

@@ -160,7 +160,8 @@ def moe_ffn(
 
 def route_top_k(x, w_router, *, top_k: int, norm_topk: bool = True,
                 score: str = "softmax", select_bias=None,
-                norm_eps: float = 0.0, scale: float = 1.0):
+                norm_eps: float = 0.0, scale: float = 1.0,
+                n_group: int = 1, topk_group: int = 1):
     """Routing over ALL experts: ``(weights [S, k] f32, expert ids [S, k]
     int32)``. The router's product and score are float32 at full precision,
     whatever ``x`` is: a token whose k-th and (k+1)-th scores are near a tie
@@ -170,19 +171,33 @@ def route_top_k(x, w_router, *, top_k: int, norm_topk: bool = True,
     ``select_bias`` (``[E_total]``) is added to the scores for the CHOICE
     only: the weights are the chosen experts' scores without it, and it
     takes no gradient. With ``norm_topk`` the weights are divided by their
-    sum plus ``norm_eps``; ``scale`` multiplies them last. The defaults are
-    softmax, no bias, no epsilon and scale 1."""
+    sum plus ``norm_eps``; ``scale`` multiplies them last. With ``n_group``
+    over 1 the choice is group-limited (DeepSeek-V3's ``noaux_tc``): the
+    experts lie in ``n_group`` groups of consecutive ids, a group's score is
+    the sum of its two best scores (bias included), and only the experts of
+    the ``topk_group`` best groups can be chosen. The defaults are softmax,
+    no bias, no epsilon, scale 1 and no groups."""
     if score not in ("softmax", "sigmoid"):
         raise ValueError(f"score is 'softmax' or 'sigmoid', not {score!r}")
+    e_total = w_router.shape[-1]
+    if n_group > 1 and (e_total % n_group or e_total // n_group < 2
+                        or not 0 < topk_group <= n_group
+                        or topk_group * (e_total // n_group) < top_k):
+        raise ValueError(
+            f"{e_total} experts in {n_group} groups of which {topk_group} "
+            f"are kept cannot give a token {top_k} experts")
     logits = jnp.matmul(x.astype(jnp.float32), w_router.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
     scores = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
               else jax.nn.sigmoid(logits))
-    if select_bias is None:
+    if select_bias is None and n_group == 1:
         weights, ids = lax.top_k(scores, top_k)
     else:
-        _, ids = lax.top_k(scores + lax.stop_gradient(
-            select_bias.astype(jnp.float32)), top_k)
+        choice = scores if select_bias is None else scores + lax.stop_gradient(
+            select_bias.astype(jnp.float32))
+        if n_group > 1:
+            choice = _keep_best_groups(choice, n_group, topk_group)
+        _, ids = lax.top_k(choice, top_k)
         weights = jnp.take_along_axis(scores, ids, axis=-1)
     if norm_topk:
         total = jnp.sum(weights, axis=-1, keepdims=True)
@@ -190,6 +205,17 @@ def route_top_k(x, w_router, *, top_k: int, norm_topk: bool = True,
     if scale != 1.0:
         weights = weights * scale
     return weights, ids.astype(jnp.int32)
+
+
+def _keep_best_groups(choice, n_group: int, topk_group: int):
+    """``choice`` (``[S, E]``) with ``-inf`` at every expert outside the
+    ``topk_group`` groups whose two best entries sum highest (on a tie the
+    group of the lower id, as ``lax.top_k`` orders)."""
+    grouped = choice.reshape(choice.shape[0], n_group, -1)
+    group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+    _, best = lax.top_k(group_score, topk_group)
+    kept = jnp.any(best[..., None] == jnp.arange(n_group), axis=-2)
+    return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(choice.shape)
 
 
 def _held_groups(ids, first_expert: int, experts_held: int):
@@ -417,6 +443,8 @@ def dropless_moe(
     select_bias=None,
     norm_eps: float = 0.0,
     scale: float = 1.0,
+    n_group: int = 1,
+    topk_group: int = 1,
     activation: Callable = jax.nn.silu,
     dtype=jnp.bfloat16,
 ) -> jax.Array:
@@ -429,7 +457,8 @@ def dropless_moe(
     E_held`` that live here. Every token is routed over all ``E_total``
     experts by :func:`route_top_k` (softmax and top-k by default, weights
     normalised over all k when ``norm_topk``; ``score``, ``select_bias``,
-    ``norm_eps`` and ``scale`` are its), and the sum ``sum_k w_k *
+    ``norm_eps``, ``scale``, ``n_group`` and ``topk_group`` are its), and
+    the sum ``sum_k w_k *
     down_e(activation(gate_e(x)) * up_e(x))`` runs over the chosen experts that
     are held here (``w_gate`` None: experts without a gate, ``down_e(
     activation(up_e(x)))``, two grouped products a tile and two weights
@@ -469,6 +498,7 @@ def dropless_moe(
         moe_top_k=top_k, moe_tile_rows=first, moe_tiles=1 + n_over,
         moe_overflow_rows=over,
         moe_score=score, moe_select_bias=select_bias is not None,
+        moe_groups=n_group, moe_groups_kept=topk_group,
         moe_gated=w_gate is not None,
         moe_combine_kernel=block is not None,
         moe_combine_block=block or 0, moe_combine_slots=slots,
@@ -476,7 +506,8 @@ def dropless_moe(
     with jax.named_scope(_trace.SCOPE_MOE_ROUTE):
         weights, ids = route_top_k(
             x, w_router, top_k=top_k, norm_topk=norm_topk, score=score,
-            select_bias=select_bias, norm_eps=norm_eps, scale=scale)
+            select_bias=select_bias, norm_eps=norm_eps, scale=scale,
+            n_group=n_group, topk_group=topk_group)
         key, sizes = _held_groups(ids, first_expert, e_held)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         pos = jnp.argsort(order).astype(jnp.int32)    # the sort's inverse

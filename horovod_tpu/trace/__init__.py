@@ -126,6 +126,13 @@ SCOPE_SPARSE_INDEX_LOSS = "sparse_index_loss"  # head-summed p, KL, backward
 SCOPE_SSM_MIXER = "ssm_mixer"
 SCOPE_SSM_CONV = "ssm_conv"        # taps, bias, silu; no projection
 SCOPE_SSM_SCAN = "ssm_scan"        # decays, chunk products, chunk pass, D x
+# The Kimi-delta-attention layers of models/ling.py (LING_SCOPES below: its
+# latent-attention layer enters latent_attn and its expert layers the moe_
+# scopes): the whole mixer, and inside it the three convolutions and the
+# per-channel delta rule (ops/kda.py), which kda_ms.train reads.
+SCOPE_KDA_MIXER = "kda_mixer"
+SCOPE_KDA_CONV = "kda_conv"        # taps and silu; no projection
+SCOPE_KDA_SCAN = "kda_scan"        # gate, sums, chunk products, chunk pass
 MODEL_SCOPES = (
     SCOPE_GDN_CONV,
     SCOPE_GDN_SCAN,
@@ -159,6 +166,15 @@ NEMOTRON_H_SCOPES = (
     SCOPE_SSM_CONV,
     SCOPE_SSM_SCAN,
     SCOPE_GQA_ATTN,
+    SCOPE_MOE_ROUTE,
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_SHARED,
+)
+LING_SCOPES = (
+    SCOPE_KDA_MIXER,
+    SCOPE_KDA_CONV,
+    SCOPE_KDA_SCAN,
+    SCOPE_LATENT_ATTN,
     SCOPE_MOE_ROUTE,
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_SHARED,
